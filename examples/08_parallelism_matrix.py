@@ -15,10 +15,7 @@ import _backend  # noqa: F401 — honors JAX_PLATFORMS=cpu + 8 virtual devices
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-try:
-    from jax import shard_map  # noqa: E402
-except ImportError:  # jax < 0.5: shard_map lives under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map  # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
 from mmlspark_tpu.core.schema import Table  # noqa: E402

@@ -7,7 +7,30 @@ JAX / XLA / Pallas / jax.sharding."""
 
 __version__ = "0.1.0"
 
-from . import core, parallel
+import os as _os
+
+
+def _place_compile_cache() -> None:
+    """Point JAX's persistent compilation cache at one fixed place before
+    anything compiles. `JAX_COMPILATION_CACHE_DIR` wins: when it is set
+    JAX reads it itself and nothing is set here. Otherwise the cache lives
+    in `.jax_cache` next to the package — the path is part of every cache
+    key's usefulness (a directory that moves never hits), so no temp name,
+    pid or timestamp. Spawned workers import this package and so follow
+    the same rule."""
+    if _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
+
+
+_place_compile_cache()
+
+from . import core, parallel  # noqa: E402
 
 
 def __getattr__(name):

@@ -483,7 +483,12 @@ class SweepScheduler:
     ledger writes); workers own only fits. Worker death at ANY point is
     survivable: the claim map is rebuilt from fleet membership, lost
     trials re-queue, and sub-checkpoints make the re-run resume
-    mid-fit byte-identically."""
+    mid-fit byte-identically.
+
+    Workers fit estimators through JAX, so they are device workers: on a
+    TPU host each owns one chip and the driver must not have touched JAX
+    (parallel/chips.py; `ServingFleet.start()` raises otherwise). Pass
+    `fleet_kw={"device_workers": False}` to fit the trials on the CPU."""
 
     def __init__(self, models, *, trials: "list | None" = None,
                  param_space=None, evaluation_metric: str = "accuracy",

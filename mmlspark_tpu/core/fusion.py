@@ -222,15 +222,12 @@ class FusionPlan:
 
 def kernel_of(stage: Any) -> tuple["DeviceKernel | None", str]:
     """(kernel, reason): a stage's declared device kernel, or why it has
-    none.  Never raises — a broken declaration just keeps the stage on the
-    host path."""
+    none.  A stage opts out by returning a reason string; an exception from
+    `device_kernel()` is a defect and propagates."""
     decl = getattr(stage, "device_kernel", None)
     if decl is None:
         return None, "no device kernel declared"
-    try:
-        k = decl()
-    except Exception as e:  # noqa: BLE001 — declaration failure == host
-        return None, f"device_kernel() failed: {e}"
+    k = decl()
     if isinstance(k, DeviceKernel):
         if not k.name:
             k.name = type(stage).__name__
@@ -993,8 +990,13 @@ class FusedPipelineModel(PipelineModel):
         segments = self._ensure_segments()
         if len(segments) != 1 or not isinstance(segments[0], _FusedSegment):
             fused = sum(1 for s in segments if isinstance(s, _FusedSegment))
+            host = "; ".join(
+                f"{type(sp.stage).__name__}: {sp.reason}"
+                for s in segments if not isinstance(s, _FusedSegment)
+                for sp in s.stages)
             return (f"plan is {len(segments)} segments ({fused} fused) — a "
-                    "resident session needs exactly one fused segment")
+                    "resident session needs exactly one fused segment"
+                    + (f" (on the host: {host})" if host else ""))
         return ResidentExecutor(segments[0])
 
     def set_mesh(self, mesh: Any) -> "FusedPipelineModel":

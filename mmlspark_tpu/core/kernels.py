@@ -13,8 +13,13 @@ Resolution order for `resolve(name)`:
      "pallas" | "pallas_interpret" | "xla" | "xla_scatter" | "auto" (default)
   2. auto: "pallas" iff the default backend is a TPU and a pallas impl is
      registered; otherwise "xla_scatter" (XLA composition using native
-     scatter — fast on CPU/GPU, pathological on TPU) falling back to "xla"
-     (the scatter-free composition that is safe everywhere).
+     scatter — fast on CPU/GPU, pathological on TPU), else "xla" (the
+     scatter-free composition that is safe everywhere).
+
+Nothing here degrades silently: a backend that fails to initialise
+propagates its error (a dead chip must not resolve as "not a TPU"), and a
+mode whose variant is not registered raises instead of substituting
+another implementation.
 """
 
 from __future__ import annotations
@@ -62,34 +67,27 @@ def kernel_mode() -> str:
     return env if env in _VALID_MODES else "auto"
 
 
-def _backend_is_tpu() -> bool:
-    import jax
-
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001 — backend init can fail; fall back
-        return False
-
-
 def resolve(name: str) -> Callable:
-    """Pick the implementation of `name` for the active mode/backend."""
+    """Pick the implementation of `name` for the active mode/backend.
+    Raises KeyError when the mode's variant is not registered and lets a
+    backend-initialisation failure propagate."""
     with _LOCK:
         impls = dict(_REGISTRY.get(name, {}))
     if not impls:
         raise KeyError(f"no kernel registered under {name!r}")
     mode = kernel_mode()
     if mode == "auto":
-        if _backend_is_tpu() and "pallas" in impls:
+        import jax
+
+        on_tpu = jax.default_backend() == "tpu"
+        if on_tpu and "pallas" in impls:
             mode = "pallas"
-        elif "xla_scatter" in impls and not _backend_is_tpu():
+        elif not on_tpu and "xla_scatter" in impls:
             mode = "xla_scatter"
         else:
             mode = "xla"
     if mode not in impls:
-        # graceful degradation: interpret falls back to pallas source,
-        # pallas falls back to xla (mirrors NativeLoader's resource search)
-        for alt in ("xla", "xla_scatter", "pallas", "pallas_interpret"):
-            if alt in impls:
-                mode = alt
-                break
+        raise KeyError(
+            f"kernel {name!r} has no {mode!r} variant "
+            f"(registered: {sorted(impls)})")
     return impls[mode]

@@ -132,10 +132,11 @@ def _hist_kernel_grouped(group, num_features, num_bins, chunk,
     """Middle ground between per-feature and fused: G features share one
     dot, so each matmul's lane axis is G·B wide (e.g. 1024 at G=4, B=256 —
     vs 256 per-feature) without the fused variant's full F·B VMEM mask.
-    The round-4 chip sweep (sweeps/r4_window1/sweep.txt) showed per-feature
-    beating both chunk=2048 and the XLA scan; this variant probes whether
-    the win was dot width or VMEM pressure. All-f32 operands — the Mosaic
-    mixed-dtype constraint observed on v5e rules out a bf16 mask."""
+    A pre-round chip sweep (source removed in PR 21; a hypothesis until a
+    ledger line) showed per-feature beating both chunk=2048 and the XLA
+    scan; this variant probes whether the win was dot width or VMEM
+    pressure. All-f32 operands — the Mosaic mixed-dtype constraint
+    observed on v5e rules out a bf16 mask."""
     import jax.experimental.pallas as pl
 
     i = pl.program_id(0)
@@ -216,9 +217,8 @@ def _hist_group() -> int:
 
 def _fused_enabled() -> bool:
     """The fused variant is opt-in (MMLSPARK_TPU_FUSED_HIST=1) until a chip
-    sweep proves it beats the per-feature kernel: the measured v5e session
-    (sweeps/r4_window1/sweep.txt) had per-feature chunk=1024 as the
-    fastest compiling variant, so that is the default the bench rides."""
+    sweep proves it beats the per-feature kernel; per-feature chunk=1024
+    is the default the bench rides."""
     import os
 
     return os.environ.get("MMLSPARK_TPU_FUSED_HIST", "0") == "1"

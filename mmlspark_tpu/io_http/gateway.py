@@ -40,6 +40,7 @@ from http.server import ThreadingHTTPServer
 from typing import Any, Callable
 
 from ..observability.sanitizer import make_lock
+from ..parallel.chips import spawn_env, worker_env
 from ..resilience.policy import RetryPolicy, SYSTEM_CLOCK
 from .clients import TargetPool
 from .schema import HTTPRequestData, HTTPResponseData
@@ -666,7 +667,10 @@ class GatewayTier:
                   list(self._members), self._shard_dir(index),
                   self.gateway_kw),
             daemon=True)
-        proc.start()
+        # a gateway worker proxies bytes and never computes: pinned to the
+        # CPU backend so it cannot take a chip from a scoring replica
+        with spawn_env(worker_env(uses_device=False)):
+            proc.start()
         child_conn.close()
         if not parent_conn.poll(self.start_timeout_s):
             proc.kill()

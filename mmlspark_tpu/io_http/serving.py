@@ -35,6 +35,7 @@ from typing import Any, Callable
 import numpy as np
 
 from ..core.dataplane import AsyncReadback, ShapeBucketer, cache_stats
+from ..core.logging import get_logger
 from ..core.schema import Table
 from ..observability.sanitizer import make_lock, make_rlock
 from .schema import (HTTPRequestData, HTTPResponseData, RequestDecoder,
@@ -165,7 +166,10 @@ class _HotPath:
     enforces that literally: each rung's resident (and native) reply
     BYTES are compared against the handler's replies for the same batch,
     and the first divergence disables the fast lane — correctness
-    degrades to the handler path, never to different answers."""
+    degrades to the handler path, never to different answers. A disabled
+    lane says so at WARNING; a compile or dispatch error is not a
+    divergence and propagates out of warmup (the server never turns
+    ready)."""
 
     # timing repetitions per rung when measuring the crossover
     WARM_REPS = 3
@@ -197,6 +201,9 @@ class _HotPath:
         self._lock = make_rlock("_HotPath._lock")
 
     def _disable(self, reason: str) -> None:
+        get_logger("serving").warning(
+            "%s hot path disabled, serving through the handler: %s",
+            self.resident_label, reason)
         with self._lock:
             self.disabled = reason
 
@@ -299,11 +306,7 @@ class _HotPath:
             expect = [r.entity
                       for r in handler(Table({"request": [req32] * rung}))
                       ["reply"]]
-        try:
-            vals = self.resident_values(feats, rung)  # first call compiles
-        except Exception as e:  # noqa: BLE001 — degrade, don't break serving
-            self._disable(f"resident dispatch failed: {e}")
-            return
+        vals = self.resident_values(feats, rung)  # first call compiles
         if [r.entity for r in self.replies_for(vals)] != expect:
             self._disable(f"resident replies diverge at rung {rung}")
             return
@@ -584,6 +587,7 @@ class ServingServer:
         # liveness probes (e.g. the reverse tunnel) hook in via
         # health_probes and surface under /healthz.
         self.warmup_request = warmup_request
+        self.warmup_error: "str | None" = None
         self._warm_rungs: set[int] = set()
         self._warmed = threading.Event()
         self.health_probes: dict[str, Callable[[], Any]] = {}
@@ -678,8 +682,14 @@ class ServingServer:
     def _warmup_async(self) -> None:
         try:
             self.warmup()
-        except Exception:  # noqa: BLE001 — a failed warmup keeps /readyz 503
-            pass
+        except Exception as e:  # noqa: BLE001 — thread boundary
+            # nobody joins this thread, so the failure is reported instead
+            # of raised: /readyz stays 503 and /healthz carries the error
+            with self._counter_lock:
+                self.warmup_error = f"{type(e).__name__}: {e}"
+            get_logger("serving").warning(
+                "warmup failed; %s stays not-ready", self.server_label,
+                exc_info=True)
 
     def health(self) -> dict:
         """The /healthz payload: process-alive facts + extra probe
@@ -692,9 +702,10 @@ class ServingServer:
                 probes[name] = {"error": str(e)}
         with self._counter_lock:
             warm = sorted(self._warm_rungs)
+            warmup_error = self.warmup_error
         return {"status": "ok", "draining": self._draining,
                 "ready": self.ready, "pending": self._load(),
-                "warm_rungs": warm,
+                "warm_rungs": warm, "warmup_error": warmup_error,
                 "probes": probes}
 
     # ------------------------------------------------------------------ #
@@ -1502,15 +1513,19 @@ def _build_hot_path(model, decoder: RequestDecoder,
                     output_col: str) -> "_HotPath | None":
     """serve_model's resident fast lane over `model`, or None when the
     model cannot host one (multi-segment plan, host-only stages, feature
-    column mismatch) — the handler path then serves everything,
-    unchanged."""
-    try:
-        rex = model.resident_executor()
-    except Exception:  # noqa: BLE001 — the fast lane is strictly optional
-        return None
+    column mismatch) — the handler path then serves everything, and the
+    reason is logged. An exception from building the executor is not
+    such a reason and propagates."""
+    rex = model.resident_executor()
+    if not isinstance(rex, str) and (
+            rex.upload_cols != ("features",)
+            or output_col not in rex.download_cols):
+        rex = (f"segment uploads {rex.upload_cols} and downloads "
+               f"{rex.download_cols}; the lane needs ('features',) -> "
+               f"{output_col!r}")
     if isinstance(rex, str):
-        return None
-    if rex.upload_cols != ("features",) or output_col not in rex.download_cols:
+        get_logger("serving").warning(
+            "no resident hot path, serving through the handler: %s", rex)
         return None
     # the native tree walk can substitute for the WHOLE segment only when
     # the segment is exactly one stage exposing a host scorer
@@ -1557,8 +1572,8 @@ def serve_model(
     params on device ONCE and routes live batches between the resident
     executor and the native tree walk per the bucket crossover measured
     at warmup — byte-identical replies with no per-request re-staging.
-    It silently stays on the handler path whenever the model cannot host
-    a resident session.
+    It stays on the handler path, and logs why, whenever the model cannot
+    host a resident session.
 
     A fitted `SARModel` delegates to `recommendation.resident
     .serve_recommender` — same warmup/byte-identity/readback contract,
@@ -1599,10 +1614,7 @@ def serve_model(
             # warmup verifies the two produce the same reply bytes
             from ..core.fusion import fuse
 
-            try:
-                hp_model = fuse(PipelineModel([model]), mesh=mesh)
-            except Exception:  # noqa: BLE001 — fast lane is optional
-                hp_model = None
+            hp_model = fuse(PipelineModel([model]), mesh=mesh)
         if isinstance(hp_model, FusedPipelineModel):
             hp = _build_hot_path(hp_model, decoder, output_col)
 
@@ -2042,10 +2054,17 @@ class ServingFleet:
     `rolling_swap(new_handler_factory)` replaces every replica's handler
     with zero downtime. `watch(callback)` observes membership changes —
     io_http.gateway.ServingGateway attaches itself this way so its
-    routing table tracks the live set."""
+    routing table tracks the live set.
+
+    One process per chip (parallel/chips.py): with `device_workers` (the
+    default — handlers score or train through JAX) each replica owns one
+    chip of the host, and a fleet that cannot give every replica a chip
+    fails at `start()`/spawn time with the reason. `device_workers=False`
+    declares the handlers host-only and pins the replicas to the CPU."""
 
     def __init__(self, handler_factory: Callable[[], Callable[[Table], Table]],
                  n_hosts: int = 2, start_timeout_s: float = 60.0,
+                 device_workers: bool = True,
                  rendezvous: bool = True, forwarding=None,
                  trace_dir: "str | None" = None,
                  flight_recorder_dir: "str | None" = None,
@@ -2057,6 +2076,9 @@ class ServingFleet:
         self.handler_factory = handler_factory
         self.n_hosts = n_hosts
         self.start_timeout_s = start_timeout_s
+        self.device_workers = bool(device_workers)
+        # slot -> index of the chip that slot's process owns
+        self._chip_of: dict[int, int] = {}
         self.server_kw = server_kw
         # io_http.forwarding.ForwardingOptions: every replica opens its own
         # reverse tunnel to the gateway and registers the public coords
@@ -2170,9 +2192,29 @@ class ServingFleet:
 
     # -- spawning ------------------------------------------------------- #
 
-    def _launch(self, partition_id: int):
-        """Start one worker process; returns (process, parent_conn) for
-        the startup handshake."""
+    def _claim_env(self, slot: int) -> dict:
+        """The environment `slot`'s next process starts in: the CPU pin for
+        host-only workers, else the lowest chip no live replica owns.
+        Raises (before anything is spawned) when there is no such chip."""
+        from ..parallel.chips import worker_env
+
+        if not self.device_workers:
+            return worker_env(uses_device=False)
+        with self._fleet_lock:
+            # a slot claimed but not launched yet still holds its chip
+            held = {c for s, c in self._chip_of.items()
+                    if s != slot and (s >= len(self._procs)
+                                      or self._procs[s].is_alive())}
+            chip = min(set(range(len(held) + 1)) - held)
+            env = worker_env(uses_device=True, chip=chip)
+            self._chip_of[slot] = chip
+        return env
+
+    def _launch(self, partition_id: int, env: dict):
+        """Start one worker process under `env` (see `_claim_env`);
+        returns (process, parent_conn) for the startup handshake."""
+        from ..parallel.chips import spawn_env
+
         ctx = multiprocessing.get_context("spawn")
         parent, child = ctx.Pipe()
         p = ctx.Process(
@@ -2183,7 +2225,8 @@ class ServingFleet:
                   self.flight_recorder_dir),
             daemon=True,
         )
-        p.start()
+        with spawn_env(env):
+            p.start()
         return p, parent
 
     def _await_url(self, slot: int, p, parent) -> str:
@@ -2242,9 +2285,10 @@ class ServingFleet:
         """Fill `slot` with a fresh worker: handshake, wait until warm
         (/readyz), then publish it to `urls`/watchers — a spawned replica
         is never routable before it is ready."""
+        env = self._claim_env(slot)
         part = self._next_part
         self._next_part += 1
-        p, parent = self._launch(part)
+        p, parent = self._launch(part, env)
         with self._fleet_lock:
             while len(self._procs) <= slot:
                 self._procs.append(p)
@@ -2255,6 +2299,9 @@ class ServingFleet:
         return url
 
     def start(self) -> "ServingFleet":
+        # every replica's chip is settled before anything starts: a fleet
+        # this host cannot seat fails here, not in a start-up timeout
+        envs = [self._claim_env(slot) for slot in range(self.n_hosts)]
         if self.rendezvous is not None:
             self.rendezvous.start()
         if self.timeline_dir is not None and self.timeline is None:
@@ -2271,7 +2318,7 @@ class ServingFleet:
         for slot in range(self.n_hosts):
             part = self._next_part
             self._next_part += 1
-            p, parent = self._launch(part)
+            p, parent = self._launch(part, envs[slot])
             with self._fleet_lock:
                 self._procs.append(p)
             started.append((slot, p, parent))

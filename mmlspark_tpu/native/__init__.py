@@ -3,14 +3,16 @@
 Reference: `NativeLoader.java:47-105` extracts the right `.so` for the
 platform and `System.load`s it before any native call. Here: the C++
 kernels in `kernels.cpp` are compiled ON DEMAND with the system toolchain
-(g++, cached by source mtime) and bound via ctypes; every entry point has a
-pure-numpy fallback, so a missing toolchain degrades to the Python path
-instead of failing (`available()` reports which path is active).
+(g++, cached under a name that carries the source's hash) and bound via
+ctypes; every entry point has a pure-numpy fallback, so a missing toolchain
+degrades to the Python path instead of failing (`available()` reports
+which path is active).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -38,13 +40,22 @@ def _build_dir() -> str:
     return d
 
 
+def _lib_name() -> str:
+    """The artefact is keyed on the SOURCE'S CONTENT, not on mtimes: a
+    copied tree has arbitrary mtimes and `_build/` is git-ignored, so an
+    mtime test would let a stale `.so` built from other source ride along."""
+    with open(_SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    return f"libmmlsparktpu-{digest}.so"
+
+
 def _compile() -> str | None:
     """Never raises: any filesystem/toolchain problem returns None (the
     caller falls back to numpy, as NativeLoader falls back on resource
     lookup failure)."""
     try:
-        out = os.path.join(_build_dir(), "libmmlsparktpu.so")
-        if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(_SRC):
+        out = os.path.join(_build_dir(), _lib_name())
+        if os.path.exists(out):
             return out
         # unique tmp + atomic rename: concurrent builders can't corrupt the .so
         fd, tmp = tempfile.mkstemp(suffix=".so", dir=_build_dir())

@@ -60,8 +60,8 @@ class DeepModelTransformer(Model):
     use_mesh = Param(False, "shard batches over the data mesh axis", ptype=bool)
     # One host->device transfer + ONE dispatch for the whole table (a jitted
     # lax.scan over minibatches) instead of one dispatch per minibatch.
-    # Per-dispatch latency dominates batched transforms when the device is
-    # remote (the reference pays the same cost per JNI evaluate call,
+    # Per-dispatch latency dominates batched transforms of small
+    # minibatches (the reference pays the same cost per JNI evaluate call,
     # CNTKModel.scala:131-138); bounded by fused_dispatch_budget_mb so huge
     # tables still stream batch-by-batch.
     fused_dispatch = Param(True, "scan all minibatches in one dispatch", ptype=bool)
@@ -336,16 +336,7 @@ class DeepModelTransformer(Model):
         from ..parallel.tensor_parallel import (dense_column_shardings,
                                                 dense_column_specs,
                                                 gathered_column_parallel)
-        try:
-            from jax import shard_map
-        except ImportError:  # jax < 0.5: shard_map lives under experimental
-            import functools
-
-            from jax.experimental.shard_map import shard_map as _shard_map
-
-            # the old rep-checker cannot see that the tiled all_gather
-            # replicates the output over the model axis; new jax proves it
-            shard_map = functools.partial(_shard_map, check_rep=False)
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         bundle = self.bundle
@@ -399,9 +390,12 @@ class DeepModelTransformer(Model):
             return h
 
         specs = {"params": dense_column_specs(params)}
+        # check_vma=False: a tiled all_gather leaves every model-axis chip
+        # with the same full feature row, but its result is typed "varying"
+        # over that axis, so out_specs' replication cannot be inferred
         body = shard_map(tp_body, mesh=mesh,
                          in_specs=(specs, P(DATA_AXIS, None)),
-                         out_specs=P(DATA_AXIS, None))
+                         out_specs=P(DATA_AXIS, None), check_vma=False)
 
         def forward(variables, x):
             x = (x.astype(jnp.float32) - mean) / std

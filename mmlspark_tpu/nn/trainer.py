@@ -78,8 +78,8 @@ class DNNLearner(HasFeaturesCol, HasLabelCol, Estimator):
     trainable_prefixes = Param(None, "list of param path prefixes to train (None=all)")
     # One dispatch per EPOCH (jitted lax.scan over minibatches on
     # device-resident data) instead of one per step — per-dispatch latency
-    # dominates small-table training when the device is remote. Gated by a
-    # memory budget; over-budget tables stream batch-by-batch.
+    # dominates small-table training. Gated by a memory budget; over-budget
+    # tables stream batch-by-batch.
     fused_epochs = Param(True, "scan a whole epoch in one dispatch", ptype=bool)
     fused_epoch_budget_mb = Param(
         512, "max table MB resident on device for the fused epoch path", ptype=int
@@ -241,17 +241,23 @@ class DNNLearner(HasFeaturesCol, HasLabelCol, Estimator):
                 xd, yd = jnp.asarray(x), jnp.asarray(y)
                 data_spec = None
 
-            def epoch_body(carry, xs):
-                p, bst, os_ = carry
-                idx, step_rng = xs
-                bx, by = xd[idx], yd[idx]
-                if data_spec is not None:
-                    bx = jax.lax.with_sharding_constraint(bx, data_spec)
-                    by = jax.lax.with_sharding_constraint(by, data_spec)
-                p, bst, os_, loss = train_step(p, bst, os_, bx, by, step_rng)
-                return (p, bst, os_), loss
+            # the table is an ARGUMENT of the jitted epoch, never a closure:
+            # a closed-over device array is baked into the program as a
+            # constant, and a table-sized constant (up to the budget above)
+            # makes the compile slow or unbounded
+            def run_epoch(params, batch_stats, opt_state, xd, yd, order,
+                          epoch_rng):
+                def epoch_body(carry, xs):
+                    p, bst, os_ = carry
+                    idx, step_rng = xs
+                    bx, by = xd[idx], yd[idx]
+                    if data_spec is not None:
+                        bx = jax.lax.with_sharding_constraint(bx, data_spec)
+                        by = jax.lax.with_sharding_constraint(by, data_spec)
+                    p, bst, os_, loss = train_step(
+                        p, bst, os_, bx, by, step_rng)
+                    return (p, bst, os_), loss
 
-            def run_epoch(params, batch_stats, opt_state, order, epoch_rng):
                 # fold_in(k) matches the per-step loop path exactly, so a
                 # dropout model trains identically fused or streamed
                 keys = jax.vmap(
@@ -285,7 +291,7 @@ class DNNLearner(HasFeaturesCol, HasLabelCol, Estimator):
                         order[: steps * bs].reshape(steps, bs), jnp.int32
                     )
                     params, batch_stats, opt_state, mean_loss = epoch_fn(
-                        params, batch_stats, opt_state, idx, epoch_rng
+                        params, batch_stats, opt_state, xd, yd, idx, epoch_rng
                     )
                     mean_loss = float(mean_loss)
                 else:

@@ -108,18 +108,11 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str):
-    # lax.axis_size is newer-jax; psum of ones is the portable spelling
-    # (constant-folded to the static mapped-axis size, no collective)
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)
 
 
 def pcast(x, axis_names, to: str = "varying"):
-    """lax.pcast where it exists; identity on older jax, whose shard_map
-    has no varying-manual-axes typing to satisfy."""
-    fn = getattr(lax, "pcast", None)
-    return fn(x, axis_names, to=to) if fn is not None else x
+    return lax.pcast(x, axis_names, to=to)
 
 
 # --------------------------------------------------------------------- #

@@ -17,10 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5: shard_map lives under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .mesh import MODEL_AXIS

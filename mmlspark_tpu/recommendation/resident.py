@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.fusion import DeviceKernel, fuse
+from ..core.logging import get_logger
 from ..core.params import Param
 from ..core.pipeline import Model, PipelineModel
 from ..core.schema import Table
@@ -229,8 +230,8 @@ def serve_recommender(
     the SAME jitted program with the SAME pinned params
     (`_FusedSegment._build` caches both), so warmup's per-rung byte
     comparison holds by construction and any divergence disables the
-    fast lane rather than changing answers. `serve_model(sar_model, ...)`
-    delegates here."""
+    fast lane (at WARNING) rather than changing answers.
+    `serve_model(sar_model, ...)` delegates here."""
     if model.user_affinity is None or model.item_similarity is None:
         raise ValueError("serve_recommender needs a fitted SARModel")
     scorer = SARTopKScorer.from_model(model, k=k, remove_seen=remove_seen)
@@ -241,12 +242,14 @@ def serve_recommender(
     decoder = RequestDecoder([user_col])
     hp = None
     if hot_path:
-        try:
-            rex = fused.resident_executor()
-        except Exception:  # noqa: BLE001 — the fast lane is strictly optional
-            rex = None
-        if rex is not None and not isinstance(rex, str) \
-                and rex.upload_cols == ("features",):
+        rex = fused.resident_executor()
+        if not isinstance(rex, str) and rex.upload_cols != ("features",):
+            rex = f"segment uploads {rex.upload_cols}, not ('features',)"
+        if isinstance(rex, str):
+            get_logger("serving").warning(
+                "no sar_resident hot path, serving through the handler: %s",
+                rex)
+        else:
             hp = SARHotPath(rex, decoder, "features", "recommendations",
                             readback_lag=fused.get("readback_lag"))
 

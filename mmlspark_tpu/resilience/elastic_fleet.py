@@ -552,6 +552,9 @@ class _ElasticFitBase:
             kw = {"rendezvous": False,
                   "flight_recorder_dir": os.path.join(
                       self.checkpoint_dir, "flight"),
+                  # GBDT workers build np.bincount histograms on the host;
+                  # DNN workers take gradients through JAX
+                  "device_workers": self.kind != "gbdt",
                   **self.fleet_kw}
             self.fleet = ServingFleet(
                 ElasticWorkerFactory(self.checkpoint_dir,

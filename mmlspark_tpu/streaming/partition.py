@@ -593,6 +593,12 @@ class ParallelStreamingQuery(StreamingQuery):
                       if self._log is not None else None)
             kw = dict(self._fleet_kw)
             kw.setdefault("flight_recorder_dir", fr_dir)
+            # one process per chip: stateful operators are numpy-only, so
+            # a chain of nothing else never opens a backend; any other
+            # stage (a fitted model) may score through JAX
+            kw.setdefault("device_workers", self._chain is not None and any(
+                not isinstance(s, StatefulOperator)
+                for s in _walk_stages(self._chain)))
             self._fleet = ServingFleet(
                 PartitionWorkerFactory(self._blob, self.name),
                 n_hosts=self._num_workers, **kw)

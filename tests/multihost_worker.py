@@ -28,10 +28,7 @@ def main() -> None:
     )
 
     import numpy as np
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5: shard_map lives under experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     devs = jax.devices()                     # global across processes

@@ -235,10 +235,7 @@ class TestMesh:
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from mmlspark_tpu.parallel import DATA_AXIS, MODEL_AXIS
 
         x = np.ones((8, 4), np.float32)
@@ -284,3 +281,57 @@ class TestReviewRegressions:
         t = Table({"a": [1, 2]}).with_meta("a", {"category_values": ["p", "q"]})
         t2 = t.with_column("a", [3, 4])
         assert "category_values" not in t2.meta("a")
+
+
+# -- compile cache ----------------------------------------------------------
+class TestCompileCachePlacement:
+    """mmlspark_tpu/__init__.py places JAX's persistent compile cache once,
+    at import: `JAX_COMPILATION_CACHE_DIR` wins and the code sets nothing;
+    otherwise a fixed in-checkout path, the same in every interpreter."""
+
+    @staticmethod
+    def _cache_dir_in_fresh_interpreter(**env_overrides):
+        """What `jax.config.jax_compilation_cache_dir` says after the
+        package import (no backend is initialised by reading it)."""
+        import os
+        import subprocess
+        import sys
+
+        from conftest import subprocess_env
+
+        env = subprocess_env()
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        env.update(env_overrides)
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import mmlspark_tpu, jax; "
+             "print(jax.config.jax_compilation_cache_dir)"],
+            capture_output=True, text=True, timeout=120, env=env,
+            cwd=os.path.sep)
+        assert out.returncode == 0, out.stderr[-500:]
+        return out.stdout.strip().splitlines()[-1]
+
+    def test_unset_env_gives_the_fixed_in_checkout_path(self):
+        import os
+
+        import mmlspark_tpu
+
+        expected = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(mmlspark_tpu.__file__))), ".jax_cache")
+        # two fresh interpreters agree: no pid, no temp name, no timestamp
+        assert self._cache_dir_in_fresh_interpreter() == expected
+        assert self._cache_dir_in_fresh_interpreter() == expected
+
+    def test_env_wins_and_code_sets_nothing(self, monkeypatch, tmp_path):
+        import jax
+
+        import mmlspark_tpu
+
+        assert self._cache_dir_in_fresh_interpreter(
+            JAX_COMPILATION_CACHE_DIR=str(tmp_path)) == str(tmp_path)
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda *a, **k: calls.append(a))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        mmlspark_tpu._place_compile_cache()
+        assert calls == []

@@ -212,10 +212,7 @@ class TestRingAllGather:
         import jax.numpy as jnp
         from jax import lax
 
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from mmlspark_tpu.parallel.mesh import make_mesh
@@ -243,10 +240,7 @@ class TestRingAllGather:
         import jax.numpy as jnp
         from jax import lax  # noqa: F401 — axis helpers used inside body
 
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from mmlspark_tpu.parallel.mesh import make_mesh

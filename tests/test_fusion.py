@@ -115,14 +115,15 @@ class TestPlanFusion:
         assert fused == 4      # 2 fused segments x (1 in + 1 out)
         assert staged == 6     # 3 device stages x (1 in + 1 out)
 
-    def test_broken_declaration_stays_on_host(self):
+    def test_broken_declaration_raises(self):
+        # opting out is a returned reason string; an exception is a defect
+        # and must not quietly put the stage on the host path
         class Broken(_AddOneOnDevice):
             def device_kernel(self):
                 raise RuntimeError("boom")
 
-        plan = plan_fusion([Broken()])
-        assert not plan.segments[0].fused
-        assert "device_kernel() failed" in plan.segments[0].stages[0].reason
+        with pytest.raises(RuntimeError, match="boom"):
+            plan_fusion([Broken()])
 
     def test_fuse_is_idempotent_and_wraps_bare_transformers(self):
         fm = fuse(pipeline_model(_AddOneOnDevice()))

@@ -885,6 +885,28 @@ class TestHistKernel:
         # auto on CPU resolves to the scatter variant (fast on CPU/GPU)
         assert kernels.resolve("gbdt_histogram").__name__ == "histogram_xla_scatter"
 
+    def test_registry_raises_instead_of_substituting(self, monkeypatch):
+        import jax
+
+        from mmlspark_tpu.core import kernels
+
+        kernels.register_kernel("only_xla", "xla", lambda: None)
+        try:
+            kernels.set_kernel_mode("pallas")
+            with pytest.raises(KeyError, match="no 'pallas' variant"):
+                kernels.resolve("only_xla")
+        finally:
+            kernels.set_kernel_mode(None)
+            kernels._REGISTRY.pop("only_xla", None)
+
+        # a backend that cannot initialise is an error, not "not a TPU"
+        def dead_backend():
+            raise RuntimeError("Unable to initialize backend 'tpu'")
+
+        monkeypatch.setattr(jax, "default_backend", dead_backend)
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            kernels.resolve("gbdt_histogram")
+
     def test_fit_under_interpret_kernel_matches_xla(self):
         from mmlspark_tpu.core import kernels
 
@@ -895,6 +917,25 @@ class TestHistKernel:
             bx = Booster.train(x, y, opts)
             kernels.set_kernel_mode("pallas_interpret")
             bp = Booster.train(x, y, opts)
+        finally:
+            kernels.set_kernel_mode(None)
+        np.testing.assert_allclose(bx.predict(x), bp.predict(x), rtol=1e-5,
+                                   atol=1e-6)
+
+    def test_mesh_fit_traces_with_the_pallas_kernel(self, mesh8):
+        """On the TPU the mesh fit's shard_map body calls pallas_call; CPU
+        meshes resolve the scatter kernel and never trace that. Forced
+        through the interpreter here: the first four-chip run failed at
+        trace time on shard_map's vma check."""
+        from mmlspark_tpu.core import kernels
+
+        x, y = make_classification(n=320)
+        opts = TrainOptions(objective="binary", num_iterations=2, num_leaves=7)
+        try:
+            kernels.set_kernel_mode("xla")
+            bx = Booster.train(x, y, opts, mesh=mesh8)
+            kernels.set_kernel_mode("pallas_interpret")
+            bp = Booster.train(x, y, opts, mesh=mesh8)
         finally:
             kernels.set_kernel_mode(None)
         np.testing.assert_allclose(bx.predict(x), bp.predict(x), rtol=1e-5,

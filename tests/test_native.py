@@ -38,6 +38,24 @@ class TestNativeKernels:
     def test_lib_builds_and_loads(self):
         assert native.available()
 
+    def test_artefact_name_follows_source_hash(self, monkeypatch, tmp_path):
+        """The cached .so is keyed on kernels.cpp's CONTENT: a copied tree
+        (arbitrary mtimes, git-ignored _build/) must never reuse a library
+        built from other source."""
+        import hashlib
+        import os
+
+        with open(native._SRC, "rb") as fh:
+            digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+        path = native._compile()
+        assert os.path.basename(path) == f"libmmlsparktpu-{digest}.so"
+        # different source -> different artefact, however old the file is
+        other = tmp_path / "kernels.cpp"
+        other.write_bytes(open(native._SRC, "rb").read() + b"\n// edit\n")
+        os.utime(other, (0, 0))
+        monkeypatch.setattr(native, "_SRC", str(other))
+        assert native._lib_name() != os.path.basename(path)
+
     def test_binning_bit_identical(self, monkeypatch):
         x, _ = make_data()
         mapper = BinMapper(max_bin=63, categorical_indexes=(2,)).fit(x)

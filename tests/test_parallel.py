@@ -345,3 +345,125 @@ class TestDistributedDeterminism:
         for a, b in zip(ref_params, dist_params):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-5)
+
+
+class TestOneProcessPerChip:
+    """parallel/chips.py: what a spawned worker's environment must say so
+    that no child ever waits on a chip its parent (or a sibling) holds."""
+
+    @pytest.fixture()
+    def host(self, monkeypatch):
+        """A fake host: set chips / whether this process holds them."""
+        from mmlspark_tpu.parallel import chips
+
+        state = {"chips": 0, "holds": False}
+        monkeypatch.setattr(chips, "local_tpu_chips", lambda: state["chips"])
+        monkeypatch.setattr(chips, "_holds_tpu", lambda: state["holds"])
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        return state
+
+    def test_private_jax_apis_still_answer(self):
+        """Unfaked: the two `jax._src` calls chips.py leans on exist and
+        answer without bringing a backend up (this box has no TPU)."""
+        from mmlspark_tpu.parallel import chips
+
+        assert chips.local_tpu_chips() == 0
+        assert chips._holds_tpu() is False
+
+    def test_host_only_worker_is_pinned_to_cpu(self, host):
+        from mmlspark_tpu.parallel.chips import worker_env
+
+        host.update(chips=4, holds=True)
+        assert worker_env(uses_device=False) == {"JAX_PLATFORMS": "cpu"}
+
+    def test_explicit_cpu_run_and_chipless_host_inherit(self, host,
+                                                        monkeypatch):
+        from mmlspark_tpu.parallel.chips import worker_env
+
+        assert worker_env(uses_device=True, chip=3) == {}   # no chips
+        host.update(chips=1, holds=True)
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert worker_env(uses_device=True, chip=3) == {}
+
+    def test_device_worker_gets_one_chip_by_index(self, host):
+        from mmlspark_tpu.parallel.chips import worker_env
+
+        host.update(chips=4)
+        env = worker_env(uses_device=True, chip=2)
+        assert env["TPU_VISIBLE_DEVICES"] == "2"
+        assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+
+    def test_impossible_seatings_fail_with_the_reason(self, host):
+        from mmlspark_tpu.parallel.chips import worker_env
+
+        host.update(chips=1)
+        with pytest.raises(RuntimeError, match="one process per chip"):
+            worker_env(uses_device=True, chip=1)
+        host.update(chips=4, holds=True)
+        with pytest.raises(RuntimeError, match="holds the host's 4 TPU"):
+            worker_env(uses_device=True, chip=0)
+
+    def test_fleet_refuses_at_start_before_spawning(self, host):
+        from mmlspark_tpu.io_http.serving import ServingFleet
+
+        host.update(chips=1)
+        fleet = ServingFleet(lambda: None, n_hosts=2, rendezvous=False)
+        with pytest.raises(RuntimeError, match="this host has 1"):
+            fleet.start()
+        assert fleet._procs == []
+
+    def test_partition_workers_are_classified_by_their_chain(
+            self, monkeypatch):
+        """Streaming partition workers: a chain of stateful operators only
+        is host-only (numpy); any other stage may score through JAX."""
+        from mmlspark_tpu.core.pipeline import Transformer, pipeline_model
+        from mmlspark_tpu.io_http import serving
+        from mmlspark_tpu.streaming import (
+            GroupedAggregator, KeyedShuffle, MemorySink, MemorySource,
+            ParallelStreamingQuery)
+
+        seen = []
+
+        class FakeFleet:
+            urls: list = []
+
+            def __init__(self, factory, n_hosts, **kw):
+                seen.append(kw["device_workers"])
+
+            def watch(self, cb):
+                pass
+
+            def start(self):
+                return self
+
+            def stop(self):
+                pass
+
+        class Scorer(Transformer):
+            def _transform(self, table):
+                return table
+
+        monkeypatch.setattr(serving, "ServingFleet", FakeFleet)
+        agg = GroupedAggregator(group_col="key", value_col="value",
+                                agg="sum", output_col="total")
+        for chain in ([agg], [Scorer(), agg]):
+            q = ParallelStreamingQuery(
+                MemorySource(), pipeline_model(
+                    KeyedShuffle(key_col="key", num_partitions=2), *chain),
+                MemorySink(), workers="fleet")
+            q._ensure_workers()
+            q.stop()
+        assert seen == [False, True]
+
+    def test_spawn_env_patches_and_restores(self, monkeypatch):
+        import os
+
+        from mmlspark_tpu.parallel.chips import spawn_env
+
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        monkeypatch.delenv("TPU_VISIBLE_DEVICES", raising=False)
+        with spawn_env({"JAX_PLATFORMS": "tpu", "TPU_VISIBLE_DEVICES": "1"}):
+            assert os.environ["JAX_PLATFORMS"] == "tpu"
+            assert os.environ["TPU_VISIBLE_DEVICES"] == "1"
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+        assert "TPU_VISIBLE_DEVICES" not in os.environ

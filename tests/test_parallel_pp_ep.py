@@ -10,10 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5: shard_map lives under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from mmlspark_tpu.parallel.moe import (

@@ -175,6 +175,57 @@ class TestThreeRouteByteIdentity:
             srv.hot_path.force_path = None
 
 
+class TestWarmupFailuresAreLoud:
+    """Nothing hides the device: a compile/dispatch error in warm-up is
+    not a 'divergence' that quietly switches the lane off."""
+
+    def test_dispatch_error_propagates_and_is_reported(self, hot_server,
+                                                       monkeypatch):
+        srv, hp = hot_server, hot_server.hot_path
+
+        def refused(*a, **k):
+            raise RuntimeError("mosaic says no")
+
+        monkeypatch.setattr(hp.executor, "dispatch", refused)
+        with pytest.raises(RuntimeError, match="mosaic says no"):
+            srv.warmup()
+        assert hp.disabled is None
+        try:
+            srv._warmup_async()   # the start() thread's entry point
+            assert "mosaic says no" in srv.health()["warmup_error"]
+        finally:
+            srv.warmup_error = None
+
+    def test_disabled_lane_and_missing_lane_say_so(self, hot_server,
+                                                   caplog):
+        import logging
+
+        from mmlspark_tpu.gbdt.estimators import GBDTClassifier
+        from mmlspark_tpu.io_http.serving import _HotPath
+
+        logging.getLogger("mmlspark_tpu").propagate = True
+        try:
+            with caplog.at_level(logging.WARNING, logger="mmlspark_tpu"):
+                hp = hot_server.hot_path
+                probe = _HotPath(hp.executor, hp.decoder, "features",
+                                 "prediction")
+                probe._disable("resident replies diverge at rung 1")
+                rng = np.random.default_rng(0)
+                X = rng.normal(size=(64, 4))
+                clf = GBDTClassifier(num_iterations=2, num_leaves=4).fit(
+                    Table({"features": X,
+                           "label": (X[:, 0] > 0).astype(float)}))
+                srv = serve_model(clf, COLS)
+                srv.stop()
+        finally:
+            logging.getLogger("mmlspark_tpu").propagate = False
+        assert srv.hot_path is None
+        text = caplog.text
+        assert "hot path disabled" in text and "diverge at rung 1" in text
+        assert "no resident hot path" in text
+        assert "float64 on host" in text   # the stage's own reason
+
+
 class TestSteadyStateSoak:
     def test_concurrent_soak_no_recompiles_one_round_trip(self):
         """High-concurrency soak on a warm server: 8 clients x 30

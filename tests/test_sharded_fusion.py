@@ -75,18 +75,25 @@ class TestShardedByteIdentity:
         # MLP variables replicate; DataConversion is parameterless
         assert seg["param_placements"] == ["replicated", "none"]
 
-    def test_tensor_parallel_2d_mesh(self):
+    @pytest.mark.parametrize("outputs", [8, pytest.param(
+        4, marks=pytest.mark.xfail(
+            reason="XLA:CPU only: the ragged tail leaves 2 rows per data "
+            "shard, and a 4-wide head halved over the model axis makes "
+            "the per-shard head a (2, K) @ (K, 2) dot, which XLA:CPU "
+            "inlines as a mul+add loop while every larger shape takes "
+            "its FMA matmul: 1 ulp off the unsharded program. "
+            "Byte-identical on the TPU, where chip_smoke.py checks this "
+            "shape on a multi-chip host."))])
+    def test_tensor_parallel_2d_mesh(self, outputs):
         import jax
 
         mesh = make_mesh(n_data=4, n_model=2, devices=jax.devices()[:8])
-        t = _mlp(mini_batch_size=32,
-                 fetch_dict={"out": "logits", "prob": "probability"})
+        kw = dict(outputs=outputs, mini_batch_size=32,
+                  fetch_dict={"out": "logits", "prob": "probability"})
+        t = _mlp(**kw)
         table = _xtable(70, seed=5)
         ref = t.transform(table)
-        fused = fuse(_mlp(mini_batch_size=32,
-                          fetch_dict={"out": "logits",
-                                      "prob": "probability"}),
-                     mini_batch_size=32, mesh=mesh)
+        fused = fuse(_mlp(**kw), mini_batch_size=32, mesh=mesh)
         got = fused.transform(table)
         for c in ("out", "prob"):
             assert np.asarray(got[c]).tobytes() == \

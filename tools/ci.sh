@@ -27,10 +27,15 @@
 #   6. threaded-subsystem shard re-run under the runtime lock-order
 #      sanitizer (MMLSPARK_TPU_SANITIZE=1 hard-fails on any lock-order
 #      cycle or blocking-under-lock the static pass could not see)
-#   7. multi-chip dryrun (sharding compiles + replicated-model check)
-#   8. benchmark smoke on CPU (fail-soft backend selection)
+#   7. multi-chip dryrun on eight forced host devices (sharding compiles
+#      + replicated-model check)
+#   8. benchmark smoke, explicitly on the CPU (bench.py refuses to run
+#      without a TPU unless JAX_PLATFORMS=cpu says so)
+# CI has no accelerator: every step runs under JAX_PLATFORMS=cpu. The chip
+# is checked separately with `python chip_smoke.py`.
 set -euo pipefail
 cd "$(dirname "$0")/.."
+export JAX_PLATFORMS=cpu
 python -m tools.graftlint --selftest
 python -m tools.graftlint
 python tools/diagnose.py --selftest
@@ -50,5 +55,5 @@ MMLSPARK_TPU_SANITIZE=1 python -m pytest -q \
     tests/test_automl_sweep.py tests/test_elastic_fleet.py \
     tests/test_dataplane.py tests/test_sharded_fusion.py \
     tests/test_donated_pipelined.py tests/test_timeline.py
-python -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun ok')"
-MMLSPARK_TPU_BENCH_FORCE_CPU=1 python bench.py
+JAX_PLATFORMS=cpu python -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun ok')"
+JAX_PLATFORMS=cpu python bench.py

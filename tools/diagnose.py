@@ -1585,7 +1585,8 @@ def _sar_serving_selftest(checks: dict) -> None:
 def selftest() -> int:
     from mmlspark_tpu.io_http.serving import ServingFleet
 
-    fleet = ServingFleet(_selftest_factory, n_hosts=2).start()
+    fleet = ServingFleet(_selftest_factory, n_hosts=2,
+                         device_workers=False).start()
     try:
         for i in range(8):
             req = urllib.request.Request(

@@ -176,7 +176,7 @@ def full_fit_ab():
     flip the default needs END-TO-END fit seconds — binning, growth, and
     the histogram stream together — plus the valid-AUC guard that a
     faster kernel didn't silently break learning. One row per candidate
-    configuration; the winner's numbers go to BENCH_TPU_MEASURED.md and
+    configuration; the winner's numbers go to PERF.md and
     the default flip happens on this table, not on µs/build."""
     import bench as bench_mod
     from mmlspark_tpu.core.kernels import set_kernel_mode
@@ -246,9 +246,6 @@ def full_fit_ab():
 
 
 def main():
-    from bench import pin_cpu_if_requested
-
-    pin_cpu_if_requested()
     print(f"device: {jax.devices()[0].device_kind}")
     bins, stats = make_inputs()
     from mmlspark_tpu.gbdt.hist_kernel import histogram_xla
