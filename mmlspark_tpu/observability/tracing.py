@@ -17,11 +17,22 @@ active span as a W3C `traceparent` header (clients attach it via
 remote parent span, so a server-side span joins the caller's trace —
 the Dapper pattern end to end.
 
-Device correlation: when `MMLSPARK_TPU_TRACE_DIR` is set (the switch
-that makes utils/profiling.device_trace capture an XPlane trace), every
-host span ALSO enters a `jax.profiler.TraceAnnotation`, so the same
-span names appear inside the device trace's annotation track and host
-spans line up with device activity in xprof/Perfetto.
+Device correlation: while a JAX profiler session is live, however it was
+started (`jax.profiler.start_trace`, a capture from TensorBoard,
+`utils/profiling.device_trace`), every host span ALSO enters a
+`jax.profiler.TraceAnnotation`. The span then sits on its thread's line of
+the `.xplane.pb`, in the trace's own nanoseconds, next to the device's
+operations. The check is made at span entry and costs well under a
+microsecond; no option or environment variable is read.
+
+Spans inside `SARModel.recommend_for_all_users` (process-default tracer):
+`sar.recommend_all`, one a call and parent of the rest (arguments `users`,
+`items`, `k`, `block`, `blocks`, `remove_seen` and, set at the end,
+`bytes_read_back`), and one a block of `sar.slice` (`lo`, `hi`: the block's
+affinity and seen rows cut out of the resident arrays), `sar.dispatch` (the
+jitted top-k call until it returns its futures), `sar.wait`
+(`block_until_ready` on them) and `sar.readback` (`bytes`: both
+`np.asarray` with their casts).
 
 The disabled path is a no-op fast path: one attribute check, a shared
 null context manager — no allocation, no locks, no contextvar writes.
@@ -142,9 +153,30 @@ class _NullCtx:
 _NULL_CTX = _NullCtx()
 
 
+def _no_session() -> bool:
+    return False
+
+
+_session_probe = None      # jaxlib's TraceMe.is_enabled, found at first use
+
+
+def _profiler_session_live() -> bool:
+    """Whether a JAX profiler session is recording right now. The import is
+    lazy and fail-soft so the tracer stays dependency-free."""
+    global _session_probe
+    if _session_probe is None:
+        try:
+            from jax._src.lib import _profiler
+
+            _session_probe = _profiler.TraceMe.is_enabled
+        except Exception:
+            _session_probe = _no_session
+    return _session_probe()
+
+
 def _device_annotation(name: str):
-    """jax.profiler.TraceAnnotation when a device trace is active; the
-    import is lazy and fail-soft so the tracer stays dependency-free."""
+    """jax.profiler.TraceAnnotation, or None without JAX (lazy and
+    fail-soft, as above)."""
     try:
         import jax
 
@@ -164,7 +196,10 @@ class _SpanCtx:
 
     def __enter__(self) -> Span:
         self._token = self._tracer._current.set(self._span)
-        if self._tracer.annotate_device:
+        annotate = self._tracer.annotate_device
+        if annotate is None:
+            annotate = _profiler_session_live()
+        if annotate:
             self._ann = _device_annotation(self._span.name)
             if self._ann is not None:
                 self._ann.__enter__()
@@ -186,10 +221,11 @@ class Tracer:
     clock            duck-typed `monotonic()` (resilience FakeClock fits);
                      span timestamps are microseconds on this clock
     max_spans        ring-buffer bound on retained completed spans
-    annotate_device  also enter jax.profiler.TraceAnnotation per span;
-                     default: on exactly when MMLSPARK_TPU_TRACE_DIR is
-                     set, so host spans appear in the device trace the
-                     same env var turns on
+    annotate_device  also enter jax.profiler.TraceAnnotation per span.
+                     None (the default): exactly while a profiler
+                     session is live, decided at span entry, so host
+                     spans appear in any device trace; True / False
+                     override the check (tests)
     """
 
     def __init__(self, clock: Any = None, enabled: bool = True,
@@ -199,8 +235,7 @@ class Tracer:
         self._clock = clock
         self.enabled = bool(enabled)
         self.annotate_device = (
-            bool(os.environ.get("MMLSPARK_TPU_TRACE_DIR"))
-            if annotate_device is None else bool(annotate_device))
+            None if annotate_device is None else bool(annotate_device))
         self._spans: deque[Span] = deque(maxlen=int(max_spans))
         self._dropped = 0
         self._lock = make_lock("Tracer._lock")

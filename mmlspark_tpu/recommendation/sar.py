@@ -29,6 +29,7 @@ from ..core.params import Param
 from ..core.pipeline import Estimator, Model
 from ..core.schema import Table
 from ..core.serialize import register_stage
+from ..observability.tracing import get_tracer
 
 __all__ = ["SAR", "SARModel"]
 
@@ -233,32 +234,53 @@ class SARModel(Model):
         k = min(k, n_items)
         block = user_block or self.USER_BLOCK
         mask_seen = remove_seen and dev["seen"] is not None
-        vals_parts, idx_parts = [], []
-        for lo in range(0, n_users, block):
-            hi = min(lo + block, n_users)
-            aff = dev["affinity"][lo:hi]
-            if mask_seen:
-                v, i = _block_topk_unseen(
-                    aff, dev["similarity"], dev["seen"][lo:hi], k)
-            else:
-                v, i = _block_topk(aff, dev["similarity"], k)
-            vals_parts.append(np.asarray(v, np.float64))
-            idx_parts.append(np.asarray(i, np.int64))
-        vals = (np.concatenate(vals_parts) if vals_parts
-                else np.zeros((0, k), np.float64))
-        idx = (np.concatenate(idx_parts) if idx_parts
-               else np.zeros((0, k), np.int64))
-        # users with fewer than k unseen items: top_k still returns the
-        # -inf (seen) entries — mark them invalid (id -1) instead of
-        # leaking seen items back as 0-rated recommendations
-        invalid = ~np.isfinite(vals)
-        idx = np.where(invalid, -1, idx)
-        vals = np.where(invalid, 0.0, vals)
-        return Table({
-            self.get("user_col"): np.arange(n_users, dtype=np.float64),
-            "recommendations": idx,
-            "ratings": vals,
-        })
+        tracer = get_tracer()
+        with tracer.start_span(
+                "sar.recommend_all", users=n_users, items=n_items, k=k,
+                block=block, blocks=-(-n_users // block),
+                remove_seen=mask_seen) as call:
+            vals_parts, idx_parts = [], []
+            bytes_read_back = 0
+            for lo in range(0, n_users, block):
+                hi = min(lo + block, n_users)
+                with tracer.start_span("sar.slice", lo=lo, hi=hi):
+                    aff = dev["affinity"][lo:hi]
+                    seen = dev["seen"][lo:hi] if mask_seen else None
+                with tracer.start_span("sar.dispatch"):
+                    if mask_seen:
+                        v, i = _block_topk_unseen(
+                            aff, dev["similarity"], seen, k)
+                    else:
+                        v, i = _block_topk(aff, dev["similarity"], k)
+                # a block's seen rows go when its scores are done, not
+                # when the next block's have been cut as well
+                del seen
+                # np.asarray would wait for the same buffers: waiting here
+                # first changes no order and tells waiting from copying
+                with tracer.start_span("sar.wait"):
+                    jax.block_until_ready((v, i))
+                block_bytes = v.nbytes + i.nbytes
+                with tracer.start_span("sar.readback", bytes=block_bytes):
+                    vals_parts.append(np.asarray(v, np.float64))
+                    idx_parts.append(np.asarray(i, np.int64))
+                bytes_read_back += block_bytes
+            vals = (np.concatenate(vals_parts) if vals_parts
+                    else np.zeros((0, k), np.float64))
+            idx = (np.concatenate(idx_parts) if idx_parts
+                   else np.zeros((0, k), np.int64))
+            # users with fewer than k unseen items: top_k still returns the
+            # -inf (seen) entries — mark them invalid (id -1) instead of
+            # leaking seen items back as 0-rated recommendations
+            invalid = ~np.isfinite(vals)
+            idx = np.where(invalid, -1, idx)
+            vals = np.where(invalid, 0.0, vals)
+            table = Table({
+                self.get("user_col"): np.arange(n_users, dtype=np.float64),
+                "recommendations": idx,
+                "ratings": vals,
+            })
+            call.set(bytes_read_back=bytes_read_back)
+        return table
 
     def _save_state(self) -> dict[str, Any]:
         return {

@@ -9,6 +9,7 @@ the query object's death.
 
 from __future__ import annotations
 
+import glob
 import json
 import logging
 import os
@@ -268,6 +269,76 @@ class TestTracer:
             with tr.start_span(f"s{i}"):
                 pass
         assert [s.name for s in tr.spans()] == ["s6", "s7", "s8", "s9"]
+
+    @pytest.mark.parametrize("annotate_device,live,entered", [
+        (None, True, True),      # the default follows a live session
+        (None, False, False),
+        (True, False, True),     # explicit overrides never ask
+        (False, True, False),
+    ])
+    def test_span_enters_annotation_iff_session_live(
+            self, monkeypatch, annotate_device, live, entered):
+        from mmlspark_tpu.observability import tracing
+
+        log = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name))
+
+        monkeypatch.setattr(tracing, "_profiler_session_live", lambda: live)
+        monkeypatch.setattr(tracing, "_device_annotation", Annotation)
+        tr = Tracer(clock=FakeClock(), annotate_device=annotate_device)
+        with tr.start_span("work"):
+            pass
+        assert log == ([("enter", "work"), ("exit", "work")]
+                       if entered else [])
+        assert [s.name for s in tr.spans()] == ["work"]
+
+    def test_trace_dir_variable_alone_annotates_nothing(self, monkeypatch,
+                                                        tmp_path):
+        from mmlspark_tpu.observability import tracing
+
+        monkeypatch.setenv("MMLSPARK_TPU_TRACE_DIR", str(tmp_path))
+        monkeypatch.setattr(
+            tracing, "_device_annotation",
+            lambda name: pytest.fail("annotation entered with no session"))
+        tr = Tracer(clock=FakeClock())
+        assert tr.annotate_device is None
+        with tr.start_span("work"):
+            pass
+
+    def test_default_spans_follow_a_real_profiler_session(self, tmp_path):
+        """No patching: the liveness check is jaxlib's own, asked at span
+        entry, so one tracer annotates inside a session and not around
+        it."""
+        import jax
+        from jax.profiler import ProfileData
+        from mmlspark_tpu.observability import tracing
+
+        tr = Tracer()
+        assert not tracing._profiler_session_live()
+        with tr.start_span("before"):
+            pass
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert tracing._profiler_session_live()
+            with tr.start_span("during"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        assert not tracing._profiler_session_live()
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        names = {e.name for plane in ProfileData.from_file(path).planes
+                 for line in plane.lines for e in line.events}
+        assert "during" in names and "before" not in names
 
 
 # --------------------------------------------------------------------- #
