@@ -28,11 +28,18 @@ microsecond; no option or environment variable is read.
 Spans inside `SARModel.recommend_for_all_users` (process-default tracer):
 `sar.recommend_all`, one a call and parent of the rest (arguments `users`,
 `items`, `k`, `block`, `blocks`, `remove_seen` and, set at the end,
-`bytes_read_back`), and one a block of `sar.slice` (`lo`, `hi`: the block's
-affinity and seen rows cut out of the resident arrays), `sar.dispatch` (the
-jitted top-k call until it returns its futures), `sar.wait`
-(`block_until_ready` on them) and `sar.readback` (`bytes`: both
-`np.asarray` with their casts).
+`bytes_read_back` and `dispatched_ahead`: the blocks that were enqueued
+while an earlier block had not been read back yet, `blocks - 1` when the
+look-ahead engages), and one a block of `sar.slice` (`lo`, `hi`: making the
+start row the jitted program cuts the block's rows at; the rows themselves
+are cut on the device, inside that program), `sar.dispatch` (the jitted
+top-k call until it returns its futures, and asking for their copy to the
+host), `sar.wait` (`block_until_ready` on them) and `sar.readback` (`bytes`:
+both copies into the call's 64-bit result arrays and the marking of the
+ranks a user has no unseen item for).
+The order interleaves: `sar.slice` and `sar.dispatch` of block b+1 come
+before `sar.wait` and `sar.readback` of block b, so at most two blocks are
+in flight and the last is drained after the loop.
 
 The disabled path is a no-op fast path: one attribute check, a shared
 null context manager — no allocation, no locks, no contextvar writes.

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.dataplane import AsyncReadback
 from ..core.params import Param
 from ..core.pipeline import Estimator, Model
 from ..core.schema import Table
@@ -41,14 +42,26 @@ def _affinity_scores(affinity, similarity):
     return affinity @ similarity
 
 
-@partial(jax.jit, static_argnames=("k",))
-def _block_topk(affinity_rows, similarity, k):
-    return jax.lax.top_k(affinity_rows @ similarity, k)
+# A block's rows are cut inside the program (the whole resident arrays go
+# in, `start` is traced, `rows` static), so a block is one enqueue. The
+# barrier keeps the affinity rows an operand of their own: XLA then writes
+# them once, already rounded for the MXU, and the product runs at 95% of
+# its roofline on a v5e; cut inside the product's fusion the same rows
+# cost it 13% (PERF.md, PR 25).
+def _block_scores(affinity, similarity, start, rows):
+    block = jax.lax.dynamic_slice_in_dim(affinity, start, rows)
+    return jax.lax.optimization_barrier(block) @ similarity
 
 
-@partial(jax.jit, static_argnames=("k",))
-def _block_topk_unseen(affinity_rows, similarity, seen_rows, k):
-    scores = affinity_rows @ similarity
+@partial(jax.jit, static_argnames=("rows", "k"))
+def _block_topk(affinity, similarity, start, rows, k):
+    return jax.lax.top_k(_block_scores(affinity, similarity, start, rows), k)
+
+
+@partial(jax.jit, static_argnames=("rows", "k"))
+def _block_topk_unseen(affinity, similarity, seen, start, rows, k):
+    scores = _block_scores(affinity, similarity, start, rows)
+    seen_rows = jax.lax.dynamic_slice_in_dim(seen, start, rows)
     return jax.lax.top_k(jnp.where(seen_rows, -jnp.inf, scores), k)
 
 
@@ -184,7 +197,7 @@ class SARModel(Model):
     _device_cache: "dict[str, Any] | None" = None
 
     # rows per device block in recommend_for_all_users: bounds peak device
-    # memory at block×I instead of U×I
+    # memory at two blocks×I instead of U×I
     USER_BLOCK = 4096
 
     def _device_arrays(self) -> dict[str, Any]:
@@ -226,8 +239,10 @@ class SARModel(Model):
         """Reference: SARModel.recommendForAllUsers (SARModel.scala:95-130).
         Returns Table{user, recommendations, ratings} with top-k item ids.
 
-        Scores `user_block` users at a time so peak device memory is
-        block×I rather than U×I; matmul rows and top_k are row-independent,
+        Scores `user_block` users at a time, the rows cut inside the jitted
+        program, and enqueues the next block before it reads this one back:
+        at most two blocks are in flight, so peak device memory is two
+        blocks×I rather than U×I. Matmul rows and top_k are row-independent,
         so the blocked result is byte-identical to the single big matmul."""
         dev = self._device_arrays()
         n_users, n_items = self.user_affinity.shape
@@ -239,47 +254,61 @@ class SARModel(Model):
                 "sar.recommend_all", users=n_users, items=n_items, k=k,
                 block=block, blocks=-(-n_users // block),
                 remove_seen=mask_seen) as call:
-            vals_parts, idx_parts = [], []
-            bytes_read_back = 0
-            for lo in range(0, n_users, block):
-                hi = min(lo + block, n_users)
-                with tracer.start_span("sar.slice", lo=lo, hi=hi):
-                    aff = dev["affinity"][lo:hi]
-                    seen = dev["seen"][lo:hi] if mask_seen else None
-                with tracer.start_span("sar.dispatch"):
-                    if mask_seen:
-                        v, i = _block_topk_unseen(
-                            aff, dev["similarity"], seen, k)
-                    else:
-                        v, i = _block_topk(aff, dev["similarity"], k)
-                # a block's seen rows go when its scores are done, not
-                # when the next block's have been cut as well
-                del seen
+            vals = np.empty((n_users, k), np.float64)
+            idx = np.empty((n_users, k), np.int64)
+
+            def read_back(enqueued) -> int:
+                lo, hi, v, i = enqueued
                 # np.asarray would wait for the same buffers: waiting here
                 # first changes no order and tells waiting from copying
                 with tracer.start_span("sar.wait"):
                     jax.block_until_ready((v, i))
                 block_bytes = v.nbytes + i.nbytes
                 with tracer.start_span("sar.readback", bytes=block_bytes):
-                    vals_parts.append(np.asarray(v, np.float64))
-                    idx_parts.append(np.asarray(i, np.int64))
-                bytes_read_back += block_bytes
-            vals = (np.concatenate(vals_parts) if vals_parts
-                    else np.zeros((0, k), np.float64))
-            idx = (np.concatenate(idx_parts) if idx_parts
-                   else np.zeros((0, k), np.int64))
-            # users with fewer than k unseen items: top_k still returns the
-            # -inf (seen) entries — mark them invalid (id -1) instead of
-            # leaking seen items back as 0-rated recommendations
-            invalid = ~np.isfinite(vals)
-            idx = np.where(invalid, -1, idx)
-            vals = np.where(invalid, 0.0, vals)
+                    block_vals, block_idx = vals[lo:hi], idx[lo:hi]
+                    block_vals[...] = v
+                    block_idx[...] = i
+                    # users with fewer than k unseen items: top_k still
+                    # returns the -inf (seen) entries — mark them invalid
+                    # (id -1) instead of leaking seen items back as
+                    # 0-rated recommendations
+                    invalid = ~np.isfinite(block_vals)
+                    block_idx[invalid] = -1
+                    block_vals[invalid] = 0.0
+                return block_bytes
+
+            if mask_seen:
+                enqueue = partial(_block_topk_unseen, dev["affinity"],
+                                  dev["similarity"], dev["seen"])
+            else:
+                enqueue = partial(_block_topk, dev["affinity"],
+                                  dev["similarity"])
+            # one block of look-ahead: block b+1 is enqueued before the
+            # host waits for block b, so the device has work queued while
+            # the host reads back, casts and marks
+            pipeline = AsyncReadback(read_back, lag=1)
+            bytes_read_back = dispatched_ahead = 0
+            for lo in range(0, n_users, block):
+                hi = min(lo + block, n_users)
+                with tracer.start_span("sar.slice", lo=lo, hi=hi):
+                    start = np.int32(lo)
+                with tracer.start_span("sar.dispatch"):
+                    v, i = enqueue(start, hi - lo, k)
+                    # the copy back starts when the block is done, under
+                    # the next block's work, not when the host asks
+                    v.copy_to_host_async()
+                    i.copy_to_host_async()
+                # 1 while the block before this one has not been read back
+                dispatched_ahead += pipeline.pending
+                bytes_read_back += sum(pipeline.push((lo, hi, v, i)))
+            bytes_read_back += sum(pipeline.drain())
             table = Table({
                 self.get("user_col"): np.arange(n_users, dtype=np.float64),
                 "recommendations": idx,
                 "ratings": vals,
             })
-            call.set(bytes_read_back=bytes_read_back)
+            call.set(bytes_read_back=bytes_read_back,
+                     dispatched_ahead=dispatched_ahead)
         return table
 
     def _save_state(self) -> dict[str, Any]:
