@@ -21,6 +21,7 @@ from dataclasses import dataclass
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
+TRANSFER_IN = "tpu::System::TransferToDevice"
 SHORT_GAP = 100e-6      # seconds
 HEAD_EVENTS = 2000
 
@@ -111,14 +112,34 @@ def is_matmul_fusion(name: str) -> bool:
     return "kind=kOutput" in name or " convolution(" in name
 
 
+def transfers_to_device(host_plane) -> list:
+    """[Event] per host-to-device transfer, from the runtime's call that
+    issues it (on whichever thread uploads) to the event that reports it
+    done (on the runtime's own worker): the two carry one flow id, `_p` on
+    the issue and `_c` on the completion (host events: `run.py` traces at
+    `host_tracer_level` 1)."""
+    issued, done = {}, {}
+    for line in host_plane.lines:
+        for e in line.events:
+            if e.name == TRANSFER_IN:
+                issued[dict(e.stats).get("_p")] = e.start_ns * 1e-9
+            elif e.name == TRANSFER_IN + "=>IssueEvent=>Done":
+                done[dict(e.stats).get("_c")] = (
+                    e.start_ns + e.duration_ns) * 1e-9
+    return [Event(TRANSFER_IN, start, done[flow])
+            for flow, start in issued.items()
+            if flow is not None and done.get(flow, start) > start]
+
+
 class Trace:
     """One traced window: device operations per chip, host events of the
     thread that carried the benchmark's annotations."""
 
     def __init__(self, device_ops: dict, host_events: list,
-                 t0: float, t1: float):
+                 t0: float, t1: float, transfers_in=()):
         self.device_ops = device_ops          # plane name -> [Event]
         self.host_events = host_events        # [Event], one thread
+        self.transfers_in = list(transfers_in)   # [Event], any thread
         self.t0, self.t1 = t0, t1
         self._own = None                      # [(event, self seconds)]
 
@@ -131,7 +152,7 @@ class Trace:
         from jax.profiler import ProfileData
 
         data = ProfileData.from_file(path)
-        device_ops, host_lines = {}, []
+        device_ops, host_lines, transfers_in = {}, [], []
         for plane in data.planes:
             if DEVICE_PLANE.match(plane.name):
                 for line in plane.lines:
@@ -143,6 +164,7 @@ class Trace:
                                   (e.start_ns + e.duration_ns) * 1e-9)
                             for e in line.events]
             elif plane.name == HOST_PLANE:
+                transfers_in = transfers_to_device(plane)
                 for line in plane.lines:
                     # a thread's line is sorted by start, and a traced call
                     # opens with its annotation: a line that shows none
@@ -160,7 +182,7 @@ class Trace:
         every = [e for ops in device_ops.values() for e in ops] + spans
         t0 = min((e.start for e in every), default=0.0)
         t1 = max((e.end for e in every), default=0.0)
-        return Trace(device_ops, host, t0, t1)
+        return Trace(device_ops, host, t0, t1, transfers_in)
 
     # -- device ------------------------------------------------------- #
 

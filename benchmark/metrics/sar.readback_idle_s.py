@@ -1,6 +1,7 @@
 """Seconds of a traced call in which the device ran nothing while the host
-was inside a `sar.readback` span (device trace): what dispatching the next
-block before reading this one back would hide."""
+was inside a `sar.readback` span (device trace). Every block but the last
+is read back with the next one queued on the device, so this is the last
+block's readback: what the look-ahead has left to hide."""
 from harness.program_spans import idle_seconds_inside
 
 

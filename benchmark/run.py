@@ -7,7 +7,9 @@ Set-up (inputs and weights from the seed, one warm-up call at the cell's own
 shape) is timed from process start to the first timed call and reported as
 `setup_s`. Then calls are made back to back (harness/window.py), then what
 they produced is compared with the plain reference, each number beside its
-limit. The last line of standard output is the result, one JSON object.
+limit (on standard output as they are read, and again as the last lines of
+standard error and the last key, `checks`, of the result). The last line of
+standard output is the result, one JSON object.
 `--trace 0` reports the cell's end-to-end metrics; `--trace 1` makes a few
 more whole calls under the profiler after the window and the comparison
 (`trace_calls` in the traffic file) and reports the cell's per-layer
@@ -140,6 +142,16 @@ def main(argv=None) -> int:
               "compiles_in_window": compiled_in_window}
     if trace is not None:
         result["breakdown"] = trace.breakdown((adapter.annotation,))
+    # each number compared beside its limit, once more: the last lines of
+    # standard error and the last key of the result are what a record of a
+    # run that was not correct keeps
+    result["checks"] = {          # a NaN would not be JSON
+        name: {"value": value if value == value else None, "limit": limit}
+        for name, value, limit in checks}
+    for name, value, limit in checks:
+        print(f"check {name}: {value:.6g} (limit {limit:.6g})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

@@ -31,11 +31,47 @@ TINY_TRAFFIC = {
         "user_block": None, "sample_users": 64, "trace_calls": 1,
         "limits": LIMITS},
 }
-# (cell, configuration, traffic, chips); each reports what
-# `sar_recommend_all` does
+TINY_ENCODER = {
+    "name": "tiny_encoder", "family": "transformer",
+    "reference": "transformer", "architecture": "transformer",
+    "precision": "float32", "vocab_size": 50,
+    "model": {"num_layers": 2, "d_model": 32, "num_heads": 4, "d_ff": 64,
+              "num_outputs": 3, "vocab_size": 50, "max_len": 24,
+              "attention_impl": "flash"},
+}
+SCORE_LIMITS = {"output_gap_p99": 1e-4, "output_gap_max": 1e-4,
+                "pad_leak": 1e-4, "nonfinite": 0,
+                "rows_or_positions_missing": 0, "call_mismatch": 0}
+TINY_SCORE_TRAFFIC = {
+    # one length, one dispatch for the whole table, 44 = 5 x 8 + 4 rows
+    "tiny_score_fused": {
+        "adapter": "dnn_transform", "rows": 44, "lengths": 16,
+        "mini_batch_size": 8, "bfloat16": False, "fused_dispatch": True,
+        "fetch_dict": {"pooled": "pooled_features"}, "sample_rows": 44,
+        "trace_calls": 1, "limits": SCORE_LIMITS},
+    # two lengths (37 rows of 24, 24 of 12), batch by batch; the longer
+    # length's last batch holds 5 rows and is padded to 8; two fetched
+    # columns
+    "tiny_score_streamed": {
+        "adapter": "dnn_transform", "rows": 61,
+        "lengths": [[24, 0.6], [12, 0.4]], "mini_batch_size": 8,
+        "bfloat16": False, "fused_dispatch": False,
+        "fetch_dict": {"pooled": "pooled_features", "scores": "logits"},
+        "sample_rows": 61, "trace_calls": 2, "limits": SCORE_LIMITS},
+}
+# (cell, configuration, traffic, chips); the first two report what
+# `sar_recommend_all` does, the last two the neural scoring lane's metrics
 TINY_CELLS = [("tiny_sar_all", "tiny_sar", "tiny_recommend_all", 1),
-              ("tiny_sar_top3", "tiny_sar", "tiny_recommend_top3", 1)]
+              ("tiny_sar_top3", "tiny_sar", "tiny_recommend_top3", 1),
+              ("tiny_score_fused", "tiny_encoder", "tiny_score_fused", 1),
+              ("tiny_score_streamed", "tiny_encoder", "tiny_score_streamed",
+               1)]
+SAR_CELLS = [cell for cell, config, *_rest in TINY_CELLS
+             if config == "tiny_sar"]
+SCORE_CELLS = [cell for cell, config, *_rest in TINY_CELLS
+               if config == "tiny_encoder"]
 SIBLING = "sar_recommend_all"
+SCORE_RATE = "transform_tokens_per_s"
 
 
 def _write(path, obj):
@@ -45,19 +81,22 @@ def _write(path, obj):
 
 @pytest.fixture(scope="session")
 def tiny_checkout(tmp_path_factory):
-    """A copy of the benchmark's files with two tiny cells added AS FILES
-    ONLY (a config, a traffic mix each, a `workloads` entry each): what a
-    later PR does to add a cell."""
+    """A copy of the benchmark's files with four tiny cells added AS FILES
+    ONLY (a configuration each, the neural one under the reference that
+    `reference/transformer.py` already is; a traffic mix each; a `workloads` entry each; their names appended to the
+    metrics' `workloads` lists): what a later PR does to add a cell."""
     root = tmp_path_factory.mktemp("checkout")
     shutil.copytree(BENCH_DIR, root / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
-    _write(root / "benchmark" / "configs" / "tiny_sar.json", TINY_SAR)
-    bench["configs"].append({
-        "name": "tiny_sar", "source": "test", "reduced": [],
-        "file": "benchmark/configs/tiny_sar.json", "why": "test"})
-    for name, traffic in TINY_TRAFFIC.items():
+    for config in (TINY_SAR, TINY_ENCODER):
+        name = config["name"]
+        _write(root / "benchmark" / "configs" / f"{name}.json", config)
+        bench["configs"].append({
+            "name": name, "source": "test", "reduced": [],
+            "file": f"benchmark/configs/{name}.json", "why": "test"})
+    for name, traffic in {**TINY_TRAFFIC, **TINY_SCORE_TRAFFIC}.items():
         _write(root / "benchmark" / "traffic" / f"{name}.json", traffic)
     for cell, config, traffic, chips in TINY_CELLS:
         bench["workloads"].append({"name": cell, "config": config,
@@ -65,7 +104,9 @@ def tiny_checkout(tmp_path_factory):
                                    "why": "test"})
     for metric in bench["end_to_end"] + bench["per_layer"]:
         if SIBLING in metric.get("workloads", ()):
-            metric["workloads"] += [cell for cell, *_rest in TINY_CELLS]
+            metric["workloads"] += SAR_CELLS
+        if SCORE_RATE in (metric["name"], metric.get("moves")):
+            metric["workloads"] += SCORE_CELLS
     _write(root / "BENCHMARK.json", bench)
     return root
 

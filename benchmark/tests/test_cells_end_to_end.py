@@ -27,7 +27,13 @@ def test_cell_reports_its_end_to_end_metrics(tiny_checkout, cell):
     assert "setup_s" in names and len(names) == 2
     assert all(m["value"] > 0 for m in line["metrics"].values())
     assert line["compiles_in_window"] == 0
-    assert "check " in proc.stdout          # every number beside its limit
+    # every number beside its limit: as it is read, as the last lines of
+    # standard error and as the last key of the result
+    assert "check " in proc.stdout
+    assert list(line)[-1] == "checks" and line["checks"]
+    tail = proc.stderr.strip().splitlines()[-len(line["checks"]):]
+    assert [t.split()[1].rstrip(":") for t in tail] == list(line["checks"])
+    assert all(c["value"] <= c["limit"] for c in line["checks"].values())
 
 
 @pytest.mark.parametrize("cell", CELLS)

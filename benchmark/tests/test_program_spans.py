@@ -17,8 +17,7 @@ from harness.window import Call
 
 RECORDED = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
                         "sar_spans_v5e.xplane.pb")
-RING = ["sar.slice_s", "sar.dispatch_s", "sar.wait_s", "sar.readback_s",
-        "sar.call_self_s"]
+RING = ["sar.dispatch_s", "sar.wait_s", "sar.readback_s", "sar.call_self_s"]
 TRACED = ["sar.readback_idle_s", "sar.idle_named_share"]
 TICK = 0.001
 
@@ -68,8 +67,9 @@ def ring():
     set_default_tracer(old)
 
 
-def _run(window_calls: int, trace_calls: int = 2) -> dict:
-    cell = types.SimpleNamespace(traffic={"trace_calls": trace_calls})
+def _run(window_calls: int, trace_calls: int = 2, **traffic) -> dict:
+    cell = types.SimpleNamespace(
+        traffic={"trace_calls": trace_calls, **traffic})
     return {"cell": cell, "trace": None, "annotation": "recommend.call",
             "calls": [Call(float(i), i + 0.5) for i in range(window_calls)]}
 
@@ -87,11 +87,20 @@ def test_ring_metrics_add_up_to_the_call(ring):
         "sar.readback": 3 * TICK, "self": 13 * TICK})
     got = {name: load_module("metrics", name).read(run) for name in RING}
     assert got == pytest.approx({
-        "sar.slice_s": 3 * TICK, "sar.dispatch_s": 3 * TICK,
-        "sar.wait_s": 3 * TICK, "sar.readback_s": 3 * TICK,
-        "sar.call_self_s": 13 * TICK})
-    assert sum(got.values()) == pytest.approx(
+        "sar.dispatch_s": 3 * TICK, "sar.wait_s": 3 * TICK,
+        "sar.readback_s": 3 * TICK, "sar.call_self_s": 13 * TICK})
+    # with `sar.slice` (which no metric reads any more) they are the call
+    assert sum(got.values()) + 3 * TICK == pytest.approx(
         program_spans.median_seconds(run, program_spans.SAR_ROOT))
+    # the root span's arguments: 3 blocks, the 2nd and 3rd enqueued while
+    # the one before was unread
+    (root_args,) = program_spans.window_args(run, program_spans.SAR_ROOT)[0]
+    assert (root_args["blocks"], root_args["dispatched_ahead"]) == (3, 2)
+    # every block that can be ahead was: 2 of 3 - 1
+    assert load_module("metrics", "sar.dispatched_ahead_share").read(
+        run) == pytest.approx(100.0)
+    assert [a["hi"] for a in program_spans.window_args(
+        run, "sar.slice")[0]] == [16, 32, 40]
 
 
 @pytest.mark.parametrize("made,window", [
@@ -106,7 +115,7 @@ def test_nothing_is_read_when_the_roots_do_not_add_up(ring, capsys, made,
     run = _run(window)
     assert program_spans.window_calls(run) is None
     assert all(load_module("metrics", name).read(run) is None
-               for name in RING)
+               for name in RING + ["sar.dispatched_ahead_share"])
     assert "expected" in capsys.readouterr().err
 
 
@@ -205,3 +214,88 @@ def test_no_device_plane_or_no_spans_reads_nothing(device, host):
                for name in TRACED)
     assert all(load_module("metrics", name).read(dict(run, trace=None))
                is None for name in TRACED)
+
+
+# ---- the runner's spans: several roots a call, arguments ---------------- #
+
+@pytest.fixture
+def runner_ring():
+    """The process-default tracer, and `transform(rows, batch)` that opens
+    the spans the streamed path of `DeepModelTransformer` opens, a full
+    batch padded to itself and a ragged one to the next power of two."""
+    from mmlspark_tpu.observability import Tracer, set_default_tracer
+
+    tracer = Tracer(clock=TickingClock())
+    old = set_default_tracer(tracer)
+
+    def transform(rows, batch):
+        with tracer.start_span("runner.transform", rows=rows,
+                               batch_size=batch):
+            for lo in range(0, rows, batch):
+                m = min(batch, rows - lo)
+                with tracer.start_span("runner.step", rows=m,
+                                       padded=1 << (m - 1).bit_length()):
+                    pass
+
+    yield transform
+    set_default_tracer(old)
+
+
+TWO_LENGTHS = {"rows": 61, "lengths": [[24, 0.6], [12, 0.4]]}
+
+
+def test_pad_share_reads_the_steps_arguments(runner_ring):
+    # a call is two tables (two lengths): 37 rows and 24 rows in batches
+    # of 8, so 61 real rows in 64 scored
+    for _ in range(1 + 4 + 2):
+        runner_ring(37, 8)
+        runner_ring(24, 8)
+    run = _run(4, **TWO_LENGTHS)
+    assert load_module("metrics", "runner.pad_share").read(
+        run) == pytest.approx(100.0 * 3 / 64)
+    steps = program_spans.window_args(run, "runner.step",
+                                      "runner.transform", 2)
+    assert len(steps) == 4 and len(steps[0]) == 5 + 3
+    sums = program_spans.window_calls(run, "runner.transform", 2)
+    assert sums[0]["runner.step"] == pytest.approx(8 * TICK)
+
+
+@pytest.mark.parametrize("tables", [
+    0,      # the fused one-dispatch path opens no `runner.*` span
+    10,     # roots that do not divide into 1 + 4 + 2 calls
+    21,     # three a call where the traffic file's two lengths make two
+])
+def test_pad_share_reads_nothing_without_whole_calls(runner_ring, capsys,
+                                                     tables):
+    for _ in range(tables):
+        runner_ring(37, 8)
+    assert load_module("metrics", "runner.pad_share").read(
+        _run(4, **TWO_LENGTHS)) is None
+    assert "expected" in capsys.readouterr().err
+
+
+def test_h2d_share_is_the_union_of_transfers_inside_the_calls():
+    # two calls of 10 s; transfers 1..3 and 2..4 overlap (union 3 s), one
+    # straddles the second call's end (1 s inside), one lies between calls
+    trace = _trace([("fusion", 2.0, 6.0)],
+                   [("transform.call", 0.0, 10.0),
+                    ("transform.call", 12.0, 22.0)])
+    trace.transfers_in = [Event("t", 1.0, 3.0), Event("t", 2.0, 4.0),
+                          Event("t", 21.0, 23.0), Event("t", 10.5, 11.5)]
+    run = {"trace": trace, "annotation": "transform.call"}
+    read = load_module("metrics", "runner.h2d_share").read
+    assert read(run) == pytest.approx(100.0 * 4.0 / 20.0)
+    trace.transfers_in = []          # a trace without transfer events
+    assert read(run) is None and read({"trace": None}) is None
+
+
+def test_recorded_v5e_trace_pairs_each_transfer_with_its_completion():
+    # 18 blocks, four small uploads each (PR 24's program sliced on the
+    # host): 72 transfers, each issued on the calling side and reported
+    # done on the runtime's worker 0.1 to 0.3 ms later
+    trace = Trace.from_file(RECORDED, annotations=("recommend.call",))
+    assert len(trace.transfers_in) == 72
+    assert all(0.05e-3 < t.seconds < 0.5e-3 for t in trace.transfers_in)
+    (call,) = trace.spans("recommend.call")
+    assert all(call.start < t.start and t.end < call.end
+               for t in trace.transfers_in)

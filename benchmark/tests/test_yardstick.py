@@ -2,17 +2,19 @@
 counts against hand-worked values, the trace reduction on a recorded
 trace, the seeded generators."""
 
+import json
 import os
 
 import numpy as np
 import pytest
 
-from harness import counts, data, window
+from harness import cells, counts, data, window
 from harness.cells import load_module
 from harness.trace import (Event, Trace, gaps, is_matmul_fusion, is_pallas,
                            is_top_k, self_seconds, short_name, union_seconds)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(os.path.dirname(HERE))
 RECORDED = os.path.join(HERE, "data", "tiny_v5e.xplane.pb")
 RECORDED_SAR = os.path.join(HERE, "data", "sar_v5e.xplane.pb")
 
@@ -75,6 +77,138 @@ def test_sar_scores_are_bound_by_operations():
     least, bound = counts.least_seconds(
         need, {"flops_per_s": 197e12, "bytes_per_s": 819e9})
     assert bound == "ops" and least == pytest.approx(80.9e-3, rel=1e-2)
+
+
+def _load_reference():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "reference_transformer",
+        os.path.join(os.path.dirname(HERE), "reference", "transformer.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_encoder_operations_against_hand_worked_values():
+    # 2 layers, width 32, feed-forward 64, 3 outputs; per token and layer
+    # 4 x 32 x 32 + 2 x 32 x 64 = 8192 weights, two operations each
+    ref = _load_reference()
+    config = {"model": {"num_layers": 2, "d_model": 32, "num_heads": 4,
+                        "d_ff": 64, "num_outputs": 3, "vocab_size": 50,
+                        "max_len": 24}}
+    need = ref.operations(config, [(24, 37), (12, 24)])
+    tokens = 37 * 24 + 24 * 12
+    scores = 2 * 4 * 32 * (37 * 24 * 24 + 24 * 12 * 12)
+    assert need["ops"] == tokens * 2 * 2 * 8192 + scores + 61 * 2 * 32 * 3
+    assert need["bytes"] == 2 * (2 * 8192 + 96) + 4 * tokens + 4 * 61 * 32
+
+
+def test_xlmr_xxl_runs_the_published_widths():
+    """The configuration's file states the source's keys; what the program
+    is built from (`model`) repeats them, every key `reduced` in
+    `BENCHMARK.json` is explained in the file, and the operations of its
+    cell's table are the hand-worked 283.2 TFLOP a call."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    entry = {c["name"]: c for c in bench["configs"]}["xlmr_xxl"]
+    with open(os.path.join(REPO, entry["file"])) as fh:
+        config = json.load(fh)
+    model = config["model"]
+    assert (config["hidden_size"], config["intermediate_size"],
+            config["num_attention_heads"], config["vocab_size"],
+            config["max_position_embeddings"]) == (4096, 16384, 32, 250880,
+                                                   514)
+    assert (model["d_model"], model["d_ff"], model["num_heads"],
+            model["vocab_size"], model["max_len"], model["num_layers"]) == (
+        config["hidden_size"], config["intermediate_size"],
+        config["num_attention_heads"], config["vocab_size"],
+        config["max_position_embeddings"], config["num_hidden_layers"])
+    assert sorted(entry["reduced"]) == sorted(config["reduced"])
+    cell = cells.load_cell("xlmr_xxl.score_table")
+    groups = data.length_groups(cell.traffic["rows"],
+                                cell.traffic["lengths"])
+    assert groups == [(512, 180), (128, 180)]
+    need = _load_reference().operations(config, groups)
+    layer = 4 * 4096 * 4096 + 2 * 4096 * 16384          # 201.3M weights
+    assert need["ops"] == pytest.approx(
+        115200 * 2 * 6 * layer                          # projections, ffn
+        + 6 * 4 * 4096 * 180 * (512 * 512 + 128 * 128)  # scores, values
+        + 360 * 2 * 4096 * 16, rel=1e-12)               # the head
+    assert need["ops"] == pytest.approx(283.2e12, rel=1e-3)
+    assert [m["name"] for m in cell.end_to_end] == [
+        "transform_tokens_per_s", "setup_s"]
+    assert [m["name"] for m in cell.per_layer] == [
+        "runner.call_s", "runner.host_s", "runner.mfu", "runner.h2d_share",
+        "runner.pad_share"]
+
+
+def test_the_reference_is_the_programs_module_to_float32():
+    """The plain reference and the program's `TransformerEncoder` given the
+    reference's weights under the module's names agree to float32's
+    rounding, for each output the reference knows."""
+    import jax
+
+    from mmlspark_tpu.nn.models import make_model
+
+    ref = _load_reference()
+    config = {"model": {"num_layers": 2, "d_model": 32, "num_heads": 4,
+                        "d_ff": 64, "num_outputs": 3, "vocab_size": 50,
+                        "max_len": 24}}
+    w = ref.weights(data.device_key(5, 21), config)
+    ids = data.rng_for(5, 22).integers(0, 50, (6, 24), dtype=np.int32)
+    module = make_model("transformer", **config["model"])
+    with jax.default_matmul_precision("highest"):
+        logits, state = module.apply(
+            ref.variables(w, config), ids, train=False,
+            capture_intermediates=True, mutable=["intermediates"])
+    caught = state["intermediates"]
+    got = {"logits": logits, "probability": jax.nn.softmax(logits, -1),
+           "pooled_features": caught["pooled_features"][0],
+           "ln_final": caught["ln_final"]["__call__"][0]}
+    for fetch, value in got.items():
+        np.testing.assert_allclose(ref.outputs(w, config, ids, fetch),
+                                   np.asarray(value), rtol=0, atol=2e-5,
+                                   err_msg=fetch)
+
+
+# ---- seeded sizes and the control's rounding --------------------------- #
+
+@pytest.mark.parametrize("rows,lengths,expected", [
+    (44, 16, [(16, 44)]),
+    (61, [[24, 0.6], [12, 0.4]], [(24, 37), (12, 24)]),   # 36.6 -> 36 + 1
+    (10, [[8, 1], [64, 1], [16, 0]], [(64, 5), (8, 5)]),  # longest first
+])
+def test_length_groups_fix_the_sizes_for_every_seed(rows, lengths, expected):
+    assert data.length_groups(rows, lengths) == expected
+    assert sum(n for _l, n in expected) == rows
+
+
+def test_through_rounds_matrices_and_keeps_vectors():
+    import jax.numpy as jnp
+
+    from harness import precision
+
+    def tree():                 # `through` spends the tree it is given
+        return {"kernel": jnp.linspace(-1.0, 1.0, 64).reshape(8, 8) / 3.0,
+                "bias": jnp.linspace(-1.0, 1.0, 8) / 3.0}
+
+    kept = {k: np.asarray(v) for k, v in tree().items()}
+    worst = {}
+    for name in ("bfloat16", "int8", "fp8"):
+        spent = tree()
+        low = precision.through(spent, name)
+        assert spent["kernel"].is_deleted()
+        assert np.array_equal(low["bias"], kept["bias"])
+        worst[name] = float(np.abs(np.asarray(low["kernel"])
+                                   - kept["kernel"]).max())
+    # half a step of each grid at the tensor's largest value, 1/3: 8 bits
+    # of [1/4, 1/2); 127 steps to 1/3; 4 bits of [256, 512) scaled to 448
+    assert 0 < worst["bfloat16"] <= 2.0 ** -10
+    assert worst["bfloat16"] < worst["int8"] <= 1 / 3 / 127 / 2 * 1.01
+    assert worst["int8"] < worst["fp8"] <= 1 / 3 * 16 / 448 * 1.01
+    with pytest.raises(ValueError):
+        precision.through(tree(), "int3")
 
 
 # ---- the trace reduction ---------------------------------------------- #
