@@ -5,7 +5,9 @@ module is the single-device half of the beyond-reference attention stack —
 the cross-device half (ring / Ulysses sequence parallelism over the mesh)
 lives in `parallel.ring_attention` and implements identical math.
 
-Three tiers, one contract (inputs (B, T, H, D), output (B, T, H, D)):
+Three tiers, one contract (q, k (B, T, H, D), v (B, T, H, Dv), output
+(B, T, H, Dv); the chunked and flash tiers take Dv != D, which latent
+attention needs):
 
 - ``dense_attention`` (re-exported from parallel.ring_attention): full
   (T, T) score matrix. The reference implementation every other tier is
@@ -64,13 +66,16 @@ def chunked_attention(q, k, v, causal: bool = False,
                       q_chunk: int = 128, k_chunk: int = 128):
     """Online-softmax attention over k/v chunks; O(T) memory.
 
-    q: (B, Tq, H, D); k, v: (B, Tk, H, D) -> (B, Tq, H, D), matching
-    `dense_attention` (tested bit-close against it). Differentiable —
-    XLA transposes the scan for the backward pass; pair with
-    `jax.checkpoint` on the caller for long sequences.
+    q: (B, Tq, H, D); k: (B, Tk, H, D); v: (B, Tk, H, Dv) -> (B, Tq, H, Dv),
+    matching `dense_attention` (tested bit-close against it). The values
+    may be narrower or wider than the scores' channels (latent attention:
+    192 for scores, 128 for values). Differentiable — XLA transposes the
+    scan for the backward pass; pair with `jax.checkpoint` on the caller
+    for long sequences.
     """
     orig_dtype = q.dtype
     b, tq_orig, h, d = q.shape
+    dv = v.shape[-1]
     tk_orig = k.shape[1]
     q_chunk = min(q_chunk, max(tq_orig, 1))
     k_chunk = min(k_chunk, max(tk_orig, 1))
@@ -83,7 +88,7 @@ def chunked_attention(q, k, v, causal: bool = False,
     # (nq, B, qc, H, D) so scan carries one q-chunk at a time
     qr = jnp.moveaxis(q.reshape(b, nq, q_chunk, h, d), 1, 0)
     kr = jnp.moveaxis(k.reshape(b, nk, k_chunk, h, d), 1, 0)
-    vr = jnp.moveaxis(v.reshape(b, nk, k_chunk, h, d), 1, 0)
+    vr = jnp.moveaxis(v.reshape(b, nk, k_chunk, h, dv), 1, 0)
 
     kpos = jnp.arange(nk * k_chunk).reshape(nk, k_chunk)
     k_valid = kpos < tk                                       # pad mask
@@ -118,7 +123,9 @@ def chunked_attention(q, k, v, causal: bool = False,
         zvar = 0.0 * qb.astype(jnp.float32).transpose(0, 2, 1, 3)
         m0 = zvar[..., 0] + _NEG_INF                      # (B, H, qc)
         l0 = zvar[..., 0]
-        a0 = zvar                                         # (B, H, qc, D)
+        # (B, H, qc, Dv): as wide as the values
+        a0 = zvar if dv == d else jnp.broadcast_to(
+            zvar[..., :1], zvar.shape[:-1] + (dv,))
         (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0),
                                       (kr, vr, kpos, k_valid))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
@@ -128,7 +135,7 @@ def chunked_attention(q, k, v, causal: bool = False,
 
     outs = jax.lax.map(lambda xs: one_q_chunk(*xs),
                        (jnp.arange(nq), qr))                  # (nq,B,qc,H,D)
-    out = jnp.moveaxis(outs, 0, 1).reshape(b, nq * q_chunk, h, d)
+    out = jnp.moveaxis(outs, 0, 1).reshape(b, nq * q_chunk, h, dv)
     return out[:, :tq].astype(orig_dtype)
 
 
@@ -149,32 +156,62 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    qb = q_ref[0]                                             # (bq, D)
-    kb = k_ref[0]                                             # (bk, D)
-    s = jax.lax.dot_general(
-        qb, kb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale           # (bq, bk)
+    def step(mask_keys: bool, mask_causal: bool):
+        """One key block folded into the running max / denominator /
+        accumulator. `mask_keys`: keys at or past `tk_valid` are padding;
+        `mask_causal`: a query sees the keys at or before it."""
+        qb = q_ref[0]                                         # (bq, D)
+        kb = k_ref[0]                                         # (bk, D)
+        s = jax.lax.dot_general(
+            qb, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale       # (bq, bk)
 
-    kpos = kv * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    ok = kpos < tk_valid
-    if causal:
-        qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        ok = ok & (qpos >= kpos)
-    s = jnp.where(ok, s, _NEG_INF)
+        ok = None
+        if mask_keys or mask_causal:
+            kpos = kv * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+        if mask_keys:
+            ok = kpos < tk_valid
+        if mask_causal:
+            qpos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            ok = (qpos >= kpos) if ok is None else ok & (qpos >= kpos)
+        if ok is not None:
+            s = jnp.where(ok, s, _NEG_INF)
 
-    m_prev = m_sc[...]                                        # (bq, 1)
-    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-    p = jnp.exp(s - m_new)                                    # (bq, bk)
-    # masked entries must contribute 0 even when the whole row is masked
-    # (then m_new == _NEG_INF and exp(s - m_new) == 1, not 0)
-    p = jnp.where(ok, p, 0.0)
-    corr = jnp.exp(m_prev - m_new)                            # (bq, 1)
-    l_sc[...] = l_sc[...] * corr + p.sum(-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                   # (bq, D)
-    acc_sc[...] = acc_sc[...] * corr + pv
-    m_sc[...] = m_new
+        m_prev = m_sc[...]                                    # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)                                # (bq, bk)
+        if ok is not None:
+            # masked entries must contribute 0 even when the whole row is
+            # masked (then m_new == _NEG_INF and exp(s - m_new) == 1, not 0)
+            p = jnp.where(ok, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)                        # (bq, 1)
+        l_sc[...] = l_sc[...] * corr + p.sum(-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # (bq, Dv)
+        acc_sc[...] = acc_sc[...] * corr + pv
+        m_sc[...] = m_new
+
+    if not causal:
+        step(True, False)
+    else:
+        # key blocks wholly above the diagonal are skipped, not masked
+        # (their index map re-names the last block needed, so nothing is
+        # fetched for them either); blocks wholly below it need no causal
+        # mask, and only a padded sequence needs the key mask
+        padded = tk_valid < num_kv * block_k
+        needed = kv * block_k <= qi * block_q + block_q - 1
+        crosses = (kv + 1) * block_k - 1 > qi * block_q
+
+        @pl.when(needed & crosses)
+        def _diagonal():
+            step(padded, True)
+
+        @pl.when(needed & jnp.logical_not(crosses))
+        def _below():
+            step(padded, False)
 
     @pl.when(kv == num_kv - 1)
     def _finalize():
@@ -189,12 +226,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
 
 
 def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
-    """Pallas forward; returns (out (B,Tq,H,D), lse (B,H,Tq) f32)."""
+    """Pallas forward; returns (out (B,Tq,H,Dv), lse (B,H,Tq) f32). The
+    values may have a width of their own (latent attention scores over
+    192 channels and weighs values of 128)."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
     orig_dtype = q.dtype
     b, tq_orig, h, d = q.shape
+    dv = v.shape[-1]
     tk_orig = k.shape[1]
     block_q = min(block_q, max(tq_orig, 1))
     block_k = min(block_k, max(tk_orig, 1))
@@ -204,10 +244,21 @@ def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
 
     # (B*H, T, D): one grid row per (batch, head)
     def bh(x):
-        return jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], d)
+        return jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], x.shape[-1])
 
     qf, kf, vf = bh(q), bh(k), bh(v)
     nq, nk = qf.shape[1] // block_q, kf.shape[1] // block_k
+
+    if causal:
+        # a key block above the diagonal is never computed on: name the
+        # last block this query block needs instead, which is already in
+        # VMEM, so that no copy is issued for the skipped steps
+        def key_block(bh_, qi, kv):
+            last = (qi * block_q + block_q - 1) // block_k
+            return (bh_, jnp.minimum(kv, last), 0)
+    else:
+        def key_block(bh_, qi, kv):
+            return (bh_, kv, 0)
 
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k, num_kv=nk,
@@ -217,29 +268,29 @@ def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
         grid=(b * h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh_, qi, kv: (bh_, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, qi, kv: (bh_, kv, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, qi, kv: (bh_, kv, 0)),
+            pl.BlockSpec((1, block_k, d), key_block),
+            pl.BlockSpec((1, block_k, dv), key_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh_, qi, kv: (bh_, qi, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh_, qi, kv: (bh_, qi, 0)),
             # lse keeps the scratch's (block_q, 1) column layout: a
             # trailing dim equal to the array's satisfies Mosaic's block
             # rule, and no sublane->lane relayout happens in the kernel
             pl.BlockSpec((1, block_q, 1), lambda bh_, qi, kv: (bh_, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(qf.shape, orig_dtype),
+            jax.ShapeDtypeStruct(qf.shape[:2] + (dv,), orig_dtype),
             jax.ShapeDtypeStruct(qf.shape[:2] + (1,), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf)
 
-    out = out.reshape(b, h, out.shape[1], d)   # already orig_dtype via
+    out = out.reshape(b, h, out.shape[1], dv)  # already orig_dtype via
     out = jnp.moveaxis(out, 1, 2)[:, :tq]      # pallas out_shape
     lse = lse.reshape(b, h, -1)[:, :, :tq]     # (B, H, Tq)
     return out, lse
@@ -267,7 +318,7 @@ def _flash_bwd_xla(q, k, v, out, lse, do, causal, k_chunk):
     vf = jnp.moveaxis(vp_, 2, 1).astype(f32)
     nk = kf.shape[2] // k_chunk
     kr = jnp.moveaxis(kf.reshape(b, h, nk, k_chunk, d), 2, 0)
-    vr = jnp.moveaxis(vf.reshape(b, h, nk, k_chunk, d), 2, 0)
+    vr = jnp.moveaxis(vf.reshape(b, h, nk, k_chunk, v.shape[-1]), 2, 0)
     kpos = jnp.arange(nk * k_chunk).reshape(nk, k_chunk)
     qpos = jnp.arange(tq)
 
@@ -294,7 +345,8 @@ def _flash_bwd_xla(q, k, v, out, lse, do, causal, k_chunk):
     dq, (dks, dvs) = jax.lax.scan(
         body, jnp.zeros_like(qf), (kr, vr, kpos))
     dk = jnp.moveaxis(dks, 0, 2).reshape(b, h, nk * k_chunk, d)[:, :, :tk]
-    dv = jnp.moveaxis(dvs, 0, 2).reshape(b, h, nk * k_chunk, d)[:, :, :tk]
+    dv = jnp.moveaxis(dvs, 0, 2).reshape(
+        b, h, nk * k_chunk, v.shape[-1])[:, :, :tk]
     return (jnp.moveaxis(dq, 1, 2).astype(q.dtype),
             jnp.moveaxis(dk, 1, 2).astype(k.dtype),
             jnp.moveaxis(dv, 1, 2).astype(v.dtype))

@@ -35,6 +35,17 @@ post-LN models (original BERT) carry the same tensor NAMES but different
 math — importing one gives a well-formed model that is not
 weight-equivalent to its source. The spec documents naming + layout, not
 architectural equivalence.
+
+`MLA_MOE_DECODER_SPEC` maps the `deepseek_v3` checkpoint naming (latent
+attention: `q_proj`, `kv_a_proj_with_mqa`, `kv_a_layernorm`, `kv_b_proj`,
+`o_proj`; routed experts: `mlp.gate.weight`,
+`mlp.gate.e_score_correction_bias`, `mlp.experts.<n>.*`,
+`mlp.shared_experts.*`) onto nn.models.MLAMoEDecoder, and here the math IS
+the checkpoint's but for one layout: those checkpoints store a head's
+rotary channels interleaved (x0, y0, x1, y1, ...), the module rotates
+halves (x0, x1, ..., y0, y1, ...), so the rope columns of `q_proj` and of
+`kv_a_proj_with_mqa` are permuted at import (scores are unchanged: queries
+and keys are permuted alike).
 """
 
 from __future__ import annotations
@@ -54,6 +65,9 @@ __all__ = [
     "TRANSFORMER_SPEC",
     "torch_transformer_to_flax",
     "import_torch_transformer",
+    "MLA_MOE_DECODER_SPEC",
+    "torch_mla_moe_decoder_to_flax",
+    "import_torch_mla_moe_decoder",
     "import_external_weights",
     "IMPORTERS",
 ]
@@ -455,6 +469,124 @@ def import_torch_transformer(
     return _validate_and_install(bundle, variables, architecture)
 
 
+# --------------------------------------------------------------------- #
+# deepseek_v3 naming -> nn.models.MLAMoEDecoder                          #
+# --------------------------------------------------------------------- #
+
+def _rope_to_halves(rope: int) -> np.ndarray:
+    """Where each channel of the rotate-half layout lies in the
+    interleaved one: (x0, y0, x1, y1, ...) -> (x0, x1, ..., y0, y1, ...)."""
+    return np.concatenate([np.arange(0, rope, 2), np.arange(1, rope, 2)])
+
+
+def _t_mla_q_kernel(v, ctx):
+    """torch (H * (nope + rope), D) -> (D, H, nope + rope), a head's rope
+    channels from interleaved to halves."""
+    h, nope, rope = (ctx["num_heads"], ctx["qk_nope_head_dim"],
+                     ctx["qk_rope_head_dim"])
+    w = np.transpose(v, (1, 0)).reshape(v.shape[1], h, nope + rope)
+    return np.concatenate(
+        [w[..., :nope], w[..., nope:][..., _rope_to_halves(rope)]], -1)
+
+
+def _t_mla_kv_a_kernel(v, ctx):
+    """torch (latent + rope, D) -> (D, latent + rope), the one rotary
+    key's channels from interleaved to halves."""
+    lat, rope = ctx["kv_lora_rank"], ctx["qk_rope_head_dim"]
+    w = np.transpose(v, (1, 0))
+    return np.concatenate(
+        [w[:, :lat], w[:, lat:][:, _rope_to_halves(rope)]], -1)
+
+
+def _t_mla_kv_b_kernel(v, ctx):
+    """torch (H * (nope + v), latent) -> (latent, H, nope + v)."""
+    h = ctx["num_heads"]
+    return np.transpose(v, (1, 0)).reshape(v.shape[1], h, v.shape[0] // h)
+
+
+_LAYER = r"model\.layers\.(?P<i>\d+)\."
+_FFN = r"(?P<proj>gate|up|down)_proj\.weight"
+# `experts_<proj>/<n>` are one expert's matrices; `torch_mla_moe_decoder_
+# to_flax` stacks the experts held into the module's (held, in, out) arrays
+MLA_MOE_DECODER_SPEC: "list[MapRule]" = [
+    MapRule(r"model\.embed_tokens\.weight", "params/embed/embedding"),
+    MapRule(_LAYER + r"input_layernorm\.weight",
+            r"params/ln_attn_\g<i>/scale"),
+    MapRule(_LAYER + r"self_attn\.q_proj\.weight",
+            r"params/mla_attn_\g<i>/q_proj/kernel", _t_mla_q_kernel),
+    MapRule(_LAYER + r"self_attn\.kv_a_proj_with_mqa\.weight",
+            r"params/mla_attn_\g<i>/kv_a_proj/kernel", _t_mla_kv_a_kernel),
+    MapRule(_LAYER + r"self_attn\.kv_a_layernorm\.weight",
+            r"params/mla_attn_\g<i>/kv_a_norm/scale"),
+    MapRule(_LAYER + r"self_attn\.kv_b_proj\.weight",
+            r"params/mla_attn_\g<i>/kv_b_proj/kernel", _t_mla_kv_b_kernel),
+    MapRule(_LAYER + r"self_attn\.o_proj\.weight",
+            r"params/mla_attn_\g<i>/out/kernel", _t_attn_out_kernel),
+    MapRule(_LAYER + r"post_attention_layernorm\.weight",
+            r"params/ln_mlp_\g<i>/scale"),
+    MapRule(_LAYER + r"mlp\.gate\.weight",
+            r"params/moe_\g<i>/router_kernel", _t_transpose),
+    MapRule(_LAYER + r"mlp\.gate\.e_score_correction_bias",
+            r"params/moe_\g<i>/router_bias"),
+    MapRule(_LAYER + r"mlp\.experts\.(?P<n>\d+)\." + _FFN,
+            r"params/moe_\g<i>/experts_\g<proj>/\g<n>", _t_transpose),
+    MapRule(_LAYER + r"mlp\.shared_experts\." + _FFN,
+            r"params/moe_\g<i>/shared/\g<proj>/kernel", _t_transpose),
+    MapRule(_LAYER + r"mlp\." + _FFN,
+            r"params/mlp_\g<i>/\g<proj>/kernel", _t_transpose),
+    MapRule(r"model\.norm\.weight", "params/ln_final/scale"),
+    MapRule(r"lm_head\.weight", "params/head_kernel", _t_transpose),
+    MapRule(r".*rotary_emb\.inv_freq", None),
+]
+
+
+def torch_mla_moe_decoder_to_flax(
+    state_dict: Mapping[str, np.ndarray], num_heads: int,
+    kv_lora_rank: int, qk_nope_head_dim: int, qk_rope_head_dim: int,
+    experts_held: "tuple[int, int] | None" = None,
+) -> dict[str, Any]:
+    """Map a `deepseek_v3`-named state dict onto nn.models.MLAMoEDecoder
+    variables. The head split and the latent's width cannot be read from
+    the fused shapes; `experts_held` (first index, count) keeps a share of
+    the routed experts (default: all the checkpoint has), while the router
+    and its selection bias keep every expert's row."""
+    out = apply_mapping_spec(state_dict, MLA_MOE_DECODER_SPEC, {
+        "num_heads": int(num_heads), "kv_lora_rank": int(kv_lora_rank),
+        "qk_nope_head_dim": int(qk_nope_head_dim),
+        "qk_rope_head_dim": int(qk_rope_head_dim)})
+    for layer in out["params"].values():
+        for proj in ("gate", "up", "down"):
+            experts = layer.get(f"experts_{proj}") if isinstance(
+                layer, dict) else None
+            if experts is None:
+                continue
+            first, count = experts_held or (0, len(experts))
+            layer[f"experts_{proj}"] = np.stack(
+                [experts[str(n)] for n in range(first, first + count)])
+    out.pop("batch_stats")
+    return out
+
+
+def import_torch_mla_moe_decoder(
+    path: str, architecture: str = "mla_moe_decoder",
+    input_shape: tuple[int, ...] = (8,), **config,
+):
+    """Load a `deepseek_v3`-named checkpoint into a ready-to-serve
+    ModelBundle of the `mla_moe_decoder` family. `config` is the module's
+    (`num_heads`, the latent's and the heads' widths, `experts_held`, ...):
+    the checkpoint's own config.json states them, its shapes do not."""
+    from .models import ModelBundle
+
+    sd = load_state_dict(path)
+    bundle = ModelBundle.init(architecture, input_shape=tuple(input_shape),
+                              seed=0, **config)
+    module = bundle.module
+    variables = torch_mla_moe_decoder_to_flax(
+        sd, module.num_heads, module.kv_lora_rank, module.qk_nope_head_dim,
+        module.qk_rope_head_dim, tuple(module.experts_held))
+    return _validate_and_install(bundle, variables, architecture)
+
+
 # architecture name -> importer; zoo.import_external dispatches here, so
 # registering a new family makes it fetchable/verifiable end to end
 IMPORTERS: "dict[str, Callable]" = {
@@ -462,6 +594,7 @@ IMPORTERS: "dict[str, Callable]" = {
     "resnet50": import_torch_resnet,
     "resnet20_cifar": import_torch_resnet,
     "transformer": import_torch_transformer,
+    "mla_moe_decoder": import_torch_mla_moe_decoder,
 }
 
 

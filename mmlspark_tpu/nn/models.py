@@ -14,6 +14,7 @@ the `dtype` argument (the standard flax mixed-precision recipe).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
 
@@ -27,6 +28,11 @@ __all__ = [
     "SimpleCNN",
     "ResNet",
     "TransformerEncoder",
+    "MLAMoEDecoder",
+    "LatentAttention",
+    "ExpertLayer",
+    "GatedFFN",
+    "RMSNorm",
     "resnet20_cifar",
     "resnet50",
     "ARCHITECTURES",
@@ -244,6 +250,294 @@ class TransformerEncoder(nn.Module):
         return nn.Dense(self.num_outputs, dtype=jnp.float32, name="head")(pooled)
 
 
+class RMSNorm(nn.Module):
+    """x / rms(x) * scale: the statistics and the product in float32."""
+
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True)
+                                + self.eps)
+        return (y * scale.astype(jnp.float32)).astype(self.dtype)
+
+
+def _rotary(x, theta: float):
+    """Rotary positions 0 .. T-1 on the channels of x (B, T, heads, c),
+    rotate-half layout (channel i pairs with channel i + c/2), computed in
+    float32. A checkpoint with the interleaved layout is permuted at import
+    (`import_weights.MLA_MOE_DECODER_SPEC`)."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freq
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    a = x[..., :half].astype(jnp.float32)
+    b = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           -1).astype(x.dtype)
+
+
+class LatentAttention(nn.Module):
+    """Causal multi-head latent attention (arXiv 2405.04434, section 2.1):
+    queries of `qk_nope + qk_rope` channels a head; ONE down-projection of
+    the input to a latent of `kv_lora_rank` channels and one rotary key of
+    `qk_rope` channels for all heads; the latent, RMS-normed, projected up
+    to every head's `qk_nope` key channels and `v_head` value channels;
+    scores over sqrt(qk_nope + qk_rope)."""
+
+    num_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    impl: str = "flash"
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y):
+        from .attention import (chunked_attention, dense_attention,
+                                flash_attention)
+
+        dt, heads, lat = self.dtype, self.num_heads, self.kv_lora_rank
+        nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                          self.v_head_dim)
+        b, t, d = y.shape
+        with jax.named_scope("mla.project"):
+            q = nn.DenseGeneral((heads, nope + rope), use_bias=False,
+                                dtype=dt, name="q_proj")(y)
+            q = jnp.concatenate(
+                [q[..., :nope], _rotary(q[..., nope:], self.rope_theta)], -1)
+            kv = nn.Dense(lat + rope, use_bias=False, dtype=dt,
+                          name="kv_a_proj")(y)
+            k_pe = _rotary(kv[:, :, None, lat:], self.rope_theta)
+            c = RMSNorm(self.eps, dt, name="kv_a_norm")(kv[..., :lat])
+            kvb = nn.DenseGeneral((heads, nope + vd), use_bias=False,
+                                  dtype=dt, name="kv_b_proj")(c)
+            k = jnp.concatenate(
+                [kvb[..., :nope],
+                 jnp.broadcast_to(k_pe, (b, t, heads, rope))], -1)
+            v = kvb[..., nope:]
+        impl = self.impl
+        if impl == "flash" and jax.default_backend() == "cpu":
+            impl = "chunked"
+        # the innermost scope names the Pallas call in a device trace
+        with jax.named_scope("mla.attend"), jax.named_scope(self.name):
+            if impl == "flash":
+                # read on a v5e (PERF.md, PR 27): the kernel alone gives
+                # 11 / 27 / 48 / 65 TFLOP/s of the causal triangle at
+                # blocks of 128 / 256 / 512 / 1024; with float32 inputs a
+                # 1024 x 1024 tile passes its 16 MB of scoped VMEM
+                block = 1024 if jnp.dtype(dt).itemsize <= 2 else 512
+                o = flash_attention(q, k, v, causal=True, block_q=block,
+                                    block_k=block)
+            elif impl == "chunked":
+                o = chunked_attention(q, k, v, causal=True)
+            elif impl == "dense":
+                o = dense_attention(q, k, v, causal=True).astype(dt)
+            else:
+                raise ValueError(f"unknown attention impl {self.impl!r}")
+        with jax.named_scope("mla.project"):
+            return nn.DenseGeneral(d, axis=(-2, -1), use_bias=False,
+                                   dtype=dt, name="out")(o)
+
+
+class GatedFFN(nn.Module):
+    """down(silu(gate y) * up y), no biases."""
+
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y):
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        hidden = nn.silu(dense(self.width, name="gate")(y).astype(
+            jnp.float32)).astype(self.dtype) * dense(self.width, name="up")(y)
+        return dense(y.shape[-1], name="down")(hidden)
+
+
+class ExpertLayer(nn.Module):
+    """Routed experts (the `experts_held` of `n_routed_experts`, top-k,
+    dropless: `parallel.moe.moe_ffn_dropless`) plus the shared feed-forward
+    every token takes. -> (output, picks (held,) int32)."""
+
+    n_routed_experts: int
+    experts_held: tuple
+    top_k: int
+    width: int
+    n_shared_experts: int = 1
+    scaling: float = 1.0
+    normalise: bool = True
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y):
+        from ..parallel.moe import moe_ffn_dropless
+
+        d, w, held = y.shape[-1], self.width, int(self.experts_held[1])
+
+        def kernel(name, shape, fan_in):
+            return self.param(name, nn.initializers.normal(fan_in ** -0.5),
+                              shape, jnp.float32)
+
+        flat = y.reshape(-1, d)
+        routed, picks = moe_ffn_dropless(
+            flat, kernel("router_kernel", (d, self.n_routed_experts), d),
+            # the selection bias (a checkpoint's `e_score_correction_bias`)
+            self.param("router_bias", nn.initializers.zeros,
+                       (self.n_routed_experts,), jnp.float32),
+            kernel("experts_gate", (held, d, w), d),
+            kernel("experts_up", (held, d, w), d),
+            kernel("experts_down", (held, w, d), w),
+            n_routed_experts=self.n_routed_experts,
+            experts_held=tuple(self.experts_held), top_k=self.top_k,
+            scaling=self.scaling, normalise=self.normalise,
+            dtype=self.dtype)
+        with jax.named_scope("moe.shared"):
+            shared = GatedFFN(self.n_shared_experts * w, self.dtype,
+                              name="shared")(flat)
+        return (routed + shared).reshape(y.shape), picks
+
+
+class MLAMoEDecoder(nn.Module):
+    """Causal decoder over token ids: latent attention, gated
+    feed-forwards (dense in the leading layers, then routed experts with a
+    shared one), RMSNorm, rotary positions on part of a head, an untied
+    head (the DeepSeek-V2/V3 block: arXiv 2405.04434 section 2.1, arXiv
+    2412.19437 section 2.1.2). The block is h = x + Attn(RMSNorm(x)),
+    out = h + FFN(RMSNorm(h)).
+
+    Scoring output: the module returns, and sows as `token_logprobs`, each
+    next token's log-probability, (rows, length - 1): the head runs over
+    `head_chunk` tokens at a time with that chunk's log-sum-exp, and only
+    the target's log-probability is kept, so the (rows x length x
+    vocabulary) logits are never whole in memory. `output="logits"`
+    returns them instead (short rows, tests).
+
+    A chip may hold a share of the model. `experts_held` (first index,
+    count) says which routed experts' weights this module has; routing is
+    always over all `n_routed_experts` and the layer adds up what its own
+    experts give (no capacity, no token ever dropped). `vocab_size` is the
+    rows of the embedding and of the head held. Per batch the module also
+    sows `moe_picks`, int32 (expert layers, experts held): the picks each
+    held expert received (`batch_counters` names it for the runner, which
+    reads it back with the batch).
+
+    Precision: products take `dtype` inputs and accumulate in float32; the
+    router's scores, the top-k, every softmax and log-sum-exp and every
+    RMSNorm's statistics are float32."""
+
+    num_layers: int = 2
+    d_model: int = 64
+    num_heads: int = 4
+    kv_lora_rank: int = 32
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    d_ff_dense: int = 128
+    first_k_dense: int = 1
+    n_routed_experts: int = 8
+    experts_held: tuple = (0, 8)    # (first index, count)
+    num_experts_per_tok: int = 3
+    d_ff_expert: int = 32
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    vocab_size: int = 256
+    max_len: int = 8192
+    # "flash": the Pallas kernel (chunked off-TPU), "chunked", "dense"
+    attention_impl: str = "flash"
+    head_chunk: int = 1024          # tokens of one block of the head
+    output: str = "token_logprobs"  # or "logits"
+    dtype: Any = jnp.float32
+
+    @property
+    def batch_counters(self) -> tuple:
+        """int32 arrays sown per batch that the runner reads back beside
+        the fetched outputs (`nn/runner.py`)."""
+        return ("moe_picks",) if self.num_layers > self.first_k_dense else ()
+
+    def _token_logprobs(self, h, ids, head):
+        """log_softmax(h @ head)[next token] for every position but a
+        row's last, `head_chunk` tokens at a time."""
+        b, t, d = h.shape
+        n = b * t
+        flat = h.reshape(n, d)
+        # the last position of a row scores a target that is cut off below
+        target = jnp.concatenate([ids[:, 1:], ids[:, :1]], 1).reshape(n)
+        chunk = min(self.head_chunk, n)
+        pad = (-n) % chunk
+        if pad:
+            flat = jnp.pad(flat, ((0, pad), (0, 0)))
+            target = jnp.pad(target, (0, pad))
+
+        def one(xs):
+            hc, tc = xs
+            logits = jnp.dot(hc, head, preferred_element_type=jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
+            return picked - lse
+
+        out = jax.lax.map(one, (flat.reshape(-1, chunk, d),
+                                target.reshape(-1, chunk)))
+        return out.reshape(-1)[:n].reshape(b, t)[:, :t - 1]
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        ids = x.astype(jnp.int32)
+        if ids.ndim != 2:
+            raise ValueError("the decoder takes (rows, length) token ids, "
+                             f"got {ids.shape}")
+        if ids.shape[1] > self.max_len:
+            raise ValueError(
+                f"sequence length {ids.shape[1]} exceeds max_len="
+                f"{self.max_len}; raise max_len in the model config")
+        dt, d = self.dtype, self.d_model
+        norm = functools.partial(RMSNorm, self.rms_norm_eps, dt)
+        h = nn.Embed(self.vocab_size, d, dtype=dt,
+                     embedding_init=nn.initializers.normal(1.0),
+                     name="embed")(ids)
+        picks = []
+        for i in range(self.num_layers):
+            h = h + LatentAttention(
+                self.num_heads, self.kv_lora_rank, self.qk_nope_head_dim,
+                self.qk_rope_head_dim, self.v_head_dim, self.rope_theta,
+                self.rms_norm_eps, self.attention_impl, dt,
+                name=f"mla_attn_{i}")(norm(name=f"ln_attn_{i}")(h))
+            y = norm(name=f"ln_mlp_{i}")(h)
+            if i < self.first_k_dense:
+                h = h + GatedFFN(self.d_ff_dense, dt, name=f"mlp_{i}")(y)
+            else:
+                out, n = ExpertLayer(
+                    self.n_routed_experts, tuple(self.experts_held),
+                    self.num_experts_per_tok, self.d_ff_expert,
+                    self.n_shared_experts, self.routed_scaling_factor,
+                    self.norm_topk_prob, dt, name=f"moe_{i}")(y)
+                h = h + out
+                picks.append(n)
+        h = norm(name="ln_final")(h)
+        self.sow("intermediates", "hidden", h)
+        if picks:
+            self.sow("intermediates", "moe_picks", jnp.stack(picks))
+        head = self.param("head_kernel", nn.initializers.normal(d ** -0.5),
+                          (d, self.vocab_size), jnp.float32).astype(dt)
+        with jax.named_scope("loglik.head"):
+            logprobs = self._token_logprobs(h, ids, head)
+            self.sow("intermediates", "token_logprobs", logprobs)
+            if self.output == "logits":
+                return jnp.dot(h, head, preferred_element_type=jnp.float32)
+        if self.output != "token_logprobs":
+            raise ValueError(f"unknown output {self.output!r}")
+        return logprobs
+
+
 def resnet20_cifar(num_outputs: int = 10, dtype=jnp.float32) -> ResNet:
     return ResNet(stage_sizes=(3, 3, 3), num_filters=16,
                   num_outputs=num_outputs, dtype=dtype)
@@ -264,6 +558,11 @@ ARCHITECTURES: dict[str, Callable[..., nn.Module]] = {
     "resnet50": lambda **kw: resnet50(**kw),
     "resnet": lambda **kw: ResNet(**kw),
     "transformer": lambda **kw: TransformerEncoder(**kw),
+    # experts_held arrives as a list from a JSON config; a module's
+    # attributes are hashable
+    "mla_moe_decoder": lambda **kw: MLAMoEDecoder(**{
+        **kw, **({"experts_held": tuple(kw["experts_held"])}
+                 if "experts_held" in kw else {})}),
 }
 
 

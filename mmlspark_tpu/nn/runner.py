@@ -27,6 +27,7 @@ from ..core.params import Param
 from ..core.pipeline import Model
 from ..core.schema import SCORE_KIND, Table
 from ..core.serialize import register_stage
+from ..observability.metrics import get_registry
 from ..observability.tracing import get_tracer
 from ..parallel.mesh import DATA_AXIS, get_mesh
 from .models import ModelBundle
@@ -106,10 +107,15 @@ class DeepModelTransformer(Model):
 
     # ------------------------------------------------------------------ #
 
-    def _forward_fn(self, fetches: tuple[str, ...]):
+    def _forward_fn(self, fetches: tuple[str, ...],
+                    counters: tuple[str, ...] = ()):
+        """`counters`: int32 arrays the module sows per batch (a module with
+        experts: `batch_counters`), returned after the fetched outputs as
+        they are. Only the streamed path asks for them."""
         bundle = self.bundle
         module = bundle.module
-        need_caps = any(f not in ("logits", "probability") for f in fetches)
+        need_caps = bool(counters) or any(
+            f not in ("logits", "probability") for f in fetches)
         mean = np.asarray(bundle.preprocess.get("mean", 0.0), np.float32)
         std = np.asarray(bundle.preprocess.get("std", 1.0), np.float32)
         use_bf16 = bool(self.get("bfloat16"))
@@ -137,12 +143,14 @@ class DeepModelTransformer(Model):
                     outs.append(
                         _fetch_from_intermediates(state, f).astype(jnp.float32)
                     )
+            outs.extend(_fetch_from_intermediates(state, c) for c in counters)
             return tuple(outs)
 
         return forward
 
-    def _make_apply(self, fetches: tuple[str, ...]):
-        forward = self._forward_fn(fetches)
+    def _make_apply(self, fetches: tuple[str, ...],
+                    counters: tuple[str, ...] = ()):
+        forward = self._forward_fn(fetches, counters)
         if self.get("use_mesh"):
             mesh = get_mesh()
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -230,8 +238,13 @@ class DeepModelTransformer(Model):
                     if jnp.issubdtype(jnp.asarray(a).dtype, jnp.floating) else a,
                     variables,
                 )
-            make = self._make_apply_fused if fused else self._make_apply
-            self._apply_cache[key] = (make(fetches), variables)
+            # what a module with experts sows per batch (routing counts)
+            # rides with the streamed path's lagged readback; a module
+            # without takes the path as it was
+            made = (self._make_apply_fused(fetches) if fused
+                    else self._make_apply(fetches, tuple(getattr(
+                        self.bundle.module, "batch_counters", ()))))
+            self._apply_cache[key] = (made, variables)
         apply_fn, variables = self._apply_cache[key]
 
         if fused:
@@ -278,16 +291,23 @@ class DeepModelTransformer(Model):
         prefetch = Prefetcher(range(0, n, bs), prepare,
                               depth=int(self.get("prefetch_depth")),
                               name="runner")
-        # fetch = block on the device result and slice the padding off;
-        # lag 1 keeps batch N-1's readback behind batch N's dispatch
+        # fetch = block on the device result and slice the padding off
+        # (a batch's counters, which follow the fetched outputs, have no
+        # rows to slice); lag 1 keeps batch N-1's readback behind batch
+        # N's dispatch
+        nf = len(fetches)
         readback = AsyncReadback(
-            lambda om: tuple(np.asarray(a)[:om[1]] for a in om[0]), lag=1)
+            lambda om: tuple(np.asarray(a)[:om[1]] for a in om[0][:nf])
+            + tuple(np.asarray(a) for a in om[0][nf:]), lag=1)
         chunks: list[tuple[np.ndarray, ...]] = []
+        scored = 0                      # rows the device scored, padding too
         tracer = get_tracer()
-        with tracer.start_span("runner.transform", rows=n, batch_size=bs):
+        with tracer.start_span("runner.transform", rows=n,
+                               batch_size=bs) as root:
             for xb, m in prefetch:
                 shape_key = (int(xb.shape[0]), tuple(xb.shape[1:]),
                              str(xb.dtype))
+                scored += int(xb.shape[0])
                 with tracer.start_span("runner.step", padded=int(xb.shape[0]),
                                        rows=m):
                     # jit compiles once per entry here; the counters make
@@ -297,6 +317,10 @@ class DeepModelTransformer(Model):
                                                        lambda: apply_fn)
                     chunks.extend(readback.push((fn(variables, xb), m)))
             chunks.extend(readback.drain())
+            if chunks and len(chunks[0]) > nf:
+                self._record_expert_load(
+                    root, sum(c[nf] for c in chunks),
+                    scored * int(np.prod(x.shape[1:])))
         self.last_pipeline_stats = {
             **prefetch.stats,
             "overlap_fraction": prefetch.overlap_fraction(),
@@ -306,6 +330,29 @@ class DeepModelTransformer(Model):
         }
         return [np.concatenate([c[j] for c in chunks])
                 for j in range(len(fetches))]
+
+    def _record_expert_load(self, root, picks: np.ndarray,
+                            tokens: int) -> None:
+        """`picks`: int (expert layers, experts held), the picks each held
+        expert received over the whole call, padding rows' included (the
+        device computed them). Added to the registry's counter by layer
+        and written on the call's root span."""
+        counter = get_registry().counter(
+            "mmlspark_tpu_moe_picks_held_total",
+            "picks routed to the experts this module holds, by expert layer",
+            labels=("layer",))
+        for layer, held in enumerate(picks.sum(axis=1)):
+            counter.labels(layer=layer).inc(float(held))
+        module = self.bundle.module
+        root.set(
+            moe_picks=int(tokens * module.num_experts_per_tok
+                          * picks.shape[0]),
+            moe_picks_held=int(picks.sum()),
+            # the busiest held expert of a layer over that layer's mean,
+            # the largest over the layers
+            moe_load_max_over_mean=float(
+                (picks.max(axis=1) / np.maximum(picks.mean(axis=1), 1e-30))
+                .max()))
 
     # -- fusion --------------------------------------------------------- #
 

@@ -1,0 +1,78 @@
+"""The kernels of the `mla_moe_decoder` family compiled for a DESCRIBED
+TPU v5e at the benchmark cell's own widths: the chip's compiler runs here
+without a chip, and refuses what interpret mode lets through (a block not
+aligned to the tiling, more fast memory than a kernel may use). Nothing
+runs, so nothing here is a time or a result.
+
+The topology is described inside a fixture (never at import: only one
+process at a time may load the TPU's library, and a test file is imported
+by every worker), and every such test lives in this one file."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                      # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep it out
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn).lower(*shapes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+
+
+@pytest.mark.parametrize("length,block,dtype", [
+    (4096, 1024, jnp.bfloat16), (512, 1024, jnp.bfloat16),
+    # float32 inputs: 1024 passes the scoped VMEM (refused on the chip,
+    # PR 27), so `LatentAttention` asks for 512 there
+    (4096, 512, jnp.float32)])
+def test_latent_attention_kernel_compiles(one_chip, length, block, dtype):
+    """192 channels for scores, 128 for values, causal with the block
+    skip, 8 rows x 16 heads, at the blocks the module asks for."""
+    from mmlspark_tpu.nn.attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((8, length, 16, 192), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((8, length, 16, 128), dtype, sharding=one_chip)
+    compiled = _compile(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=block,
+                                        block_k=block), q, q, v)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_dropless_expert_layer_compiles_to_grouped_kernels(one_chip):
+    """A batch of 8 x 512 tokens through 16 of 64 experts of width 1408:
+    the grouped products are the compiler's own kernels (not a dense
+    product a group), in both branches of the buffer-size switch."""
+    from mmlspark_tpu.parallel.moe import moe_ffn_dropless
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        lambda x, router, bias, gate, up, down: moe_ffn_dropless(
+            x, router, bias, gate, up, down, n_routed_experts=64,
+            experts_held=(0, 16), top_k=6, scaling=2.446,
+            dtype=jnp.bfloat16),
+        spec(4096, 2048), spec(2048, 64), spec(64),
+        spec(16, 2048, 1408), spec(16, 2048, 1408), spec(16, 1408, 2048))
+    text = compiled.as_text()
+    assert text.count("ragged-dot") >= 4
+    assert "conditional" in text
