@@ -300,14 +300,14 @@ class DeepModelTransformer(Model):
             lambda om: tuple(np.asarray(a)[:om[1]] for a in om[0][:nf])
             + tuple(np.asarray(a) for a in om[0][nf:]), lag=1)
         chunks: list[tuple[np.ndarray, ...]] = []
-        scored = 0                      # rows the device scored, padding too
+        scored: list[int] = []          # rows a batch scored, padding too
         tracer = get_tracer()
         with tracer.start_span("runner.transform", rows=n,
                                batch_size=bs) as root:
             for xb, m in prefetch:
                 shape_key = (int(xb.shape[0]), tuple(xb.shape[1:]),
                              str(xb.dtype))
-                scored += int(xb.shape[0])
+                scored.append(int(xb.shape[0]))
                 with tracer.start_span("runner.step", padded=int(xb.shape[0]),
                                        rows=m):
                     # jit compiles once per entry here; the counters make
@@ -318,9 +318,10 @@ class DeepModelTransformer(Model):
                     chunks.extend(readback.push((fn(variables, xb), m)))
             chunks.extend(readback.drain())
             if chunks and len(chunks[0]) > nf:
+                per_row = int(np.prod(x.shape[1:]))
                 self._record_expert_load(
-                    root, sum(c[nf] for c in chunks),
-                    scored * int(np.prod(x.shape[1:])))
+                    root, np.stack([c[nf] for c in chunks]),
+                    [rows * per_row for rows in scored])
         self.last_pipeline_stats = {
             **prefetch.stats,
             "overlap_fraction": prefetch.overlap_fraction(),
@@ -332,26 +333,44 @@ class DeepModelTransformer(Model):
                 for j in range(len(fetches))]
 
     def _record_expert_load(self, root, picks: np.ndarray,
-                            tokens: int) -> None:
-        """`picks`: int (expert layers, experts held), the picks each held
-        expert received over the whole call, padding rows' included (the
-        device computed them). Added to the registry's counter by layer
-        and written on the call's root span."""
-        counter = get_registry().counter(
+                            tokens: list[int]) -> None:
+        """`picks`: int (batches, expert layers, experts held), the picks
+        each held expert received in each batch, padding rows' included
+        (the device computed them); `tokens`: each batch's tokens. Added to
+        the registry's counters by layer and written on the call's root
+        span, with the (layer, batch) pairs whose picks outgrew the expert
+        layer's dispatch buffer (`moe_ffn_dropless` then runs the whole
+        T x k)."""
+        from ..parallel.moe import dropless_buffer_rows
+
+        module = self.bundle.module
+        k, held = module.num_experts_per_tok, picks.shape[2]
+        whole = np.asarray(tokens) * k
+        buffer = np.asarray([dropless_buffer_rows(
+            t, k, held, module.n_routed_experts) for t in tokens])
+        outgrown = ((buffer < whole) & (picks.sum(axis=2).T >= buffer)).sum(
+            axis=1)                                        # by layer
+        registry = get_registry()
+        held_total = registry.counter(
             "mmlspark_tpu_moe_picks_held_total",
             "picks routed to the experts this module holds, by expert layer",
             labels=("layer",))
-        for layer, held in enumerate(picks.sum(axis=1)):
-            counter.labels(layer=layer).inc(float(held))
-        module = self.bundle.module
+        whole_total = registry.counter(
+            "mmlspark_tpu_moe_whole_buffer_total",
+            "batches whose picks outgrew the dispatch buffer, by expert layer",
+            labels=("layer",))
+        call = picks.sum(axis=0)                           # (layers, held)
+        for layer, here in enumerate(call.sum(axis=1)):
+            held_total.labels(layer=layer).inc(float(here))
+            whole_total.labels(layer=layer).inc(float(outgrown[layer]))
         root.set(
-            moe_picks=int(tokens * module.num_experts_per_tok
-                          * picks.shape[0]),
-            moe_picks_held=int(picks.sum()),
+            moe_picks=int(whole.sum() * picks.shape[1]),
+            moe_picks_held=int(call.sum()),
+            moe_whole_buffer=int(outgrown.sum()),
             # the busiest held expert of a layer over that layer's mean,
             # the largest over the layers
             moe_load_max_over_mean=float(
-                (picks.max(axis=1) / np.maximum(picks.mean(axis=1), 1e-30))
+                (call.max(axis=1) / np.maximum(call.mean(axis=1), 1e-30))
                 .max()))
 
     # -- fusion --------------------------------------------------------- #

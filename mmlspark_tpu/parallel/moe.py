@@ -12,13 +12,14 @@ computed, whatever the skew. The layer is TOLD which experts it holds
 (`experts_held`: first index and count, separately from
 `n_routed_experts`) and returns the part of the result its own experts
 give: picks are sorted by expert, the rows of the experts held come first,
-and one grouped product a projection (`jax.lax.ragged_dot`, which the TPU
+one grouped product a projection (`jax.lax.ragged_dot`, which the TPU
 compiler lowers to its own grouped-matmul kernel with a grid as long as the
-rows that are really there) runs over them. The parts of the chips of an
-expert-parallel host add up to the whole layer; on one chip the layer runs
-without its exchange, and nothing stands in for the absent chips. The
-shared experts are a plain gated feed-forward (`nn.models.GatedFFN`) that
-the model adds once.
+rows that are really there) runs over them, and a Pallas call
+(`moe_combine`) adds each token's weighted rows up from where they lie.
+The parts of the chips of an expert-parallel host add up to the whole
+layer; on one chip the layer runs without its exchange, and nothing stands
+in for the absent chips. The shared experts are a plain gated feed-forward
+(`nn.models.GatedFFN`) that the model adds once.
 
 **The capacity-dropping top-1 pair: `moe_ffn_local` / `moe_ffn_sharded`**
 (Switch-Transformer-style, no model calls it). Tokens are sharded over the
@@ -30,6 +31,7 @@ collectives move token slabs to their experts' devices and back over ICI.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -38,7 +40,7 @@ from jax import lax
 
 from .collectives import axis_size
 
-__all__ = ["moe_ffn_dropless", "route_top_k",
+__all__ = ["moe_ffn_dropless", "route_top_k", "dropless_buffer_rows",
            "MoEParams", "init_moe", "moe_ffn_local", "moe_ffn_sharded"]
 
 EXPERT_AXIS = "expert"
@@ -154,7 +156,12 @@ def route_top_k(x, router, bias, top_k: int, scaling: float = 1.0,
         scores = jax.nn.sigmoid(jnp.dot(
             x, router.astype(x.dtype), preferred_element_type=jnp.float32))
         _best, picked = lax.top_k(scores + bias.astype(jnp.float32), top_k)
-        weights = jnp.take_along_axis(scores, picked, axis=-1)
+        # the scores at the picks, as a sum with zeros over the experts'
+        # axis (the same bits as a gather, which XLA runs a scalar at a
+        # time: 2.1 against 0.2 ms for 8 x 4096 tokens on a v5e)
+        weights = jnp.where(
+            picked[..., None] == jnp.arange(scores.shape[-1]),
+            scores[:, None, :], 0.0).sum(-1)
         if normalise:
             weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
         return picked.astype(jnp.int32), weights * scaling
@@ -162,6 +169,240 @@ def route_top_k(x, router, bias, top_k: int, scaling: float = 1.0,
 
 def _round_up(n: int, to: int) -> int:
     return -(-n // to) * to
+
+
+def dropless_buffer_rows(tokens: int, top_k: int, held: int,
+                         n_routed: int) -> int:
+    """Rows of `moe_ffn_dropless`'s dispatch buffer for a batch of `tokens`
+    tokens when `held` of `n_routed` experts lie here: half again the even
+    share of the T x k picks (in 512s), or all of them where that is no
+    less. A batch whose picks here reach it runs the whole T x k."""
+    whole = tokens * top_k
+    even = whole * held // n_routed
+    return min(_round_up(even + even // 2 + 1, 512), whole)
+
+
+# What the combine's kernel may keep in VMEM: it stays inside XLA's own
+# allowance for a custom call (16 MB). Asking for more takes it from the
+# whole program: at 64 MB the compiler no longer kept the grouped products'
+# rows and weights in VMEM, and they ran 8% longer (PERF.md, PR 28).
+_COMBINE_VMEM = 15 * 1024 * 1024
+
+
+def _combine_shape(tokens: int, top_k: int, n_routed: int, held: int,
+                   d: int, itemsize: int, vmem: int = _COMBINE_VMEM):
+    """-> (tile, chunk, group): the tokens a grid step adds up, the rows
+    it reads of an expert at a time (what even picks give a tile, plus the
+    15 rows an aligned start may lie before the first, in 16s) and the
+    experts it reads together: all that are held where the kernel's VMEM
+    takes them, in the larger tile if that does."""
+    for tile in (256, 128):
+        chunk = min(_round_up(tile * top_k // n_routed + 16, 16), tile + 16)
+        # an expert's rows and their tokens (two slots), its part of the
+        # 0/1 matrix and of that matrix transposed; then the output's two
+        # blocks and three float32 sums (the compiler's own count for 16
+        # experts in tiles of 256 is 16.18 MB; this says 16.25)
+        expert = 2 * chunk * (d * itemsize + 512 + tile * itemsize)
+        fits = (vmem - tile * d * (2 * itemsize + 12)) // expert
+        if tile == 128 or (tokens > 128 and fits >= held):
+            groups = -(-held // max(fits, 1))
+            return tile, chunk, -(-held // groups)
+
+
+# jitted by itself: a model calls it once a layer and a branch, and the
+# kernel is traced and lowered once a shape, not once a call (0.4 s each)
+@functools.partial(jax.jit, static_argnames=(
+    "tokens", "top_k", "n_routed", "interpret", "vmem"))
+def _combine_pallas(weighed, token_of_row, key, picks, *, tokens: int,
+                    top_k: int, n_routed: int, interpret: bool = False,
+                    vmem: int = _COMBINE_VMEM):
+    """The combine as a Pallas call (`moe_combine`): see `_combine`.
+
+    The rows of one expert are in token order (the sort is stable), so the
+    rows that a tile of tokens gets from one expert are CONSECUTIVE in
+    `weighed`: [seg[e, i], seg[e, i + 1]), counted from the picks. A grid
+    step copies `chunk` rows of each expert of a group from an aligned
+    start (one DMA an expert, the next step's in flight while this one is
+    added up), marks which row belongs to which of its tokens ((rows,
+    tile) of 0 and 1; rows outside the segment belong to none) and adds
+    the rows up as ONE product on the MXU, 1 x row in float32: the
+    products are exact, so this is the float32 sum of a token's rows. A
+    tile in which an expert has more rows than `chunk` takes further
+    rounds, as many as the crowded expert needs: no row is ever left out.
+    Experts beyond what VMEM takes at once are further groups, added into
+    the same float32 sum."""
+    import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    rows, d = weighed.shape
+    dtype = weighed.dtype
+    tile, chunk, group = _combine_shape(
+        tokens, top_k, n_routed, picks.shape[0], d, dtype.itemsize, vmem)
+    tiles, groups = -(-tokens // tile), -(-picks.shape[0] // group)
+    held = groups * group                  # with experts nobody picks
+    padded = max(_round_up(rows, 16), chunk)
+    if padded != rows:                     # sizes no model has; tests do
+        weighed = jnp.pad(weighed, ((0, padded - rows), (0, 0)))
+        token_of_row = jnp.pad(token_of_row, (0, padded - rows))
+    # a row's token along the lanes, as the kernel compares it
+    tok = jnp.broadcast_to(token_of_row[:, None], (padded, 128))
+    # seg[e, i]: the first row of expert e's picks by tokens of tile i on
+    key = jnp.pad(key, (0, tiles * tile * top_k - key.shape[0]),
+                  constant_values=held)
+    per_tile = (key.reshape(tiles, tile * top_k, 1)
+                == jnp.arange(held, dtype=jnp.int32)).sum(1, dtype=jnp.int32)
+    before = jnp.concatenate(
+        [jnp.zeros((1, held), jnp.int32), jnp.cumsum(per_tile, 0)])
+    picks = jnp.pad(picks, (0, held - picks.shape[0]))
+    seg = (before + (jnp.cumsum(picks) - picks)[None, :]).T
+    # rounds a step needs: its most crowded expert's rows from the aligned
+    # start, in chunks
+    span = seg[:, 1:] - seg[:, :-1] // 16 * 16
+    rounds = jnp.maximum(
+        (-(-span // chunk)).reshape(groups, group, tiles).max(1), 1)
+    width, stride, steps = group * chunk, tiles + 1, tiles * groups
+    exact = lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+    def kernel(seg_ref, rounds_ref, w_hbm, tok_hbm, out_ref, buf, tbuf, hot,
+               total, sem):
+        i, g = pl.program_id(0), pl.program_id(1)
+        step = i * groups + g
+        slot = step % 2
+
+        def window(e, t, r):
+            """Round r of expert e for tile t -> (first row copied, first
+            and one past the last row that counts)."""
+            lo = seg_ref[e * stride + t]
+            hi = seg_ref[e * stride + t + 1]
+            begin = lo // 16 * 16 + r * chunk
+            first = pl.multiple_of(jnp.minimum(begin, padded - chunk), 16)
+            return (first, jnp.maximum(lo, begin),
+                    jnp.minimum(hi, begin + chunk))
+
+        def rows_of(e):
+            return pl.ds(pl.multiple_of(e * chunk, chunk), chunk)
+
+        def copies(src, dst, slot):
+            return (pltpu.make_async_copy(w_hbm.at[src, :],
+                                          buf.at[slot, dst, :], sem.at[slot]),
+                    pltpu.make_async_copy(tok_hbm.at[src, :],
+                                          tbuf.at[slot, dst, :], sem.at[slot]))
+
+        # loops over the group's experts, not unrolled: a model has a
+        # kernel a layer and a branch, and each is traced, lowered,
+        # compiled and loaded by itself
+        def start(t, g, r, slot):
+            def one(e, _):
+                first, _lo, _hi = window(g * group + e, t, r)
+                for c in copies(pl.ds(first, chunk), rows_of(e), slot):
+                    c.start()
+                return 0
+            lax.fori_loop(0, group, one, 0)
+
+        def wait(slot):
+            def one(_e, _):
+                for c in copies(pl.ds(0, chunk), pl.ds(0, chunk), slot):
+                    c.wait()                # by a copy's size, not its place
+                return 0
+            lax.fori_loop(0, group, one, 0)
+
+        def added(r):
+            row = lax.broadcasted_iota(jnp.int32, (chunk, 128), 0)
+            lane = lax.broadcasted_iota(jnp.int32, (chunk, 128), 1)
+
+            def one(e, _):
+                first, lo, hi = window(g * group + e, i, r)
+                dst = rows_of(e)
+                counts = (row >= lo - first) & (row < hi - first)
+                local = tbuf[slot, dst, :] - i * tile
+                for j in range(tile // 128):
+                    hot[dst, j * 128:(j + 1) * 128] = jnp.where(
+                        counts & (local == lane + j * 128), 1.0, 0.0
+                    ).astype(dtype)
+                # a neighbour's row is weighed 0, and 0 x inf is nan
+                buf[slot, dst, :] = jnp.where(
+                    counts[:, :1], buf[slot, dst, :], jnp.zeros((), dtype))
+                return 0
+            lax.fori_loop(0, group, one, 0)
+            return lax.dot_general(
+                hot[...], buf[slot], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=exact)
+
+        @pl.when(step == 0)
+        def _first():
+            start(0, 0, 0, 0)
+
+        @pl.when(step + 1 < steps)
+        def _ahead():
+            nxt = step + 1
+            start(nxt // groups, nxt % groups, 0, 1 - slot)
+
+        wait(slot)
+
+        def further(r, acc):
+            start(i, g, r, slot)
+            wait(slot)
+            return acc + added(r)
+
+        part = lax.fori_loop(1, rounds_ref[g * tiles + i], further, added(0))
+        if groups == 1:
+            out_ref[...] = part.astype(dtype)
+        else:
+            @pl.when(g == 0)
+            def _opens():
+                total[...] = part
+
+            @pl.when(g > 0)
+            def _adds():
+                total[...] += part
+
+            @pl.when(g == groups - 1)
+            def _closes():
+                out_ref[...] = total[...].astype(dtype)
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(tiles, groups),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile, d), lambda i, g, *_: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((2, width, d), dtype),
+                            pltpu.VMEM((2, width, 128), jnp.int32),
+                            pltpu.VMEM((width, tile), dtype),
+                            # the sum over groups; one group needs none
+                            pltpu.VMEM((tile, d) if groups > 1 else (8, 128),
+                                       jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((tiles * tile, d), dtype),
+        # the next step's copies are started a step ahead: steps in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="moe_combine",
+    )(seg.reshape(-1), rounds.reshape(-1).astype(jnp.int32), weighed, tok)
+    return out[:tokens]
+
+
+def _combine(weighed, token_of_row, key, picks, *, tokens: int, top_k: int,
+             n_routed: int):
+    """Each token's weighted rows added up in float32 and rounded once.
+
+    weighed: (rows, d), the buffer's rows in expert order, those past the
+    picks that are here zero. token_of_row: (rows,) int32. key: (T x k,)
+    the flat picks' expert here, or `held` for one that lies elsewhere;
+    picks: (held,) their counts. -> (tokens, d) in `weighed`'s dtype.
+
+    What moves is bounded by the buffer's rows, never by T x k: on a TPU
+    the Pallas call reads each expert's rows of a tile of tokens where
+    they lie; elsewhere (the CPU's tests) XLA adds the buffer's rows into
+    their tokens, a row at a time."""
+    d = weighed.shape[1]
+    if jax.default_backend() == "cpu" or d % 128:
+        return jnp.zeros((tokens, d), jnp.float32).at[token_of_row].add(
+            weighed.astype(jnp.float32)).astype(weighed.dtype)
+    return _combine_pallas(weighed, token_of_row, key, picks, tokens=tokens,
+                           top_k=top_k, n_routed=n_routed)
 
 
 def moe_ffn_dropless(x, router, bias, gate, up, down, *,
@@ -183,9 +424,10 @@ def moe_ffn_dropless(x, router, bias, gate, up, down, *,
     Static shapes: the picks are sorted by expert with those of absent
     experts last, and the first `rows` of that order are gathered and go
     through the grouped products. `rows` is the whole T x k only when more
-    picks than half again the even share land here (`lax.cond`): the
-    common case gathers and activates a buffer a quarter the size at
-    16 of 64 experts held."""
+    picks than half again the even share land here (`lax.cond`,
+    `dropless_buffer_rows`): the common case gathers and activates a
+    buffer a quarter the size at 16 of 64 experts held, and the combine
+    (`_combine`) moves that buffer's rows, not one row a pick."""
     t, d = x.shape
     first, held = (int(v) for v in experts_held)
     if gate.shape[0] != held:
@@ -197,19 +439,20 @@ def moe_ffn_dropless(x, router, bias, gate, up, down, *,
         local = picked.reshape(-1) - first                    # (T*k,)
         here = (local >= 0) & (local < held)
         key = jnp.where(here, local, held)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        # the picks' weights ride through the sort beside their places
+        _key, order, weight_of_row = lax.sort(
+            (key, jnp.arange(t * top_k, dtype=jnp.int32),
+             weights.reshape(-1)), num_keys=1, is_stable=True)
         picks = (key[:, None] == jnp.arange(held, dtype=jnp.int32)).sum(
             0, dtype=jnp.int32)
         n_here = picks.sum()
-        # where each pick sits in the sorted order
-        place = jnp.argsort(order).astype(jnp.int32)
     gate_up = jnp.concatenate([gate, up], axis=-1).astype(dtype)
     down = down.astype(dtype)
 
     def routed(rows: int):
         with jax.named_scope("moe.dispatch"):
-            first_rows = order[:rows]
-            xs = x.astype(dtype)[first_rows // top_k]          # (rows, d)
+            token_of_row = order[:rows] // top_k
+            xs = x.astype(dtype)[token_of_row]                 # (rows, d)
         with jax.named_scope("moe.experts"):
             hidden = lax.ragged_dot(xs, gate_up, picks,
                                     preferred_element_type=dtype)
@@ -220,23 +463,17 @@ def moe_ffn_dropless(x, router, bias, gate, up, down, *,
                                 preferred_element_type=dtype)  # (rows, d)
         with jax.named_scope("moe.combine"):
             # weigh in the sorted order; rows past the picks that are here
-            # hold nothing defined and become zero. Whenever a pick lies
-            # elsewhere the last row is such a row (`rows` exceeds the
-            # picks here, or is all of them), so those picks read it
+            # hold nothing defined and become zero
             here_rows = jnp.arange(rows, dtype=jnp.int32) < n_here
             weighed = jnp.where(
                 here_rows[:, None],
-                ys.astype(jnp.float32)
-                * weights.reshape(-1)[first_rows][:, None], 0.0).astype(dtype)
-            # (k, T): a token's picks lie T rows apart, so the gathered
-            # rows add up as k slabs of (T, d) with no relayout between
-            at = jnp.where(place < n_here, place, rows - 1).reshape(
-                t, top_k).T
-            return weighed[at].astype(jnp.float32).sum(0).astype(dtype)
+                ys.astype(jnp.float32) * weight_of_row[:rows, None],
+                0.0).astype(dtype)
+            return _combine(weighed, token_of_row, key, picks, tokens=t,
+                            top_k=top_k, n_routed=n_routed_experts)
 
     whole = t * top_k
-    even = whole * held // n_routed_experts
-    small = _round_up(even + even // 2 + 1, 512)
+    small = dropless_buffer_rows(t, top_k, held, n_routed_experts)
     if small >= whole:
         return routed(whole), picks
     out = lax.cond(n_here < small, lambda: routed(small),

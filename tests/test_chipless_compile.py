@@ -57,11 +57,24 @@ def test_latent_attention_kernel_compiles(one_chip, length, block, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_dropless_expert_layer_compiles_to_grouped_kernels(one_chip):
-    """A batch of 8 x 512 tokens through 16 of 64 experts of width 1408:
-    the grouped products are the compiler's own kernels (not a dense
-    product a group), in both branches of the buffer-size switch."""
+@pytest.mark.parametrize("tokens,held", [
+    (8 * 512, 16), (8 * 4096, 16),
+    # every expert held: no switch, and the combine reads the experts' rows
+    # in two groups of 32
+    (8 * 512, 64)])
+def test_dropless_expert_layer_compiles_to_grouped_kernels(
+        one_chip, tokens, held, monkeypatch):
+    """A batch of 8 x 512 and one of 8 x 4096 tokens through 16 of 64
+    experts of width 1408: the grouped products are the compiler's own
+    kernels (not a dense product a group) and the combine is the Pallas
+    call (its VMEM and its table in SMEM fit), in both branches of the
+    buffer-size switch; no array of one row a pick, (k, T, d), is left in
+    either."""
     from mmlspark_tpu.parallel.moe import moe_ffn_dropless
+
+    # the layer asks the backend whether its Pallas combine can run: here
+    # the chip is described, not attached, so the test answers for it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def spec(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -69,10 +82,15 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(one_chip):
     compiled = _compile(
         lambda x, router, bias, gate, up, down: moe_ffn_dropless(
             x, router, bias, gate, up, down, n_routed_experts=64,
-            experts_held=(0, 16), top_k=6, scaling=2.446,
+            experts_held=(0, held), top_k=6, scaling=2.446,
             dtype=jnp.bfloat16),
-        spec(4096, 2048), spec(2048, 64), spec(64),
-        spec(16, 2048, 1408), spec(16, 2048, 1408), spec(16, 1408, 2048))
+        spec(tokens, 2048), spec(2048, 64), spec(64),
+        spec(held, 2048, 1408), spec(held, 2048, 1408),
+        spec(held, 1408, 2048))
     text = compiled.as_text()
-    assert text.count("ragged-dot") >= 4
-    assert "conditional" in text
+    branches = 2 if held < 64 else 1
+    assert text.count("ragged-dot") >= 2 * branches
+    assert ("conditional" in text) == (held < 64)
+    assert text.count("moe_combine") >= branches
+    assert f"[6,{tokens},2048]" not in text
+    assert f"[{tokens},6,2048]" not in text

@@ -23,8 +23,11 @@ from mmlspark_tpu.core.schema import Table
 from mmlspark_tpu.nn.attention import dense_attention, flash_attention
 from mmlspark_tpu.nn.models import ExpertLayer, ModelBundle, make_model
 from mmlspark_tpu.nn.runner import DeepModelTransformer
+from mmlspark_tpu.observability.metrics import get_registry
 from mmlspark_tpu.observability.tracing import get_tracer
-from mmlspark_tpu.parallel.moe import moe_ffn_dropless, route_top_k
+from mmlspark_tpu.parallel import moe
+from mmlspark_tpu.parallel.moe import (dropless_buffer_rows, moe_ffn_dropless,
+                                       route_top_k)
 
 F32_LIMIT = 1e-4
 FAULT_FLOOR = 1e-2
@@ -187,6 +190,47 @@ class TestThroughTheRunner:
         assert "moe_picks" not in root.args
 
 
+    @pytest.mark.parametrize("crowded", [False, True])
+    def test_a_batch_that_outgrows_the_buffer_is_counted(self, ref, crowded):
+        """Experts 0 and 1 of 8 held, batches of 4 x 64 tokens: the
+        dispatch buffer is 512 of 768 picks. Seeded routers send a quarter
+        of the picks here (about 192) and no batch leaves it; a selection
+        bias that sends every token to both held experts fills it (512),
+        and every (layer, batch) pair runs the whole T x k."""
+        held = dict(MODEL, experts_held=[0, 2])
+        config = {"model": held}
+        weights = dict(ref.weights(jax.random.PRNGKey(11), config))
+        if crowded:
+            weights["router_bias"] = weights["router_bias"].at[:, :2].add(9.0)
+        assert dropless_buffer_rows(4 * 64, 3, 2, 8) == 512
+        stage = DeepModelTransformer(
+            input_col="tokens", fetch_dict={"logprob": "token_logprobs"},
+            mini_batch_size=4, fused_dispatch=False).set_model(ModelBundle(
+                architecture="mla_moe_decoder",
+                config=dict(held, dtype="float32"),
+                variables=ref.variables(weights, config), input_shape=(64,)))
+        family = get_registry().counter(
+            "mmlspark_tpu_moe_whole_buffer_total",
+            "batches whose picks outgrew the dispatch buffer, by expert layer",
+            labels=("layer",))
+        before = [family.labels(layer=j).value for j in range(2)]
+        ids = _ids(7, 64, seed=8)           # two batches, the second padded
+        out = np.asarray(stage.transform(Table({"tokens": ids}))["logprob"])
+        want = ref.outputs(weights, config, ids, "token_logprobs")
+        scale = ref.outputs(weights, config, ids, "logits").std()
+        assert np.abs(out - want).max() / scale < F32_LIMIT
+        root = [s for s in get_tracer().spans()
+                if s.name == "runner.transform"][-1]
+        counted = [family.labels(layer=j).value - before[j] for j in range(2)]
+        if crowded:
+            assert root.args["moe_picks_held"] == 2 * 2 * 4 * 64 * 2
+            assert root.args["moe_whole_buffer"] == 4      # 2 layers x 2
+            assert counted == [2.0, 2.0]
+        else:
+            assert root.args["moe_whole_buffer"] == 0
+            assert counted == [0.0, 0.0]
+
+
 # --------------------------------------------------------------------- #
 # the expert layer                                                      #
 # --------------------------------------------------------------------- #
@@ -316,6 +360,121 @@ class TestExpertLayer:
         else:
             got, _n = _routed(dict(p, bias=jnp.zeros_like(p["bias"])), 0, 8)
         assert _gap(got, want) > FAULT_FLOOR
+
+
+# --------------------------------------------------------------------- #
+# the combine                                                           #
+# --------------------------------------------------------------------- #
+
+def _dropless_pr27(x, router, bias, gate, up, down, *, n_routed_experts,
+                   experts_held, top_k, scaling=1.0, dtype=jnp.float32):
+    """The layer as PR 27 had it, plain `jnp`: the scores gathered at the
+    picks, a second argsort for each pick's place, and the combine as k
+    slabs of (T, d) gathered one row a pick (an absent pick reads the
+    last, zero, row) and added up."""
+    t, _d = x.shape
+    first, held = experts_held
+    scores = jax.nn.sigmoid(jnp.dot(x, router.astype(x.dtype),
+                                    preferred_element_type=jnp.float32))
+    _best, picked = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    weights = jnp.take_along_axis(scores, picked, axis=-1)
+    weights = weights / (weights.sum(-1, keepdims=True) + 1e-20) * scaling
+    local = picked.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True)
+    picks = (key[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
+    n_here = picks.sum()
+    place = jnp.argsort(order)
+    rows = t * top_k
+    xs = x.astype(dtype)[order // top_k]
+    hidden = jax.lax.ragged_dot(
+        xs, jnp.concatenate([gate, up], -1).astype(dtype), picks,
+        preferred_element_type=dtype)
+    w = hidden.shape[-1] // 2
+    act = (jax.nn.silu(hidden[:, :w].astype(jnp.float32))
+           * hidden[:, w:]).astype(dtype)
+    ys = jax.lax.ragged_dot(act, down.astype(dtype), picks,
+                            preferred_element_type=dtype)
+    weighed = jnp.where(
+        (jnp.arange(rows) < n_here)[:, None],
+        ys.astype(jnp.float32) * weights.reshape(-1)[order][:, None],
+        0.0).astype(dtype)
+    at = jnp.where(place < n_here, place, rows - 1).reshape(t, top_k).T
+    return weighed[at].astype(jnp.float32).sum(0).astype(dtype), picks
+
+
+def _last_place(a: np.ndarray, bits: int) -> np.ndarray:
+    """One unit in the last place of a float with `bits` bits of
+    precision, at each value of `a`."""
+    _m, e = np.frexp(np.abs(a))
+    return np.ldexp(1.0, e - bits)
+
+
+COMBINE_CASES = {
+    # tokens, experts held (first, count), top_k, bias added to experts
+    "all_held": (40, (0, 8), 3, {}),
+    "a_quarter": (40, (2, 2), 3, {}),
+    "none_picked": (40, (6, 2), 3, {6: -9.0, 7: -9.0}),
+    # 768 picks, a buffer of 512: both held experts crowded fill it
+    "whole_buffer": (256, (0, 2), 3, {0: 9.0, 1: 9.0}),
+    # the buffer's branch taken, three tiles of tokens, the last ragged
+    "small_buffer": (300, (0, 2), 3, {}),
+    "ragged": (43, (1, 3), 3, {}),           # 129 picks
+    "top_1": (40, (0, 4), 1, {}),
+}
+
+
+class TestCombine:
+    @pytest.mark.parametrize("path", ["xla", "pallas", "pallas_in_groups"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", list(COMBINE_CASES))
+    def test_equals_the_k_slab_gather(self, monkeypatch, case, dtype, path):
+        """Rows weighed and rounded where PR 27 rounded them, a token's
+        rows added in float32 and rounded once: only the order of up to k
+        additions differs. `pallas` is the chip's kernel, interpreted;
+        `pallas_in_groups` with VMEM for one expert's rows at a time."""
+        tokens, (first, count), top_k, nudge = COMBINE_CASES[case]
+        dtype = jnp.dtype(dtype)
+        p = _layer_inputs(seed=4, tokens=tokens)
+        for e, by in nudge.items():
+            p["bias"] = p["bias"].at[e].add(by)
+        if path == "pallas":
+            monkeypatch.setattr(moe, "_combine", functools.partial(
+                moe._combine_pallas, interpret=True))
+        if path == "pallas_in_groups":
+            monkeypatch.setattr(moe, "_combine", functools.partial(
+                moe._combine_pallas, interpret=True, vmem=0))
+            assert moe._combine_shape(tokens, top_k, 8, count, 64, 4, 0)[
+                2] == 1
+        lo, hi = first, first + count
+        args = (p["x"], p["router"], p["bias"], p["gate"][lo:hi],
+                p["up"][lo:hi], p["down"][lo:hi])
+        kw = dict(n_routed_experts=8, experts_held=(first, count),
+                  top_k=top_k, scaling=2.446, dtype=dtype)
+        got, picks = moe_ffn_dropless(*args, **kw)
+        want, picks_want = _dropless_pr27(*args, **kw)
+        assert np.array_equal(picks, picks_want)
+        n_here, whole = int(picks.sum()), tokens * top_k
+        small = dropless_buffer_rows(tokens, top_k, count, 8)
+        assert {"none_picked": n_here == 0,
+                "whole_buffer": small <= n_here < whole,
+                "small_buffer": n_here < small < whole}.get(case, True)
+        assert got.dtype == dtype and got.shape == want.shape
+        got, want = (np.asarray(a, np.float64) for a in (got, want))
+        if dtype == jnp.float32:
+            assert np.abs(got - want).max() <= 1e-6 * max(
+                1.0, np.abs(want).max())
+        else:
+            assert (np.abs(got - want) <= _last_place(want, 8)).all()
+
+    def test_the_weights_are_the_gathered_scores_bit_for_bit(self):
+        p = _layer_inputs(seed=9, tokens=300)
+        picked, weights = route_top_k(p["x"], p["router"], p["bias"], 3,
+                                      scaling=1.0, normalise=False)
+        scores = jax.nn.sigmoid(jnp.dot(
+            p["x"], p["router"], preferred_element_type=jnp.float32))
+        assert np.array_equal(
+            weights, jnp.take_along_axis(scores, picked, axis=-1))
 
 
 # --------------------------------------------------------------------- #
