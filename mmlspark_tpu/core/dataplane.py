@@ -1,8 +1,8 @@
 """Async data plane: host<->device pipelining primitives.
 
-BENCH_r05's headroom note names the bottleneck: end-to-end model-runner
-throughput is host->device transfer bound — the chip idles while Python
-featurizes, pads, and `device_put`s the next batch one step at a time.
+A batch loop that featurizes, pads and `device_put`s the next batch one
+step at a time leaves the chip idle while Python works, and is bound by
+the host->device transfer.
 Input pipelining as a first-class reusable layer is the standard cure
 (tf.data, Murray et al. 2021; Pathways' asynchronous dispatch, Barham et
 al. 2022). This module is that layer, shared by the four batch loops that

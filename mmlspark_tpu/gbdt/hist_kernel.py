@@ -218,7 +218,7 @@ def _hist_group() -> int:
 def _fused_enabled() -> bool:
     """The fused variant is opt-in (MMLSPARK_TPU_FUSED_HIST=1) until a chip
     sweep proves it beats the per-feature kernel; per-feature chunk=1024
-    is the default the bench rides."""
+    is the default."""
     import os
 
     return os.environ.get("MMLSPARK_TPU_FUSED_HIST", "0") == "1"

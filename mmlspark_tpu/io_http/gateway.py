@@ -616,8 +616,8 @@ class GatewayTier:
       (`checkpoint_dir/worker-N`): any single worker's death loses
       nothing — its shard replays on respawn, and no two workers ever
       contend on one journal file
-    * `kill_worker`/`respawn_worker` are the chaos hooks the bench's
-      kill-window drill drives; a killed worker's in-flight connections
+    * `kill_worker`/`respawn_worker` are the chaos hooks a kill-window
+      drill drives; a killed worker's in-flight connections
       reset, which clients absorb with a status-0-safe resend
     * a small control server (`control_url`, parent process) serves
       GET /workers for `diagnose.py --gateway` — the shared data port

@@ -441,8 +441,7 @@ class DeepModelTransformer(Model):
         # gather schedule: XLA's monolithic all_gather by default; the
         # hand-scheduled collective-permute ring (same bytes, each step
         # independently schedulable) when the phase ledger showed the
-        # gather NOT overlapping compute on this mesh.  bench's TP rung
-        # measures both and prints which schedule hides the collective.
+        # gather NOT overlapping compute on this mesh.
         ring = os.environ.get("MMLSPARK_TPU_RING_GATHER", "") == "1"
 
         def tp_body(variables, x):

@@ -1,10 +1,8 @@
 """Phase ledger: per-dispatch performance attribution ("where did the µs go").
 
-The ROADMAP's two loudest open items are performance indictments nothing
-in the codebase can explain: MULTICHIP_r07 shows per-chip throughput
-collapsing to 0.06x at 8 devices (shard_skew_ratio 4.67), and the PR 9
-bench shows the resident device path losing to the host tree walk at
-every concurrency. Metrics say *that* it is slow; spans say *when*; this
+Two questions metrics and spans cannot answer: why per-chip throughput
+falls as a data mesh grows, and why a resident device path loses to the
+host path it replaced. Metrics say *that* it is slow; spans say *when*; this
 module says *where*: every fused-segment dispatch and every serving
 hot-path request decomposes into a fixed vocabulary of attributed
 phases, and the per-segment / per-shard totals aggregate into an
@@ -30,8 +28,7 @@ Design constraints mirror metrics/tracing/recorder:
   cycles; jax is only touched inside the fail-soft cost-analysis helper.
 * The DISARMED path is one attribute check: `profiler.ledger(...)`
   returns a shared null ledger whose every method is a no-op — the
-  instrumentation stays in production code (bench.py gates the armed
-  cost at <=1.02x serving p50, same bar as the flight recorder).
+  instrumentation stays in production code.
 * Injectable clock (duck-typed `monotonic()`, resilience FakeClock
   fits): ledger unit tests advance time explicitly, no real sleeps.
 * Every sink is optional and fail-soft: histograms into a

@@ -38,7 +38,7 @@ Query engine (on the store)
     firing means the fleet is firing).
 
 `RegressionWatch`
-    The runtime analogue of `tools/bench_gate.py`: continuously compares
+    Drift detection at run time: continuously compares
     current phase-ledger attribution (compute/collective/d2h shares,
     shard skew) and serving p50/p99 against a recorded-baseline window
     and raises a `timeline.regression` alert when a series drifts
@@ -820,8 +820,8 @@ _WATCH_PHASES = ("compute", "collective", "d2h")
 
 
 class RegressionWatch:
-    """Live analogue of `tools/bench_gate.py`: drift detection against a
-    recorded baseline instead of an offline round trajectory.
+    """Drift detection against a recorded baseline, while the process
+    runs.
 
     Every evaluation derives the current value of each watched series
     over the last `current_s` seconds, then rebuilds the same value for

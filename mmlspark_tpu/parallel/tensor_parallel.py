@@ -63,8 +63,7 @@ def ring_all_gather(y, axis_name: str, axis: int = -1):
     behind the matmul.  Decomposed into a ring of permutes, each step is
     independently schedulable, so compute slides between steps — the
     classic fallback when the phase ledger shows the gather NOT
-    overlapping (SNIPPETS.md [3] pattern; bench_fused_sharded's TP rung
-    measures both schedules and reports which one hides the collective).
+    overlapping (SNIPPETS.md [3] pattern).
 
     Bit-exact by construction: blocks are moved, never added — chip i's
     slice lands in slot i on every chip, the same disjoint concatenation

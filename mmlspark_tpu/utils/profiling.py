@@ -15,8 +15,6 @@ where HBM bandwidth and MXU time go. Usage:
 
 `device_trace` is also switchable by env var: MMLSPARK_TPU_TRACE_DIR set ->
 every `device_trace(None)` call traces into it; unset -> no-op context.
-bench.py wraps its timed sections in `device_trace(None)` so a single env
-var turns the headline benchmark into a profiled run.
 """
 
 from __future__ import annotations

@@ -53,3 +53,26 @@ def subprocess_env():
         [repo] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else [])
     )
     return env
+
+
+def at_device_shapes(pipeline_at, table, bs, shards=1):
+    """`table` scored by `pipeline_at(rows)`, the same stages built to run
+    `rows` at a time on one device, one batch of a fused pipeline (`bs`
+    rows) after the other, `rows` being what ONE DEVICE of a `shards`-way
+    data mesh holds of that batch once it is padded to its rung. The same
+    program at the same shape answers bit for bit; at another shape it
+    need not (XLA:CPU sums a one-row product in another order than a
+    larger one's, and where the sum cancels the outputs drift by tens of
+    units in the last place)."""
+    from mmlspark_tpu.core.dataplane import ShapeBucketer
+
+    bucketer = ShapeBucketer(bs, shards=shards)
+    out = None
+    for lo in range(0, table.num_rows, bs):
+        m = min(bs, table.num_rows - lo)
+        rows = bucketer.bucket_for(m) // shards
+        # whole batches of `rows`: the last row again, as the bucketer pads
+        idx = np.minimum(np.arange(lo, lo + -(-m // rows) * rows), lo + m - 1)
+        part = pipeline_at(rows).transform(table.gather(idx)).take(m)
+        out = part if out is None else out.concat(part)
+    return out

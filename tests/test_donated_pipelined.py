@@ -5,8 +5,9 @@ The r08 dispatch path adds three throughput levers and this suite pins
 the contract that none of them may move a single bit:
 
 * buffer donation (`donate_buffers`) aliases the uploaded batch into the
-  executable's workspace — byte-identity at EVERY bucket rung, ragged
-  tails included, single-device and on the 8-device mesh, because a
+  executable's workspace — byte-identity at EVERY bucket rung (against
+  the staged stages run at that rung's shape), ragged tails included,
+  single-device and on the 8-device mesh, because a
   donated program that re-read its input would corrupt exactly the rungs
   the ladder exercises;
 * dispatch pipelining (`pipeline_depth`) keeps K+1 batches in flight —
@@ -21,10 +22,12 @@ Runs on the conftest-forced 8 host-platform CPU devices.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as np
 import pytest
+from conftest import at_device_shapes
 
 from mmlspark_tpu.core.dataplane import ShapeBucketer
 from mmlspark_tpu.core.fusion import fuse
@@ -47,6 +50,11 @@ def _xtable(n, seed=3):
     return Table({"x": rng.normal(size=(n, 16)).astype(np.float32)})
 
 
+@functools.cache
+def _staged_at(rows):
+    return pipeline_model(*_stages(bs=rows))
+
+
 # --------------------------------------------------------------------- #
 # donation byte-identity
 # --------------------------------------------------------------------- #
@@ -64,29 +72,34 @@ class TestDonationByteIdentity:
                 sizes.add(rung - 1)
         return sorted(sizes)
 
+    # The staged side runs each batch at the rung the fused side pads it
+    # to (on the mesh, at one device's share of it): the same program at
+    # the same shape, which is what `_HotPath.warm_rung` holds a resident
+    # lane to at serving warm-up, bit for bit.
+
     def test_every_rung_single_device(self):
-        staged = pipeline_model(*_stages())
         donated = fuse(pipeline_model(*_stages()), mini_batch_size=32,
                        donate_buffers=True)
         plain = fuse(pipeline_model(*_stages()), mini_batch_size=32,
                      donate_buffers=False)
         for n in self._rung_sizes(32, 1):
             table = _xtable(n)
-            ref = np.asarray(staged.transform(table)["output"])
+            ref = np.asarray(
+                at_device_shapes(_staged_at, table, 32)["output"])
             out_d = np.asarray(donated.transform(table)["output"])
             out_p = np.asarray(plain.transform(table)["output"])
             assert out_d.tobytes() == ref.tobytes(), f"donated != staged @ {n}"
             assert out_p.tobytes() == ref.tobytes(), f"plain != staged @ {n}"
 
     def test_every_rung_ragged_mesh8(self, mesh8):
-        staged = pipeline_model(*_stages())
         donated = fuse(pipeline_model(*_stages()), mini_batch_size=32,
                        mesh=mesh8, donate_buffers=True)
         plain = fuse(pipeline_model(*_stages()), mini_batch_size=32,
                      mesh=mesh8, donate_buffers=False)
         for n in self._rung_sizes(32, 8):
             table = _xtable(n)
-            ref = np.asarray(staged.transform(table)["output"])
+            ref = np.asarray(
+                at_device_shapes(_staged_at, table, 32, shards=8)["output"])
             out_d = np.asarray(donated.transform(table)["output"])
             out_p = np.asarray(plain.transform(table)["output"])
             assert out_d.tobytes() == ref.tobytes(), \

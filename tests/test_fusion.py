@@ -11,12 +11,14 @@ streaming score through the fused path automatically.
 
 from __future__ import annotations
 
+import functools
 import json
 import threading
 import urllib.request
 
 import numpy as np
 import pytest
+from conftest import at_device_shapes
 
 from mmlspark_tpu.core import (
     DeviceKernel,
@@ -222,7 +224,11 @@ class TestByteIdentity:
         t = _table(33)
         asm = AssembleFeatures(columns_to_featurize=list("abcdefgh")).fit(t)
         runner = _mlp(bfloat16=True)
-        staged = pipeline_model(asm, runner).transform(t)
+        # 33 rows in batches of 8 end in a batch of ONE row: the staged
+        # stages run each batch at the fused side's shape (conftest)
+        staged = at_device_shapes(functools.cache(
+            lambda rows: pipeline_model(asm, _mlp(
+                bfloat16=True, mini_batch_size=rows))), t, 8)
         fm = fuse(pipeline_model(asm, runner), mini_batch_size=8)
         fused = fm.transform(t)
         assert fm.last_stats["segments"][0]["kind"] == "fused"
@@ -329,7 +335,9 @@ class TestRaggedLadder:
         asm = AssembleFeatures(columns_to_featurize=list("abcdefgh")).fit(
             asm_fit)
         fm = fuse(pipeline_model(asm, runner), mini_batch_size=16)
-        staged = pipeline_model(asm, runner)
+        # the staged stages at the rung the fused side pads a size to
+        staged_at = functools.cache(lambda rows: pipeline_model(
+            asm, _mlp(mini_batch_size=rows)))
 
         # warm the full ladder (every bucket compiles once)
         for n in ShapeBucketer(16).ladder:
@@ -339,7 +347,7 @@ class TestRaggedLadder:
 
         for i, n in enumerate((3, 7, 1, 29, 16, 2, 41, 5)):
             t = _table(n, seed=100 + i)
-            s, f = staged.transform(t), fm.transform(t)
+            s, f = at_device_shapes(staged_at, t, 16), fm.transform(t)
             for c in s.columns:
                 assert s[c].tobytes() == f[c].tobytes(), (n, c)
         soaked = seg._exec_cache.stats()

@@ -1,16 +1,14 @@
 """Perf-attribution layer (ISSUE 13): phase-ledger units on FakeClock,
 the serving hot path's phase decomposition vs its measured RTT, the
-Perfetto round trip of phase child-spans, fleet-aggregated attribution
-across two replicas, and the bench regression gate's selftest.
+Perfetto round trip of phase child-spans, and fleet-aggregated
+attribution across two replicas.
 
 Everything time-dependent runs on FakeClock except the one live-server
 test, whose assertion is a coverage band (phase sum vs RTT), not an
 absolute latency.
 """
 
-import importlib.util
 import json
-import os
 import urllib.request
 
 import pytest
@@ -27,17 +25,6 @@ from mmlspark_tpu.observability.profiler import (
 from mmlspark_tpu.observability.tracing import (Tracer, load_jsonl,
                                                 phase_children)
 from mmlspark_tpu.resilience.policy import FakeClock
-
-TOOLS = os.path.join(os.path.dirname(__file__), "..", "tools")
-
-
-def _load_tool(name: str):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(TOOLS, f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
 
 # --------------------------------------------------------------------- #
 # ledger units on FakeClock                                             #
@@ -309,69 +296,3 @@ class TestFleetAttribution:
         assert snap["phase_us"]["compute"] == \
             pytest.approx(live["phase_us"]["compute"])
         assert snap["phase_sum_us"] == pytest.approx(live["phase_sum_us"])
-
-
-# --------------------------------------------------------------------- #
-# bench regression gate                                                 #
-# --------------------------------------------------------------------- #
-
-
-class TestBenchGate:
-    @pytest.fixture(scope="class")
-    def bg(self):
-        return _load_tool("bench_gate")
-
-    def test_direction_inference(self, bg):
-        assert bg.direction("gbdt_rows_per_sec") == "higher"
-        assert bg.direction("serving_p50_ms") == "lower"
-        assert bg.direction("profiler_overhead") == "lower"
-        assert bg.direction("shard_skew_ratio") == "lower"
-        assert bg.direction("seq_len") is None  # config scalar: ungated
-
-    def _rounds(self, bg, tmp_path, per_round):
-        for i, metrics in enumerate(per_round, start=1):
-            bg._fake_round(str(tmp_path / f"BENCH_r{i:02d}.json"), metrics)
-        return bg.load_rounds(str(tmp_path / "BENCH_r*.json"),
-                              bg.bench_metrics)
-
-    def test_stable_history_catches_regression(self, bg, tmp_path):
-        rounds = self._rounds(bg, tmp_path, [
-            {"serving_p50_ms": 1.00, "gbdt_rows_per_sec": 1e6},
-            {"serving_p50_ms": 1.05, "gbdt_rows_per_sec": 1.02e6},
-            {"serving_p50_ms": 2.40, "gbdt_rows_per_sec": 0.4e6},
-        ])
-        probs, _ = bg.gate_rounds(rounds, 0.15, "t")
-        assert len(probs) == 2
-        assert any("serving_p50_ms" in p for p in probs)
-        assert any("gbdt_rows_per_sec" in p for p in probs)
-
-    def test_noisy_history_widens_the_band(self, bg, tmp_path):
-        rounds = self._rounds(bg, tmp_path, [
-            {"serving_p50_ms": 1.0}, {"serving_p50_ms": 3.1},
-            {"serving_p50_ms": 0.9}, {"serving_p50_ms": 2.4},
-        ])
-        probs, _ = bg.gate_rounds(rounds, 0.15, "t")
-        assert probs == []
-
-    def test_new_row_is_reported_never_gated(self, bg, tmp_path):
-        rounds = self._rounds(bg, tmp_path, [
-            {"serving_p50_ms": 1.0},
-            {"serving_p50_ms": 1.0, "profiler_overhead": 1.01},
-        ])
-        probs, report = bg.gate_rounds(rounds, 0.15, "t")
-        assert probs == []
-        assert any("NEW" in ln and "profiler_overhead" in ln
-                   for ln in report)
-
-    def test_truncated_tail_still_yields_metrics(self, bg):
-        # artifacts keep only the LAST ~2000 chars of stdout, so the
-        # JSON line is usually cut mid-object — the pair scan must
-        # recover complete rows anyway
-        rec = {"rc": 0, "parsed": None,
-               "tail": '... "serving_p50_ms": 0.61, "gbdt_rows_per'}
-        assert bg.bench_metrics(rec) == {"serving_p50_ms": 0.61}
-
-    def test_single_round_gates_nothing(self, bg, tmp_path):
-        rounds = self._rounds(bg, tmp_path, [{"serving_p50_ms": 1.0}])
-        probs, report = bg.gate_rounds(rounds, 0.15, "t")
-        assert probs == [] and "nothing to gate" in report[0]

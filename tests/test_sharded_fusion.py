@@ -4,7 +4,8 @@ The contract layered on top of test_fusion.py's: a mesh changes WHERE a
 fused segment's work lands (rows sharded over the data axis, params
 replicated or kernel-placed), never WHAT it produces.  Fused-sharded,
 fused-single-device, and staged runs are byte-identical — including
-ragged tails riding mesh-divisible buckets and the tensor-parallel MLP
+ragged tails riding mesh-divisible buckets (a shard answering what one
+device answers at the shard's row count) and the tensor-parallel MLP
 body on a 2-D data x model mesh.  Mesh shape is part of the executable
 cache's family key (a chip-count change is a new family, never a
 recompile of an old one), a fixed mesh shape soaks with zero steady-state
@@ -17,8 +18,11 @@ with (partitions-in-one-JVM local[*] sessions).
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import pytest
+from conftest import at_device_shapes
 
 from mmlspark_tpu.core.dataplane import ExecutableCache, ShapeBucketer
 from mmlspark_tpu.core.fusion import FusedPipelineModel, fuse
@@ -67,7 +71,13 @@ class TestShardedByteIdentity:
         out_1 = np.asarray(fused1.transform(table)["output"])
         out_8 = np.asarray(fused8.transform(table)["output"])
         assert out_1.tobytes() == out_s.tobytes()
-        assert out_8.tobytes() == out_1.tobytes()
+        # a shard holds 4 rows of a full chunk and ONE of the tail's rung
+        # of 8: the mesh answers what one device answers at a shard's shape
+        fused1_at = functools.cache(lambda rows: fuse(
+            pipeline_model(*_stages()), mini_batch_size=rows))
+        out_1_sharded = np.asarray(
+            at_device_shapes(fused1_at, table, 32, shards=8)["output"])
+        assert out_8.tobytes() == out_1_sharded.tobytes()
         assert fused8.last_stats["mesh_shape"] == "8x1"
         seg = fused8.last_stats["segments"][0]
         assert seg["kind"] == "fused"

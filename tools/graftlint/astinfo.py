@@ -370,8 +370,7 @@ def index_module(path: str, relpath: str, source: "str | None" = None
 
 def build_index(root: str, scan: "list[str] | None" = None) -> Index:
     if scan is None:
-        scan = [os.path.join(root, "mmlspark_tpu"),
-                os.path.join(root, "bench.py")]
+        scan = [os.path.join(root, "mmlspark_tpu")]
     paths = []
     for entry in scan:
         if os.path.isfile(entry):

@@ -4,8 +4,6 @@
 #   tools/runme.sh test      full suite on the 8-virtual-device CPU mesh
 #   tools/runme.sh quick     fast subset (core + gbdt + ops)
 #   tools/runme.sh dryrun    multi-chip sharding dryrun (8 forced CPU devices)
-#   tools/runme.sh bench     headline benchmark (needs a TPU; fails without one)
-#   tools/runme.sh bench-cpu benchmark smoke, explicitly on the CPU
 #   tools/runme.sh smoke     chip_smoke.py (needs a TPU; exits 2 without one)
 #   tools/runme.sh docs      regenerate docs/api.md from the stage registry
 #   tools/runme.sh ci        everything the CI gate runs (tools/ci.sh)
@@ -16,8 +14,6 @@ case "${1:-help}" in
   test)      python -m pytest tests/ -q ;;
   quick)     python -m pytest tests/test_core.py tests/test_gbdt.py tests/test_ops.py -q ;;
   dryrun)    JAX_PLATFORMS=cpu python -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun ok')" ;;
-  bench)     python bench.py ;;
-  bench-cpu) JAX_PLATFORMS=cpu python bench.py ;;
   smoke)     python chip_smoke.py ;;
   docs)      python tools/gen_api_docs.py ;;
   ci)        bash tools/ci.sh ;;

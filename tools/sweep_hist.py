@@ -178,12 +178,13 @@ def full_fit_ab():
     faster kernel didn't silently break learning. One row per candidate
     configuration; the winner's numbers go to PERF.md and
     the default flip happens on this table, not on µs/build."""
-    import bench as bench_mod
+    from mmlspark_tpu.automl.metrics import auc as roc_auc
     from mmlspark_tpu.core.kernels import set_kernel_mode
     from mmlspark_tpu.gbdt.booster import Booster, TrainOptions
+    from tools.build_zoo import make_dataset
 
     n_fit, n_valid, f_dim = 200_000, 8_192, 28
-    x, y = bench_mod.make_dataset(n_fit + n_valid, f_dim)
+    x, y = make_dataset(n_fit + n_valid, f_dim)
     x, x_v, y, y_v = x[:n_fit], x[n_fit:], y[:n_fit], y[n_fit:]
     base = dict(objective="binary", num_iterations=50, num_leaves=63,
                 learning_rate=0.1)
@@ -220,7 +221,7 @@ def full_fit_ab():
                 t0 = time.perf_counter()
                 b = Booster.train(x, y, TrainOptions(**base, **extra))
                 fit_s = time.perf_counter() - t0
-            auc = bench_mod._auc(y_v, np.asarray(b.predict(x_v)))
+            auc = roc_auc(y_v, np.asarray(b.predict(x_v)))
             rows.append((label, fit_s, auc))
             print(f"{label:34s} warm {fit_s:7.2f} s "
                   f"(cold {cold_s:6.2f})   {n_fit / fit_s:12,.0f} rows/s"
@@ -233,9 +234,8 @@ def full_fit_ab():
     if rows:
         # the winner must LEARN, not just finish: a fast config with a
         # silently broken kernel (AUC collapse) can never take the table
-        best_auc = max(r[2] for r in rows if r[2] is not None)
-        sound = [r for r in rows
-                 if r[2] is not None and r[2] >= max(0.75, best_auc - 0.01)]
+        best_auc = max(r[2] for r in rows)
+        sound = [r for r in rows if r[2] >= max(0.75, best_auc - 0.01)]
         if sound:
             best = min(sound, key=lambda r: r[1])
             print(f"FULL-FIT WINNER: {best[0]} ({best[1]:.2f} s, "
