@@ -19,7 +19,8 @@ attention needs):
 - ``flash_attention``: a Pallas TPU kernel for the forward hot path —
   the (block_q, block_k) score tile lives only in VMEM, never HBM, with
   the online-softmax running max / denominator / accumulator carried in
-  VMEM scratch across the sequential key-block grid dimension.
+  VMEM scratch across the sequential key-block grid dimension. The tile
+  is chosen here, by `flash_tiles`, from the lengths and the dtype.
   DIFFERENTIABLE via `jax.custom_vjp`: the kernel also emits the per-row
   logsumexp, and the backward is the standard flash recomputation as a
   pure-XLA k-block scan (compiles on every backend; O(T) score memory).
@@ -42,10 +43,11 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..observability.metrics import get_registry
 from ..parallel.ring_attention import dense_attention
 
 __all__ = ["dense_attention", "chunked_attention", "flash_attention",
-           "SelfAttention"]
+           "flash_tiles", "SelfAttention"]
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/max NaN-free
 
@@ -143,29 +145,50 @@ def chunked_attention(q, k, v, causal: bool = False,
 # Pallas flash forward                                                  #
 # --------------------------------------------------------------------- #
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
+def flash_tiles(tq: int, tk: int, dtype) -> tuple[int, int]:
+    """The (block_q, block_k) the flash forward works on, from what it can
+    see. Read on a v5e (PERF.md, PRs 27 and 30): the kernel pays about
+    0.6 us a grid step whatever is in it, so the largest tile wins: alone
+    it gives 11 / 27 / 48 / 65 TFLOP/s of the causal triangle at tiles of
+    128 / 256 / 512 / 1024 over 4096 tokens, and 14 / 30 / 97 of the
+    square at 128 / 256 / 512 over 512. The cap is 1024 for inputs of 2
+    bytes and 512 for float32, where a 1024 x 1024 tile passes the default
+    16 MB of scoped VMEM (what a kernel asks beyond the default is taken
+    from the whole program). A tile is a multiple of 128, or the whole of
+    a sequence shorter than that, and is never bought with padding: the
+    padded length stays within one eighth of the length rounded up to 128
+    (512 -> 512, 514 -> 640, 1100 -> two of 640, 4096 -> 1024)."""
+    cap = 1024 if jnp.dtype(dtype).itemsize <= 2 else 512
+
+    def padded(t, b):
+        return -(-t // b) * b
+
+    def tile(t):
+        if t <= 128:
+            return max(t, 1)
+        most = padded(t, 128) + padded(t, 128) // 8
+        return max(b for b in range(128, cap + 1, 128)
+                   if padded(t, b) <= most)
+
+    return tile(tq), tile(tk)
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                   block_q, block_k, num_kv, causal, tk_valid, scale):
     import jax.experimental.pallas as pl
 
     qi = pl.program_id(1)
     kv = pl.program_id(2)
+    # only a padded sequence needs the key mask: decided here, in Python
+    padded = tk_valid < num_kv * block_k
 
-    @pl.when(kv == 0)
-    def _init():
-        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
-
-    def step(mask_keys: bool, mask_causal: bool):
-        """One key block folded into the running max / denominator /
-        accumulator. `mask_keys`: keys at or past `tk_valid` are padding;
+    def scores(mask_keys: bool, mask_causal: bool):
+        """This step's (bq, bk) score tile, and which of it counts (None:
+        all of it). `mask_keys`: keys at or past `tk_valid` are padding;
         `mask_causal`: a query sees the keys at or before it."""
-        qb = q_ref[0]                                         # (bq, D)
-        kb = k_ref[0]                                         # (bk, D)
         s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # (bq, bk)
-
         ok = None
         if mask_keys or mask_causal:
             kpos = kv * block_k + jax.lax.broadcasted_iota(
@@ -178,30 +201,66 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
             ok = (qpos >= kpos) if ok is None else ok & (qpos >= kpos)
         if ok is not None:
             s = jnp.where(ok, s, _NEG_INF)
+        return s, ok
 
-        m_prev = m_sc[...]                                    # (bq, 1)
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        p = jnp.exp(s - m_new)                                # (bq, bk)
+    def weigh(s, ok, m):
+        """exp(s - m): its row sums (bq, 1) and its product with the
+        values (bq, Dv)."""
+        p = jnp.exp(s - m)                                    # (bq, bk)
         if ok is not None:
             # masked entries must contribute 0 even when the whole row is
-            # masked (then m_new == _NEG_INF and exp(s - m_new) == 1, not 0)
+            # masked (then m == _NEG_INF and exp(s - m) == 1, not 0)
             p = jnp.where(ok, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)                        # (bq, 1)
-        l_sc[...] = l_sc[...] * corr + p.sum(-1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (bq, Dv)
+            preferred_element_type=jnp.float32)
+        return p.sum(-1, keepdims=True), pv
+
+    def write(m, l, acc):
+        out = acc / jnp.maximum(l, 1e-30)
+        out = jnp.where(l > 0, out, 0.0)
+        o_ref[0] = out.astype(o_ref.dtype)
+        # per-row logsumexp, the backward pass's softmax residual;
+        # +inf on fully-masked rows makes exp(s - lse) vanish there
+        lse_ref[0] = jnp.where(
+            l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), jnp.inf)
+
+    if num_kv == 1:
+        # the softmax is whole in this tile: no running maximum, no
+        # correction, no accumulator through scratch (the same numbers,
+        # bit for bit, as one step of the path below)
+        s, ok = scores(padded, causal)
+        m = s.max(-1, keepdims=True)
+        write(m, *weigh(s, ok, m))
+        return
+
+    m_sc, l_sc, acc_sc = scratch
+
+    @pl.when(kv == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    def step(mask_keys: bool, mask_causal: bool):
+        """One key block folded into the running max / denominator /
+        accumulator."""
+        s, ok = scores(mask_keys, mask_causal)
+        m_prev = m_sc[...]                                    # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        l, pv = weigh(s, ok, m_new)
+        corr = jnp.exp(m_prev - m_new)                        # (bq, 1)
+        l_sc[...] = l_sc[...] * corr + l
         acc_sc[...] = acc_sc[...] * corr + pv
         m_sc[...] = m_new
 
     if not causal:
-        step(True, False)
+        step(padded, False)
     else:
         # key blocks wholly above the diagonal are skipped, not masked
         # (their index map re-names the last block needed, so nothing is
         # fetched for them either); blocks wholly below it need no causal
-        # mask, and only a padded sequence needs the key mask
-        padded = tk_valid < num_kv * block_k
+        # mask
         needed = kv * block_k <= qi * block_q + block_q - 1
         crosses = (kv + 1) * block_k - 1 > qi * block_q
 
@@ -215,29 +274,20 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
 
     @pl.when(kv == num_kv - 1)
     def _finalize():
-        l = l_sc[...]
-        out = acc_sc[...] / jnp.maximum(l, 1e-30)
-        out = jnp.where(l > 0, out, 0.0)
-        o_ref[0] = out.astype(o_ref.dtype)
-        # per-row logsumexp, the backward pass's softmax residual;
-        # +inf on fully-masked rows makes exp(s - lse) vanish there
-        lse_ref[0] = jnp.where(
-            l > 0, m_sc[...] + jnp.log(jnp.maximum(l, 1e-30)), jnp.inf)
+        write(m_sc[...], l_sc[...], acc_sc[...])
 
 
 def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
-    """Pallas forward; returns (out (B,Tq,H,Dv), lse (B,H,Tq) f32). The
+    """Pallas forward at the given tile (a multiple of what Mosaic tiles,
+    or the whole length); returns (out (B,Tq,H,Dv), lse (B,H,Tq) f32). The
     values may have a width of their own (latent attention scores over
     192 channels and weighs values of 128)."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
     orig_dtype = q.dtype
-    b, tq_orig, h, d = q.shape
+    b, _, h, d = q.shape
     dv = v.shape[-1]
-    tk_orig = k.shape[1]
-    block_q = min(block_q, max(tq_orig, 1))
-    block_k = min(block_k, max(tk_orig, 1))
     q, tq = _pad_seq(q, block_q)
     k, tk = _pad_seq(k, block_k)
     v, _ = _pad_seq(v, block_k)
@@ -282,7 +332,8 @@ def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct(qf.shape[:2] + (dv,), orig_dtype),
             jax.ShapeDtypeStruct(qf.shape[:2] + (1,), jnp.float32),
         ],
-        scratch_shapes=[
+        # one key block carries nothing from step to step
+        scratch_shapes=[] if nk == 1 else [
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
@@ -352,34 +403,51 @@ def _flash_bwd_xla(q, k, v, out, lse, do, causal, k_chunk):
             jnp.moveaxis(dv, 1, 2).astype(v.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_diff(q, k, v, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_diff(q, k, v, causal, block_q, block_k, bwd_chunk, interpret):
     out, _ = _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret)
     return out
 
 
-def _flash_diff_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _flash_diff_fwd(q, k, v, causal, block_q, block_k, bwd_chunk, interpret):
     out, lse = _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret)
     return out, (q, k, v, out, lse)
 
 
-def _flash_diff_bwd(causal, block_q, block_k, interpret, res, do):
+def _flash_diff_bwd(causal, block_q, block_k, bwd_chunk, interpret, res, do):
     q, k, v, out, lse = res
-    return _flash_bwd_xla(q, k, v, out, lse, do, causal, block_k)
+    return _flash_bwd_xla(q, k, v, out, lse, do, causal, bwd_chunk)
 
 
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
 
 
-def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False):
+def flash_attention(q, k, v, causal: bool = False,
+                    block_q: int | None = None, block_k: int | None = None,
+                    bwd_chunk: int | None = 128, interpret: bool = False):
     """Pallas TPU flash attention, DIFFERENTIABLE: the forward is the
     Pallas online-softmax kernel (score tile only in VMEM) and the
     backward is the standard flash recomputation as a pure-XLA k-block
     scan driven by the kernel's saved logsumexp. Same contract as
-    `dense_attention`. `interpret=True` runs the forward kernel on CPU
-    for tests."""
-    return _flash_diff(q, k, v, causal, block_q, block_k, interpret)
+    `dense_attention`.
+
+    The forward's tile is `flash_tiles`' unless a test names one.
+    `bwd_chunk` is the backward scan's key chunk and no tile: the scan
+    materialises a (B, H, Tq, chunk) float32 score slab in HBM, so it
+    does not follow the forward to 512 or 1024 (None: the forward's key
+    tile). `interpret=True` runs the forward kernel on CPU for tests."""
+    tq, tk = q.shape[1], k.shape[1]
+    rule_q, rule_k = flash_tiles(tq, tk, q.dtype)
+    block_q = rule_q if block_q is None else min(block_q, max(tq, 1))
+    block_k = rule_k if block_k is None else min(block_k, max(tk, 1))
+    # counted where the call is traced: once a compiled shape
+    get_registry().counter(
+        "mmlspark_tpu_flash_calls_total",
+        "flash-attention forward calls traced, by the tile they run at",
+        labels=("tile", "causal")).labels(
+            tile=f"{block_q}x{block_k}", causal=str(causal).lower()).inc()
+    return _flash_diff(q, k, v, causal, block_q, block_k,
+                       block_k if bwd_chunk is None else bwd_chunk, interpret)
 
 
 # --------------------------------------------------------------------- #

@@ -329,13 +329,8 @@ class LatentAttention(nn.Module):
         # the innermost scope names the Pallas call in a device trace
         with jax.named_scope("mla.attend"), jax.named_scope(self.name):
             if impl == "flash":
-                # read on a v5e (PERF.md, PR 27): the kernel alone gives
-                # 11 / 27 / 48 / 65 TFLOP/s of the causal triangle at
-                # blocks of 128 / 256 / 512 / 1024; with float32 inputs a
-                # 1024 x 1024 tile passes its 16 MB of scoped VMEM
-                block = 1024 if jnp.dtype(dt).itemsize <= 2 else 512
-                o = flash_attention(q, k, v, causal=True, block_q=block,
-                                    block_k=block)
+                # None: the backward scans the keys a forward tile at a time
+                o = flash_attention(q, k, v, causal=True, bwd_chunk=None)
             elif impl == "chunked":
                 o = chunked_attention(q, k, v, causal=True)
             elif impl == "dense":

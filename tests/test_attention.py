@@ -11,13 +11,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from mmlspark_tpu.nn import attention
 from mmlspark_tpu.nn.attention import (
     SelfAttention,
     chunked_attention,
     dense_attention,
     flash_attention,
+    flash_tiles,
 )
 from mmlspark_tpu.nn.models import make_model
+from mmlspark_tpu.observability.metrics import get_registry
 
 SHAPES = [
     # (B, Tq, Tk, H, D, causal, chunk)
@@ -81,7 +84,7 @@ class TestParity:
         gd = jax.grad(loss(lambda q, k, v: dense_attention(
             q, k, v, causal=causal)), argnums=(0, 1, 2))(q, k, v)
         gf = jax.grad(loss(lambda q, k, v: flash_attention(
-            q, k, v, causal=causal, block_q=16, block_k=16,
+            q, k, v, causal=causal, block_q=16, block_k=16, bwd_chunk=16,
             interpret=True)), argnums=(0, 1, 2))(q, k, v)
         for a, b_ in zip(gd, gf):
             np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
@@ -98,7 +101,7 @@ class TestParity:
         gd = jax.grad(loss(lambda q, k, v: dense_attention(
             q, k, v, causal=True)), argnums=(0, 1, 2))(q, k, v)
         gf = jax.grad(loss(lambda q, k, v: flash_attention(
-            q, k, v, causal=True, block_q=8, block_k=8,
+            q, k, v, causal=True, block_q=8, block_k=8, bwd_chunk=8,
             interpret=True)), argnums=(0, 1, 2))(q, k, v)
         for a, b_ in zip(gd, gf):
             np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
@@ -130,6 +133,132 @@ class TestParity:
                              interpret=True)
         np.testing.assert_allclose(ch, ref, atol=2e-5)
         np.testing.assert_allclose(fl, ref, atol=2e-5)
+
+
+def _flash_calls(tile, causal):
+    return get_registry().counter(
+        "mmlspark_tpu_flash_calls_total", labels=("tile", "causal")).labels(
+            tile=tile, causal=str(causal).lower()).value
+
+
+class TestFlashTiles:
+    """The tile the flash forward works on is chosen inside
+    `flash_attention` from the lengths and the dtype."""
+
+    TABLE = {
+        # tokens: (tile for 2-byte inputs, tile for float32)
+        1: (1, 1), 100: (100, 100), 128: (128, 128), 512: (512, 512),
+        514: (640, 128), 640: (640, 128), 1100: (640, 384),
+        1408: (768, 512), 4096: (1024, 512),
+    }
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("t", sorted(TABLE))
+    def test_rule_as_a_table(self, t, dtype, causal):
+        want = self.TABLE[t][jnp.dtype(dtype).itemsize > 2]
+        assert flash_tiles(t, t, dtype) == (want, want)
+        # keys and queries choose alone
+        assert flash_tiles(t, 128, dtype) == (want, 128)
+        padded = -(-t // want) * want
+        if t < 128:
+            assert padded == want == t        # the whole sequence, as ever
+        else:
+            aligned = -(-t // 128) * 128
+            assert want % 128 == 0
+            assert aligned <= padded <= aligned + aligned // 8
+        assert want <= (1024 if jnp.dtype(dtype).itemsize <= 2 else 512)
+        # and that is the tile the call is traced at, counted once
+        tile = f"{want}x{want}"
+        before = _flash_calls(tile, causal)
+        x = jax.ShapeDtypeStruct((1, t, 2, 8), dtype)
+        out = jax.eval_shape(
+            lambda q, k, v: flash_attention(q, k, v, causal=causal), x, x, x)
+        assert out.shape == x.shape and out.dtype == x.dtype
+        assert _flash_calls(tile, causal) == before + 1
+
+    @pytest.mark.parametrize("tq,tk,dtype,causal,key_blocks,masked", [
+        (256, 256, jnp.float32, False, 1, False),   # the encoder's case
+        (1024, 1024, jnp.float32, False, 2, False),
+        (200, 200, jnp.float32, False, 1, True),    # padded: mask stays
+        (600, 600, jnp.float32, False, 5, True),
+        (130, 300, jnp.float32, False, 1, True),    # keys and queries differ
+        (256, 256, jnp.bfloat16, True, 1, True),    # the decoder at one block
+        (1024, 1024, jnp.float32, True, 2, True),   # ... and with the skip
+        (1100, 1100, jnp.bfloat16, False, 2, True),
+    ])
+    def test_kernel_at_the_rules_tiles_matches_dense(
+            self, tq, tk, dtype, causal, key_blocks, masked):
+        q, k, v = _qkv(1, tq, tk, 2, 8, seed=tq + tk)
+        ref = dense_attention(q, k, v, causal=causal)
+        low = [x.astype(dtype) for x in (q, k, v)]
+
+        def call(q, k, v):
+            return flash_attention(q, k, v, causal=causal, interpret=True)
+
+        got = call(*low)
+        assert got.dtype == dtype
+        tol = 2e-5 if dtype == jnp.float32 else 3e-2
+        np.testing.assert_allclose(got.astype(jnp.float32), ref, atol=tol,
+                                   rtol=tol)
+        block_k = flash_tiles(tq, tk, dtype)[1]
+        assert -(-tk // block_k) == key_blocks
+        # a tile does no work the shapes rule out: no key positions where no
+        # key is padding, and with one key block no correction to a running
+        # maximum (its second exponential)
+        kernel = str(jax.make_jaxpr(call)(*low))
+        assert ("= iota[" in kernel) == masked
+        assert (kernel.count("= exp ") == 1) == (key_blocks == 1)
+
+    def test_backward_chunk_is_not_the_tile(self):
+        """A caller that names nothing (`SelfAttention`) gets the rule's
+        forward tile, 384 here, and the backward's key chunk of 128 it
+        always had: the scan takes three steps, and the gradients are
+        dense attention's."""
+        q, k, v = _qkv(1, 300, 300, 2, 8, seed=11)
+
+        def loss(fn):
+            return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+        flash = jax.grad(loss(lambda q, k, v: flash_attention(
+            q, k, v, interpret=True)), argnums=(0, 1, 2))
+        assert flash_tiles(300, 300, q.dtype) == (384, 384)
+        text = str(jax.make_jaxpr(flash)(q, k, v))
+        assert "length=3" in text and "length=1" not in text
+        whole = str(jax.make_jaxpr(jax.grad(loss(lambda q, k, v:
+            flash_attention(q, k, v, bwd_chunk=None, interpret=True)),
+            argnums=(0, 1, 2)))(q, k, v))
+        assert "length=1" in whole            # the decoder: a forward tile
+        gd = jax.grad(loss(dense_attention), argnums=(0, 1, 2))(q, k, v)
+        for a, b_ in zip(gd, flash(q, k, v)):
+            np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
+
+    def test_self_attention_names_no_block(self, monkeypatch):
+        """Off the CPU `SelfAttention(impl="flash")` calls the kernel and
+        leaves tile and backward chunk to it; values and gradients through
+        that call are the dense module's."""
+        seen = []
+
+        def recorded(q, k, v, **kw):
+            seen.append(kw)
+            return flash_attention(q, k, v, interpret=True, **kw)
+
+        x = jnp.asarray(np.random.default_rng(2).normal(size=(2, 150, 16)),
+                        jnp.float32)
+        dense = SelfAttention(num_heads=2, impl="dense")
+        variables = dense.init(jax.random.PRNGKey(0), x)
+
+        def loss(module):
+            return lambda v, x: (module.apply(v, x) ** 2).sum()
+
+        want = jax.value_and_grad(loss(dense))(variables, x)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(attention, "flash_attention", recorded)
+        got = jax.value_and_grad(loss(SelfAttention(
+            num_heads=2, impl="flash")))(variables, x)
+        assert seen == [{"causal": False}]
+        for a, b_ in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+            np.testing.assert_allclose(a, b_, atol=2e-4, rtol=2e-4)
 
 
 class TestSelfAttentionModule:

@@ -39,21 +39,35 @@ def _compile(fn, *shapes):
         jax.config.update("jax_enable_compilation_cache", before)
 
 
-@pytest.mark.parametrize("length,block,dtype", [
-    (4096, 1024, jnp.bfloat16), (512, 1024, jnp.bfloat16),
-    # float32 inputs: 1024 passes the scoped VMEM (refused on the chip,
-    # PR 27), so `LatentAttention` asks for 512 there
-    (4096, 512, jnp.float32)])
-def test_latent_attention_kernel_compiles(one_chip, length, block, dtype):
+@pytest.mark.parametrize("length,dtype", [
+    (4096, jnp.bfloat16), (512, jnp.bfloat16),
+    # float32 inputs: a tile of 1024 passes the scoped VMEM (refused on the
+    # chip, PR 27), so the rule stops at 512 there
+    (4096, jnp.float32)])
+def test_latent_attention_kernel_compiles(one_chip, length, dtype):
     """192 channels for scores, 128 for values, causal with the block
-    skip, 8 rows x 16 heads, at the blocks the module asks for."""
+    skip, 8 rows x 16 heads, at the tiles `flash_attention` chooses."""
     from mmlspark_tpu.nn.attention import flash_attention
 
     q = jax.ShapeDtypeStruct((8, length, 16, 192), dtype, sharding=one_chip)
     v = jax.ShapeDtypeStruct((8, length, 16, 128), dtype, sharding=one_chip)
     compiled = _compile(
-        lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=block,
-                                        block_k=block), q, q, v)
+        lambda q, k, v: flash_attention(q, k, v, causal=True), q, q, v)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("length,dtype", [
+    (512, jnp.bfloat16), (128, jnp.bfloat16), (512, jnp.float32),
+    # the configuration's `max_len`: a tile of 640, one key block, masked
+    (514, jnp.bfloat16)])
+def test_encoder_attention_kernel_compiles(one_chip, length, dtype):
+    """`xlmr_xxl.score_table`'s calls: 32 rows x 32 heads x 128 channels,
+    not causal, at the tiles `flash_attention` chooses (one step a row and
+    head at every length here)."""
+    from mmlspark_tpu.nn.attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((32, length, 32, 128), dtype, sharding=one_chip)
+    compiled = _compile(flash_attention, q, q, q)
     assert "tpu_custom_call" in compiled.as_text()
 
 

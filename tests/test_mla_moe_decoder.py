@@ -611,7 +611,8 @@ class TestFlashKernel:
             return lambda q, k, v: (fn(q, k, v) ** 2).sum()
 
         got = jax.grad(loss(lambda q, k, v: flash_attention(
-            q, k, v, causal=True, block_q=16, block_k=16, interpret=True)),
+            q, k, v, causal=True, block_q=16, block_k=16, bwd_chunk=16,
+            interpret=True)),
             argnums=(0, 1, 2))(q, k, v)
         want = jax.grad(loss(lambda q, k, v: dense_attention(
             q, k, v, causal=True)), argnums=(0, 1, 2))(q, k, v)

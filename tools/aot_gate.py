@@ -67,8 +67,9 @@ def hist_build(group=None, fused=False, bins_dtype=jnp.int32):
 
 def flash_build(t, grad=False):
     """Flash attention at the bench transformer's shipped head geometry
-    (d_model=512 / 8 heads -> D=64, bf16, block 128). Batch is small: the
-    Mosaic kernel is identical per block; grid count doesn't change it."""
+    (d_model=512 / 8 heads -> D=64, bf16, at the tile the kernel chooses).
+    Batch is small: the Mosaic kernel is identical per block; grid count
+    doesn't change it."""
     from mmlspark_tpu.nn.attention import flash_attention
 
     q = sds((2, t, 8, 64), jnp.bfloat16)
