@@ -7,7 +7,9 @@ lives in `parallel.ring_attention` and implements identical math.
 
 Three tiers, one contract (q, k (B, T, H, D), v (B, T, H, Dv), output
 (B, T, H, Dv); the chunked and flash tiers take Dv != D, which latent
-attention needs):
+attention needs). Every tier takes k and v with FEWER heads than q (a
+divisor: grouped-query attention, query head j reads key/value head
+j // group), and none repeats K or V to do it:
 
 - ``dense_attention`` (re-exported from parallel.ring_attention): full
   (T, T) score matrix. The reference implementation every other tier is
@@ -44,7 +46,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..observability.metrics import get_registry
-from ..parallel.ring_attention import dense_attention
+from ..parallel.ring_attention import (dense_attention, key_head_group,
+                                       over_key_heads)
 
 __all__ = ["dense_attention", "chunked_attention", "flash_attention",
            "flash_tiles", "SelfAttention"]
@@ -73,8 +76,13 @@ def chunked_attention(q, k, v, causal: bool = False,
     may be narrower or wider than the scores' channels (latent attention:
     192 for scores, 128 for values). Differentiable — XLA transposes the
     scan for the backward pass; pair with `jax.checkpoint` on the caller
-    for long sequences.
+    for long sequences. Fewer key/value heads than query heads: query
+    head j reads head j // group.
     """
+    if q.shape[2] != k.shape[2]:
+        return over_key_heads(
+            lambda q, k, v: chunked_attention(q, k, v, causal, q_chunk,
+                                              k_chunk), q, k, v)
     orig_dtype = q.dtype
     b, tq_orig, h, d = q.shape
     dv = v.shape[-1]
@@ -157,7 +165,12 @@ def flash_tiles(tq: int, tk: int, dtype) -> tuple[int, int]:
     from the whole program). A tile is a multiple of 128, or the whole of
     a sequence shorter than that, and is never bought with padding: the
     padded length stays within one eighth of the length rounded up to 128
-    (512 -> 512, 514 -> 640, 1100 -> two of 640, 4096 -> 1024)."""
+    (512 -> 512, 514 -> 640, 1100 -> two of 640, 4096 -> 1024). The head's
+    width plays no part: at 64 channels and 16384 tokens (32 query heads
+    over 8 key/value heads, bfloat16, causal; PERF.md, PR 31) 1024 x 1024
+    gives 36.8 ms a call, 512 x 2048 43.7, 512 x 1024 41.3, 2048 x 512
+    59.0, 1024 x 512 69.9, and 2048 x 1024 does not fit the 16 MB: the
+    score tile, not the head, fills VMEM."""
     cap = 1024 if jnp.dtype(dtype).itemsize <= 2 else 512
 
     def padded(t, b):
@@ -281,23 +294,32 @@ def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
     """Pallas forward at the given tile (a multiple of what Mosaic tiles,
     or the whole length); returns (out (B,Tq,H,Dv), lse (B,H,Tq) f32). The
     values may have a width of their own (latent attention scores over
-    192 channels and weighs values of 128)."""
+    192 channels and weighs values of 128), and keys and values fewer
+    heads than the queries: the key block of query head j is head
+    j // group's, named by the index map, so K and V stay as they lie."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
     orig_dtype = q.dtype
     b, _, h, d = q.shape
     dv = v.shape[-1]
+    group = key_head_group(q, k, v)
     q, tq = _pad_seq(q, block_q)
     k, tk = _pad_seq(k, block_k)
     v, _ = _pad_seq(v, block_k)
 
-    # (B*H, T, D): one grid row per (batch, head)
+    # (B*H, T, D): one grid row per (batch, query head); keys and values
+    # (B*H/group, T, .), a row per (batch, key/value head)
     def bh(x):
-        return jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], x.shape[-1])
+        return jnp.moveaxis(x, 2, 1).reshape(
+            b * x.shape[2], x.shape[1], x.shape[-1])
 
     qf, kf, vf = bh(q), bh(k), bh(v)
     nq, nk = qf.shape[1] // block_q, kf.shape[1] // block_k
+
+    # row b * H + j of the queries reads row b * H/group + j // group
+    def key_row(bh_):
+        return bh_ if group == 1 else bh_ // group
 
     if causal:
         # a key block above the diagonal is never computed on: name the
@@ -305,10 +327,10 @@ def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
         # VMEM, so that no copy is issued for the skipped steps
         def key_block(bh_, qi, kv):
             last = (qi * block_q + block_q - 1) // block_k
-            return (bh_, jnp.minimum(kv, last), 0)
+            return (key_row(bh_), jnp.minimum(kv, last), 0)
     else:
         def key_block(bh_, qi, kv):
-            return (bh_, kv, 0)
+            return (key_row(bh_), kv, 0)
 
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k, num_kv=nk,
@@ -416,7 +438,24 @@ def _flash_diff_fwd(q, k, v, causal, block_q, block_k, bwd_chunk, interpret):
 
 def _flash_diff_bwd(causal, block_q, block_k, bwd_chunk, interpret, res, do):
     q, k, v, out, lse = res
-    return _flash_bwd_xla(q, k, v, out, lse, do, causal, bwd_chunk)
+    group = key_head_group(q, k, v)
+    if group == 1:
+        return _flash_bwd_xla(q, k, v, out, lse, do, causal, bwd_chunk)
+    # grouped-query heads: a member of every group at a time against the
+    # one K and V; a key/value head's gradient is the sum over its members
+    b, tq, h, _d = q.shape
+
+    def members(x):
+        return x.reshape(b, tq, h // group, group, x.shape[-1])
+
+    dq, dk, dv = jax.vmap(
+        lambda q1, out1, lse1, do1: _flash_bwd_xla(
+            q1, k, v, out1, lse1, do1, causal, bwd_chunk),
+        in_axes=(3, 3, 2, 3), out_axes=(3, 0, 0))(
+            members(q), members(out), lse.reshape(b, h // group, group, tq),
+            members(do))
+    return (dq.reshape(q.shape), dk.sum(0).astype(k.dtype),
+            dv.sum(0).astype(v.dtype))
 
 
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
@@ -429,7 +468,8 @@ def flash_attention(q, k, v, causal: bool = False,
     Pallas online-softmax kernel (score tile only in VMEM) and the
     backward is the standard flash recomputation as a pure-XLA k-block
     scan driven by the kernel's saved logsumexp. Same contract as
-    `dense_attention`.
+    `dense_attention`, grouped-query heads included (k and v with a
+    divisor of q's heads).
 
     The forward's tile is `flash_tiles`' unless a test names one.
     `bwd_chunk` is the backward scan's key chunk and no tile: the scan
@@ -446,6 +486,14 @@ def flash_attention(q, k, v, causal: bool = False,
         "flash-attention forward calls traced, by the tile they run at",
         labels=("tile", "causal")).labels(
             tile=f"{block_q}x{block_k}", causal=str(causal).lower()).inc()
+    group = key_head_group(q, k, v)
+    if group > 1:
+        get_registry().counter(
+            "mmlspark_tpu_flash_grouped_calls_total",
+            "flash-attention forward calls traced whose query heads share "
+            "key/value heads, by the heads a key/value head serves",
+            labels=("group", "tile")).labels(
+                group=str(group), tile=f"{block_q}x{block_k}").inc()
     return _flash_diff(q, k, v, causal, block_q, block_k,
                        block_k if bwd_chunk is None else bwd_chunk, interpret)
 

@@ -46,6 +46,15 @@ rotary channels interleaved (x0, y0, x1, y1, ...), the module rotates
 halves (x0, x1, ..., y0, y1, ...), so the rope columns of `q_proj` and of
 `kv_a_proj_with_mqa` are permuted at import (scores are unchanged: queries
 and keys are permuted alike).
+
+`HYBRID_MOE_DECODER_SPEC` maps the `lfm2_moe` checkpoint naming
+(`operator_norm`, `ffn_norm`; `conv.in_proj` / `conv.conv` /
+`conv.out_proj`; `self_attn.q_proj` .. `out_proj` with `q_layernorm` /
+`k_layernorm`; `feed_forward.w1` / `w3` / `w2` dense, and
+`feed_forward.gate`, `expert_bias`, `experts.<n>.w1` / `w3` / `w2` routed;
+`embedding_norm`) onto nn.models.HybridMoEDecoder. Those checkpoints
+rotate halves already and tie the head to the embedding: nothing is
+permuted, and an `lm_head.weight` is the embedding written twice.
 """
 
 from __future__ import annotations
@@ -68,6 +77,9 @@ __all__ = [
     "MLA_MOE_DECODER_SPEC",
     "torch_mla_moe_decoder_to_flax",
     "import_torch_mla_moe_decoder",
+    "HYBRID_MOE_DECODER_SPEC",
+    "torch_hybrid_moe_decoder_to_flax",
+    "import_torch_hybrid_moe_decoder",
     "import_external_weights",
     "IMPORTERS",
 ]
@@ -540,6 +552,22 @@ MLA_MOE_DECODER_SPEC: "list[MapRule]" = [
 ]
 
 
+def _stack_experts_held(out: dict, experts_held) -> dict:
+    """`experts_<proj>/<n>` matrices -> the module's (held, in, out)
+    arrays of the experts `experts_held` names (default: all)."""
+    for layer in out["params"].values():
+        for proj in ("gate", "up", "down"):
+            experts = layer.get(f"experts_{proj}") if isinstance(
+                layer, dict) else None
+            if experts is None:
+                continue
+            first, count = experts_held or (0, len(experts))
+            layer[f"experts_{proj}"] = np.stack(
+                [experts[str(n)] for n in range(first, first + count)])
+    out.pop("batch_stats")
+    return out
+
+
 def torch_mla_moe_decoder_to_flax(
     state_dict: Mapping[str, np.ndarray], num_heads: int,
     kv_lora_rank: int, qk_nope_head_dim: int, qk_rope_head_dim: int,
@@ -554,17 +582,7 @@ def torch_mla_moe_decoder_to_flax(
         "num_heads": int(num_heads), "kv_lora_rank": int(kv_lora_rank),
         "qk_nope_head_dim": int(qk_nope_head_dim),
         "qk_rope_head_dim": int(qk_rope_head_dim)})
-    for layer in out["params"].values():
-        for proj in ("gate", "up", "down"):
-            experts = layer.get(f"experts_{proj}") if isinstance(
-                layer, dict) else None
-            if experts is None:
-                continue
-            first, count = experts_held or (0, len(experts))
-            layer[f"experts_{proj}"] = np.stack(
-                [experts[str(n)] for n in range(first, first + count)])
-    out.pop("batch_stats")
-    return out
+    return _stack_experts_held(out, experts_held)
 
 
 def import_torch_mla_moe_decoder(
@@ -587,6 +605,94 @@ def import_torch_mla_moe_decoder(
     return _validate_and_install(bundle, variables, architecture)
 
 
+# --------------------------------------------------------------------- #
+# lfm2_moe naming -> nn.models.HybridMoEDecoder                          #
+# --------------------------------------------------------------------- #
+
+def _t_heads_kernel(v, ctx):
+    """torch (heads * width, D) -> (D, heads, width): queries and the
+    fewer key/value heads alike, told by the head's width."""
+    width = ctx["head_dim"]
+    return np.transpose(v, (1, 0)).reshape(v.shape[1], v.shape[0] // width,
+                                           width)
+
+
+def _t_taps(v, ctx):
+    """torch depthwise Conv1d (D, 1, taps) -> (D, taps); the last tap
+    meets the newest token in both."""
+    return v.reshape(v.shape[0], v.shape[-1])
+
+
+# the gated feed-forward's names in this family: w1 gate, w3 up, w2 down
+_W = {"w1": "gate", "w3": "up", "w2": "down"}
+_WN = r"(?P<w>w[123])\.weight"
+HYBRID_MOE_DECODER_SPEC: "list[MapRule]" = [
+    MapRule(r"model\.embed_tokens\.weight", "params/embed/embedding"),
+    MapRule(_LAYER + r"operator_norm\.weight", r"params/ln_op_\g<i>/scale"),
+    MapRule(_LAYER + r"ffn_norm\.weight", r"params/ln_mlp_\g<i>/scale"),
+    MapRule(_LAYER + r"conv\.in_proj\.weight",
+            r"params/conv_\g<i>/in_proj/kernel", _t_transpose),
+    MapRule(_LAYER + r"conv\.conv\.weight",
+            r"params/conv_\g<i>/conv_kernel", _t_taps),
+    MapRule(_LAYER + r"conv\.out_proj\.weight",
+            r"params/conv_\g<i>/out_proj/kernel", _t_transpose),
+    MapRule(_LAYER + r"self_attn\.(?P<p>[qkv])_proj\.weight",
+            r"params/gqa_attn_\g<i>/\g<p>_proj/kernel", _t_heads_kernel),
+    MapRule(_LAYER + r"self_attn\.(?P<p>[qk])_layernorm\.weight",
+            r"params/gqa_attn_\g<i>/\g<p>_norm/scale"),
+    MapRule(_LAYER + r"self_attn\.out_proj\.weight",
+            r"params/gqa_attn_\g<i>/out/kernel", _t_attn_out_kernel),
+    MapRule(_LAYER + r"feed_forward\.gate\.weight",
+            r"params/moe_\g<i>/router_kernel", _t_transpose),
+    MapRule(_LAYER + r"feed_forward\.expert_bias",
+            r"params/moe_\g<i>/router_bias"),
+    MapRule(_LAYER + r"feed_forward\.experts\.(?P<n>\d+)\." + _WN,
+            lambda m: f"params/moe_{m['i']}/experts_{_W[m['w']]}/{m['n']}",
+            _t_transpose),
+    MapRule(_LAYER + r"feed_forward\." + _WN,
+            lambda m: f"params/mlp_{m['i']}/{_W[m['w']]}/kernel",
+            _t_transpose),
+    MapRule(r"model\.embedding_norm\.weight", "params/ln_final/scale"),
+    # tied: a checkpoint that writes the head writes the embedding again
+    MapRule(r"lm_head\.weight", None),
+    MapRule(r".*rotary_emb\.inv_freq", None),
+]
+
+
+def torch_hybrid_moe_decoder_to_flax(
+    state_dict: Mapping[str, np.ndarray], num_heads: int, head_dim: int,
+    experts_held: "tuple[int, int] | None" = None,
+) -> dict[str, Any]:
+    """Map an `lfm2_moe`-named state dict onto nn.models.HybridMoEDecoder
+    variables (head tied to the embedding; rotary already in the
+    rotate-half layout). `experts_held` (first index, count) keeps a share
+    of the routed experts (default: all the checkpoint has), while the
+    router and its selection bias keep every expert's row."""
+    out = apply_mapping_spec(state_dict, HYBRID_MOE_DECODER_SPEC, {
+        "num_heads": int(num_heads), "head_dim": int(head_dim)})
+    return _stack_experts_held(out, experts_held)
+
+
+def import_torch_hybrid_moe_decoder(
+    path: str, architecture: str = "hybrid_moe_decoder",
+    input_shape: tuple[int, ...] = (8,), **config,
+):
+    """Load an `lfm2_moe`-named checkpoint into a ready-to-serve
+    ModelBundle of the `hybrid_moe_decoder` family. `config` is the
+    module's (`layer_types`, the head counts, `experts_held`, ...): the
+    checkpoint's own config.json states them, its shapes do not."""
+    from .models import ModelBundle
+
+    sd = load_state_dict(path)
+    bundle = ModelBundle.init(architecture, input_shape=tuple(input_shape),
+                              seed=0, **config)
+    module = bundle.module
+    variables = torch_hybrid_moe_decoder_to_flax(
+        sd, module.num_heads, module.d_model // module.num_heads,
+        tuple(module.experts_held))
+    return _validate_and_install(bundle, variables, architecture)
+
+
 # architecture name -> importer; zoo.import_external dispatches here, so
 # registering a new family makes it fetchable/verifiable end to end
 IMPORTERS: "dict[str, Callable]" = {
@@ -595,6 +701,7 @@ IMPORTERS: "dict[str, Callable]" = {
     "resnet20_cifar": import_torch_resnet,
     "transformer": import_torch_transformer,
     "mla_moe_decoder": import_torch_mla_moe_decoder,
+    "hybrid_moe_decoder": import_torch_hybrid_moe_decoder,
 }
 
 

@@ -29,7 +29,10 @@ __all__ = [
     "ResNet",
     "TransformerEncoder",
     "MLAMoEDecoder",
+    "HybridMoEDecoder",
     "LatentAttention",
+    "GroupedQueryAttention",
+    "ShortConv",
     "ExpertLayer",
     "GatedFFN",
     "RMSNorm",
@@ -281,6 +284,24 @@ def _rotary(x, theta: float):
                            -1).astype(x.dtype)
 
 
+def _causal_attention(q, k, v, impl: str, dtype):
+    """A decoder's attention core: "flash" (the Pallas kernel; chunked on
+    the CPU, where Mosaic cannot lower), "chunked" or "dense"."""
+    from .attention import (chunked_attention, dense_attention,
+                            flash_attention)
+
+    if impl == "flash" and jax.default_backend() == "cpu":
+        impl = "chunked"
+    if impl == "flash":
+        # None: the backward scans the keys a forward tile at a time
+        return flash_attention(q, k, v, causal=True, bwd_chunk=None)
+    if impl == "chunked":
+        return chunked_attention(q, k, v, causal=True)
+    if impl == "dense":
+        return dense_attention(q, k, v, causal=True).astype(dtype)
+    raise ValueError(f"unknown attention impl {impl!r}")
+
+
 class LatentAttention(nn.Module):
     """Causal multi-head latent attention (arXiv 2405.04434, section 2.1):
     queries of `qk_nope + qk_rope` channels a head; ONE down-projection of
@@ -301,9 +322,6 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, y):
-        from .attention import (chunked_attention, dense_attention,
-                                flash_attention)
-
         dt, heads, lat = self.dtype, self.num_heads, self.kv_lora_rank
         nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                           self.v_head_dim)
@@ -323,23 +341,88 @@ class LatentAttention(nn.Module):
                 [kvb[..., :nope],
                  jnp.broadcast_to(k_pe, (b, t, heads, rope))], -1)
             v = kvb[..., nope:]
-        impl = self.impl
-        if impl == "flash" and jax.default_backend() == "cpu":
-            impl = "chunked"
         # the innermost scope names the Pallas call in a device trace
         with jax.named_scope("mla.attend"), jax.named_scope(self.name):
-            if impl == "flash":
-                # None: the backward scans the keys a forward tile at a time
-                o = flash_attention(q, k, v, causal=True, bwd_chunk=None)
-            elif impl == "chunked":
-                o = chunked_attention(q, k, v, causal=True)
-            elif impl == "dense":
-                o = dense_attention(q, k, v, causal=True).astype(dt)
-            else:
-                raise ValueError(f"unknown attention impl {self.impl!r}")
+            o = _causal_attention(q, k, v, self.impl, dt)
         with jax.named_scope("mla.project"):
             return nn.DenseGeneral(d, axis=(-2, -1), use_bias=False,
                                    dtype=dt, name="out")(o)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal attention whose `num_heads` query heads share `num_kv_heads`
+    key/value heads (query head j reads head j // group; K and V are never
+    repeated: `nn/attention.py`), with an RMSNorm over the channels of
+    every query and of every key head before the rotary positions (one
+    scale vector for all query heads, one for all key heads), rotary over
+    the whole head, scores over sqrt(head width). No biases."""
+
+    num_heads: int
+    num_kv_heads: int
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    impl: str = "flash"
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y):
+        dt, d = self.dtype, y.shape[-1]
+        if d % self.num_heads or self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"{self.num_heads} query heads over {self.num_kv_heads} "
+                f"key/value heads do not divide a width of {d}")
+        width = d // self.num_heads
+
+        def heads(n, name):
+            return nn.DenseGeneral((n, width), use_bias=False, dtype=dt,
+                                   name=name)(y)
+
+        with jax.named_scope("gqa.project"):
+            q = _rotary(RMSNorm(self.eps, dt, name="q_norm")(
+                heads(self.num_heads, "q_proj")), self.rope_theta)
+            k = _rotary(RMSNorm(self.eps, dt, name="k_norm")(
+                heads(self.num_kv_heads, "k_proj")), self.rope_theta)
+            v = heads(self.num_kv_heads, "v_proj")
+        # the innermost scope names the Pallas call in a device trace
+        with jax.named_scope("gqa.attend"), jax.named_scope(
+                self.name or "gqa_attn"):
+            o = _causal_attention(q, k, v, self.impl, dt)
+        with jax.named_scope("gqa.project"):
+            return nn.DenseGeneral(d, axis=(-2, -1), use_bias=False,
+                                   dtype=dt, name="out")(o)
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution (LFM2, arXiv 2511.23404, section 2):
+    [B, C, u] = y W_in; z = B * u; c[t] = sum_j w[:, j] z[t - (taps-1) + j]
+    per channel, z zero before a row's first token (a depthwise causal
+    convolution); out = (C * c) W_out. No activation, no bias, no state
+    across rows. The taps are shifted multiply-adds that XLA fuses with
+    both gates into one pass over the (T, 3 d) projection: the mix is
+    bound by memory, float32 inside and rounded once."""
+
+    taps: int = 3
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y):
+        dt, (_b, t, d) = self.dtype, y.shape
+        with jax.named_scope("conv.project"):
+            gated = nn.Dense(3 * d, use_bias=False, dtype=dt,
+                             name="in_proj")(y)
+        kernel = self.param("conv_kernel",
+                            nn.initializers.normal(self.taps ** -0.5),
+                            (d, self.taps), jnp.float32)
+        with jax.named_scope("conv.mix"):
+            gate_in, gate_out, u = (
+                gated[..., i * d:(i + 1) * d].astype(jnp.float32)
+                for i in range(3))
+            z = jnp.pad(gate_in * u, ((0, 0), (self.taps - 1, 0), (0, 0)))
+            c = sum(kernel[:, j] * z[:, j:j + t] for j in range(self.taps))
+            mixed = (gate_out * c).astype(dt)
+        with jax.named_scope("conv.project"):
+            return nn.Dense(d, use_bias=False, dtype=dt,
+                            name="out_proj")(mixed)
 
 
 class GatedFFN(nn.Module):
@@ -359,7 +442,8 @@ class GatedFFN(nn.Module):
 class ExpertLayer(nn.Module):
     """Routed experts (the `experts_held` of `n_routed_experts`, top-k,
     dropless: `parallel.moe.moe_ffn_dropless`) plus the shared feed-forward
-    every token takes. -> (output, picks (held,) int32)."""
+    every token takes, where the model has one (`n_shared_experts` 0
+    builds none). -> (output, picks (held,) int32)."""
 
     n_routed_experts: int
     experts_held: tuple
@@ -369,6 +453,7 @@ class ExpertLayer(nn.Module):
     scaling: float = 1.0
     normalise: bool = True
     dtype: Any = jnp.float32
+    epsilon: float = 1e-20          # added to the sum the weights are over
 
     @nn.compact
     def __call__(self, y):
@@ -392,20 +477,23 @@ class ExpertLayer(nn.Module):
             n_routed_experts=self.n_routed_experts,
             experts_held=tuple(self.experts_held), top_k=self.top_k,
             scaling=self.scaling, normalise=self.normalise,
-            dtype=self.dtype)
-        with jax.named_scope("moe.shared"):
-            shared = GatedFFN(self.n_shared_experts * w, self.dtype,
-                              name="shared")(flat)
-        return (routed + shared).reshape(y.shape), picks
+            epsilon=self.epsilon, dtype=self.dtype)
+        if self.n_shared_experts:
+            with jax.named_scope("moe.shared"):
+                routed = routed + GatedFFN(self.n_shared_experts * w,
+                                           self.dtype, name="shared")(flat)
+        return routed.reshape(y.shape), picks
 
 
-class MLAMoEDecoder(nn.Module):
-    """Causal decoder over token ids: latent attention, gated
-    feed-forwards (dense in the leading layers, then routed experts with a
-    shared one), RMSNorm, rotary positions on part of a head, an untied
-    head (the DeepSeek-V2/V3 block: arXiv 2405.04434 section 2.1, arXiv
-    2412.19437 section 2.1.2). The block is h = x + Attn(RMSNorm(x)),
-    out = h + FFN(RMSNorm(h)).
+class _ScoringDecoder(nn.Module):
+    """What the decoder families share, written once: the block loop over
+    token ids (h = x + Op(RMSNorm(x)), out = h + FF(RMSNorm(h)); FF a
+    gated feed-forward in the leading dense layers, then an `ExpertLayer`),
+    the final RMSNorm, the chunked log-likelihood head and what is sown per
+    batch. A family states its sizes as attributes under the names used
+    here and gives `_dense_layers`, the layers that lead with a dense
+    feed-forward, and `_operator(i)`, layer i's mixer with the name of the
+    norm before it.
 
     Scoring output: the module returns, and sows as `token_logprobs`, each
     next token's log-probability, (rows, length - 1): the head runs over
@@ -426,6 +514,101 @@ class MLAMoEDecoder(nn.Module):
     Precision: products take `dtype` inputs and accumulate in float32; the
     router's scores, the top-k, every softmax and log-sum-exp and every
     RMSNorm's statistics are float32."""
+
+    # what a family may state as an attribute of its own
+    route_epsilon = 1e-20           # added to the sum of a token's weights
+    tie_embeddings = False          # the head is the embedding, transposed
+
+    @property
+    def batch_counters(self) -> tuple:
+        """int32 arrays sown per batch that the runner reads back beside
+        the fetched outputs (`nn/runner.py`)."""
+        return ("moe_picks",) if self.num_layers > self._dense_layers else ()
+
+    def _token_logprobs(self, h, ids, head):
+        """log_softmax(h @ head)[next token] for every position but a
+        row's last, `head_chunk` tokens at a time."""
+        b, t, d = h.shape
+        n = b * t
+        flat = h.reshape(n, d)
+        # the last position of a row scores a target that is cut off below
+        target = jnp.concatenate([ids[:, 1:], ids[:, :1]], 1).reshape(n)
+        chunk = min(self.head_chunk, n)
+        pad = (-n) % chunk
+        if pad:
+            flat = jnp.pad(flat, ((0, pad), (0, 0)))
+            target = jnp.pad(target, (0, pad))
+
+        def one(xs):
+            hc, tc = xs
+            logits = jnp.dot(hc, head, preferred_element_type=jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
+            return picked - lse
+
+        out = jax.lax.map(one, (flat.reshape(-1, chunk, d),
+                                target.reshape(-1, chunk)))
+        return out.reshape(-1)[:n].reshape(b, t)[:, :t - 1]
+
+    def _score(self, x):
+        """The forward every family's `__call__` is."""
+        ids = x.astype(jnp.int32)
+        if ids.ndim != 2:
+            raise ValueError("the decoder takes (rows, length) token ids, "
+                             f"got {ids.shape}")
+        if ids.shape[1] > self.max_len:
+            raise ValueError(
+                f"sequence length {ids.shape[1]} exceeds max_len="
+                f"{self.max_len}; raise max_len in the model config")
+        dt, d = self.dtype, self.d_model
+        norm = functools.partial(RMSNorm, self.rms_norm_eps, dt)
+        embed = nn.Embed(self.vocab_size, d, dtype=dt,
+                         embedding_init=nn.initializers.normal(1.0),
+                         name="embed")
+        h = embed(ids)
+        picks = []
+        for i in range(self.num_layers):
+            before, operator = self._operator(i)
+            h = h + operator(norm(name=before)(h))
+            y = norm(name=f"ln_mlp_{i}")(h)
+            if i < self._dense_layers:
+                h = h + GatedFFN(self.d_ff_dense, dt, name=f"mlp_{i}")(y)
+            else:
+                out, n = ExpertLayer(
+                    self.n_routed_experts, tuple(self.experts_held),
+                    self.num_experts_per_tok, self.d_ff_expert,
+                    self.n_shared_experts, self.routed_scaling_factor,
+                    self.norm_topk_prob, dt, self.route_epsilon,
+                    name=f"moe_{i}")(y)
+                h = h + out
+                picks.append(n)
+        h = norm(name="ln_final")(h)
+        self.sow("intermediates", "hidden", h)
+        if picks:
+            self.sow("intermediates", "moe_picks", jnp.stack(picks))
+        if self.tie_embeddings:
+            head = embed.embedding.T.astype(dt)
+        else:
+            head = self.param(
+                "head_kernel", nn.initializers.normal(d ** -0.5),
+                (d, self.vocab_size), jnp.float32).astype(dt)
+        with jax.named_scope("loglik.head"):
+            logprobs = self._token_logprobs(h, ids, head)
+            self.sow("intermediates", "token_logprobs", logprobs)
+            if self.output == "logits":
+                return jnp.dot(h, head, preferred_element_type=jnp.float32)
+        if self.output != "token_logprobs":
+            raise ValueError(f"unknown output {self.output!r}")
+        return logprobs
+
+
+class MLAMoEDecoder(_ScoringDecoder):
+    """Causal decoder over token ids: latent attention, gated
+    feed-forwards (dense in the leading layers, then routed experts with a
+    shared one), RMSNorm, rotary positions on part of a head, an untied
+    head (the DeepSeek-V2/V3 block: arXiv 2405.04434 section 2.1, arXiv
+    2412.19437 section 2.1.2). The block loop, the head, the outputs and
+    the share of a model a chip may hold are `_ScoringDecoder`'s."""
 
     num_layers: int = 2
     d_model: int = 64
@@ -454,83 +637,85 @@ class MLAMoEDecoder(nn.Module):
     dtype: Any = jnp.float32
 
     @property
-    def batch_counters(self) -> tuple:
-        """int32 arrays sown per batch that the runner reads back beside
-        the fetched outputs (`nn/runner.py`)."""
-        return ("moe_picks",) if self.num_layers > self.first_k_dense else ()
+    def _dense_layers(self) -> int:
+        return self.first_k_dense
 
-    def _token_logprobs(self, h, ids, head):
-        """log_softmax(h @ head)[next token] for every position but a
-        row's last, `head_chunk` tokens at a time."""
-        b, t, d = h.shape
-        n = b * t
-        flat = h.reshape(n, d)
-        # the last position of a row scores a target that is cut off below
-        target = jnp.concatenate([ids[:, 1:], ids[:, :1]], 1).reshape(n)
-        chunk = min(self.head_chunk, n)
-        pad = (-n) % chunk
-        if pad:
-            flat = jnp.pad(flat, ((0, pad), (0, 0)))
-            target = jnp.pad(target, (0, pad))
-
-        def one(xs):
-            hc, tc = xs
-            logits = jnp.dot(hc, head, preferred_element_type=jnp.float32)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            picked = jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
-            return picked - lse
-
-        out = jax.lax.map(one, (flat.reshape(-1, chunk, d),
-                                target.reshape(-1, chunk)))
-        return out.reshape(-1)[:n].reshape(b, t)[:, :t - 1]
+    def _operator(self, i: int):
+        return f"ln_attn_{i}", LatentAttention(
+            self.num_heads, self.kv_lora_rank, self.qk_nope_head_dim,
+            self.qk_rope_head_dim, self.v_head_dim, self.rope_theta,
+            self.rms_norm_eps, self.attention_impl, self.dtype,
+            name=f"mla_attn_{i}")
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        ids = x.astype(jnp.int32)
-        if ids.ndim != 2:
-            raise ValueError("the decoder takes (rows, length) token ids, "
-                             f"got {ids.shape}")
-        if ids.shape[1] > self.max_len:
-            raise ValueError(
-                f"sequence length {ids.shape[1]} exceeds max_len="
-                f"{self.max_len}; raise max_len in the model config")
-        dt, d = self.dtype, self.d_model
-        norm = functools.partial(RMSNorm, self.rms_norm_eps, dt)
-        h = nn.Embed(self.vocab_size, d, dtype=dt,
-                     embedding_init=nn.initializers.normal(1.0),
-                     name="embed")(ids)
-        picks = []
-        for i in range(self.num_layers):
-            h = h + LatentAttention(
-                self.num_heads, self.kv_lora_rank, self.qk_nope_head_dim,
-                self.qk_rope_head_dim, self.v_head_dim, self.rope_theta,
-                self.rms_norm_eps, self.attention_impl, dt,
-                name=f"mla_attn_{i}")(norm(name=f"ln_attn_{i}")(h))
-            y = norm(name=f"ln_mlp_{i}")(h)
-            if i < self.first_k_dense:
-                h = h + GatedFFN(self.d_ff_dense, dt, name=f"mlp_{i}")(y)
-            else:
-                out, n = ExpertLayer(
-                    self.n_routed_experts, tuple(self.experts_held),
-                    self.num_experts_per_tok, self.d_ff_expert,
-                    self.n_shared_experts, self.routed_scaling_factor,
-                    self.norm_topk_prob, dt, name=f"moe_{i}")(y)
-                h = h + out
-                picks.append(n)
-        h = norm(name="ln_final")(h)
-        self.sow("intermediates", "hidden", h)
-        if picks:
-            self.sow("intermediates", "moe_picks", jnp.stack(picks))
-        head = self.param("head_kernel", nn.initializers.normal(d ** -0.5),
-                          (d, self.vocab_size), jnp.float32).astype(dt)
-        with jax.named_scope("loglik.head"):
-            logprobs = self._token_logprobs(h, ids, head)
-            self.sow("intermediates", "token_logprobs", logprobs)
-            if self.output == "logits":
-                return jnp.dot(h, head, preferred_element_type=jnp.float32)
-        if self.output != "token_logprobs":
-            raise ValueError(f"unknown output {self.output!r}")
-        return logprobs
+        return self._score(x)
+
+
+class HybridMoEDecoder(_ScoringDecoder):
+    """Causal decoder over token ids whose layers are told apart by a
+    list (the LFM2 block, arXiv 2511.23404): `layer_types[i]` is "conv"
+    (`ShortConv`, a gated depthwise convolution of `conv_taps` taps) or
+    "full_attention" (`GroupedQueryAttention`, `num_heads` query heads over
+    `num_kv_heads` key/value heads, an RMSNorm on every query and key head,
+    rotary over the whole head); `num_dense_layers` leading gated
+    feed-forwards, then routed experts with no shared one and 1e-6 under
+    the weights' sum; a head tied to the embedding. The block loop, the
+    head, the outputs and the share of a model a chip may hold are
+    `_ScoringDecoder`'s."""
+
+    layer_types: tuple = ("conv", "full_attention")
+    d_model: int = 64
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    conv_taps: int = 3
+    d_ff_dense: int = 128
+    num_dense_layers: int = 1
+    n_routed_experts: int = 8
+    experts_held: tuple = (0, 8)    # (first index, count)
+    num_experts_per_tok: int = 2
+    d_ff_expert: int = 32
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    route_epsilon: float = 1e-6
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 1000000.0
+    vocab_size: int = 256
+    tie_embeddings: bool = True
+    max_len: int = 16384
+    # "flash": the Pallas kernel (chunked off-TPU), "chunked", "dense"
+    attention_impl: str = "flash"
+    head_chunk: int = 1024          # tokens of one block of the head
+    output: str = "token_logprobs"  # or "logits"
+    dtype: Any = jnp.float32
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def _dense_layers(self) -> int:
+        return self.num_dense_layers
+
+    def _operator(self, i: int):
+        kind = self.layer_types[i]
+        if kind == "full_attention":
+            operator = GroupedQueryAttention(
+                self.num_heads, self.num_kv_heads, self.rope_theta,
+                self.rms_norm_eps, self.attention_impl, self.dtype,
+                name=f"gqa_attn_{i}")
+        elif kind == "conv":
+            operator = ShortConv(self.conv_taps, self.dtype,
+                                 name=f"conv_{i}")
+        else:
+            raise ValueError(f"unknown layer type {kind!r} at layer {i}: "
+                             "'conv' or 'full_attention'")
+        return f"ln_op_{i}", operator
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        return self._score(x)
 
 
 def resnet20_cifar(num_outputs: int = 10, dtype=jnp.float32) -> ResNet:
@@ -543,6 +728,13 @@ def resnet50(num_outputs: int = 1000, dtype=jnp.float32) -> ResNet:
                   stem_strides=2, num_outputs=num_outputs, dtype=dtype)
 
 
+def _hashable(config: dict) -> dict:
+    """`experts_held` and `layer_types` arrive as lists from a JSON config;
+    a module's attributes are hashable."""
+    return {k: tuple(v) if isinstance(v, list) else v
+            for k, v in config.items()}
+
+
 # Architecture registry: name -> factory(**config). The zoo's ModelSchema
 # references architectures by name (the reference's ModelSchema carries a
 # remote URI instead, downloader/Schema.scala:30+).
@@ -553,11 +745,8 @@ ARCHITECTURES: dict[str, Callable[..., nn.Module]] = {
     "resnet50": lambda **kw: resnet50(**kw),
     "resnet": lambda **kw: ResNet(**kw),
     "transformer": lambda **kw: TransformerEncoder(**kw),
-    # experts_held arrives as a list from a JSON config; a module's
-    # attributes are hashable
-    "mla_moe_decoder": lambda **kw: MLAMoEDecoder(**{
-        **kw, **({"experts_held": tuple(kw["experts_held"])}
-                 if "experts_held" in kw else {})}),
+    "mla_moe_decoder": lambda **kw: MLAMoEDecoder(**_hashable(kw)),
+    "hybrid_moe_decoder": lambda **kw: HybridMoEDecoder(**_hashable(kw)),
 }
 
 

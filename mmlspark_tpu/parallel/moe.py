@@ -145,13 +145,14 @@ def moe_ffn_sharded(params: MoEParams, x, axis_name: str = EXPERT_AXIS,
 # --------------------------------------------------------------------- #
 
 def route_top_k(x, router, bias, top_k: int, scaling: float = 1.0,
-                normalise: bool = True):
+                normalise: bool = True, epsilon: float = 1e-20):
     """-> (picked (T, k) int32 expert ids, weights (T, k) float32).
 
     Scores are sigmoid(x @ router) in float32. The `top_k` experts of a
     token are the best of scores + bias; their weights are the SCORES at
     those experts (the bias selects, it does not weigh), over their sum
-    (+1e-20) if `normalise`, times `scaling`."""
+    (+ `epsilon`, which a checkpoint's family states) if `normalise`, times
+    `scaling`."""
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(jnp.dot(
             x, router.astype(x.dtype), preferred_element_type=jnp.float32))
@@ -163,7 +164,7 @@ def route_top_k(x, router, bias, top_k: int, scaling: float = 1.0,
             picked[..., None] == jnp.arange(scores.shape[-1]),
             scores[:, None, :], 0.0).sum(-1)
         if normalise:
-            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+            weights = weights / (weights.sum(-1, keepdims=True) + epsilon)
         return picked.astype(jnp.int32), weights * scaling
 
 
@@ -408,7 +409,8 @@ def _combine(weighed, token_of_row, key, picks, *, tokens: int, top_k: int,
 def moe_ffn_dropless(x, router, bias, gate, up, down, *,
                      n_routed_experts: int, experts_held: tuple,
                      top_k: int, scaling: float = 1.0,
-                     normalise: bool = True, dtype=jnp.float32):
+                     normalise: bool = True, epsilon: float = 1e-20,
+                     dtype=jnp.float32):
     """The routed part of an expert layer that the experts held here give.
 
     x: (T, d). router: (d, n_routed_experts); bias: (n_routed_experts,)
@@ -433,7 +435,8 @@ def moe_ffn_dropless(x, router, bias, gate, up, down, *,
     if gate.shape[0] != held:
         raise ValueError(f"experts_held says {held} experts, the weights "
                          f"hold {gate.shape[0]}")
-    picked, weights = route_top_k(x, router, bias, top_k, scaling, normalise)
+    picked, weights = route_top_k(x, router, bias, top_k, scaling, normalise,
+                                  epsilon)
 
     with jax.named_scope("moe.dispatch"):
         local = picked.reshape(-1) - first                    # (T*k,)

@@ -43,10 +43,40 @@ __all__ = [
 ]
 
 
+def key_head_group(q, k, v) -> int:
+    """How many query heads read one key/value head: q (B, Tq, H, D) over
+    k, v (B, Tk, H / group, .); query head j reads head j // group."""
+    h, hk = q.shape[2], k.shape[2]
+    if v.shape[2] != hk or hk < 1 or h % hk:
+        raise ValueError(
+            f"{h} query heads cannot share {hk} key and {v.shape[2]} value "
+            "heads: the key/value heads have to divide the query heads")
+    return h // hk
+
+
+def over_key_heads(attend, q, k, v):
+    """`attend(q, k, v)` for grouped-query heads: the members of a group
+    meet their one key/value head as it lies, a member at a time (a
+    `vmap` over the members with K and V unbatched: nothing is repeated).
+    Equal head counts call `attend` as it is."""
+    group = key_head_group(q, k, v)
+    if group == 1:
+        return attend(q, k, v)
+    b, tq, h, d = q.shape
+    out = jax.vmap(lambda member: attend(member, k, v), in_axes=3,
+                   out_axes=3)(q.reshape(b, tq, h // group, group, d))
+    return out.reshape(b, tq, h, out.shape[-1])
+
+
 def dense_attention(q, k, v, causal: bool = False,
                     q_offset: int = 0, k_offset: int = 0):
     """Reference implementation: full softmax attention.
-    q: (B, Tq, H, D); k, v: (B, Tk, H, D) -> (B, Tq, H, D)."""
+    q: (B, Tq, H, D); k, v: (B, Tk, H, D) -> (B, Tq, H, D). With fewer
+    key/value heads (a divisor of H), query head j reads head j // group."""
+    if q.shape[2] != k.shape[2]:
+        return over_key_heads(
+            lambda q, k, v: dense_attention(q, k, v, causal, q_offset,
+                                            k_offset), q, k, v)
     scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:

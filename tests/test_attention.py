@@ -299,3 +299,145 @@ class TestSelfAttentionModule:
         x = jnp.zeros((1, 4, 8), jnp.float32)
         with pytest.raises(ValueError, match="unknown attention impl"):
             mod.init(jax.random.PRNGKey(0), x)
+
+
+# --------------------------------------------------------------------- #
+# grouped-query heads                                                   #
+# --------------------------------------------------------------------- #
+
+def _grouped_qkv(b, t, h, group, d, seed=0, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.normal(size=(b, t, h, d)), dtype)
+    k = jnp.asarray(rng.normal(size=(b, t, h // group, d)), dtype)
+    v = jnp.asarray(rng.normal(size=(b, t, h // group, d)), dtype)
+    return q, k, v
+
+
+def _pallas_equation(fn, *args):
+    """The one `pallas_call` equation under `fn`, however deep."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn)
+                continue
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (tuple, list))
+                            else (value,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert len(found) == 1
+    return found[0]
+
+
+class TestGroupedQueryHeads:
+    """k and v with fewer heads than q (a divisor): query head j reads
+    key/value head j // group, in every tier, and nothing is repeated."""
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("group", [1, 2, 4])
+    def test_interpreted_kernel_matches_dense(self, group, d, causal):
+        # 40 tokens in tiles of 16: two whole tiles and a padded third
+        q, k, v = _grouped_qkv(2, 40, 4, group, d, seed=group + d)
+        want = dense_attention(q, jnp.repeat(k, group, 2),
+                               jnp.repeat(v, group, 2), causal=causal)
+        np.testing.assert_allclose(
+            dense_attention(q, k, v, causal=causal), want, atol=2e-5)
+        np.testing.assert_allclose(
+            chunked_attention(q, k, v, causal=causal, q_chunk=16,
+                              k_chunk=16), want, atol=2e-5)
+        got = flash_attention(q, k, v, causal=causal, block_q=16,
+                              block_k=16, interpret=True)
+        assert got.shape == q.shape
+        np.testing.assert_allclose(got, want, atol=2e-5)
+
+    @pytest.mark.parametrize("group", [2, 4])
+    def test_kernel_equals_repeated_heads_bit_for_bit(self, group):
+        """The same tiles and the same arithmetic: only the index map
+        differs from a call whose K and V were repeated."""
+        q, k, v = _grouped_qkv(1, 48, 4, group, 64, seed=7)
+        call = lambda q, k, v: flash_attention(     # noqa: E731
+            q, k, v, causal=True, block_q=16, block_k=16, interpret=True)
+        assert np.array_equal(
+            np.asarray(call(q, k, v)),
+            np.asarray(call(q, jnp.repeat(k, group, 2),
+                            jnp.repeat(v, group, 2))))
+
+    @pytest.mark.parametrize("group", [1, 4])
+    def test_key_and_value_operands_keep_their_own_heads(self, group):
+        q, k, v = _grouped_qkv(2, 32, 8, group, 64)
+        eqn = _pallas_equation(
+            lambda q, k, v: flash_attention(q, k, v, block_q=16, block_k=16,
+                                            interpret=True), q, k, v)
+        assert [x.aval.shape for x in eqn.invars] == [
+            (16, 32, 64), (16 // group, 32, 64), (16 // group, 32, 64)]
+        # equal head counts: the key block's index map is the parent's
+        # (it names its grid indices and computes nothing); grouped heads
+        # add the one division
+        maps = eqn.params["grid_mapping"].block_mappings
+        computed = [len(m.index_map_jaxpr.jaxpr.eqns) for m in maps[:3]]
+        assert computed[0] == 0
+        assert (computed[1] == computed[2] == 0) == (group == 1)
+
+    def test_grouped_gradients_match_dense(self):
+        q, k, v = _grouped_qkv(1, 24, 4, 2, 8, seed=3)
+
+        def loss(fn):
+            return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+        got = jax.grad(loss(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=8, block_k=8, bwd_chunk=8,
+            interpret=True)), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(lambda q, k, v: dense_attention(
+            q, jnp.repeat(k, 2, 2), jnp.repeat(v, 2, 2), causal=True)),
+            argnums=(0, 1, 2))(q, k, v)
+        assert [g.shape for g in got] == [q.shape, k.shape, v.shape]
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, atol=5e-5, rtol=1e-4)
+
+    def test_heads_that_do_not_divide_are_refused(self):
+        q, k, v = _grouped_qkv(1, 8, 4, 1, 8)
+        for fn in (dense_attention, chunked_attention,
+                   lambda q, k, v: flash_attention(q, k, v, interpret=True)):
+            with pytest.raises(ValueError, match="divide"):
+                fn(q, k[:, :, :3], v[:, :, :3])
+
+    def test_a_grouped_call_is_counted(self):
+        def counted(name, **labels):
+            return get_registry().counter(
+                name, labels=tuple(labels)).labels(**labels).value
+
+        before = (counted("mmlspark_tpu_flash_grouped_calls_total",
+                          group="4", tile="128x128"),
+                  _flash_calls("128x128", True))
+        x = jax.ShapeDtypeStruct((1, 128, 8, 64), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((1, 128, 2, 64), jnp.bfloat16)
+        jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                       x, kv, kv)
+        # under both of the call counter's labels as ever, and by group
+        assert _flash_calls("128x128", True) == before[1] + 1
+        assert counted("mmlspark_tpu_flash_grouped_calls_total", group="4",
+                       tile="128x128") == before[0] + 1
+
+    @pytest.mark.parametrize("dtype,tile", [(jnp.bfloat16, 1024),
+                                            (jnp.float32, 512)])
+    def test_rule_at_16384_tokens(self, dtype, tile):
+        """Read on a v5e at head width 64 (PERF.md, PR 31): 1024 x 1024
+        stays the best tile at 16384 tokens, and the next larger one does
+        not fit the default VMEM."""
+        assert flash_tiles(16384, 16384, dtype) == (tile, tile)
+        assert flash_tiles(1024, 1024, dtype) == (tile, tile)
+
+    def test_kernel_at_head_width_64_and_the_rules_tile(self):
+        q, k, v = _grouped_qkv(1, 300, 8, 4, 64, seed=5, dtype=jnp.bfloat16)
+        assert flash_tiles(300, 300, q.dtype) == (384, 384)
+        got = flash_attention(q, k, v, causal=True, interpret=True)
+        want = dense_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                               causal=True)
+        np.testing.assert_allclose(got.astype(jnp.float32), want, atol=3e-2,
+                                   rtol=3e-2)
