@@ -5,17 +5,25 @@ Like pipeline parallelism, MoE is beyond the reference's capability set
 first-class distributed story. Two layers live here.
 
 **The layer a model calls: `moe_ffn_dropless`** (`nn/models.py`'s
-`mla_moe_decoder` family). Top-k routing over ALL `n_routed_experts` with
-sigmoid scores and a selection bias (DeepSeek-V3's `noaux_tc`: the bias
-moves the picks, never the weights), and NO capacity: every pick is
-computed, whatever the skew. The layer is TOLD which experts it holds
-(`experts_held`: first index and count, separately from
+`ExpertLayer`, in both decoder families). Top-k routing over ALL
+`n_routed_experts` with sigmoid scores and a selection bias (DeepSeek-V3's
+`noaux_tc`: the bias moves the picks, never the weights), and NO capacity:
+every pick is computed, whatever the skew. The layer is TOLD which experts
+it holds (`experts_held`: first index and count, separately from
 `n_routed_experts`) and returns the part of the result its own experts
 give: picks are sorted by expert, the rows of the experts held come first,
-one grouped product a projection (`jax.lax.ragged_dot`, which the TPU
-compiler lowers to its own grouped-matmul kernel with a grid as long as the
-rows that are really there) runs over them, and a Pallas call
-(`moe_combine`) adds each token's weighted rows up from where they lie.
+and two grouped products of ours (`_grouped_pallas`: the custom calls
+`ragged-dot-gated` and `ragged-dot-down`) run over them with
+`experts_gate`, `experts_up` and `experts_down` read where the parameters
+keep them. The first multiplies a tile of rows by one expert's gate AND up
+blocks, applies silu(a) * b to the two float32 sums and writes (rows, w)
+once; the second multiplies by the expert's down block and weighs each row
+by its pick's weight before the one rounding. Their grid is as long as the
+tiles that the picks here touch, and the tiles come from the shapes
+(`grouped_tiles`). On the CPU, for float32 operands and for extents that
+are no multiple of 128, the products are `jax.lax.ragged_dot`, gate and up
+apart: no path concatenates the weights. A Pallas call (`moe_combine`)
+adds each token's weighted rows up from where they lie.
 The parts of the chips of an expert-parallel host add up to the whole
 layer; on one chip the layer runs without its exchange, and nothing stands
 in for the absent chips. The shared experts are a plain gated feed-forward
@@ -38,6 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..observability.metrics import get_registry
 from .collectives import axis_size
 
 __all__ = ["moe_ffn_dropless", "route_top_k", "dropless_buffer_rows",
@@ -385,12 +394,20 @@ def _combine_pallas(weighed, token_of_row, key, picks, *, tokens: int,
     return out[:tokens]
 
 
+def _combine_in_kernel(d: int) -> bool:
+    """Whether `_combine` is the Pallas call: on a TPU, for rows of whole
+    lanes. That call reads the picks' rows only; XLA's adds every row."""
+    return jax.default_backend() != "cpu" and d % 128 == 0
+
+
 def _combine(weighed, token_of_row, key, picks, *, tokens: int, top_k: int,
              n_routed: int):
     """Each token's weighted rows added up in float32 and rounded once.
 
-    weighed: (rows, d), the buffer's rows in expert order, those past the
-    picks that are here zero. token_of_row: (rows,) int32. key: (T x k,)
+    weighed: (rows, d), the buffer's rows in expert order; those past the
+    picks that are here are zero where XLA adds the rows up and may hold
+    anything where the kernel does (`_combine_in_kernel`), which reads the
+    picks' rows only. token_of_row: (rows,) int32. key: (T x k,)
     the flat picks' expert here, or `held` for one that lies elsewhere;
     picks: (held,) their counts. -> (tokens, d) in `weighed`'s dtype.
 
@@ -399,11 +416,203 @@ def _combine(weighed, token_of_row, key, picks, *, tokens: int, top_k: int,
     they lie; elsewhere (the CPU's tests) XLA adds the buffer's rows into
     their tokens, a row at a time."""
     d = weighed.shape[1]
-    if jax.default_backend() == "cpu" or d % 128:
+    if not _combine_in_kernel(d):
         return jnp.zeros((tokens, d), jnp.float32).at[token_of_row].add(
             weighed.astype(jnp.float32)).astype(weighed.dtype)
     return _combine_pallas(weighed, token_of_row, key, picks, tokens=tokens,
                            top_k=top_k, n_routed=n_routed)
+
+
+# What a grouped product's kernel may keep in VMEM, by `_grouped_bytes`'
+# count: inside the 16 MB a custom call gets, like the combine's.
+_GROUPED_VMEM = 15 * 1024 * 1024
+# The rows a grid step of a grouped product multiplies (measured on a v5e
+# at the two decoder cells' five buffers, 256 to 6,200 rows an expert: 128
+# and 512 are within 3% either way, 1024 loses a third; PERF.md, PR 32).
+_GROUPED_ROWS = 256
+# What a grid step costs besides its product, in multiply-adds: the step's
+# own 0.3 us and the rows' tile fetched again for every column block. Read
+# from the same runs: 25 columns of 256 rows by two operands of 2048.
+_GROUPED_STEP = 25 * 256 * 2048 * 2
+
+
+def _grouped_bytes(tm: int, k: int, tn: int, itemsize: int,
+                   operands: int) -> int:
+    """VMEM a grid step of `_grouped_pallas` holds: the rows' tile, each
+    operand's block and the result's, two slots each (the pipeline fetches
+    the next step's while this one is multiplied), and in float32 a sum an
+    operand and the activation (the compiler's own count for two operands
+    at 512 x 512 is 16.15 MB, this says 16.0; at 256 x 768 16.53, 17.0)."""
+    blocks = tm * k + operands * k * tn + tm * tn
+    return 2 * blocks * itemsize + (2 * operands - 1) * tm * tn * 4
+
+
+def grouped_tiles(rows: int, k: int, n: int, itemsize: int, operands: int):
+    """-> (tm, tn): the rows and the columns a grid step of `_grouped_pallas`
+    takes of a product of (rows, k) by `operands` x (held, k, n); None where
+    the kernel does not take the product (float32 operands, an extent that
+    is no multiple of 128) and `lax.ragged_dot` runs it.
+
+    Rows: `_GROUPED_ROWS`, or the buffer where it is smaller. Columns: the
+    multiple of 128 that fits VMEM and makes the call cheapest, counting the
+    columns computed (the last block hangs over where tn does not divide n:
+    1408 = 11 x 128 is computed as 3 x 512) and a step's own cost beside
+    them. That gives 512 for Moonlight's experts (2048 -> 1408), 256 for
+    LFM2's (2048 -> 1792 = 7 x 256) and 1024 for both second products
+    (-> 2048), at every buffer: each within 2% of the best tile timed."""
+    if itemsize != 2 or k % 128 or n % 128:
+        return None
+    tm = min(_GROUPED_ROWS, _round_up(rows, 16))
+    step = _GROUPED_STEP / (tm * k * operands)      # in columns
+
+    def cost(tn):
+        return _round_up(n, tn) * (1.0 + step / tn)
+
+    fits = [tn for tn in range(128, n + 1, 128) if _grouped_bytes(
+        tm, k, tn, itemsize, operands) <= _GROUPED_VMEM]
+    return (tm, min(fits, key=cost)) if fits else None
+
+
+def _grouped_plan(picks, rows: int, tm: int):
+    """The visits of a grouped product over row tiles of `tm`: a visit is
+    a tile and one expert with picks inside it, in the rows' order.
+    -> (group (V,), tile (V,), offsets (held + 1,), visits ()), V the most
+    there can be; only the first `visits` are real, and no tile wholly past
+    the picks that are here is among them."""
+    held = picks.shape[0]
+    ends = jnp.cumsum(picks)
+    starts = ends - picks
+    tiles = jnp.where(picks > 0, -(-ends // tm) - starts // tm, 0)
+    last = jnp.cumsum(tiles)
+    v = jnp.arange(-(-rows // tm) + held - 1, dtype=jnp.int32)
+    group = jnp.minimum((last[None, :] <= v[:, None]).sum(1, dtype=jnp.int32),
+                        held - 1)
+    tile = starts[group] // tm + v - (last - tiles)[group]
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return group, jnp.clip(tile, 0, -(-rows // tm) - 1), offsets, last[-1]
+
+
+def _grouped_pallas(xs, weights, plan, *, tm: int, tn: int, name: str,
+                    scale=None, interpret: bool = False):
+    """One grouped product as a Pallas call: `xs` (rows, k) in expert order
+    against each expert's own (k, n) matrix of every operand in `weights`
+    ((held, k, n) each, read where the parameters keep them). One operand:
+    the product, rounded once. Two: silu(xs @ first) * (xs @ second), both
+    sums and the activation in float32, rounded once. `scale` (rows, 1)
+    float32 multiplies each row before the rounding.
+
+    The grid is (n // tn, visits): a step multiplies one tile of `tm` rows
+    by one expert's (k, tn) blocks, the whole of k at once (no sum carried
+    between steps), and writes the rows of the tile that are that expert's;
+    a tile that several experts share is visited once by each, and stays in
+    VMEM between them. Column blocks are the outer axis, so an expert's
+    block is fetched ONCE a call, when the visits reach the expert; the
+    rows' tile is fetched once a column block. The number of visits is the
+    batch's own (a dynamic grid extent): tiles past the picks that are here
+    are not visited, and their rows of the result hold nothing defined, as
+    do the rows of the last tile that are no expert's."""
+    import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    group, tile, offsets, visits = plan
+    rows, k = xs.shape
+    n, dtype = weights[0].shape[2], xs.dtype
+    exact = lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+    def kernel(group_ref, tile_ref, offsets_ref, xs_ref, *refs):
+        operands, out_ref = refs[:len(weights)], refs[-1]
+        v = pl.program_id(1)
+        g = group_ref[v]
+        x = xs_ref[...]
+        out = jnp.dot(x, operands[0][...], precision=exact,
+                      preferred_element_type=jnp.float32)
+        if len(operands) == 2:
+            out = jax.nn.silu(out) * jnp.dot(
+                x, operands[1][...], precision=exact,
+                preferred_element_type=jnp.float32)
+        if scale is not None:
+            out = out * refs[-2][...]
+        row = tile_ref[v] * tm + lax.broadcasted_iota(jnp.int32, (tm, tn), 0)
+        mine = (row >= offsets_ref[g]) & (row < offsets_ref[g + 1])
+        out_ref[...] = jnp.where(mine, out.astype(dtype), out_ref[...])
+
+    def block(n_i, v, group_ref, tile_ref, offsets_ref):
+        return group_ref[v], 0, n_i
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(-(-n // tn), visits),
+            in_specs=[pl.BlockSpec((tm, k), lambda n_i, v, g, t, o: (t[v], 0))]
+            + [pl.BlockSpec((None, k, tn), block) for _ in weights]
+            + ([] if scale is None else [pl.BlockSpec(
+                (tm, 1), lambda n_i, v, g, t, o: (t[v], 0))]),
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda n_i, v, g, t, o: (t[v], n_i))),
+        out_shape=jax.ShapeDtypeStruct((rows, n), dtype),
+        # a tile shared by experts is revisited: visits in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(group, tile, offsets, xs, *weights,
+      *(() if scale is None else (scale,)))
+
+
+# jitted by itself, like the combine: one trace and lowering a shape
+@functools.partial(jax.jit, static_argnames=("first", "second", "interpret"))
+def _grouped_ffn(xs, gate, up, down, picks, weight_of_row, *, first, second,
+                 interpret: bool = False):
+    """weight_of_row x (silu(xs @ gate[e]) * (xs @ up[e])) @ down[e] over
+    the rows of each expert e. A stage at a tile is the Pallas call, at
+    None `lax.ragged_dot` over the parameters as they lie, rounded where
+    PR 27 rounded (each sum, the activation, the product, the weighing)."""
+    rows, dtype = xs.shape[0], xs.dtype
+    plans = {tile[0]: _grouped_plan(picks, rows, tile[0])
+             for tile in (first, second) if tile}
+    if first:
+        act = _grouped_pallas(xs, (gate, up), plans[first[0]], tm=first[0],
+                              tn=first[1], name="ragged-dot-gated",
+                              interpret=interpret)
+    else:
+        a, b = (lax.ragged_dot(xs, m, picks, preferred_element_type=dtype)
+                for m in (gate, up))
+        act = (jax.nn.silu(a.astype(jnp.float32)) * b).astype(dtype)
+    if second:
+        return _grouped_pallas(act, (down,), plans[second[0]], tm=second[0],
+                               tn=second[1], name="ragged-dot-down",
+                               scale=weight_of_row[:, None],
+                               interpret=interpret)
+    ys = lax.ragged_dot(act, down, picks, preferred_element_type=dtype)
+    return (ys.astype(jnp.float32) * weight_of_row[:, None]).astype(dtype)
+
+
+def _experts(xs, gate, up, down, picks, weight_of_row, *, tiles=None,
+             interpret: bool = False):
+    """The gated feed-forward of each held expert over its rows of the
+    buffer, weighed: xs (rows, d) in expert order, picks (held,) the rows
+    of each, weight_of_row (rows,) float32. -> (rows, d); the rows past
+    the picks hold nothing defined.
+
+    On a TPU each product runs at the tile `grouped_tiles` gives it; on the
+    CPU (the tests) and where it gives none, `lax.ragged_dot` does
+    (`tiles` and `interpret` are a test's way to the kernel)."""
+    (rows, d), w = xs.shape, gate.shape[2]
+    if tiles is None:
+        size = xs.dtype.itemsize
+        tiles = (None, None) if jax.default_backend() == "cpu" else (
+            grouped_tiles(rows, d, w, size, 2),
+            grouped_tiles(rows, w, d, size, 1))
+    # counted where the call is traced: once a layer and compiled shape
+    calls = get_registry().counter(
+        "mmlspark_tpu_moe_grouped_calls_total",
+        "grouped products of the experts held traced, by what runs them",
+        labels=("kernel", "stage", "tile"))
+    for stage, tile in zip(("gated", "down"), tiles):
+        calls.labels(kernel="pallas" if tile else "ragged_dot", stage=stage,
+                     tile="x".join(map(str, tile)) if tile else "none").inc()
+    return _grouped_ffn(xs, gate, up, down, picks, weight_of_row,
+                        first=tiles[0], second=tiles[1], interpret=interpret)
 
 
 def moe_ffn_dropless(x, router, bias, gate, up, down, *,
@@ -429,7 +638,17 @@ def moe_ffn_dropless(x, router, bias, gate, up, down, *,
     picks than half again the even share land here (`lax.cond`,
     `dropless_buffer_rows`): the common case gathers and activates a
     buffer a quarter the size at 16 of 64 experts held, and the combine
-    (`_combine`) moves that buffer's rows, not one row a pick."""
+    (`_combine`) moves that buffer's rows, not one row a pick.
+
+    The products (`_experts`): on a TPU, for 2-byte operands with d and w
+    multiples of 128, two Pallas calls that read gate, up and down where
+    they lie, in tiles of 256 rows by the columns `grouped_tiles` counts
+    cheapest (512 of w = 1408, 256 of w = 1792, 1024 of d = 2048); the
+    hidden values are rounded once, after the activation, and the rows are
+    weighed in the second call's epilogue. Everywhere else three
+    `lax.ragged_dot` (gate, up, down) with the activation and the weighing
+    in XLA, rounded after each. Neither makes a (held, d, 2w) or a
+    (rows, 2w) array."""
     t, d = x.shape
     first, held = (int(v) for v in experts_held)
     if gate.shape[0] != held:
@@ -449,29 +668,23 @@ def moe_ffn_dropless(x, router, bias, gate, up, down, *,
         picks = (key[:, None] == jnp.arange(held, dtype=jnp.int32)).sum(
             0, dtype=jnp.int32)
         n_here = picks.sum()
-    gate_up = jnp.concatenate([gate, up], axis=-1).astype(dtype)
-    down = down.astype(dtype)
+    gate, up, down = (m.astype(dtype) for m in (gate, up, down))
 
     def routed(rows: int):
         with jax.named_scope("moe.dispatch"):
             token_of_row = order[:rows] // top_k
             xs = x.astype(dtype)[token_of_row]                 # (rows, d)
         with jax.named_scope("moe.experts"):
-            hidden = lax.ragged_dot(xs, gate_up, picks,
-                                    preferred_element_type=dtype)
-            w = hidden.shape[-1] // 2
-            act = (jax.nn.silu(hidden[:, :w].astype(jnp.float32))
-                   * hidden[:, w:]).astype(dtype)
-            ys = lax.ragged_dot(act, down, picks,
-                                preferred_element_type=dtype)  # (rows, d)
+            weighed = _experts(xs, gate, up, down, picks,
+                               weight_of_row[:rows])           # (rows, d)
         with jax.named_scope("moe.combine"):
-            # weigh in the sorted order; rows past the picks that are here
-            # hold nothing defined and become zero
-            here_rows = jnp.arange(rows, dtype=jnp.int32) < n_here
-            weighed = jnp.where(
-                here_rows[:, None],
-                ys.astype(jnp.float32) * weight_of_row[:rows, None],
-                0.0).astype(dtype)
+            # rows past the picks that are here hold nothing defined: the
+            # Pallas combine counts only the picks' rows, XLA's adds every
+            # row into a token, so there they become zero
+            if not _combine_in_kernel(d):
+                here_rows = jnp.arange(rows, dtype=jnp.int32) < n_here
+                weighed = jnp.where(here_rows[:, None], weighed,
+                                    jnp.zeros((), dtype))
             return _combine(weighed, token_of_row, key, picks, tokens=t,
                             top_k=top_k, n_routed=n_routed_experts)
 

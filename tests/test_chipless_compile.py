@@ -1,5 +1,5 @@
-"""The kernels of the `mla_moe_decoder` family compiled for a DESCRIBED
-TPU v5e at the benchmark cell's own widths: the chip's compiler runs here
+"""The kernels of the two decoder families compiled for a DESCRIBED
+TPU v5e at the benchmark cells' own widths: the chip's compiler runs here
 without a chip, and refuses what interpret mode lets through (a block not
 aligned to the tiling, more fast memory than a kernel may use). Nothing
 runs, so nothing here is a time or a result.
@@ -71,23 +71,40 @@ def test_encoder_attention_kernel_compiles(one_chip, length, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("tokens,held", [
-    (8 * 512, 16), (8 * 4096, 16),
+EXPERT_LAYERS = {
+    # tokens a batch, routed, held, a token's picks, the experts' width:
+    # the five buffers of the two decoder cells
+    "moonlight_8x4096": (8 * 4096, 64, 16, 6, 1408),
+    "moonlight_8x512": (8 * 512, 64, 16, 6, 1408),
+    "lfm2_2x16384": (2 * 16384, 32, 8, 4, 1792),
+    "lfm2_2x1024": (2 * 1024, 32, 8, 4, 1792),
+    "lfm2_1x1024": (1024, 32, 8, 4, 1792),
     # every expert held: no switch, and the combine reads the experts' rows
     # in two groups of 32
-    (8 * 512, 64)])
-def test_dropless_expert_layer_compiles_to_grouped_kernels(
-        one_chip, tokens, held, monkeypatch):
-    """A batch of 8 x 512 and one of 8 x 4096 tokens through 16 of 64
-    experts of width 1408: the grouped products are the compiler's own
-    kernels (not a dense product a group) and the combine is the Pallas
-    call (its VMEM and its table in SMEM fit), in both branches of the
-    buffer-size switch; no array of one row a pick, (k, T, d), is left in
-    either."""
-    from mmlspark_tpu.parallel.moe import moe_ffn_dropless
+    "all_64_held": (8 * 512, 64, 64, 6, 1408),
+}
 
-    # the layer asks the backend whether its Pallas combine can run: here
-    # the chip is described, not attached, so the test answers for it
+
+@pytest.mark.parametrize("case", list(EXPERT_LAYERS))
+def test_dropless_expert_layer_compiles_to_grouped_kernels(
+        one_chip, case, monkeypatch):
+    """The expert layer of `moonlight_16b_a3b.score_loglik` and of
+    `lfm2_8b_a1b.score_long_docs` at every buffer the cells have: both
+    grouped products are the Pallas calls, named as the benchmark's readers
+    select them (`ragged-dot*`), each result with the buffer's rows as its
+    first extent; the weights are read where they lie (no (held, d, 2w)
+    array) and the hidden values are written once ((rows, w), never
+    (rows, 2w)); the combine is the Pallas call (its VMEM and its table in
+    SMEM fit); all in both branches of the buffer-size switch; no array of
+    one row a pick, (k, T, d), is left in either."""
+    import re
+
+    from mmlspark_tpu.parallel.moe import (dropless_buffer_rows,
+                                           moe_ffn_dropless)
+
+    tokens, routed, held, top_k, w = EXPERT_LAYERS[case]
+    # the layer asks the backend whether its kernels can run: here the
+    # chip is described, not attached, so the test answers for it
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def spec(*shape, dtype=jnp.bfloat16):
@@ -95,16 +112,24 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(
 
     compiled = _compile(
         lambda x, router, bias, gate, up, down: moe_ffn_dropless(
-            x, router, bias, gate, up, down, n_routed_experts=64,
-            experts_held=(0, held), top_k=6, scaling=2.446,
+            x, router, bias, gate, up, down, n_routed_experts=routed,
+            experts_held=(0, held), top_k=top_k, scaling=2.446,
             dtype=jnp.bfloat16),
-        spec(tokens, 2048), spec(2048, 64), spec(64),
-        spec(held, 2048, 1408), spec(held, 2048, 1408),
-        spec(held, 1408, 2048))
+        spec(tokens, 2048), spec(2048, routed), spec(routed),
+        spec(held, 2048, w), spec(held, 2048, w), spec(held, w, 2048))
     text = compiled.as_text()
-    branches = 2 if held < 64 else 1
-    assert text.count("ragged-dot") >= 2 * branches
-    assert ("conditional" in text) == (held < 64)
-    assert text.count("moe_combine") >= branches
-    assert f"[6,{tokens},2048]" not in text
-    assert f"[{tokens},6,2048]" not in text
+    whole = tokens * top_k
+    buffers = sorted({dropless_buffer_rows(tokens, top_k, held, routed),
+                      whole})
+    assert ("conditional" in text) == (len(buffers) == 2)
+    assert text.count("ragged-dot") >= 2 * len(buffers)
+    for rows in buffers:
+        assert re.search(rf"%ragged-dot-gated[.\d]* = bf16\[{rows},{w}\]",
+                         text)
+        assert re.search(rf"%ragged-dot-down[.\d]* = bf16\[{rows},2048\]",
+                         text)
+        assert f"[{rows},{2 * w}]" not in text
+    assert f"[{held},2048,{2 * w}]" not in text
+    assert text.count("moe_combine") >= len(buffers)
+    assert f"[{top_k},{tokens},2048]" not in text
+    assert f"[{tokens},{top_k},2048]" not in text
