@@ -27,6 +27,11 @@ j // group), and none repeats K or V to do it:
   logsumexp, and the backward is the standard flash recomputation as a
   pure-XLA k-block scan (compiles on every backend; O(T) score memory).
 
+A fourth core, ``eva_attention``, reads TWO sets of keys in one softmax
+(EVA, arXiv 2302.04542): the keys of the query's own window, exactly and
+causally, and one pooled key and value (``eva_summaries``) for every chunk
+of the windows before it; the same three tiers behind one switch.
+
 The chunked and flash tiers compute scores and the softmax accumulator in
 float32 whatever the input dtype (bf16 inputs stay bf16 through the
 projections; the numerically sensitive reduction is f32 — the standard
@@ -50,7 +55,8 @@ from ..parallel.ring_attention import (dense_attention, key_head_group,
                                        over_key_heads)
 
 __all__ = ["dense_attention", "chunked_attention", "flash_attention",
-           "flash_tiles", "SelfAttention"]
+           "flash_tiles", "causal_attention", "eva_summaries",
+           "eva_attention", "SelfAttention"]
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/max NaN-free
 
@@ -153,7 +159,8 @@ def chunked_attention(q, k, v, causal: bool = False,
 # Pallas flash forward                                                  #
 # --------------------------------------------------------------------- #
 
-def flash_tiles(tq: int, tk: int, dtype) -> tuple[int, int]:
+def flash_tiles(tq: int, tk: int, dtype,
+                window: int | None = None) -> tuple[int, int]:
     """The (block_q, block_k) the flash forward works on, from what it can
     see. Read on a v5e (PERF.md, PRs 27 and 30): the kernel pays about
     0.6 us a grid step whatever is in it, so the largest tile wins: alone
@@ -170,8 +177,23 @@ def flash_tiles(tq: int, tk: int, dtype) -> tuple[int, int]:
     over 8 key/value heads, bfloat16, causal; PERF.md, PR 31) 1024 x 1024
     gives 36.8 ms a call, 512 x 2048 43.7, 512 x 1024 41.3, 2048 x 512
     59.0, 1024 x 512 69.9, and 2048 x 1024 does not fit the 16 MB: the
-    score tile, not the head, fills VMEM."""
+    score tile, not the head, fills VMEM.
+
+    Told a `window` (`eva_attention`: a query reads the keys of its own
+    window of that many positions), both tiles are the largest under the
+    cap that DIVIDE the window, so that a block of queries lies in one
+    window and a window is whole blocks of keys: 1024 x 1024 of 2048
+    (PERF.md, PR 33). Without one the answers are what they were."""
     cap = 1024 if jnp.dtype(dtype).itemsize <= 2 else 512
+    if window is not None:
+        if window <= 128:
+            return window, window
+        fits = [b for b in range(128, cap + 1, 128) if window % b == 0]
+        if not fits:
+            raise ValueError(
+                f"no tile of the flash forward divides a window of {window} "
+                "positions: a multiple of 128 does, or one of at most 128")
+        return max(fits), max(fits)
 
     def padded(t, b):
         return -(-t // b) * b
@@ -496,6 +518,385 @@ def flash_attention(q, k, v, causal: bool = False,
                 group=str(group), tile=f"{block_q}x{block_k}").inc()
     return _flash_diff(q, k, v, causal, block_q, block_k,
                        block_k if bwd_chunk is None else bwd_chunk, interpret)
+
+
+def causal_attention(q, k, v, impl: str = "flash", **flash_options):
+    """Plain causal attention by the tier's name: "flash" (None: the
+    backward scans the keys a forward tile at a time; `flash_options` are
+    that tier's, for tests), "chunked" or "dense" (in the queries' type)."""
+    if impl == "flash":
+        return flash_attention(q, k, v, causal=True, bwd_chunk=None,
+                               **flash_options)
+    if impl == "chunked":
+        return chunked_attention(q, k, v, causal=True)
+    if impl == "dense":
+        return dense_attention(q, k, v, causal=True).astype(q.dtype)
+    raise ValueError(f"unknown attention impl {impl!r}; have 'flash', "
+                     "'chunked', 'dense'")
+
+
+# --------------------------------------------------------------------- #
+# a window read exactly, the windows before it as chunk summaries (EVA)  #
+# --------------------------------------------------------------------- #
+
+def eva_summaries(k, v, phi, mu, chunk: int, upto: int | None = None):
+    """One pooled key and value for every chunk of `chunk` positions (EVA's
+    control-variate estimate of a chunk, arXiv 2302.04542 section 4, with
+    a learned vector a head in place of a sampled one). k, v: (B, T, H,
+    D); phi, mu: (H, D) float32. For chunk c, over its positions m:
+    a_m = softmax_m(k_m . phi / sqrt(D)); kbar_c = sum_m a_m k_m + mu;
+    vbar_c = sum_m a_m v_m. -> kbar, vbar (B, C, H, D) in k's and v's
+    types for the C whole chunks of the first `upto` positions (all T by
+    default). Float32 throughout and rounded once. This is the dense and
+    chunked tiers' pooling, in XLA, which keeps float32 copies of k and v
+    (8.4 ms at 2 x 32768 x 32 x 128 on a v5e); the flash tier pools in a
+    kernel of its own (`_eva_pool_kernel`, 1.5 ms; PERF.md, PR 33)."""
+    b, t, h, d = k.shape
+    c = (t if upto is None else min(upto, t)) // chunk
+    f32 = jnp.float32
+
+    def chunks(x):
+        return x[:, :c * chunk].reshape(b, c, chunk, h, x.shape[-1]).astype(
+            f32)
+
+    kc, vc = chunks(k), chunks(v)
+    scores = (kc * phi.astype(f32)).sum(-1) * d ** -0.5       # (B, C, m, H)
+    a = jax.nn.softmax(scores, axis=2)[..., None]
+    kbar = (a * kc).sum(2) + mu.astype(f32)
+    vbar = (a * vc).sum(2)
+    return kbar.astype(k.dtype), vbar.astype(v.dtype)
+
+
+def _eva_masked(q, kbar, k, ok_remote, ok_local, vbar, v):
+    """ONE softmax over [summaries; keys]: float32 scores (B, H, q, .) of
+    the queries against both, what of them counts, and the weighted values.
+    Every query sees itself, so no row is empty."""
+    f32, c = jnp.float32, kbar.shape[1]
+    scale = q.shape[-1] ** -0.5
+    s = jnp.concatenate([
+        jnp.where(ok_remote, jnp.einsum(
+            "bqhd,bchd->bhqc", q, kbar, preferred_element_type=f32) * scale,
+            -jnp.inf),
+        jnp.where(ok_local, jnp.einsum(
+            "bqhd,bkhd->bhqk", q, k, preferred_element_type=f32) * scale,
+            -jnp.inf)], -1)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhqc,bchd->bqhd", p[..., :c], vbar.astype(f32),
+                     preferred_element_type=f32)
+    out = out + jnp.einsum("bhqk,bkhd->bqhd", p[..., c:], v.astype(f32),
+                           preferred_element_type=f32)
+    return out.astype(q.dtype)
+
+
+def _eva_dense(q, k, v, kbar, vbar, window, chunk):
+    """One masked score matrix over [summaries; keys]: tests, short rows."""
+    pos = jnp.arange(q.shape[1])
+    own = pos // window
+    ok_local = (pos[:, None] >= pos[None, :]) & (own[:, None] == own[None, :])
+    ok_remote = ((jnp.arange(kbar.shape[1]) + 1) * chunk
+                 <= (own * window)[:, None])
+    return _eva_masked(q, kbar, k, ok_remote, ok_local, vbar, v)
+
+
+def _eva_chunked(q, k, v, kbar, vbar, window, chunk, q_chunk: int = 128):
+    """XLA, a window of keys at a time: a block of queries against the keys
+    of its own window and the summaries, never a (T, T) matrix. Runs on
+    every backend (the CPU's path, where Mosaic cannot lower)."""
+    b, t, h, _d = q.shape
+    q_chunk = max(n for n in range(1, min(q_chunk, window) + 1)
+                  if window % n == 0)
+    q, _ = _pad_seq(q, window)
+    k, _ = _pad_seq(k, window)
+    v, _ = _pad_seq(v, window)
+    chunk_end = (jnp.arange(kbar.shape[1]) + 1) * chunk
+
+    def some_queries(first):
+        start = (first // window) * window
+        qpos = first + jnp.arange(q_chunk)
+        return _eva_masked(
+            jax.lax.dynamic_slice_in_dim(q, first, q_chunk, 1), kbar,
+            jax.lax.dynamic_slice_in_dim(k, start, window, 1),
+            (chunk_end <= start)[None, :],
+            qpos[:, None] >= (start + jnp.arange(window))[None, :],
+            vbar, jax.lax.dynamic_slice_in_dim(v, start, window, 1))
+
+    out = jax.lax.map(some_queries, jnp.arange(0, q.shape[1], q_chunk))
+    return jnp.moveaxis(out, 0, 1).reshape(b, -1, h, v.shape[-1])[:, :t]
+
+
+def _eva_kernel(q_ref, k_ref, v_ref, kb_ref, vb_ref, o_ref, m_sc, l_sc,
+                acc_sc, *, block_q, block_k, block_s, n_local, n_remote,
+                window, per_window, scale):
+    """A block of queries, which lies in ONE window, over the grid's last
+    axis: first the `n_local` key blocks of its window (those above the
+    diagonal skipped), then the `n_remote` blocks of summaries (those past
+    the `per_window` x window index that lie before it skipped), all into
+    one running maximum, denominator and accumulator."""
+    import jax.experimental.pallas as pl
+
+    qi, j = pl.program_id(1), pl.program_id(2)
+    first = qi * block_q
+    own = first // window                       # this block's window
+
+    @pl.when(j == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    def fold(keys_ref, values_ref, counts):
+        """One block of keys or summaries folded in; `counts(shape)` is
+        what of the (bq, bk) tile counts, or None for all of it."""
+        s = jax.lax.dot_general(
+            q_ref[0], keys_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        ok = None if counts is None else counts(s.shape)
+        if ok is not None:
+            s = jnp.where(ok, s, _NEG_INF)
+        m_prev = m_sc[...]
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        if ok is not None:
+            # a row with nothing yet has m_new == _NEG_INF: exp(0), not 0
+            p = jnp.where(ok, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[...] = l_sc[...] * corr + p.sum(-1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
+            p.astype(values_ref.dtype), values_ref[0],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_sc[...] = m_new
+
+    # the window's own keys, causally
+    kfirst = (own * (window // block_k) + j) * block_k
+    needed = (j < n_local) & (kfirst <= first + block_q - 1)
+    crosses = kfirst + block_k - 1 > first
+
+    def causal(shape):
+        return (first + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                >= kfirst + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+    @pl.when(needed & crosses)
+    def _diagonal():
+        fold(k_ref, v_ref, causal)
+
+    @pl.when(needed & jnp.logical_not(crosses))
+    def _below():
+        fold(k_ref, v_ref, None)
+
+    # the summaries of the windows before it
+    sfirst = (j - n_local) * block_s
+    seen = own * per_window
+    reads = (j >= n_local) & (sfirst < seen)
+    if per_window % block_s:
+        # a block of summaries may end past the windows before this one
+        partly = sfirst + block_s > seen
+
+        @pl.when(reads & partly)
+        def _edge():
+            fold(kb_ref, vb_ref, lambda shape: sfirst
+                 + jax.lax.broadcasted_iota(jnp.int32, shape, 1) < seen)
+
+        reads = reads & jnp.logical_not(partly)
+
+    @pl.when(reads)
+    def _before():
+        fold(kb_ref, vb_ref, None)
+
+    @pl.when(j == n_local + n_remote - 1)
+    def _finalize():
+        o_ref[0] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
+
+
+def _eva_pool_kernel(k_ref, v_ref, phi_ref, mu_ref, kb_ref, vb_ref, *,
+                     chunk, pooled_blocks, scale):
+    """`eva_summaries` for one block of positions of one head, read where
+    the attention kernel reads them: (positions, D) in, (positions / chunk,
+    D) out, float32 inside. A block past the positions that are pooled
+    (the padding of the summaries to whole tiles) is zeros: a masked
+    summary still meets the values' product, where 0 x NaN is NaN."""
+    import jax.experimental.pallas as pl
+
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(1) >= pooled_blocks)
+    def _padding():
+        kb_ref[0] = jnp.zeros_like(kb_ref[0])
+        vb_ref[0] = jnp.zeros_like(vb_ref[0])
+
+    @pl.when(pl.program_id(1) < pooled_blocks)
+    def _pool():
+        def chunks(ref):
+            x = ref[0].astype(f32)
+            return x.reshape(x.shape[0] // chunk, chunk, x.shape[1])
+
+        kc = chunks(k_ref)
+        scores = (kc * phi_ref[0].astype(f32)).sum(-1, keepdims=True) * scale
+        e = jnp.exp(scores - scores.max(1, keepdims=True))
+        a = e / e.sum(1, keepdims=True)                  # (chunks, m, 1)
+        kb_ref[0] = ((a * kc).sum(1) + mu_ref[0].astype(f32)).astype(
+            kb_ref.dtype)
+        vb_ref[0] = (a * chunks(v_ref)).sum(1).astype(vb_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "window", "chunk", "block_q", "block_k", "block_s", "interpret"))
+def _eva_flash(q, k, v, phi, mu, kbar=None, vbar=None, *, window, chunk,
+               block_q, block_k, block_s, interpret=False):
+    """The Pallas forward, jitted by itself: traced and lowered once a
+    shape, not once a layer. Two calls: `eva_pool_*` pools the windows
+    before the last a window a step (unless a test hands the summaries
+    in), `eva_attn_*` attends. Nothing of size T x T or T x T / chunk is
+    ever whole in HBM; a key block outside the query's window and a
+    summary block at or past it are neither fetched (their index maps
+    name a block that is already in VMEM) nor computed on."""
+    import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    b, t, h, d = q.shape
+    dv = v.shape[-1]
+    per_window = window // chunk
+    windows_before = -(-t // window) - 1
+    q, _ = _pad_seq(q, max(block_q, block_k))
+    k, _ = _pad_seq(k, max(block_q, block_k))
+    v, _ = _pad_seq(v, max(block_q, block_k))
+
+    def bh(x):
+        return jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], x.shape[-1])
+
+    qf, kf, vf = bh(q), bh(k), bh(v)
+    n_local = window // block_k
+    n_remote = -(-windows_before * per_window // block_s)
+    tag = f"w{window}c{chunk}"      # a device trace's readers select by name
+
+    if kbar is None:
+        # as many rows as whole tiles of summaries, a window's a step
+        steps = -(-n_remote * block_s // per_window)
+        with jax.named_scope("eva.summarise"):
+            kbf, vbf = pl.pallas_call(
+                functools.partial(
+                    _eva_pool_kernel, chunk=chunk,
+                    pooled_blocks=windows_before, scale=d ** -0.5),
+                grid=(b * h, steps),
+                in_specs=[
+                    pl.BlockSpec((1, window, d), lambda bh_, i: (
+                        bh_, jnp.minimum(i, windows_before - 1), 0)),
+                    pl.BlockSpec((1, window, dv), lambda bh_, i: (
+                        bh_, jnp.minimum(i, windows_before - 1), 0)),
+                    pl.BlockSpec((1, 1, d), lambda bh_, i: (bh_ % h, 0, 0)),
+                    pl.BlockSpec((1, 1, d), lambda bh_, i: (bh_ % h, 0, 0))],
+                out_specs=[
+                    pl.BlockSpec((1, per_window, d),
+                                 lambda bh_, i: (bh_, i, 0)),
+                    pl.BlockSpec((1, per_window, dv),
+                                 lambda bh_, i: (bh_, i, 0))],
+                out_shape=[
+                    jax.ShapeDtypeStruct((b * h, steps * per_window, d),
+                                         k.dtype),
+                    jax.ShapeDtypeStruct((b * h, steps * per_window, dv),
+                                         v.dtype)],
+                interpret=interpret, name=f"eva_pool_{tag}",
+            )(kf, vf, phi[:, None], mu[:, None])
+    else:
+        kbf, vbf = (bh(_pad_seq(x[:, :windows_before * per_window],
+                                block_s)[0]) for x in (kbar, vbar))
+
+    def key_block(bh_, qi, j):
+        own = (qi * block_q) // window
+        last = (qi * block_q + block_q - 1) // block_k     # the diagonal's
+        return (bh_, jnp.minimum(own * n_local + jnp.minimum(j, n_local - 1),
+                                 last), 0)
+
+    def summary_block(bh_, qi, j):
+        seen = ((qi * block_q) // window) * per_window
+        last = jnp.maximum(-(-seen // block_s) - 1, 0)
+        return (bh_, jnp.clip(j - n_local, 0, last), 0)
+
+    def query_block(bh_, qi, j):
+        return (bh_, qi, 0)
+
+    with jax.named_scope("eva.attend"):
+        out = pl.pallas_call(
+            functools.partial(
+                _eva_kernel, block_q=block_q, block_k=block_k,
+                block_s=block_s, n_local=n_local, n_remote=n_remote,
+                window=window, per_window=per_window, scale=d ** -0.5),
+            grid=(b * h, qf.shape[1] // block_q, n_local + n_remote),
+            in_specs=[pl.BlockSpec((1, block_q, d), query_block),
+                      pl.BlockSpec((1, block_k, d), key_block),
+                      pl.BlockSpec((1, block_k, dv), key_block),
+                      pl.BlockSpec((1, block_s, d), summary_block),
+                      pl.BlockSpec((1, block_s, dv), summary_block)],
+            out_specs=pl.BlockSpec((1, block_q, dv), query_block),
+            out_shape=jax.ShapeDtypeStruct(qf.shape[:2] + (dv,), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32),
+                            pltpu.VMEM((block_q, dv), jnp.float32)],
+            interpret=interpret, name=f"eva_attn_{tag}",
+        )(qf, kf, vf, kbf, vbf)
+    return jnp.moveaxis(out.reshape(b, h, -1, dv), 1, 2)[:, :t]
+
+
+def eva_attention(q, k, v, phi, mu, window: int, chunk: int,
+                  impl: str = "flash", summaries=None,
+                  block_q: int | None = None, block_k: int | None = None,
+                  block_s: int | None = None, interpret: bool = False):
+    """Causal attention in which query t, in window w = t // window, reads
+    in ONE softmax the keys of its own window at or before it and the
+    summary of every chunk of the windows before it (none of its own):
+    Z = sum_L exp(s q.k_m) + sum_R exp(s q.kbar_c), out = (sum_L exp(..)
+    v_m + sum_R exp(..) vbar_c) / Z, s = 1 / sqrt(D). q, k, v: (B, T, H,
+    D); phi, mu: (H, D), the learned vectors the summaries are pooled
+    with (`eva_summaries`; the last window's chunks are read by nobody
+    and are not pooled). `summaries` (kbar, vbar), each (B, C, H, D) with
+    C at least the chunks of every window but the last, takes their
+    place (tests). `window` is a multiple of `chunk`. A row of at most
+    one window is plain causal attention and takes that tier of it.
+    `impl`: "dense" (one masked score matrix over [summaries; keys]),
+    "chunked" (XLA, a window of keys at a time) or "flash" (Pallas: the
+    pooling and the attention a kernel each, forward only; tiles by
+    `flash_tiles` unless a test names them)."""
+    if window % chunk:
+        raise ValueError(f"a window of {window} positions is not whole "
+                         f"chunks of {chunk}")
+    t = q.shape[1]
+    if t <= window:
+        return causal_attention(q, k, v, impl,
+                                **({"interpret": True} if interpret else {}))
+    before = (-(-t // window) - 1) * window      # positions that are pooled
+    if summaries is not None and summaries[0].shape[1] < before // chunk:
+        raise ValueError(
+            f"a row of {t} positions reads {before // chunk} summaries "
+            f"(windows of {window}, chunks of {chunk}); got "
+            f"{summaries[0].shape[1]}")
+    if impl not in ("dense", "chunked", "flash"):
+        raise ValueError(f"unknown attention impl {impl!r}; have 'flash', "
+                         "'chunked', 'dense'")
+    if impl != "flash":
+        if summaries is None:
+            with jax.named_scope("eva.summarise"):
+                summaries = eva_summaries(k, v, phi, mu, chunk, upto=before)
+        kbar, vbar = (x[:, :before // chunk] for x in summaries)
+        with jax.named_scope("eva.attend"):
+            return (_eva_dense if impl == "dense" else _eva_chunked)(
+                q, k, v, kbar, vbar, window, chunk)
+    rule_q, rule_k = flash_tiles(t, t, q.dtype, window=window)
+    block_q, block_k = block_q or rule_q, block_k or rule_k
+    block_s = block_s or flash_tiles(t, before // chunk, q.dtype)[1]
+    if window % block_q or window % block_k:
+        raise ValueError(f"tiles of {block_q} x {block_k} do not divide a "
+                         f"window of {window}")
+    # counted where the call is traced (the kernels are traced once a
+    # shape, this once a layer)
+    get_registry().counter(
+        "mmlspark_tpu_eva_calls_total",
+        "windowed-and-summarised attention forward calls traced, by the "
+        "window, the chunk and the tile (queries x keys x summaries)",
+        labels=("window", "chunk", "tile")).labels(
+            window=str(window), chunk=str(chunk),
+            tile=f"{block_q}x{block_k}x{block_s}").inc()
+    return _eva_flash(q, k, v, phi, mu, *(summaries or ()), window=window,
+                      chunk=chunk, block_q=block_q, block_k=block_k,
+                      block_s=block_s, interpret=interpret)
 
 
 # --------------------------------------------------------------------- #

@@ -55,6 +55,14 @@ and keys are permuted alike).
 `embedding_norm`) onto nn.models.HybridMoEDecoder. Those checkpoints
 rotate halves already and tie the head to the embedding: nothing is
 permuted, and an `lm_head.weight` is the embedding written twice.
+
+`EVA_DECODER_SPEC` maps the `evabyte` checkpoint naming (`q_proj`,
+`k_proj`, `v_proj`, `o_proj`, `adaptive_phi`, `adaptive_mu_k`;
+`gate_proj`, `up_proj`, `down_proj`; `input_layernorm`,
+`post_attention_layernorm`, `norm`; `embed_tokens`, `lm_head`) onto
+nn.models.EvaDecoder. Those checkpoints multiply a norm by 1 + w
+(`norm_add_unit_offset`): 1 is added to every norm's weight at import,
+and the module's scale is what multiplies.
 """
 
 from __future__ import annotations
@@ -693,6 +701,76 @@ def import_torch_hybrid_moe_decoder(
     return _validate_and_install(bundle, variables, architecture)
 
 
+# --------------------------------------------------------------------- #
+# evabyte naming -> nn.models.EvaDecoder                                 #
+# --------------------------------------------------------------------- #
+
+def _t_unit_offset(v, ctx):
+    """`norm_add_unit_offset`: the checkpoint stores w and multiplies by
+    1 + w; the module's scale is what multiplies."""
+    return np.asarray(v, np.float32) + 1.0
+
+
+def _t_head_vector(v, ctx):
+    """A learned vector a head, stored with broadcast axes ((1, heads, 1,
+    width)) or without -> (heads, width)."""
+    return np.asarray(v).reshape(ctx["num_heads"], ctx["head_dim"])
+
+
+EVA_DECODER_SPEC: "list[MapRule]" = [
+    MapRule(r"model\.embed_tokens\.weight", "params/embed/embedding"),
+    MapRule(_LAYER + r"input_layernorm\.weight",
+            r"params/ln_attn_\g<i>/scale", _t_unit_offset),
+    MapRule(_LAYER + r"post_attention_layernorm\.weight",
+            r"params/ln_mlp_\g<i>/scale", _t_unit_offset),
+    MapRule(_LAYER + r"self_attn\.(?P<p>[qkv])_proj\.weight",
+            r"params/eva_attn_\g<i>/\g<p>_proj/kernel", _t_heads_kernel),
+    MapRule(_LAYER + r"self_attn\.o_proj\.weight",
+            r"params/eva_attn_\g<i>/out/kernel", _t_attn_out_kernel),
+    MapRule(_LAYER + r"self_attn\.adaptive_phi",
+            r"params/eva_attn_\g<i>/phi", _t_head_vector),
+    MapRule(_LAYER + r"self_attn\.adaptive_mu_k",
+            r"params/eva_attn_\g<i>/mu", _t_head_vector),
+    MapRule(_LAYER + r"mlp\." + _FFN,
+            r"params/mlp_\g<i>/\g<proj>/kernel", _t_transpose),
+    MapRule(r"model\.norm\.weight", "params/ln_final/scale",
+            _t_unit_offset),
+    # (num_pred_heads x vocabulary, d): prediction p's rows together
+    MapRule(r"lm_head\.weight", "params/head_kernel", _t_transpose),
+    MapRule(r".*rotary_emb\.inv_freq", None),
+]
+
+
+def torch_eva_decoder_to_flax(
+    state_dict: Mapping[str, np.ndarray], num_heads: int, head_dim: int,
+) -> dict[str, Any]:
+    """Map an `evabyte`-named state dict onto nn.models.EvaDecoder
+    variables: every norm's weight plus 1 (`norm_add_unit_offset`), the
+    two learned vectors a head as (heads, width), rotary already in the
+    rotate-half layout. A name no rule places is an error that names it."""
+    return apply_mapping_spec(state_dict, EVA_DECODER_SPEC, {
+        "num_heads": int(num_heads), "head_dim": int(head_dim)})
+
+
+def import_torch_eva_decoder(
+    path: str, architecture: str = "eva_decoder",
+    input_shape: tuple[int, ...] = (8,), **config,
+):
+    """Load an `evabyte`-named checkpoint into a ready-to-serve
+    ModelBundle of the `eva_decoder` family. `config` is the module's
+    (`num_layers`, the head count, the window and the chunk, ...): the
+    checkpoint's own config.json states them, its shapes do not."""
+    from .models import ModelBundle
+
+    sd = load_state_dict(path)
+    bundle = ModelBundle.init(architecture, input_shape=tuple(input_shape),
+                              seed=0, **config)
+    module = bundle.module
+    variables = torch_eva_decoder_to_flax(
+        sd, module.num_heads, module.d_model // module.num_heads)
+    return _validate_and_install(bundle, variables, architecture)
+
+
 # architecture name -> importer; zoo.import_external dispatches here, so
 # registering a new family makes it fetchable/verifiable end to end
 IMPORTERS: "dict[str, Callable]" = {
@@ -702,6 +780,7 @@ IMPORTERS: "dict[str, Callable]" = {
     "transformer": import_torch_transformer,
     "mla_moe_decoder": import_torch_mla_moe_decoder,
     "hybrid_moe_decoder": import_torch_hybrid_moe_decoder,
+    "eva_decoder": import_torch_eva_decoder,
 }
 
 

@@ -30,8 +30,10 @@ __all__ = [
     "TransformerEncoder",
     "MLAMoEDecoder",
     "HybridMoEDecoder",
+    "EvaDecoder",
     "LatentAttention",
     "GroupedQueryAttention",
+    "EvaAttention",
     "ShortConv",
     "ExpertLayer",
     "GatedFFN",
@@ -284,22 +286,22 @@ def _rotary(x, theta: float):
                            -1).astype(x.dtype)
 
 
-def _causal_attention(q, k, v, impl: str, dtype):
+def _causal_attention(q, k, v, impl: str, dtype, pooling=None,
+                      window: int = 0, chunk: int = 0):
     """A decoder's attention core: "flash" (the Pallas kernel; chunked on
-    the CPU, where Mosaic cannot lower), "chunked" or "dense"."""
-    from .attention import (chunked_attention, dense_attention,
-                            flash_attention)
+    the CPU, where Mosaic cannot lower), "chunked" or "dense"; any other
+    name is an error that lists these. With a `window`, a query reads its
+    own window exactly and the windows before it a summary a `chunk`,
+    pooled with `pooling` (phi, mu) (`attention.eva_attention`, which
+    takes plain causal attention for a row of at most one window)."""
+    from .attention import causal_attention, eva_attention
 
     if impl == "flash" and jax.default_backend() == "cpu":
         impl = "chunked"
-    if impl == "flash":
-        # None: the backward scans the keys a forward tile at a time
-        return flash_attention(q, k, v, causal=True, bwd_chunk=None)
-    if impl == "chunked":
-        return chunked_attention(q, k, v, causal=True)
-    if impl == "dense":
-        return dense_attention(q, k, v, causal=True).astype(dtype)
-    raise ValueError(f"unknown attention impl {impl!r}")
+    if window:
+        return eva_attention(q, k, v, *pooling, window, chunk,
+                             impl=impl).astype(dtype)
+    return causal_attention(q, k, v, impl).astype(dtype)
 
 
 class LatentAttention(nn.Module):
@@ -388,6 +390,52 @@ class GroupedQueryAttention(nn.Module):
                 self.name or "gqa_attn"):
             o = _causal_attention(q, k, v, self.impl, dt)
         with jax.named_scope("gqa.project"):
+            return nn.DenseGeneral(d, axis=(-2, -1), use_bias=False,
+                                   dtype=dt, name="out")(o)
+
+
+class EvaAttention(nn.Module):
+    """Causal attention that reads its own window exactly and everything
+    before it as one summary a chunk (EVA, arXiv 2302.04542, as EvaByte
+    runs it): `num_heads` heads of d / num_heads channels, rotary over the
+    whole head, no biases; two learned vectors a head, `phi` (the weights
+    inside a chunk: softmax over its positions of k . phi / sqrt(width))
+    and `mu` (added to the pooled key), float32. Query t in window
+    w = t // window_size attends, in ONE softmax, to the keys of window w
+    at or before it and to the summaries of every chunk of windows 0 ..
+    w - 1 (`nn/attention.py` `eva_summaries`, `eva_attention`)."""
+
+    num_heads: int
+    window_size: int = 2048
+    chunk_size: int = 16
+    rope_theta: float = 100000.0
+    impl: str = "flash"
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y):
+        dt, d = self.dtype, y.shape[-1]
+        if d % self.num_heads:
+            raise ValueError(f"{self.num_heads} heads do not divide a "
+                             f"width of {d}")
+        width = d // self.num_heads
+
+        def heads(name):
+            return nn.DenseGeneral((self.num_heads, width), use_bias=False,
+                                   dtype=dt, name=name)(y)
+
+        with jax.named_scope("eva.project"):
+            q = _rotary(heads("q_proj"), self.rope_theta)
+            k = _rotary(heads("k_proj"), self.rope_theta)
+            v = heads("v_proj")
+        phi, mu = (self.param(name, nn.initializers.normal(1.0),
+                              (self.num_heads, width), jnp.float32)
+                   for name in ("phi", "mu"))
+        # `eva.summarise` and `eva.attend` are opened inside
+        with jax.named_scope(self.name or "eva_attn"):
+            o = _causal_attention(q, k, v, self.impl, dt, (phi, mu),
+                                  self.window_size, self.chunk_size)
+        with jax.named_scope("eva.project"):
             return nn.DenseGeneral(d, axis=(-2, -1), use_bias=False,
                                    dtype=dt, name="out")(o)
 
@@ -488,19 +536,25 @@ class ExpertLayer(nn.Module):
 class _ScoringDecoder(nn.Module):
     """What the decoder families share, written once: the block loop over
     token ids (h = x + Op(RMSNorm(x)), out = h + FF(RMSNorm(h)); FF a
-    gated feed-forward in the leading dense layers, then an `ExpertLayer`),
-    the final RMSNorm, the chunked log-likelihood head and what is sown per
-    batch. A family states its sizes as attributes under the names used
-    here and gives `_dense_layers`, the layers that lead with a dense
-    feed-forward, and `_operator(i)`, layer i's mixer with the name of the
-    norm before it.
+    gated feed-forward in the leading dense layers, then an `ExpertLayer`
+    in the layers that are left, if any), the final RMSNorm, the chunked
+    log-likelihood head and what is sown per batch. A family states its
+    sizes as attributes under the names used here and gives
+    `_dense_layers`, the layers that lead with a dense feed-forward (all
+    of them in a family without experts), and `_operator(i)`, layer i's
+    mixer with the name of the norm before it.
 
     Scoring output: the module returns, and sows as `token_logprobs`, each
     next token's log-probability, (rows, length - 1): the head runs over
     `head_chunk` tokens at a time with that chunk's log-sum-exp, and only
     the target's log-probability is kept, so the (rows x length x
     vocabulary) logits are never whole in memory. `output="logits"`
-    returns them instead (short rows, tests).
+    returns them instead (short rows, tests). A family whose head makes
+    several predictions a position states `num_pred_heads`: the head's
+    kernel is (d, num_pred_heads x vocab_size), prediction p in columns
+    p x vocab_size onwards; the scoring output reads prediction 0, the
+    next token, and "logits" returns all, (rows, length, num_pred_heads,
+    vocab_size). Predictions past the next token are held and never read.
 
     A chip may hold a share of the model. `experts_held` (first index,
     count) says which routed experts' weights this module has; routing is
@@ -518,6 +572,7 @@ class _ScoringDecoder(nn.Module):
     # what a family may state as an attribute of its own
     route_epsilon = 1e-20           # added to the sum of a token's weights
     tie_embeddings = False          # the head is the embedding, transposed
+    num_pred_heads = 1              # predictions a position the head makes
 
     @property
     def batch_counters(self) -> tuple:
@@ -591,12 +646,18 @@ class _ScoringDecoder(nn.Module):
         else:
             head = self.param(
                 "head_kernel", nn.initializers.normal(d ** -0.5),
-                (d, self.vocab_size), jnp.float32).astype(dt)
+                (d, self.num_pred_heads * self.vocab_size),
+                jnp.float32).astype(dt)
         with jax.named_scope("loglik.head"):
-            logprobs = self._token_logprobs(h, ids, head)
+            # the next token is prediction 0's columns
+            logprobs = self._token_logprobs(
+                h, ids, head[:, :self.vocab_size]
+                if self.num_pred_heads > 1 else head)
             self.sow("intermediates", "token_logprobs", logprobs)
             if self.output == "logits":
-                return jnp.dot(h, head, preferred_element_type=jnp.float32)
+                logits = jnp.dot(h, head, preferred_element_type=jnp.float32)
+                return logits if self.num_pred_heads == 1 else logits.reshape(
+                    *logits.shape[:2], self.num_pred_heads, self.vocab_size)
         if self.output != "token_logprobs":
             raise ValueError(f"unknown output {self.output!r}")
         return logprobs
@@ -718,6 +779,48 @@ class HybridMoEDecoder(_ScoringDecoder):
         return self._score(x)
 
 
+class EvaDecoder(_ScoringDecoder):
+    """Causal decoder over bytes (EvaByte): every layer `EvaAttention`
+    (its own window of `window_size` exactly, the windows before it a
+    summary a chunk of `chunk_size`) and a gated feed-forward, no expert
+    layer, so nothing is sown per batch for the runner to read back; an
+    untied head of `num_pred_heads` predictions a position, of which the
+    scoring output reads the next byte's. RMSNorm scales are stored as
+    they multiply (a checkpoint's `norm_add_unit_offset` is met at import).
+    The block loop, the head and the outputs are `_ScoringDecoder`'s."""
+
+    num_layers: int = 2
+    d_model: int = 64
+    num_heads: int = 4
+    window_size: int = 2048
+    chunk_size: int = 16
+    d_ff_dense: int = 128
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 100000.0
+    vocab_size: int = 320
+    num_pred_heads: int = 8
+    max_len: int = 32768
+    # "flash": the Pallas kernel (chunked off-TPU), "chunked", "dense"
+    attention_impl: str = "flash"
+    head_chunk: int = 1024          # tokens of one block of the head
+    output: str = "token_logprobs"  # or "logits"
+    dtype: Any = jnp.float32
+
+    @property
+    def _dense_layers(self) -> int:
+        return self.num_layers
+
+    def _operator(self, i: int):
+        return f"ln_attn_{i}", EvaAttention(
+            self.num_heads, self.window_size, self.chunk_size,
+            self.rope_theta, self.attention_impl, self.dtype,
+            name=f"eva_attn_{i}")
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        return self._score(x)
+
+
 def resnet20_cifar(num_outputs: int = 10, dtype=jnp.float32) -> ResNet:
     return ResNet(stage_sizes=(3, 3, 3), num_filters=16,
                   num_outputs=num_outputs, dtype=dtype)
@@ -737,7 +840,10 @@ def _hashable(config: dict) -> dict:
 
 # Architecture registry: name -> factory(**config). The zoo's ModelSchema
 # references architectures by name (the reference's ModelSchema carries a
-# remote URI instead, downloader/Schema.scala:30+).
+# remote URI instead, downloader/Schema.scala:30+). Families: `mlp`,
+# `simple_cnn` and the `resnet*` over images or features; over token ids the
+# `transformer` encoder and three causal decoders on one skeleton,
+# `mla_moe_decoder`, `hybrid_moe_decoder` and `eva_decoder`.
 ARCHITECTURES: dict[str, Callable[..., nn.Module]] = {
     "mlp": lambda **kw: MLP(**kw),
     "simple_cnn": lambda **kw: SimpleCNN(**kw),
@@ -747,6 +853,7 @@ ARCHITECTURES: dict[str, Callable[..., nn.Module]] = {
     "transformer": lambda **kw: TransformerEncoder(**kw),
     "mla_moe_decoder": lambda **kw: MLAMoEDecoder(**_hashable(kw)),
     "hybrid_moe_decoder": lambda **kw: HybridMoEDecoder(**_hashable(kw)),
+    "eva_decoder": lambda **kw: EvaDecoder(**kw),
 }
 
 

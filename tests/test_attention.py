@@ -16,6 +16,8 @@ from mmlspark_tpu.nn.attention import (
     SelfAttention,
     chunked_attention,
     dense_attention,
+    eva_attention,
+    eva_summaries,
     flash_attention,
     flash_tiles,
 )
@@ -441,3 +443,213 @@ class TestGroupedQueryHeads:
                                causal=True)
         np.testing.assert_allclose(got.astype(jnp.float32), want, atol=3e-2,
                                    rtol=3e-2)
+
+
+# --------------------------------------------------------------------- #
+# a window read exactly, the windows before it as chunk summaries       #
+# --------------------------------------------------------------------- #
+
+EVA_WINDOW, EVA_CHUNK = 32, 4
+EVA_IMPLS = {"dense": {}, "chunked": {}, "flash": {"interpret": True}}
+# float32 throughout on the CPU: the tiers differ in the order of their sums
+# (online against whole softmax, blocks of keys), observed at most 8e-7 at
+# values of spread 0.5
+EVA_LIMIT = 1e-5
+
+
+def _eva_inputs(t, b=2, h=3, d=8, seed=0):
+    rng = np.random.default_rng([seed, t])
+    q, k, v = (jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.float32)
+               for _ in range(3))
+    phi = jnp.asarray(rng.normal(size=(h, d)), jnp.float32)
+    mu = jnp.asarray(0.5 * rng.normal(size=(h, d)), jnp.float32)
+    return q, k, v, phi, mu
+
+
+def _eva_by_masks(q, k, v, phi, mu, window, chunk):
+    """The equations as written, numpy float64: pooled keys and values a
+    chunk, then ONE softmax over [summaries; keys] under two masks."""
+    q, k, v, phi, mu = (np.asarray(x, np.float64) for x in (q, k, v, phi, mu))
+    b, t, h, d = q.shape
+    c, s = t // chunk, d ** -0.5
+    kc = k[:, :c * chunk].reshape(b, c, chunk, h, d)
+    vc = v[:, :c * chunk].reshape(b, c, chunk, h, d)
+    a = np.einsum("bcmhd,hd->bcmh", kc, phi) * s
+    a = np.exp(a - a.max(2, keepdims=True))
+    a /= a.sum(2, keepdims=True)
+    kbar = np.einsum("bcmh,bcmhd->bchd", a, kc) + mu
+    vbar = np.einsum("bcmh,bcmhd->bchd", a, vc)
+    pos = np.arange(t)
+    first = (pos // window) * window              # a query's window starts
+    seen = np.concatenate([
+        (np.arange(c)[None, :] + 1) * chunk <= first[:, None],
+        (pos[None, :] <= pos[:, None]) & (pos[None, :] >= first[:, None])], 1)
+    scores = np.einsum("bqhd,bkhd->bhqk", q,
+                       np.concatenate([kbar, k], 1)) * s
+    scores = np.where(seen, scores, -np.inf)
+    p = np.exp(scores - scores.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return (np.einsum("bhqk,bkhd->bqhd", p, np.concatenate([vbar, v], 1)),
+            kbar, vbar)
+
+
+def _eva_calls(window, chunk, tile) -> float:
+    return get_registry().counter(
+        "mmlspark_tpu_eva_calls_total",
+        labels=("window", "chunk", "tile")).labels(
+            window=str(window), chunk=str(chunk), tile=tile).value
+
+
+class TestEvaAttention:
+    """`eva_attention`: window 32 and chunk 4 unless said."""
+
+    # 20: inside one window; 96: three whole windows; 80: a ragged last
+    # window; 70: a ragged last chunk too
+    @pytest.mark.parametrize("impl", sorted(EVA_IMPLS))
+    @pytest.mark.parametrize("t", [20, 96, 80, 70])
+    def test_three_tiers_match_the_masks(self, t, impl):
+        q, k, v, phi, mu = _eva_inputs(t)
+        want, _kbar, _vbar = _eva_by_masks(q, k, v, phi, mu, EVA_WINDOW,
+                                           EVA_CHUNK)
+        got = eva_attention(q, k, v, phi, mu, EVA_WINDOW, EVA_CHUNK,
+                            impl=impl, **EVA_IMPLS[impl])
+        assert got.shape == q.shape and got.dtype == q.dtype
+        assert np.abs(np.asarray(got) - want).max() < EVA_LIMIT
+
+    @pytest.mark.parametrize("impl", sorted(EVA_IMPLS))
+    def test_one_window_is_plain_causal_attention(self, impl):
+        q, k, v, phi, mu = _eva_inputs(20)
+        got = eva_attention(q, k, v, phi, mu, EVA_WINDOW, EVA_CHUNK,
+                            impl=impl, **EVA_IMPLS[impl])
+        # the same tier of plain causal attention, bit for bit: no summary
+        # exists, and phi and mu play no part
+        plain = {"dense": lambda: dense_attention(q, k, v, causal=True),
+                 "chunked": lambda: chunked_attention(q, k, v, causal=True),
+                 "flash": lambda: flash_attention(q, k, v, causal=True,
+                                                  interpret=True)}[impl]()
+        assert np.array_equal(np.asarray(got), np.asarray(plain))
+        np.testing.assert_allclose(
+            got, dense_attention(q, k, v, causal=True), atol=EVA_LIMIT)
+
+    def test_the_pooling_is_the_equations(self):
+        q, k, v, phi, mu = _eva_inputs(70)
+        _out, kbar, vbar = _eva_by_masks(q, k, v, phi, mu, EVA_WINDOW,
+                                         EVA_CHUNK)
+        got_k, got_v = eva_summaries(k, v, phi, mu, EVA_CHUNK)
+        assert got_k.shape == (2, 17, 3, 8)        # 70 // 4 whole chunks
+        assert np.abs(np.asarray(got_k) - kbar).max() < EVA_LIMIT
+        assert np.abs(np.asarray(got_v) - vbar).max() < EVA_LIMIT
+        # only what is read: the windows before the last
+        short_k, _ = eva_summaries(k, v, phi, mu, EVA_CHUNK, upto=64)
+        assert np.array_equal(np.asarray(short_k), np.asarray(got_k[:, :16]))
+
+    # one tile a window (PR 28's lesson 4: the interpreted kernel finds a
+    # wrong count of steps), then tiles that cross: two key blocks a window,
+    # summaries in blocks that end inside a window's share (8 a window)
+    @pytest.mark.parametrize("tiles", [(32, 32, 16), (16, 16, 16),
+                                       (32, 16, 8), (16, 32, 24),
+                                       (8, 8, 3)])
+    @pytest.mark.parametrize("t", [96, 70])
+    def test_the_interpreted_kernel_at_tiles_that_cross(self, t, tiles):
+        q, k, v, phi, mu = _eva_inputs(t, seed=3)
+        want, _kbar, _vbar = _eva_by_masks(q, k, v, phi, mu, EVA_WINDOW,
+                                           EVA_CHUNK)
+        block_q, block_k, block_s = tiles
+        got = eva_attention(q, k, v, phi, mu, EVA_WINDOW, EVA_CHUNK,
+                            impl="flash", block_q=block_q, block_k=block_k,
+                            block_s=block_s, interpret=True)
+        assert np.abs(np.asarray(got) - want).max() < EVA_LIMIT
+
+    @pytest.mark.parametrize("impl", sorted(EVA_IMPLS))
+    def test_a_change_at_p_moves_nothing_before_p(self, impl):
+        q, k, v, phi, mu = _eva_inputs(96, seed=1)
+        p = 70                      # in the third window, inside a chunk
+
+        def run(q, k, v):
+            return np.asarray(eva_attention(
+                q, k, v, phi, mu, EVA_WINDOW, EVA_CHUNK, impl=impl,
+                **EVA_IMPLS[impl]))
+
+        base = run(q, k, v)
+        moved = run(q.at[:, p].add(1.0), k.at[:, p].add(1.0),
+                    v.at[:, p].add(1.0))
+        assert np.array_equal(moved[:, :p], base[:, :p])
+        assert not np.array_equal(moved[:, p:], base[:, p:])
+
+    @pytest.mark.parametrize("impl", sorted(EVA_IMPLS))
+    def test_an_earlier_window_is_seen_through_its_summaries_only(self,
+                                                                  impl):
+        """The 4 keys and values of chunk 3 (positions 12 .. 15, window 0)
+        replaced by any others under the SAME kbar, vbar: every query of a
+        later window reads the same, bit for bit; the chunk's own window,
+        which reads the keys, does not."""
+        q, k, v, phi, mu = _eva_inputs(96, seed=2)
+        summaries = eva_summaries(k, v, phi, mu, EVA_CHUNK)
+
+        def run(k, v):
+            return np.asarray(eva_attention(
+                q, k, v, None, None, EVA_WINDOW, EVA_CHUNK, impl=impl,
+                summaries=summaries, **EVA_IMPLS[impl]))
+
+        other = jnp.asarray(np.random.default_rng(9).normal(
+            size=(2, 4, 3, 8)), jnp.float32)
+        base = run(k, v)
+        moved = run(k.at[:, 12:16].set(other), v.at[:, 12:16].set(-other))
+        assert np.array_equal(moved[:, 32:], base[:, 32:])
+        assert not np.array_equal(moved[:, 16:32], base[:, 16:32])
+        # and positions before the chunk see nothing of it
+        assert np.array_equal(moved[:, :12], base[:, :12])
+
+    def test_too_few_summaries_and_a_window_of_broken_chunks_are_refused(
+            self):
+        q, k, v, phi, mu = _eva_inputs(96)
+        few = eva_summaries(k, v, phi, mu, EVA_CHUNK, upto=32)
+        with pytest.raises(ValueError, match="reads 16 summaries"):
+            eva_attention(q, k, v, phi, mu, EVA_WINDOW, EVA_CHUNK,
+                          impl="dense", summaries=few)
+        with pytest.raises(ValueError, match="whole"):
+            eva_attention(q, k, v, phi, mu, 30, EVA_CHUNK, impl="dense")
+
+    def test_a_call_is_counted_by_window_chunk_and_tile(self):
+        x = jax.ShapeDtypeStruct((1, 4096, 2, 128), jnp.bfloat16)
+        vec = jax.ShapeDtypeStruct((2, 128), jnp.float32)
+        before = (_eva_calls(2048, 16, "1024x1024x128"),
+                  _flash_calls("1024x1024", True))
+        out = jax.eval_shape(
+            lambda q, k, v, phi, mu: eva_attention(q, k, v, phi, mu, 2048,
+                                                   16), x, x, x, vec, vec)
+        assert out.shape == x.shape and out.dtype == x.dtype
+        assert _eva_calls(2048, 16, "1024x1024x128") == before[0] + 1
+        # the plain forward's counter keeps its two labels and counts what
+        # it counted: not this call, and a row of one window as ever
+        assert _flash_calls("1024x1024", True) == before[1]
+        short = jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.bfloat16)
+        jax.eval_shape(
+            lambda q, k, v, phi, mu: eva_attention(q, k, v, phi, mu, 2048,
+                                                   16),
+            short, short, short, vec, vec)
+        assert _flash_calls("1024x1024", True) == before[1] + 1
+        assert _eva_calls(2048, 16, "1024x1024x128") == before[0] + 1
+
+
+class TestFlashTilesToldTheWindow:
+    @pytest.mark.parametrize("window,dtype,tile", [
+        (2048, jnp.bfloat16, 1024), (2048, jnp.float32, 512),
+        (32, jnp.float32, 32), (128, jnp.bfloat16, 128),
+        (384, jnp.bfloat16, 384), (1536, jnp.bfloat16, 768)])
+    def test_tiles_divide_the_window(self, window, dtype, tile):
+        assert flash_tiles(32768, 32768, dtype, window=window) == (tile, tile)
+        assert window % tile == 0
+
+    def test_a_window_no_tile_divides_is_refused(self):
+        with pytest.raises(ValueError, match="divides a window"):
+            flash_tiles(4096, 4096, jnp.bfloat16, window=1100)
+
+    # every (tq, tk, dtype) the three neural cells of PR 32 call the plain
+    # forward at: `xlmr_xxl.score_table`, `moonlight_16b_a3b.score_loglik`,
+    # `lfm2_8b_a1b.score_long_docs`, and the answers they got there
+    @pytest.mark.parametrize("t,tile", [(512, 512), (128, 128), (4096, 1024),
+                                        (16384, 1024), (1024, 1024)])
+    def test_without_a_window_the_answers_are_the_parents(self, t, tile):
+        assert flash_tiles(t, t, jnp.bfloat16) == (tile, tile)
+        assert flash_tiles(t, t, jnp.bfloat16, window=None) == (tile, tile)

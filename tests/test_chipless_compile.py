@@ -133,3 +133,22 @@ def test_dropless_expert_layer_compiles_to_grouped_kernels(
     assert text.count("moe_combine") >= len(buffers)
     assert f"[{top_k},{tokens},2048]" not in text
     assert f"[{tokens},{top_k},2048]" not in text
+
+
+@pytest.mark.parametrize("rows,length", [(2, 32768), (2, 4096), (1, 4096)])
+def test_windowed_and_summarised_attention_kernels_compile(one_chip, rows,
+                                                           length):
+    """`evabyte_6_5b.score_byte_docs`'s three batches: 32 heads of 128
+    channels, a window of 2048 and chunks of 16, bfloat16, at the tiles
+    the rule chooses: two Pallas calls, the pooling and the attention,
+    inside the default scoped VMEM."""
+    from mmlspark_tpu.nn.attention import eva_attention
+
+    q = jax.ShapeDtypeStruct((rows, length, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    phi = jax.ShapeDtypeStruct((32, 128), jnp.float32, sharding=one_chip)
+    text = _compile(
+        lambda q, k, v, phi, mu: eva_attention(q, k, v, phi, mu, 2048, 16),
+        q, q, q, phi, phi).as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "eva_attn_w2048c16" in text and "eva_pool_w2048c16" in text
