@@ -55,8 +55,9 @@ from ..parallel.ring_attention import (dense_attention, key_head_group,
                                        over_key_heads)
 
 __all__ = ["dense_attention", "chunked_attention", "flash_attention",
-           "flash_tiles", "causal_attention", "eva_summaries",
-           "eva_attention", "SelfAttention"]
+           "flash_tiles", "causal_attention", "latent_attention",
+           "eva_summaries", "eva_attention", "rotary_in_lanes",
+           "rotary_lanes_whole", "HeadsDense", "SelfAttention"]
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/max NaN-free
 
@@ -65,8 +66,164 @@ def _pad_seq(x, mult):
     t = x.shape[1]
     pad = (-t) % mult
     if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
     return x, t
+
+
+# --------------------------------------------------------------------- #
+# the layout the Pallas kernels speak                                    #
+# --------------------------------------------------------------------- #
+
+def _lanes_whole(*widths: int) -> bool:
+    """The rule, by shape: a head whose channels are whole lane blocks
+    (multiples of 128) can be named as a block inside (B, T, H x D), the
+    array its projection wrote; any other width (64, a test's 8) cannot,
+    and takes the head-major copy."""
+    return all(w % 128 == 0 for w in widths)
+
+
+def _rows(x, in_place: bool):
+    """(B, T, H, D) as a kernel's grid reads it. In place: (B, T, H x D),
+    the same bytes in row-major order, head j's channels lane block j.
+    Head-major: (B x H, T, D), a transposed copy in HBM."""
+    b, t, h, d = x.shape
+    if in_place:
+        return x.reshape(b, t, h * d)
+    return jnp.moveaxis(x, 2, 1).reshape(b * h, t, d)
+
+
+def _heads(x, b: int, h: int, in_place: bool):
+    """`_rows`' way back: (B, T, H, D) of a kernel's output."""
+    if in_place:
+        return x.reshape(b, x.shape[1], h, x.shape[2] // h)
+    return jnp.moveaxis(x.reshape(b, h, x.shape[1], x.shape[2]), 1, 2)
+
+
+def _block_at(in_place: bool, heads: int):
+    """(row b, head j, block i along the sequence) -> the index of that
+    (1, positions, D) block in `_rows`' array of `heads` heads."""
+    if in_place:
+        return lambda b_, j, i: (b_, i, j)
+    return lambda b_, j, i: (b_ * heads + j, i, 0)
+
+
+def rotary_cos_sin(t: int, half: int, theta: float):
+    """cos and sin of positions 0 .. t-1 times the `half` rotary
+    frequencies theta ** (-i / half): (t, half) float32 each."""
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def _rotary_tables(t: int, width: int, theta: float):
+    """cos and sin of rotary positions 0 .. t-1 over 128 lanes, rotate-half
+    layout a head of `width` channels (channel i pairs with i + width/2):
+    [cos, cos] and [-sin, sin] a head, 128 / width heads a lane block.
+    (t, 128) float32 each."""
+    cos, sin = rotary_cos_sin(t, width // 2, theta)
+    return (jnp.tile(jnp.concatenate([cos, cos], -1), (1, 128 // width)),
+            jnp.tile(jnp.concatenate([-sin, sin], -1), (1, 128 // width)))
+
+
+def _rotary_kernel(x_ref, cos_ref, sin_ref, o_ref, *, width):
+    """x cos + partner(x) [-sin, sin] on ONE lane block of a block of
+    positions (the grid walks the lane blocks): a channel's partner is
+    `width / 2` lanes away inside its head, which is a lane rotation of
+    every vector register and nothing in HBM."""
+    import jax.experimental.pallas.tpu as pltpu
+
+    half = width // 2
+    x = x_ref[0].astype(jnp.float32)                          # (rows, 128)
+    if width == 128:
+        partner = pltpu.roll(x, half, 1)
+    else:
+        lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        partner = jnp.where(lane % width < half,
+                            pltpu.roll(x, 128 - half, 1),     # x[l + half]
+                            pltpu.roll(x, half, 1))           # x[l - half]
+    o_ref[0] = (x * cos_ref[...] + partner * sin_ref[...]).astype(o_ref.dtype)
+
+
+# positions a grid step at inputs of 2 bytes (half as many at 4): blocks of
+# 1 MB in and out and 2 MB of each table, double-buffered, inside the
+# default 16 MB of scoped VMEM. The largest wins, as for `flash_tiles`
+# (PERF.md, PR 35)
+_ROTARY_ROWS = 4096
+
+
+@functools.partial(jax.jit, static_argnames=("width", "theta", "rows",
+                                             "interpret"))
+def _rotary_flat(flat, *, width, theta, rows, interpret=False):
+    """`rotary_in_lanes` on (B, T, heads x width) as it lies, `rows`
+    positions a grid step. Jitted by itself, `theta` static, as
+    `_eva_flash` is: traced and lowered once a shape, not once a tensor
+    and layer (a model's q and k share one body), and the lane blocks of
+    a row are the grid's last axis, not a Python loop in the body
+    (PERF.md, PR 35: PR 34's form cost a warm start 4 s)."""
+    import jax.experimental.pallas as pl
+
+    b, t, lanes = flat.shape
+    flat, _ = _pad_seq(flat, rows)
+    cos, sin = _rotary_tables(flat.shape[1], width, theta)
+
+    # lane blocks last: a block of positions keeps its tables across them
+    def block(b_, i, j):
+        return (b_, i, j)
+
+    def table(b_, i, j):
+        return (i, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_rotary_kernel, width=width),
+        grid=(b, flat.shape[1] // rows, lanes // 128),
+        in_specs=[pl.BlockSpec((1, rows, 128), block),
+                  pl.BlockSpec((rows, 128), table),
+                  pl.BlockSpec((rows, 128), table)],
+        out_specs=pl.BlockSpec((1, rows, 128), block),
+        out_shape=jax.ShapeDtypeStruct(flat.shape, flat.dtype),
+        interpret=interpret, name=f"rotary_c{width}",
+    )(flat, cos, sin)
+    return out[:, :t]
+
+
+def rotary_in_lanes(x, theta: float, interpret: bool = False):
+    """Rotary positions 0 .. T-1 on the channels of x (B, T, heads, c),
+    rotate-half layout, float32 inside: `nn/models.py` `_rotary`'s numbers
+    (a cos - b sin as a cos + b (-sin): the same bits), computed by a
+    Pallas call on x IN PLACE as (B, T, heads x c), for heads of 128
+    channels or pairs of heads of 64 (`rotary_lanes_whole`). XLA's own
+    form slices half a head's channels, which the TPU's compiler does with
+    positions in lanes: between a projection and a kernel that reads
+    channels in lanes that costs a pass for the halves, one for their
+    concatenation and a layout copy (PERF.md, PR 34); this is one pass.
+    The reshapes stay out here, beside the projection's and the kernel's
+    own, where they cancel: handed four dimensions, the jitted call gets
+    them positions-minor and a copy (PERF.md, PR 35)."""
+    b, t, h, c = x.shape
+    # the fewest steps under the cap, of equal heights (multiples of 16):
+    # a length just over the cap is not padded to twice it
+    steps = -(-t // (_ROTARY_ROWS * 2 // max(x.dtype.itemsize, 2)))
+    rows = t if steps == 1 else -(-t // (16 * steps)) * 16
+    return _rotary_flat(x.reshape(b, t, h * c), width=c, theta=float(theta),
+                        rows=rows, interpret=interpret).reshape(b, t, h, c)
+
+
+def rotary_lanes_whole(heads: int, width: int) -> bool:
+    """Whether `rotary_in_lanes` takes heads of this width: whole heads
+    fill whole lane blocks."""
+    return width in (64, 128) and (heads * width) % 128 == 0
+
+
+def _count_operands(kernel: str, in_place: bool) -> None:
+    """Counted where a forward is traced: which path a shape took."""
+    get_registry().counter(
+        "mmlspark_tpu_attention_operands_total",
+        "attention forward calls traced, by the kernel and by how it reads "
+        "its operands: in place where the projections wrote them, or from "
+        "a head-major copy",
+        labels=("kernel", "layout")).labels(
+            kernel=kernel,
+            layout="in_place" if in_place else "head_major").inc()
 
 
 # --------------------------------------------------------------------- #
@@ -208,12 +365,17 @@ def flash_tiles(tq: int, tk: int, dtype,
     return tile(tq), tile(tk)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                  block_q, block_k, num_kv, causal, tk_valid, scale):
+def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
+                block_k, num_kv, causal, tk_valid, scale):
+    """What every flash forward does with a score tile, over a grid of
+    (row, head, query block, key block): `products()` is this step's raw
+    (bq, bk) float32 products of queries and keys (over a head's channels,
+    or over the latent score's two parts); the masks, the online softmax,
+    the block skips and the finalisation are here."""
     import jax.experimental.pallas as pl
 
-    qi = pl.program_id(1)
-    kv = pl.program_id(2)
+    qi = pl.program_id(2)
+    kv = pl.program_id(3)
     # only a padded sequence needs the key mask: decided here, in Python
     padded = tk_valid < num_kv * block_k
 
@@ -221,9 +383,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         """This step's (bq, bk) score tile, and which of it counts (None:
         all of it). `mask_keys`: keys at or past `tk_valid` are padding;
         `mask_causal`: a query sees the keys at or before it."""
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # (bq, bk)
+        s = products() * scale                                # (bq, bk)
         ok = None
         if mask_keys or mask_causal:
             kpos = kv * block_k + jax.lax.broadcasted_iota(
@@ -312,69 +472,92 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         write(m_sc[...], l_sc[...], acc_sc[...])
 
 
-def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
-    """Pallas forward at the given tile (a multiple of what Mosaic tiles,
-    or the whole length); returns (out (B,Tq,H,Dv), lse (B,H,Tq) f32). The
-    values may have a width of their own (latent attention scores over
-    192 channels and weighs values of 128), and keys and values fewer
-    heads than the queries: the key block of query head j is head
-    j // group's, named by the index map, so K and V stay as they lie."""
+def _qk(q_ref, k_ref):
+    """(bq, D) x (bk, D) -> (bq, bk), float32 sums."""
+    return jax.lax.dot_general(
+        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, **static):
+    _flash_fold(lambda: _qk(q_ref, k_ref), v_ref, o_ref, lse_ref, scratch,
+                **static)
+
+
+def _latent_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                   *scratch, **static):
+    """The latent score as what it is, a sum of two products: a head's own
+    channels against its own keys, and its rotary channels against the ONE
+    rotary key. `qr_ref` holds the rotary channels of the heads that share
+    a lane block, `kr_ref` the rotary key in this head's lanes of it and
+    zeros in the others. The two parts are set side by side in VMEM, lane
+    blocks both, so that the MXU sums them in ONE product's float32
+    accumulator: added as two (bq, bk) tiles they cost the VPU a pass over
+    the score tile, 6% of the kernel (PERF.md, PR 34)."""
+    def products():
+        return jax.lax.dot_general(
+            jnp.concatenate([qn_ref[0], qr_ref[0]], -1),
+            jnp.concatenate([kn_ref[0], kr_ref[0]], -1),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+    _flash_fold(products, v_ref, o_ref, lse_ref, scratch, **static)
+
+
+def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
+                tk, causal, scale, block_q, block_k, interpret, name=None):
+    """ONE Pallas forward over a grid of (row, head, query block, key
+    block). `queries`, `keys` and `value` are (array, block width, at):
+    `at(row, head, block along the sequence)` names the (1, positions,
+    width) block of that head in the array, wherever it lies; the value
+    block is the last input. The output's blocks are named by `out_at` in
+    an array of `out_shape` (as wide a block as the value's). -> (out in
+    that shape, lse (B x H, Tq, 1) float32); `tk` is the keys' length
+    before padding. `name` is the call's own in a device trace; without
+    one the innermost `jax.named_scope` around it names it."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
-    orig_dtype = q.dtype
-    b, _, h, d = q.shape
-    dv = v.shape[-1]
-    group = key_head_group(q, k, v)
-    q, tq = _pad_seq(q, block_q)
-    k, tk = _pad_seq(k, block_k)
-    v, _ = _pad_seq(v, block_k)
+    keys = [*keys, value]
+    dv = value[1]
+    nq = queries[0][0].shape[1] // block_q
+    nk = keys[0][0].shape[1] // block_k
 
-    # (B*H, T, D): one grid row per (batch, query head); keys and values
-    # (B*H/group, T, .), a row per (batch, key/value head)
-    def bh(x):
-        return jnp.moveaxis(x, 2, 1).reshape(
-            b * x.shape[2], x.shape[1], x.shape[-1])
+    def query_spec(width, at):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda b_, j, qi, kv: at(b_, j, qi))
 
-    qf, kf, vf = bh(q), bh(k), bh(v)
-    nq, nk = qf.shape[1] // block_q, kf.shape[1] // block_k
+    def key_spec(width, at):
+        if causal:
+            # a key block above the diagonal is never computed on: name
+            # the last block this query block needs instead, which is
+            # already in VMEM, so that no copy is issued for the skipped
+            # steps
+            def index(b_, j, qi, kv):
+                last = (qi * block_q + block_q - 1) // block_k
+                return at(b_, j, jnp.minimum(kv, last))
+        else:
+            def index(b_, j, qi, kv):
+                return at(b_, j, kv)
+        return pl.BlockSpec((1, block_k, width), index)
 
-    # row b * H + j of the queries reads row b * H/group + j // group
-    def key_row(bh_):
-        return bh_ if group == 1 else bh_ // group
-
-    if causal:
-        # a key block above the diagonal is never computed on: name the
-        # last block this query block needs instead, which is already in
-        # VMEM, so that no copy is issued for the skipped steps
-        def key_block(bh_, qi, kv):
-            last = (qi * block_q + block_q - 1) // block_k
-            return (key_row(bh_), jnp.minimum(kv, last), 0)
-    else:
-        def key_block(bh_, qi, kv):
-            return (key_row(bh_), kv, 0)
-
-    kernel = functools.partial(
-        _flash_kernel, block_q=block_q, block_k=block_k, num_kv=nk,
-        causal=causal, tk_valid=tk, scale=d ** -0.5)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh_, qi, kv: (bh_, qi, 0)),
-            pl.BlockSpec((1, block_k, d), key_block),
-            pl.BlockSpec((1, block_k, dv), key_block),
-        ],
+    return pl.pallas_call(
+        functools.partial(
+            kernel, block_q=block_q, block_k=block_k, num_kv=nk,
+            causal=causal, tk_valid=tk, scale=scale),
+        grid=(b, h, nq, nk),
+        in_specs=[query_spec(w, at) for _x, w, at in queries]
+        + [key_spec(w, at) for _x, w, at in keys],
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda bh_, qi, kv: (bh_, qi, 0)),
+            query_spec(dv, out_at),
             # lse keeps the scratch's (block_q, 1) column layout: a
             # trailing dim equal to the array's satisfies Mosaic's block
             # rule, and no sublane->lane relayout happens in the kernel
-            pl.BlockSpec((1, block_q, 1), lambda bh_, qi, kv: (bh_, qi, 0)),
+            pl.BlockSpec((1, block_q, 1),
+                         lambda b_, j, qi, kv: (b_ * h + j, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(qf.shape[:2] + (dv,), orig_dtype),
-            jax.ShapeDtypeStruct(qf.shape[:2] + (1,), jnp.float32),
+            out_shape,
+            jax.ShapeDtypeStruct((b * h, nq * block_q, 1), jnp.float32),
         ],
         # one key block carries nothing from step to step
         scratch_shapes=[] if nk == 1 else [
@@ -382,11 +565,48 @@ def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
-        interpret=interpret,
-    )(qf, kf, vf)
+        interpret=interpret, name=name,
+    )(*(x for x, _w, _at in [*queries, *keys]))
 
-    out = out.reshape(b, h, out.shape[1], dv)  # already orig_dtype via
-    out = jnp.moveaxis(out, 1, 2)[:, :tq]      # pallas out_shape
+
+def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
+    """Pallas forward at the given tile (a multiple of what Mosaic tiles,
+    or the whole length); q, k (B, T, H, D), v (B, T, H, Dv) in, returns
+    (out (B,Tq,H,Dv), lse (B,H,Tq) f32). The values may have a width of
+    their own, and keys and values fewer heads than the queries: the key
+    block of query head j is head j // group's, named by the index map,
+    so K and V stay as they lie.
+
+    The layout, by shape (`_lanes_whole`): where D and Dv are multiples of
+    128 the kernel reads q, k and v IN PLACE, a head's channels one lane
+    block of the (B, T, H x D) array the projection wrote, and writes the
+    output where the output projection reads it, (B, T, H x Dv): the
+    reshapes around the call move nothing. At any other width (64, 192, a
+    test's 8) q, k and v are copied head-major to (B x H, T, D) first and
+    the output is copied back. A block holds the same values in the same
+    order either way."""
+    b, _, h, d = q.shape
+    hk, dv = k.shape[2], v.shape[-1]
+    group = key_head_group(q, k, v)
+    in_place = _lanes_whole(d, dv)
+    # laid out first, padded there: in place, a pad of the array as it lies
+    qf, tq = _pad_seq(_rows(q, in_place), block_q)
+    kf, tk = _pad_seq(_rows(k, in_place), block_k)
+    vf, _ = _pad_seq(_rows(v, in_place), block_k)
+    at, key_at = _block_at(in_place, h), _block_at(in_place, hk)
+
+    # query head j reads key/value head j // group
+    def key_head_at(b_, j, i):
+        return key_at(b_, j if group == 1 else j // group, i)
+
+    out, lse = _flash_call(
+        _flash_kernel, [(qf, d, at)], [(kf, d, key_head_at)],
+        (vf, dv, key_head_at), at,
+        jax.ShapeDtypeStruct(qf.shape[:-1] + (qf.shape[-1] // d * dv,),
+                             q.dtype),
+        b=b, h=h, tk=tk, causal=causal, scale=d ** -0.5, block_q=block_q,
+        block_k=block_k, interpret=interpret)
+    out = _heads(out[:, :tq], b, h, in_place)
     lse = lse.reshape(b, h, -1)[:, :, :tq]     # (B, H, Tq)
     return out, lse
 
@@ -483,6 +703,20 @@ def _flash_diff_bwd(causal, block_q, block_k, bwd_chunk, interpret, res, do):
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
 
 
+def _call_tiles(tq: int, tk: int, dtype, block_q, block_k, causal: bool):
+    """A forward's (block_q, block_k): `flash_tiles`' unless a test names
+    one; counted where the call is traced, once a compiled shape."""
+    rule_q, rule_k = flash_tiles(tq, tk, dtype)
+    block_q = rule_q if block_q is None else min(block_q, max(tq, 1))
+    block_k = rule_k if block_k is None else min(block_k, max(tk, 1))
+    get_registry().counter(
+        "mmlspark_tpu_flash_calls_total",
+        "flash-attention forward calls traced, by the tile they run at",
+        labels=("tile", "causal")).labels(
+            tile=f"{block_q}x{block_k}", causal=str(causal).lower()).inc()
+    return block_q, block_k
+
+
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: int | None = None, block_k: int | None = None,
                     bwd_chunk: int | None = 128, interpret: bool = False):
@@ -491,23 +725,21 @@ def flash_attention(q, k, v, causal: bool = False,
     backward is the standard flash recomputation as a pure-XLA k-block
     scan driven by the kernel's saved logsumexp. Same contract as
     `dense_attention`, grouped-query heads included (k and v with a
-    divisor of q's heads).
+    divisor of q's heads): q, k (B, T, H, D), v (B, T, H, Dv) in, (B, T,
+    H, Dv) out. Heads whose D and Dv are multiples of 128 are read and
+    written in place, as blocks of the (B, T, H x D) arrays around the
+    call; any other width pays a head-major copy of q, k and v in and of
+    the output back (`_flash_fwd_lse`; the registry's
+    `mmlspark_tpu_attention_operands_total` says which, by `kernel` and
+    `layout`).
 
     The forward's tile is `flash_tiles`' unless a test names one.
     `bwd_chunk` is the backward scan's key chunk and no tile: the scan
     materialises a (B, H, Tq, chunk) float32 score slab in HBM, so it
     does not follow the forward to 512 or 1024 (None: the forward's key
     tile). `interpret=True` runs the forward kernel on CPU for tests."""
-    tq, tk = q.shape[1], k.shape[1]
-    rule_q, rule_k = flash_tiles(tq, tk, q.dtype)
-    block_q = rule_q if block_q is None else min(block_q, max(tq, 1))
-    block_k = rule_k if block_k is None else min(block_k, max(tk, 1))
-    # counted where the call is traced: once a compiled shape
-    get_registry().counter(
-        "mmlspark_tpu_flash_calls_total",
-        "flash-attention forward calls traced, by the tile they run at",
-        labels=("tile", "causal")).labels(
-            tile=f"{block_q}x{block_k}", causal=str(causal).lower()).inc()
+    block_q, block_k = _call_tiles(q.shape[1], k.shape[1], q.dtype, block_q,
+                                   block_k, causal)
     group = key_head_group(q, k, v)
     if group > 1:
         get_registry().counter(
@@ -516,6 +748,7 @@ def flash_attention(q, k, v, causal: bool = False,
             "key/value heads, by the heads a key/value head serves",
             labels=("group", "tile")).labels(
                 group=str(group), tile=f"{block_q}x{block_k}").inc()
+    _count_operands("flash", _lanes_whole(q.shape[-1], v.shape[-1]))
     return _flash_diff(q, k, v, causal, block_q, block_k,
                        block_k if bwd_chunk is None else bwd_chunk, interpret)
 
@@ -533,6 +766,133 @@ def causal_attention(q, k, v, impl: str = "flash", **flash_options):
         return dense_attention(q, k, v, causal=True).astype(q.dtype)
     raise ValueError(f"unknown attention impl {impl!r}; have 'flash', "
                      "'chunked', 'dense'")
+
+
+# --------------------------------------------------------------------- #
+# latent attention: a score of two parts, read where they lie            #
+# --------------------------------------------------------------------- #
+
+def _latent_concatenated(q_nope, q_rope, kv, k_rope):
+    """The operands as one product of nope + rope channels takes them:
+    q, k (B, T, H, nope + rope), the rotary key broadcast to every head,
+    and v (B, T, H, Dv) sliced off the keys' projection."""
+    nope = q_nope.shape[-1]
+    b, t, h, _ = kv.shape
+    q = jnp.concatenate([q_nope, q_rope], -1)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(k_rope[:, :, None], (b, t, h, k_rope.shape[-1]))],
+        -1)
+    return q, k, kv[..., nope:]
+
+
+def _latent_in_place(q_nope, q_rope, kv) -> bool:
+    """The rule, by shape: a head's own key channels and its values are a
+    lane block each of the keys' projection (equal widths, multiples of
+    128), and the rotary channels of whole heads fill a lane block."""
+    h, nope, rope = q_nope.shape[2], q_nope.shape[-1], q_rope.shape[-1]
+    return (nope % 128 == 0 and kv.shape[-1] == 2 * nope
+            and 128 % rope == 0 and (h * rope) % 128 == 0)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _latent_fwd_lse(q_nope, q_rope, kv, k_rope, block_q, block_k, interpret):
+    """The causal Pallas forward of latent attention with NOTHING laid out
+    again in HBM (shapes by `_latent_in_place`). q_nope (B, T, H, nope)
+    and q_rope (B, T, H, rope) are read as the q fusion wrote them; the
+    head's keys and values are lane blocks 2j and 2j + 1 of `kv` (B, T, H,
+    nope + Dv), the keys' projection itself; the ONE rotary key k_rope (B,
+    T, rope) is read by every head and never broadcast: the heads that
+    share a lane block of q_rope read it against the key placed in their
+    own lanes of 128, zeros in the others (a copy of the one key a head of
+    the block: (B, T, 128 / rope x 128)). -> (out (B, T, H, Dv), lse (B,
+    H, T)): the score is summed over nope + 128 channels, the zeros among
+    them, where the plain kernel sums nope + rope. Jitted by itself, as
+    `_eva_flash` is: lowered once a shape, not once a layer, so the call
+    is named by its widths (`mla_attn_n128r64`; a device trace's readers
+    select `mla_attn_*`) and not by the layer's scope."""
+    b, _, h, nope = q_nope.shape
+    rope = q_rope.shape[-1]
+    share = 128 // rope                    # heads to a lane block of q_rope
+    placed = jnp.concatenate(
+        [jnp.pad(k_rope, ((0, 0), (0, 0), (i * rope, 128 - (i + 1) * rope)))
+         for i in range(share)], -1)                       # (B, T, share x 128)
+    qn, tq = _pad_seq(_rows(q_nope, True), block_q)
+    qr, _ = _pad_seq(_rows(q_rope, True), block_q)
+    kvf, tk = _pad_seq(_rows(kv, True), block_k)
+    placed, _ = _pad_seq(placed, block_k)
+    out, lse = _flash_call(
+        _latent_kernel,
+        [(qn, nope, lambda b_, j, i: (b_, i, j)),
+         (qr, 128, lambda b_, j, i: (b_, i, j // share))],
+        [(kvf, nope, lambda b_, j, i: (b_, i, 2 * j)),
+         (placed, 128, lambda b_, j, i: (b_, i, j % share))],
+        (kvf, nope, lambda b_, j, i: (b_, i, 2 * j + 1)),
+        lambda b_, j, i: (b_, i, j),
+        jax.ShapeDtypeStruct(qn.shape, qn.dtype),
+        b=b, h=h, tk=tk, causal=True, scale=(nope + rope) ** -0.5,
+        block_q=block_q, block_k=block_k, interpret=interpret,
+        name=f"mla_attn_n{nope}r{rope}")
+    return (_heads(out[:, :tq], b, h, True),
+            lse.reshape(b, h, -1)[:, :, :tq])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _latent_diff(q_nope, q_rope, kv, k_rope, block_q, block_k, interpret):
+    return _latent_fwd_lse(q_nope, q_rope, kv, k_rope, block_q, block_k,
+                           interpret)[0]
+
+
+def _latent_diff_fwd(q_nope, q_rope, kv, k_rope, block_q, block_k, interpret):
+    out, lse = _latent_fwd_lse(q_nope, q_rope, kv, k_rope, block_q, block_k,
+                               interpret)
+    return out, (q_nope, q_rope, kv, k_rope, out, lse)
+
+
+def _latent_diff_bwd(block_q, block_k, interpret, res, do):
+    """The plain flash backward over the concatenated operands, as the
+    forward used to build them; the rotary key's gradient is the sum over
+    the heads that read it."""
+    q_nope, q_rope, kv, k_rope, out, lse = res
+    nope = q_nope.shape[-1]
+    dq, dk, dv = _flash_bwd_xla(
+        *_latent_concatenated(q_nope, q_rope, kv, k_rope), out, lse, do,
+        True, block_k)
+    return (dq[..., :nope], dq[..., nope:],
+            jnp.concatenate([dk[..., :nope], dv], -1),
+            dk[..., nope:].sum(2).astype(k_rope.dtype))
+
+
+_latent_diff.defvjp(_latent_diff_fwd, _latent_diff_bwd)
+
+
+def latent_attention(q_nope, q_rope, kv, k_rope, impl: str = "flash",
+                     block_q: int | None = None, block_k: int | None = None,
+                     interpret: bool = False):
+    """Causal multi-head latent attention over the operands as the
+    projections leave them: q_nope (B, T, H, nope), q_rope (B, T, H, rope)
+    (rotary applied), kv (B, T, H, nope + Dv) (a head's own key channels,
+    then its values) and the ONE rotary key k_rope (B, T, rope) that every
+    head reads. -> (B, T, H, Dv); scores over sqrt(nope + rope).
+
+    "flash" at widths of whole lanes (`_latent_in_place`: nope = Dv a
+    multiple of 128, the rotary channels of whole heads filling 128) is a
+    path of its own for the score's assembly (`_latent_fwd_lse`: nothing
+    sliced, concatenated, broadcast or transposed in HBM), differentiable
+    with the plain flash backward. Every other tier and shape builds q and
+    k of nope + rope channels and v, and is `causal_attention`."""
+    if impl != "flash" or not _latent_in_place(q_nope, q_rope, kv):
+        if impl == "flash":
+            _count_operands("mla", False)
+        return causal_attention(
+            *_latent_concatenated(q_nope, q_rope, kv, k_rope), impl,
+            **({"interpret": True} if interpret else {}))
+    t = q_nope.shape[1]
+    # under the plain forward's counter too: its fold, at its tile
+    block_q, block_k = _call_tiles(t, t, q_nope.dtype, block_q, block_k, True)
+    _count_operands("mla", True)
+    return _latent_diff(q_nope, q_rope, kv, k_rope, block_q, block_k,
+                        interpret)
 
 
 # --------------------------------------------------------------------- #
@@ -627,14 +987,15 @@ def _eva_chunked(q, k, v, kbar, vbar, window, chunk, q_chunk: int = 128):
 def _eva_kernel(q_ref, k_ref, v_ref, kb_ref, vb_ref, o_ref, m_sc, l_sc,
                 acc_sc, *, block_q, block_k, block_s, n_local, n_remote,
                 window, per_window, scale):
-    """A block of queries, which lies in ONE window, over the grid's last
-    axis: first the `n_local` key blocks of its window (those above the
-    diagonal skipped), then the `n_remote` blocks of summaries (those past
-    the `per_window` x window index that lie before it skipped), all into
-    one running maximum, denominator and accumulator."""
+    """A grid of (row, head, query block, source block). A block of
+    queries, which lies in ONE window, over the grid's last axis: first
+    the `n_local` key blocks of its window (those above the diagonal
+    skipped), then the `n_remote` blocks of summaries (those past the
+    `per_window` x window index that lie before it skipped), all into one
+    running maximum, denominator and accumulator."""
     import jax.experimental.pallas as pl
 
-    qi, j = pl.program_id(1), pl.program_id(2)
+    qi, j = pl.program_id(2), pl.program_id(3)
     first = qi * block_q
     own = first // window                       # this block's window
 
@@ -709,21 +1070,22 @@ def _eva_kernel(q_ref, k_ref, v_ref, kb_ref, vb_ref, o_ref, m_sc, l_sc,
 
 def _eva_pool_kernel(k_ref, v_ref, phi_ref, mu_ref, kb_ref, vb_ref, *,
                      chunk, pooled_blocks, scale):
-    """`eva_summaries` for one block of positions of one head, read where
-    the attention kernel reads them: (positions, D) in, (positions / chunk,
-    D) out, float32 inside. A block past the positions that are pooled
-    (the padding of the summaries to whole tiles) is zeros: a masked
-    summary still meets the values' product, where 0 x NaN is NaN."""
+    """`eva_summaries` for one block of positions of one head (a grid of
+    (row, head, window)), read where the attention kernel reads them:
+    (positions, D) in, (positions / chunk, D) out, float32 inside. A block
+    past the positions that are pooled (the padding of the summaries to
+    whole tiles) is zeros: a masked summary still meets the values'
+    product, where 0 x NaN is NaN."""
     import jax.experimental.pallas as pl
 
     f32 = jnp.float32
 
-    @pl.when(pl.program_id(1) >= pooled_blocks)
+    @pl.when(pl.program_id(2) >= pooled_blocks)
     def _padding():
         kb_ref[0] = jnp.zeros_like(kb_ref[0])
         vb_ref[0] = jnp.zeros_like(vb_ref[0])
 
-    @pl.when(pl.program_id(1) < pooled_blocks)
+    @pl.when(pl.program_id(2) < pooled_blocks)
     def _pool():
         def chunks(ref):
             x = ref[0].astype(f32)
@@ -748,71 +1110,79 @@ def _eva_flash(q, k, v, phi, mu, kbar=None, vbar=None, *, window, chunk,
     in), `eva_attn_*` attends. Nothing of size T x T or T x T / chunk is
     ever whole in HBM; a key block outside the query's window and a
     summary block at or past it are neither fetched (their index maps
-    name a block that is already in VMEM) nor computed on."""
+    name a block that is already in VMEM) nor computed on.
+
+    q, k, v (B, T, H, D) in, (B, T, H, D) out. The layout is
+    `_flash_fwd_lse`'s, by shape: heads of whole lane blocks (multiples of
+    128 channels) are read IN PLACE from the (B, T, H x D) arrays the
+    projections wrote, the summaries are written and read the same way,
+    and the output is written where the output projection reads it; at
+    any other width (a test's 8) every operand is copied head-major to
+    (B x H, T, D) first and the output copied back."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
     b, t, h, d = q.shape
     dv = v.shape[-1]
+    in_place = _lanes_whole(d, dv)
+    at = _block_at(in_place, h)
     per_window = window // chunk
     windows_before = -(-t // window) - 1
-    q, _ = _pad_seq(q, max(block_q, block_k))
-    k, _ = _pad_seq(k, max(block_q, block_k))
-    v, _ = _pad_seq(v, max(block_q, block_k))
-
-    def bh(x):
-        return jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], x.shape[-1])
-
-    qf, kf, vf = bh(q), bh(k), bh(v)
+    qf, kf, vf = (_pad_seq(_rows(x, in_place), max(block_q, block_k))[0]
+                  for x in (q, k, v))
     n_local = window // block_k
     n_remote = -(-windows_before * per_window // block_s)
     tag = f"w{window}c{chunk}"      # a device trace's readers select by name
 
+    def like(x, positions, width):
+        """An array of `positions` in x's layout, heads of `width`."""
+        return jax.ShapeDtypeStruct(
+            (x.shape[0], positions, x.shape[2] // d * width), x.dtype)
+
     if kbar is None:
         # as many rows as whole tiles of summaries, a window's a step
         steps = -(-n_remote * block_s // per_window)
+
+        def pooled_window(b_, j, i):
+            return at(b_, j, jnp.minimum(i, windows_before - 1))
+
+        def vector(b_, j, i):
+            return (j, 0, 0)
+
         with jax.named_scope("eva.summarise"):
             kbf, vbf = pl.pallas_call(
                 functools.partial(
                     _eva_pool_kernel, chunk=chunk,
                     pooled_blocks=windows_before, scale=d ** -0.5),
-                grid=(b * h, steps),
-                in_specs=[
-                    pl.BlockSpec((1, window, d), lambda bh_, i: (
-                        bh_, jnp.minimum(i, windows_before - 1), 0)),
-                    pl.BlockSpec((1, window, dv), lambda bh_, i: (
-                        bh_, jnp.minimum(i, windows_before - 1), 0)),
-                    pl.BlockSpec((1, 1, d), lambda bh_, i: (bh_ % h, 0, 0)),
-                    pl.BlockSpec((1, 1, d), lambda bh_, i: (bh_ % h, 0, 0))],
-                out_specs=[
-                    pl.BlockSpec((1, per_window, d),
-                                 lambda bh_, i: (bh_, i, 0)),
-                    pl.BlockSpec((1, per_window, dv),
-                                 lambda bh_, i: (bh_, i, 0))],
-                out_shape=[
-                    jax.ShapeDtypeStruct((b * h, steps * per_window, d),
-                                         k.dtype),
-                    jax.ShapeDtypeStruct((b * h, steps * per_window, dv),
-                                         v.dtype)],
+                grid=(b, h, steps),
+                in_specs=[pl.BlockSpec((1, window, d), pooled_window),
+                          pl.BlockSpec((1, window, dv), pooled_window),
+                          pl.BlockSpec((1, 1, d), vector),
+                          pl.BlockSpec((1, 1, d), vector)],
+                out_specs=[pl.BlockSpec((1, per_window, d), at),
+                           pl.BlockSpec((1, per_window, dv), at)],
+                out_shape=[like(kf, steps * per_window, d),
+                           like(vf, steps * per_window, dv)],
                 interpret=interpret, name=f"eva_pool_{tag}",
             )(kf, vf, phi[:, None], mu[:, None])
     else:
-        kbf, vbf = (bh(_pad_seq(x[:, :windows_before * per_window],
-                                block_s)[0]) for x in (kbar, vbar))
+        kbf, vbf = (_pad_seq(_rows(x[:, :windows_before * per_window],
+                                   in_place), block_s)[0]
+                    for x in (kbar, vbar))
 
-    def key_block(bh_, qi, j):
+    def key_block(b_, j, qi, s):
         own = (qi * block_q) // window
         last = (qi * block_q + block_q - 1) // block_k     # the diagonal's
-        return (bh_, jnp.minimum(own * n_local + jnp.minimum(j, n_local - 1),
-                                 last), 0)
+        return at(b_, j, jnp.minimum(
+            own * n_local + jnp.minimum(s, n_local - 1), last))
 
-    def summary_block(bh_, qi, j):
+    def summary_block(b_, j, qi, s):
         seen = ((qi * block_q) // window) * per_window
         last = jnp.maximum(-(-seen // block_s) - 1, 0)
-        return (bh_, jnp.clip(j - n_local, 0, last), 0)
+        return at(b_, j, jnp.clip(s - n_local, 0, last))
 
-    def query_block(bh_, qi, j):
-        return (bh_, qi, 0)
+    def query_block(b_, j, qi, s):
+        return at(b_, j, qi)
 
     with jax.named_scope("eva.attend"):
         out = pl.pallas_call(
@@ -820,20 +1190,20 @@ def _eva_flash(q, k, v, phi, mu, kbar=None, vbar=None, *, window, chunk,
                 _eva_kernel, block_q=block_q, block_k=block_k,
                 block_s=block_s, n_local=n_local, n_remote=n_remote,
                 window=window, per_window=per_window, scale=d ** -0.5),
-            grid=(b * h, qf.shape[1] // block_q, n_local + n_remote),
+            grid=(b, h, qf.shape[1] // block_q, n_local + n_remote),
             in_specs=[pl.BlockSpec((1, block_q, d), query_block),
                       pl.BlockSpec((1, block_k, d), key_block),
                       pl.BlockSpec((1, block_k, dv), key_block),
                       pl.BlockSpec((1, block_s, d), summary_block),
                       pl.BlockSpec((1, block_s, dv), summary_block)],
             out_specs=pl.BlockSpec((1, block_q, dv), query_block),
-            out_shape=jax.ShapeDtypeStruct(qf.shape[:2] + (dv,), q.dtype),
+            out_shape=like(qf, qf.shape[1], dv),
             scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
                             pltpu.VMEM((block_q, 1), jnp.float32),
                             pltpu.VMEM((block_q, dv), jnp.float32)],
             interpret=interpret, name=f"eva_attn_{tag}",
         )(qf, kf, vf, kbf, vbf)
-    return jnp.moveaxis(out.reshape(b, h, -1, dv), 1, 2)[:, :t]
+    return _heads(out[:, :t], b, h, in_place)
 
 
 def eva_attention(q, k, v, phi, mu, window: int, chunk: int,
@@ -894,6 +1264,7 @@ def eva_attention(q, k, v, phi, mu, window: int, chunk: int,
         labels=("window", "chunk", "tile")).labels(
             window=str(window), chunk=str(chunk),
             tile=f"{block_q}x{block_k}x{block_s}").inc()
+    _count_operands("eva", _lanes_whole(q.shape[-1], v.shape[-1]))
     return _eva_flash(q, k, v, phi, mu, *(summaries or ()), window=window,
                       chunk=chunk, block_q=block_q, block_k=block_k,
                       block_s=block_s, interpret=interpret)
@@ -902,6 +1273,51 @@ def eva_attention(q, k, v, phi, mu, window: int, chunk: int,
 # --------------------------------------------------------------------- #
 # param-compatible self-attention module                                #
 # --------------------------------------------------------------------- #
+
+class HeadsDense(nn.Module):
+    """x (.., d) projected to `heads` heads of `width` channels, (..,
+    heads, width). Parameter-compatible with `nn.DenseGeneral((heads,
+    width))` (kernel (d, heads, width), bias (heads, width), its
+    initialisers), and the same sums; but computed as ONE product to (..,
+    heads x width), bias added there, and reshaped. That three-
+    dimensional array is the one the TPU's compiler lays out, channels in
+    lanes and positions in sublanes: the array a kernel reads in place. A
+    product to four dimensions it writes positions-minor (PERF.md, PR
+    34), and a copy to the kernel's layout follows. `parts`: widths that
+    split every head's channels (latent attention's own and rotary query
+    channels): a product and an array each, from the kernel's columns."""
+
+    heads: int
+    width: int
+    use_bias: bool = True
+    parts: tuple[int, ...] = ()
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        d, flat = x.shape[-1], self.heads * self.width
+
+        def kernel_init(rng, shape, dtype=jnp.float32):
+            return nn.initializers.lecun_normal()(
+                rng, (d, flat), dtype).reshape(shape)
+
+        kernel = self.param("kernel", kernel_init,
+                            (d, self.heads, self.width), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          (self.heads, self.width),
+                          jnp.float32) if self.use_bias else None
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias,
+                                                  dtype=self.dtype)
+        outs, first = [], 0
+        for width in self.parts or (self.width,):
+            columns = slice(first, first + width)
+            out = jnp.dot(x, kernel[:, :, columns].reshape(d, -1))
+            if bias is not None:
+                out = out + bias[:, columns].reshape(-1)
+            outs.append(out.reshape(x.shape[:-1] + (self.heads, width)))
+            first += width
+        return tuple(outs) if self.parts else outs[0]
+
 
 class SelfAttention(nn.Module):
     """Multi-head self-attention with a selectable attention core.
@@ -931,9 +1347,8 @@ class SelfAttention(nn.Module):
             raise ValueError(f"d_model={d_model} not divisible by "
                              f"num_heads={self.num_heads}")
         head_dim = d_model // self.num_heads
-        proj = functools.partial(
-            nn.DenseGeneral, features=(self.num_heads, head_dim),
-            dtype=self.dtype)
+        proj = functools.partial(HeadsDense, self.num_heads, head_dim,
+                                 dtype=self.dtype)
         q = proj(name="query")(x)
         k = proj(name="key")(x)
         v = proj(name="value")(x)

@@ -271,19 +271,33 @@ class RMSNorm(nn.Module):
         return (y * scale.astype(jnp.float32)).astype(self.dtype)
 
 
-def _rotary(x, theta: float):
+def _rotary(x, theta: float, impl: str = "dense"):
     """Rotary positions 0 .. T-1 on the channels of x (B, T, heads, c),
     rotate-half layout (channel i pairs with channel i + c/2), computed in
     float32. A checkpoint with the interleaved layout is permuted at import
-    (`import_weights.MLA_MOE_DECODER_SPEC`)."""
+    (`import_weights.MLA_MOE_DECODER_SPEC`). Told that the "flash" tier
+    reads x, heads of whole lanes are rotated where they lie
+    (`attention.rotary_in_lanes`: the same numbers, no relayout before the
+    kernel)."""
+    from .attention import (rotary_cos_sin, rotary_in_lanes,
+                            rotary_lanes_whole)
+
+    if _tier(impl) == "flash" and rotary_lanes_whole(*x.shape[2:]):
+        return rotary_in_lanes(x, theta)
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freq
-    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    cos, sin = (table[:, None, :]
+                for table in rotary_cos_sin(x.shape[1], half, theta))
     a = x[..., :half].astype(jnp.float32)
     b = x[..., half:].astype(jnp.float32)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            -1).astype(x.dtype)
+
+
+def _tier(impl: str) -> str:
+    """The attention tier that runs: "flash" is the Pallas kernels, and
+    the chunked tier on the CPU, where Mosaic cannot lower."""
+    return ("chunked" if impl == "flash" and jax.default_backend() == "cpu"
+            else impl)
 
 
 def _causal_attention(q, k, v, impl: str, dtype, pooling=None,
@@ -296,8 +310,7 @@ def _causal_attention(q, k, v, impl: str, dtype, pooling=None,
     takes plain causal attention for a row of at most one window)."""
     from .attention import causal_attention, eva_attention
 
-    if impl == "flash" and jax.default_backend() == "cpu":
-        impl = "chunked"
+    impl = _tier(impl)
     if window:
         return eva_attention(q, k, v, *pooling, window, chunk,
                              impl=impl).astype(dtype)
@@ -310,7 +323,18 @@ class LatentAttention(nn.Module):
     the input to a latent of `kv_lora_rank` channels and one rotary key of
     `qk_rope` channels for all heads; the latent, RMS-normed, projected up
     to every head's `qk_nope` key channels and `v_head` value channels;
-    scores over sqrt(qk_nope + qk_rope)."""
+    scores over sqrt(qk_nope + qk_rope).
+
+    What goes in and comes out of the core (`attention.latent_attention`),
+    each as its projection leaves it, (B, T, heads x width) in memory: a
+    head's own query channels and its rotary ones from two products of
+    `q_proj`'s columns (`HeadsDense`), the rotary ones rotated where they
+    lie; `kv_b_proj`'s output whole, a head's key channels then its
+    values; the one rotary key (B, T, qk_rope). On the "flash" tier at
+    the published widths (128 + 64, 128) nothing is sliced, concatenated,
+    broadcast or transposed before the kernel, and the output projection
+    reads what it wrote; every other tier and shape builds q and k of
+    qk_nope + qk_rope channels as before."""
 
     num_heads: int
     kv_lora_rank: int
@@ -324,28 +348,29 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, y):
+        from .attention import HeadsDense, latent_attention
+
         dt, heads, lat = self.dtype, self.num_heads, self.kv_lora_rank
         nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                           self.v_head_dim)
-        b, t, d = y.shape
+        d = y.shape[-1]
         with jax.named_scope("mla.project"):
-            q = nn.DenseGeneral((heads, nope + rope), use_bias=False,
-                                dtype=dt, name="q_proj")(y)
-            q = jnp.concatenate(
-                [q[..., :nope], _rotary(q[..., nope:], self.rope_theta)], -1)
+            q_nope, q_rope = HeadsDense(
+                heads, nope + rope, use_bias=False, parts=(nope, rope),
+                dtype=dt, name="q_proj")(y)
+            q_rope = _rotary(q_rope, self.rope_theta, self.impl)
             kv = nn.Dense(lat + rope, use_bias=False, dtype=dt,
                           name="kv_a_proj")(y)
-            k_pe = _rotary(kv[:, :, None, lat:], self.rope_theta)
+            k_pe = _rotary(kv[:, :, None, lat:], self.rope_theta)[:, :, 0]
             c = RMSNorm(self.eps, dt, name="kv_a_norm")(kv[..., :lat])
-            kvb = nn.DenseGeneral((heads, nope + vd), use_bias=False,
-                                  dtype=dt, name="kv_b_proj")(c)
-            k = jnp.concatenate(
-                [kvb[..., :nope],
-                 jnp.broadcast_to(k_pe, (b, t, heads, rope))], -1)
-            v = kvb[..., nope:]
-        # the innermost scope names the Pallas call in a device trace
+            kvb = HeadsDense(heads, nope + vd, use_bias=False, dtype=dt,
+                             name="kv_b_proj")(c)
+        # the innermost scope names the plain flash call in a device trace;
+        # the latent forward of whole lanes names itself (`mla_attn_n<nope>
+        # r<rope>`: lowered once a shape, so no layer's name is in it)
         with jax.named_scope("mla.attend"), jax.named_scope(self.name):
-            o = _causal_attention(q, k, v, self.impl, dt)
+            o = latent_attention(q_nope, q_rope, kvb, k_pe,
+                                 _tier(self.impl)).astype(dt)
         with jax.named_scope("mla.project"):
             return nn.DenseGeneral(d, axis=(-2, -1), use_bias=False,
                                    dtype=dt, name="out")(o)
@@ -403,7 +428,10 @@ class EvaAttention(nn.Module):
     and `mu` (added to the pooled key), float32. Query t in window
     w = t // window_size attends, in ONE softmax, to the keys of window w
     at or before it and to the summaries of every chunk of windows 0 ..
-    w - 1 (`nn/attention.py` `eva_summaries`, `eva_attention`)."""
+    w - 1 (`nn/attention.py` `eva_summaries`, `eva_attention`). Heads of
+    whole lanes (128 channels) on the "flash" tier are projected, rotated
+    and attended to as (B, T, heads x width) arrays, nothing laid out
+    again between the projections and the output projection."""
 
     num_heads: int
     window_size: int = 2048
@@ -414,6 +442,8 @@ class EvaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, y):
+        from .attention import HeadsDense
+
         dt, d = self.dtype, y.shape[-1]
         if d % self.num_heads:
             raise ValueError(f"{self.num_heads} heads do not divide a "
@@ -421,12 +451,12 @@ class EvaAttention(nn.Module):
         width = d // self.num_heads
 
         def heads(name):
-            return nn.DenseGeneral((self.num_heads, width), use_bias=False,
-                                   dtype=dt, name=name)(y)
+            return HeadsDense(self.num_heads, width, use_bias=False,
+                              dtype=dt, name=name)(y)
 
         with jax.named_scope("eva.project"):
-            q = _rotary(heads("q_proj"), self.rope_theta)
-            k = _rotary(heads("k_proj"), self.rope_theta)
+            q = _rotary(heads("q_proj"), self.rope_theta, self.impl)
+            k = _rotary(heads("k_proj"), self.rope_theta, self.impl)
             v = heads("v_proj")
         phi, mu = (self.param(name, nn.initializers.normal(1.0),
                               (self.num_heads, width), jnp.float32)

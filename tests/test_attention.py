@@ -315,23 +315,28 @@ def _grouped_qkv(b, t, h, group, d, seed=0, dtype=jnp.float32):
     return q, k, v
 
 
-def _pallas_equation(fn, *args):
-    """The one `pallas_call` equation under `fn`, however deep."""
-    found = []
-
+def _equations(fn, *args):
+    """Every equation under `fn`, however deep; a `pallas_call`'s own
+    body is not entered."""
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
+            yield eqn
             if eqn.primitive.name == "pallas_call":
-                found.append(eqn)
                 continue
             for value in eqn.params.values():
                 for sub in (value if isinstance(value, (tuple, list))
                             else (value,)):
                     inner = getattr(sub, "jaxpr", sub)
                     if hasattr(inner, "eqns"):
-                        walk(inner)
+                        yield from walk(inner)
 
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _pallas_equation(fn, *args):
+    """The one `pallas_call` equation under `fn`."""
+    found = [eqn for eqn in _equations(fn, *args)
+             if eqn.primitive.name == "pallas_call"]
     assert len(found) == 1
     return found[0]
 
@@ -370,21 +375,25 @@ class TestGroupedQueryHeads:
             np.asarray(call(q, jnp.repeat(k, group, 2),
                             jnp.repeat(v, group, 2))))
 
+    @pytest.mark.parametrize("d", [64, 128])
     @pytest.mark.parametrize("group", [1, 4])
-    def test_key_and_value_operands_keep_their_own_heads(self, group):
-        q, k, v = _grouped_qkv(2, 32, 8, group, 64)
+    def test_key_and_value_operands_keep_their_own_heads(self, group, d):
+        q, k, v = _grouped_qkv(2, 32, 8, group, d)
         eqn = _pallas_equation(
             lambda q, k, v: flash_attention(q, k, v, block_q=16, block_k=16,
                                             interpret=True), q, k, v)
-        assert [x.aval.shape for x in eqn.invars] == [
-            (16, 32, 64), (16 // group, 32, 64), (16 // group, 32, 64)]
-        # equal head counts: the key block's index map is the parent's
-        # (it names its grid indices and computes nothing); grouped heads
-        # add the one division
+        # 64 channels: head-major rows; 128: the arrays as they lie
+        assert [x.aval.shape for x in eqn.invars] == (
+            [(16, 32, 64), (16 // group, 32, 64), (16 // group, 32, 64)]
+            if d == 64 else
+            [(2, 32, 8 * 128)] + [(2, 32, 8 // group * 128)] * 2)
+        # what an index map computes: in place nothing (it names its grid
+        # indices), head-major the row b x H + j; grouped heads add the one
+        # division
         maps = eqn.params["grid_mapping"].block_mappings
         computed = [len(m.index_map_jaxpr.jaxpr.eqns) for m in maps[:3]]
-        assert computed[0] == 0
-        assert (computed[1] == computed[2] == 0) == (group == 1)
+        row = 0 if d == 128 else 2
+        assert computed == [row, row + (group > 1), row + (group > 1)]
 
     def test_grouped_gradients_match_dense(self):
         q, k, v = _grouped_qkv(1, 24, 4, 2, 8, seed=3)
@@ -443,6 +452,246 @@ class TestGroupedQueryHeads:
                                causal=True)
         np.testing.assert_allclose(got.astype(jnp.float32), want, atol=3e-2,
                                    rtol=3e-2)
+
+
+# --------------------------------------------------------------------- #
+# the layout the kernels speak                                          #
+# --------------------------------------------------------------------- #
+
+def _operands(kernel, layout):
+    return get_registry().counter(
+        "mmlspark_tpu_attention_operands_total",
+        labels=("kernel", "layout")).labels(
+            kernel=kernel, layout=layout).value
+
+
+def _head_major(monkeypatch):
+    """The copy path at ANY width, for a comparison: the rule is by shape
+    and the program has no switch, so the test takes the rule away (and
+    the jitted forwards' cached traces with it)."""
+    monkeypatch.setattr(attention, "_lanes_whole", lambda *widths: False)
+    jax.clear_caches()
+
+
+def _transposed_lengths(fn, *args):
+    """The extents of every operand a `transpose` equation under `fn`
+    moves."""
+    return [eqn.invars[0].aval.shape for eqn in _equations(fn, *args)
+            if eqn.primitive.name == "transpose"]
+
+
+def _latent_inputs(t, b=2, h=4, nope=128, rope=64, seed=0, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.normal(size=(b, t, h, nope)), dtype),
+            jnp.asarray(rng.normal(size=(b, t, h, rope)), dtype),
+            jnp.asarray(rng.normal(size=(b, t, h, 2 * nope)), dtype),
+            jnp.asarray(rng.normal(size=(b, t, rope)), dtype))
+
+
+class TestOperandsInPlace:
+    """Heads of whole lanes (multiples of 128 channels) are read as blocks
+    of the (B, T, H x D) arrays around the call and written the same way;
+    any other width is copied head-major. The same values in the same
+    order in every block: bit for bit."""
+
+    # 40 tokens in tiles of 16 pad to 48; 32 do not
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("t,group", [(32, 1), (40, 1), (40, 2), (32, 4)])
+    def test_flash_in_place_equals_head_major(self, monkeypatch, t, group,
+                                              causal):
+        q, k, v = _grouped_qkv(2, t, 4, group, 128, seed=t + group)
+        call = lambda: np.asarray(flash_attention(    # noqa: E731
+            q, k, v, causal=causal, block_q=16, block_k=16, interpret=True))
+        before = _operands("flash", "in_place"), _operands("flash",
+                                                           "head_major")
+        in_place = call()
+        assert _operands("flash", "in_place") == before[0] + 1
+        _head_major(monkeypatch)
+        assert np.array_equal(in_place, call())
+        assert _operands("flash", "head_major") == before[1] + 1
+        np.testing.assert_allclose(
+            in_place, dense_attention(q, k, v, causal=causal), atol=2e-5)
+
+    def test_values_of_another_width_in_place(self, monkeypatch):
+        q, k, _ = _qkv(1, 24, 24, 2, 128, seed=3)
+        v = jnp.asarray(np.random.default_rng(4).normal(size=(1, 24, 2, 256)),
+                        jnp.float32)
+        call = lambda: np.asarray(flash_attention(    # noqa: E731
+            q, k, v, causal=True, block_q=8, block_k=8, interpret=True))
+        in_place = call()
+        assert in_place.shape == (1, 24, 2, 256)
+        _head_major(monkeypatch)
+        assert np.array_equal(in_place, call())
+
+    # window 32, chunk 4: 96 is three whole windows, 70 ends inside one
+    # (and inside a chunk)
+    @pytest.mark.parametrize("t", [96, 70])
+    def test_eva_in_place_equals_head_major(self, monkeypatch, t):
+        q, k, v, phi, mu = _eva_inputs(t, h=2, d=128)
+        call = lambda: np.asarray(eva_attention(      # noqa: E731
+            q, k, v, phi, mu, EVA_WINDOW, EVA_CHUNK, interpret=True))
+        before = _operands("eva", "in_place"), _operands("eva", "head_major")
+        in_place = call()
+        assert _operands("eva", "in_place") == before[0] + 1
+        _head_major(monkeypatch)
+        assert np.array_equal(in_place, call())
+        assert _operands("eva", "head_major") == before[1] + 1
+        want, _kbar, _vbar = _eva_by_masks(q, k, v, phi, mu, EVA_WINDOW,
+                                           EVA_CHUNK)
+        assert np.abs(in_place - want).max() < 5 * EVA_LIMIT
+
+    @pytest.mark.parametrize("d,moved", [(128, False), (64, True),
+                                         (8, True)])
+    @pytest.mark.parametrize("kernel", ["flash", "eva"])
+    def test_the_rule_is_by_shape(self, kernel, d, moved):
+        """No operand with the sequence's extent is transposed around the
+        kernel at whole lanes; at 64 channels (and a test's 8) one is."""
+        t = 96
+        q, k, v, phi, mu = _eva_inputs(t, h=2, d=d)
+        if kernel == "flash":
+            fn = lambda q, k, v: flash_attention(     # noqa: E731
+                q, k, v, causal=True, block_q=32, block_k=32, interpret=True)
+            args = (q, k, v)
+        else:
+            fn = lambda *a: eva_attention(            # noqa: E731
+                *a, EVA_WINDOW, EVA_CHUNK, interpret=True)
+            args = (q, k, v, phi, mu)
+        layout = "head_major" if moved else "in_place"
+        before = _operands(kernel, layout)
+        moves = [s for s in _transposed_lengths(fn, *args) if t in s]
+        assert bool(moves) == moved, moves
+        assert _operands(kernel, layout) == before + 1
+
+    @pytest.mark.parametrize("t,tile", [(48, 16), (40, 16), (24, 24)])
+    def test_split_latent_score_equals_the_concatenated_one(self, t, tile):
+        """The score over a head's own and its rotary channels as they
+        lie (with the zeros beside the rotary key) against ONE product of
+        192 concatenated channels: float32 rounding apart at most, and the
+        concatenated path is `flash_attention` as ever."""
+        parts = _latent_inputs(t, seed=t)
+        before = _operands("mla", "in_place")
+        got = attention.latent_attention(*parts, block_q=tile, block_k=tile,
+                                         interpret=True)
+        assert _operands("mla", "in_place") == before + 1
+        q, k, v = attention._latent_concatenated(*parts)
+        assert q.shape[-1] == k.shape[-1] == 192 and v.shape[-1] == 128
+        want = flash_attention(q, k, v, causal=True, block_q=tile,
+                               block_k=tile, interpret=True)
+        assert got.shape == want.shape == (2, t, 4, 128)
+        np.testing.assert_allclose(got, want, atol=2e-6)
+        np.testing.assert_allclose(
+            got, dense_attention(q, k, v, causal=True), atol=2e-5)
+        # nothing of the sequence's extent is transposed, sliced apart or
+        # broadcast to the heads around the kernel
+        fn = lambda *a: attention.latent_attention(   # noqa: E731
+            *a, block_q=tile, block_k=tile, interpret=True)
+        assert not [s for s in _transposed_lengths(fn, *parts) if t in s]
+        eqn = _pallas_equation(fn, *parts)
+        assert [x.aval.shape[-1] for x in eqn.invars] == [
+            4 * 128, 4 * 64, 4 * 256, 2 * 128, 4 * 256]
+
+    def test_split_latent_gradient_equals_todays(self):
+        parts = _latent_inputs(24, seed=5)
+
+        def loss(fn):
+            return lambda *a: (fn(*a) ** 2).sum()
+
+        got = jax.grad(loss(lambda *a: attention.latent_attention(
+            *a, block_q=8, block_k=8, interpret=True)),
+            argnums=(0, 1, 2, 3))(*parts)
+        want = jax.grad(loss(lambda *a: flash_attention(
+            *attention._latent_concatenated(*a), causal=True, block_q=8,
+            block_k=8, bwd_chunk=8, interpret=True)),
+            argnums=(0, 1, 2, 3))(*parts)
+        assert [g.shape for g in got] == [p.shape for p in parts]
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, atol=2e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("nope,rope,heads,impl", [
+        (8, 4, 2, "flash"),         # a test's widths: no lane block
+        (128, 64, 3, "flash"),      # rotary channels that fill no block
+        (128, 64, 4, "chunked"), (128, 64, 4, "dense")])
+    def test_other_shapes_and_tiers_take_the_concatenated_path(
+            self, nope, rope, heads, impl):
+        parts = _latent_inputs(20, h=heads, nope=nope, rope=rope, seed=heads)
+        before = _operands("mla", "head_major")
+        got = attention.latent_attention(*parts, impl=impl, interpret=True)
+        assert _operands("mla", "head_major") == before + (impl == "flash")
+        np.testing.assert_allclose(
+            got, dense_attention(*attention._latent_concatenated(*parts),
+                                 causal=True), atol=2e-5)
+
+    # blocks of 16 positions: 40 pad to 48, 300 to 304, float32 in steps of 8
+    @pytest.mark.parametrize("shape,dtype", [
+        ((2, 40, 4, 128), jnp.bfloat16), ((2, 40, 4, 64), jnp.bfloat16),
+        ((1, 300, 2, 128), jnp.bfloat16), ((1, 24, 2, 128), jnp.float32),
+        ((1, 32, 2, 64), jnp.float32)])
+    def test_rotary_in_lanes_is_the_models_rotary(self, monkeypatch, shape,
+                                                  dtype):
+        from mmlspark_tpu.nn.models import _rotary
+
+        monkeypatch.setattr(attention, "_ROTARY_ROWS", 16)
+        x = jnp.asarray(np.random.default_rng(1).normal(size=shape), dtype)
+        got = attention.rotary_in_lanes(x, 1e5, interpret=True)
+        want = _rotary(x, 1e5)
+        assert got.shape == x.shape and got.dtype == x.dtype
+        # the same float32 arithmetic, up to a multiply-add that XLA:CPU
+        # may fuse on either side: a last bit, rarely
+        got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+        ulp = 2.0 ** -7 if dtype == jnp.bfloat16 else 2.0 ** -22
+        np.testing.assert_allclose(got, want, rtol=ulp, atol=ulp)
+        if dtype == jnp.bfloat16:      # the one rounding hides it, mostly
+            assert (got != want).mean() < 0.02
+        assert attention.rotary_lanes_whole(*shape[2:])
+        assert not attention.rotary_lanes_whole(3, 64)
+        assert not attention.rotary_lanes_whole(4, 32)
+
+    def test_rotary_in_lanes_is_traced_once_a_shape(self, monkeypatch):
+        """Jitted by itself: two tensors of one shape and one theta (a
+        layer's q and k, and every layer's) share ONE trace of the kernel,
+        whose grid walks the lane blocks a block of positions."""
+        traced = []
+        kernel = attention._rotary_kernel
+        monkeypatch.setattr(
+            attention, "_rotary_kernel",
+            lambda *refs, **static: (traced.append(static),
+                                     kernel(*refs, **static))[1])
+        x = jnp.ones((1, 32, 4, 128), jnp.bfloat16)
+        theta = 54321.0                  # no other test's: a trace of its own
+        eqns = _equations(lambda x: attention.rotary_in_lanes(
+            attention.rotary_in_lanes(x, theta, interpret=True), theta,
+            interpret=True), x)
+        assert traced == [{"width": 128}]
+        calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+        assert len(calls) == 2
+        assert calls[0].params["grid_mapping"].grid == (1, 1, 4)
+        assert [x.aval.shape for x in calls[0].invars] == [
+            (1, 32, 512), (32, 128), (32, 128)]
+
+    @pytest.mark.parametrize("bias,parts", [(True, ()), (False, ()),
+                                            (False, (128, 64))])
+    def test_heads_dense_is_dense_general(self, bias, parts):
+        """The same parameters (names, shapes, initial values) and the same
+        numbers as `nn.DenseGeneral((heads, width))`."""
+        from flax import linen as nn
+
+        heads, width = 4, sum(parts) or 128
+        x = jnp.asarray(np.random.default_rng(2).normal(size=(2, 10, 48)),
+                        jnp.float32)
+        ref = nn.DenseGeneral((heads, width), use_bias=bias)
+        mod = attention.HeadsDense(heads, width, use_bias=bias, parts=parts)
+        params = ref.init(jax.random.PRNGKey(0), x)
+        ours = mod.init(jax.random.PRNGKey(0), x)
+        assert jax.tree.map(jnp.shape, ours) == jax.tree.map(jnp.shape,
+                                                             params)
+        assert all(np.array_equal(a, b) for a, b in zip(
+            jax.tree.leaves(ours), jax.tree.leaves(params)))
+        if bias:
+            params = jax.tree.map(lambda p: p + 0.5, params)
+        got, want = mod.apply(params, x), ref.apply(params, x)
+        if parts:
+            got = jnp.concatenate(got, -1)
+        np.testing.assert_allclose(got, want, atol=1e-5)
 
 
 # --------------------------------------------------------------------- #

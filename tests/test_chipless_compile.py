@@ -8,6 +8,9 @@ The topology is described inside a fixture (never at import: only one
 process at a time may load the TPU's library, and a test file is imported
 by every worker), and every such test lives in this one file."""
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -152,3 +155,141 @@ def test_windowed_and_summarised_attention_kernels_compile(one_chip, rows,
         q, q, q, phi, phi).as_text()
     assert text.count("tpu_custom_call") >= 2
     assert "eva_attn_w2048c16" in text and "eva_pool_w2048c16" in text
+
+
+def _entry_operations(text: str):
+    """(operation, result's type, whether it is computed from the
+    argument `y`) of every instruction of the optimised program's entry
+    computation: what follows from `y` is an activation, the rest are
+    weights and tables."""
+    entry = text[text.index("ENTRY"):]
+    found, from_y = [], set()
+    for line in entry.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*)",
+            line)
+        if not m:
+            continue
+        name, kind_, op, rest = m.groups()
+        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+        if re.fullmatch(r"y(\.\d+)?", name) or from_y & set(operands):
+            from_y.add(name)
+        found.append((op, kind_, name in from_y))
+    return found
+
+
+LAYERS = {
+    # module, its input's width, (rows, length) of a cell's batch
+    "eva_2x32768": ("eva", 4096, (2, 32768)),
+    "eva_1x4096": ("eva", 4096, (1, 4096)),
+    "latent_8x4096": ("latent", 2048, (8, 4096)),
+    "latent_8x512": ("latent", 2048, (8, 512)),
+    "encoder_32x512": ("encoder", 4096, (32, 512)),
+    "encoder_32x128": ("encoder", 4096, (32, 128)),
+}
+
+# what is left, by the traces of PR 35 (PERF.md section 5): at ONE row of
+# 4096 the compiler contracts the output projection's (heads, width) as
+# two dimensions and copies the kernel's output heads-in-sublanes first,
+# 0.1 ms a layer (0.03% of EvaByte's call); at two rows it does not
+KNOWN_MOVES = {"eva_1x4096": [("copy", "bf16[512,8,32,128]")]}
+
+
+def _attention_layer(kind: str, i: int = 0):
+    """A cell's attention module at its published widths, bfloat16."""
+    from mmlspark_tpu.nn import attention, models
+
+    bf = jnp.bfloat16
+    if kind == "eva":
+        return models.EvaAttention(num_heads=32, dtype=bf,
+                                   name=f"eva_attn_{i}")
+    if kind == "latent":
+        return models.LatentAttention(
+            num_heads=16, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, rope_theta=50000.0,
+            dtype=bf, name=f"mla_attn_{i}")
+    return attention.SelfAttention(num_heads=32, dtype=bf, impl="flash",
+                                   name=f"attn_{i}")
+
+
+def _layers_for_the_chip(one_chip, monkeypatch, kind, width, rows, length,
+                         layers=1):
+    """(function of (params, y), their shapes on the described chip) for
+    `layers` attention layers of a cell in a row, each with its residual
+    sum, projections to output projection."""
+    from flax import linen as nn
+
+    from mmlspark_tpu.nn import attention, models
+
+    # the modules ask the backend which tier runs; here it is the CPU's
+    for mod in (models, attention):
+        monkeypatch.setattr(mod.jax, "default_backend", lambda: "tpu")
+
+    class Stack(nn.Module):
+        @nn.compact
+        def __call__(self, y):
+            for i in range(layers):
+                y = y + _attention_layer(kind, i)(y)
+            return y
+
+    stack = Stack()
+    bf = jnp.bfloat16
+    y = jax.ShapeDtypeStruct((rows, length, width), bf, sharding=one_chip)
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(
+            p.shape, bf if p.ndim == 3 or p.shape[-1] > 128 else p.dtype,
+            sharding=one_chip),
+        jax.eval_shape(lambda: stack.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, width), bf))))
+    return (lambda p, y: stack.apply(p, y)), params, y
+
+
+@pytest.mark.parametrize("case", list(LAYERS))
+def test_no_activation_is_laid_out_again_around_the_kernels(
+        one_chip, monkeypatch, case):
+    """ONE attention layer of a cell, projections to output projection,
+    compiled for the chip: between the product fusions and the Pallas
+    calls the optimised program holds no copy, transpose or reshape of an
+    array with the batch's extents (PERF.md, PR 34: a projection to four
+    dimensions is written positions-minor and copied; XLA's rotary too)."""
+    kind, width, (rows, length) = LAYERS[case]
+    text = _compile(*_layers_for_the_chip(one_chip, monkeypatch, kind, width,
+                                          rows, length)).as_text()
+    assert "tpu_custom_call" in text
+
+    # an activation at least as large as the smallest the kernels read:
+    # latent attention's rotary query channels, 1024 a position (the ONE
+    # rotary key, 64 a position, is not)
+    def large(kind_):
+        return any(
+            math.prod(int(n) for n in found.split(","))
+            >= rows * length * 1024
+            for found in re.findall(r"\[([\d,]+)\]", kind_))
+
+    moved = [(op, kind_.split("{")[0])
+             for op, kind_, activation in _entry_operations(text)
+             if op in ("copy", "transpose", "reshape") and activation
+             and large(kind_)]
+    assert moved == KNOWN_MOVES.get(case, []), moved
+
+
+@pytest.mark.parametrize("kind,width,rows,length,bodies", [
+    # rotary (q and k share it), the pooling, the attention
+    ("eva", 4096, 2, 32768, 3), ("eva", 4096, 1, 4096, 3),
+    # rotary of the queries' rotary channels, the latent forward
+    ("latent", 2048, 8, 4096, 2), ("latent", 2048, 8, 512, 2)])
+def test_a_kernel_is_lowered_once_a_shape_not_once_a_layer(
+        one_chip, monkeypatch, kind, width, rows, length, bodies):
+    """The guard PR 34 lacked (its rotary call was lowered once a tensor,
+    layer and shape: 66 times in EvaByte's cell, 4 s of every warm start):
+    the lowered text of THREE layers holds as many Pallas bodies as that
+    of ONE, every Pallas call of these families being jitted by itself.
+    Traced and lowered only: nothing is compiled."""
+    def pallas_bodies(layers):
+        fn, params, y = _layers_for_the_chip(
+            one_chip, monkeypatch, kind, width, rows, length, layers)
+        return jax.jit(fn).lower(params, y).as_text().count(
+            "tpu_custom_call")
+
+    assert pallas_bodies(1) == bodies
+    assert pallas_bodies(3) == bodies
