@@ -5,6 +5,10 @@ auto-featurization, hyperparameter tuning, evaluation, interpretation, a SAR
 recommender, HTTP integration, and low-latency serving) built on
 JAX / XLA / Pallas / jax.sharding."""
 
+import time as _time
+
+_IMPORT_STARTED = _time.monotonic()     # -> a `package.import` span, below
+
 __version__ = "0.1.0"
 
 import os as _os
@@ -55,3 +59,13 @@ from .core import (
     Param,
     Params,
 )
+
+# last lines: the import so far as a span of the process-default tracer,
+# and JAX's trace, lowering and compile events as spans from here on
+from .observability.tracing import (  # noqa: E402
+    install_jax_bridge as _install_jax_bridge,
+    record_import as _record_import,
+)
+
+_install_jax_bridge()
+_record_import(__name__, _IMPORT_STARTED)

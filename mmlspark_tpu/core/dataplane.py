@@ -432,8 +432,9 @@ class ExecutableCache:
         self.hits = 0
         self.misses = 0
         self.recompiles = 0
-        # wall-clock seconds inside `builder()` per (family, shape) —
-        # the compile-time ledger that makes warmup cost and recompile
+        # wall-clock seconds inside `builder()`, and of trace, lowering
+        # and compile in a jitted entry's first call, per (family, shape)
+        # — the compile-time ledger that makes warmup cost and recompile
         # spikes a number instead of an inference from `recompiles`
         self.compile_seconds = 0.0
         self._compile_log: dict[tuple, float] = {}
@@ -478,13 +479,22 @@ class ExecutableCache:
             self._bump(**deltas)
             t0 = time.perf_counter()
             value = builder()
-            dt = time.perf_counter() - t0
-            self.compile_seconds += dt
-            self._compile_log[key] = self._compile_log.get(key, 0.0) + dt
-            self._bump(compile_seconds=dt)
+            self.add_compile_seconds(family, shape, time.perf_counter() - t0)
             self._entries[key] = value
             seen.add(shape)
             return value
+
+    def add_compile_seconds(self, family: Any, shape: Any,
+                            seconds: float) -> None:
+        """Charge `seconds` to the entry's line of the ledger. A builder
+        that hands back a jitted function returns at once; its trace,
+        lowering and compile are paid by the entry's first CALL, and the
+        caller adds here what that call paid."""
+        with self._lock:
+            key = (family, shape)
+            self.compile_seconds += seconds
+            self._compile_log[key] = self._compile_log.get(key, 0.0) + seconds
+            self._bump(compile_seconds=seconds)
 
     def __len__(self) -> int:
         with self._lock:

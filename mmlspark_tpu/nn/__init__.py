@@ -7,6 +7,10 @@ src/cntk-train/ (CNTKLearner.scala), src/image-featurizer/
 (ImageFeaturizer.scala), src/downloader/ (ModelDownloader.scala).
 """
 
+import time as _time
+
+_IMPORT_STARTED = _time.monotonic()     # -> a `package.import` span, below
+
 from .models import (
     MLP,
     SimpleCNN,
@@ -39,3 +43,7 @@ __all__ = [
     "ModelDownloader",
     "retry_with_timeout",
 ]
+
+from ..observability.tracing import record_import as _record_import  # noqa: E402
+
+_record_import(__name__, _IMPORT_STARTED)

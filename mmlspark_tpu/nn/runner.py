@@ -28,7 +28,7 @@ from ..core.pipeline import Model
 from ..core.schema import SCORE_KIND, Table
 from ..core.serialize import register_stage
 from ..observability.metrics import get_registry
-from ..observability.tracing import get_tracer
+from ..observability.tracing import get_tracer, jax_compile_seconds
 from ..parallel.mesh import DATA_AXIS, get_mesh
 from .models import ModelBundle
 
@@ -301,6 +301,16 @@ class DeepModelTransformer(Model):
             + tuple(np.asarray(a) for a in om[0][nf:]), lag=1)
         chunks: list[tuple[np.ndarray, ...]] = []
         scored: list[int] = []          # rows a batch scored, padding too
+        paid_before: list[float] = []
+
+        def build():
+            # runs on a miss only, and returns at once: the new entry's
+            # first CALL traces, lowers and compiles, and what the tracer's
+            # bridge sees of that on this thread is the entry's compile
+            # seconds
+            paid_before.append(jax_compile_seconds())
+            return apply_fn
+
         tracer = get_tracer()
         with tracer.start_span("runner.transform", rows=n,
                                batch_size=bs) as root:
@@ -314,8 +324,13 @@ class DeepModelTransformer(Model):
                     # ragged shapes defeating the ladder visible
                     # (recompiles > 0)
                     fn = self._exec_cache.get_or_build(family, shape_key,
-                                                       lambda: apply_fn)
-                    chunks.extend(readback.push((fn(variables, xb), m)))
+                                                       build)
+                    out = fn(variables, xb)
+                    if paid_before:
+                        self._exec_cache.add_compile_seconds(
+                            family, shape_key,
+                            jax_compile_seconds() - paid_before.pop())
+                    chunks.extend(readback.push((out, m)))
             chunks.extend(readback.drain())
             if chunks and len(chunks[0]) > nf:
                 per_row = int(np.prod(x.shape[1:]))

@@ -41,6 +41,12 @@ The order interleaves: `sar.slice` and `sar.dispatch` of block b+1 come
 before `sar.wait` and `sar.readback` of block b, so at most two blocks are
 in flight and the last is drained after the loop.
 
+Where a start, or a recompile, goes (end of this file): the package's
+imports (`package.import`) and JAX's own trace, lowering and compile events
+(`jax.trace`, `jax.lower`, `jax.compile`) are recorded as spans whose end
+is already known (`Tracer.record_span`), each under the span that was
+active on the thread that paid for it.
+
 The disabled path is a no-op fast path: one attribute check, a shared
 null context manager — no allocation, no locks, no contextvar writes.
 """
@@ -61,7 +67,9 @@ from typing import Any
 __all__ = ["Span", "Tracer", "get_tracer", "set_default_tracer",
            "load_jsonl", "merge_jsonl", "CHROME_EVENT_KEYS",
            "format_traceparent", "parse_traceparent",
-           "current_traceparent", "PHASE_SPAN_PREFIX", "phase_children"]
+           "current_traceparent", "PHASE_SPAN_PREFIX", "phase_children",
+           "IMPORT_SPAN", "JAX_SPANS", "install_jax_bridge",
+           "jax_compile_seconds", "record_import"]
 
 # the profiler's phase child-spans are named `phase.<name>` under the
 # dispatch/request span they decompose (observability.profiler)
@@ -297,6 +305,24 @@ class Tracer:
                     self._now_us(), dict(args))
         return _SpanCtx(self, span)
 
+    def record_span(self, name: str, start_us: float, dur_us: float,
+                    parent: "Span | None" = None, **args: Any) -> "Span | None":
+        """A span whose end is already known (JAX reports a trace, a
+        lowering or a compile when it is over; an import is timed by two
+        stamps) goes into the ring like any other: the same ids, the same
+        parent rule as `start_span`. It was never active, so it enters no
+        `TraceAnnotation`. Disabled tracers return at this check."""
+        if not self.enabled:
+            return None
+        if parent is None:
+            parent = self._current.get()
+        trace_id = parent.trace_id if parent is not None else next(self._ids)
+        span = Span(name, trace_id, next(self._ids), parent,
+                    float(start_us), dict(args))
+        span.dur_us = float(dur_us)
+        self._record(span)
+        return span
+
     def current_span(self) -> "Span | None":
         """The active span on this thread (None when outside any span)."""
         if not self.enabled:
@@ -504,3 +530,134 @@ def current_traceparent() -> "str | None":
     """`traceparent` for the process-default tracer's active span — the
     one-liner HTTP clients call to propagate the trace downstream."""
     return get_tracer().inject()
+
+
+# --------------------------------------------------------------------- #
+# JAX's compile events and the package's imports, as spans              #
+# --------------------------------------------------------------------- #
+
+IMPORT_SPAN = "package.import"
+
+# jax.monitoring's duration events (JAX 0.9.0, jax/_src/dispatch.py) and
+# the span each one becomes
+JAX_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class _ThreadCompiles(threading.local):
+    seconds = 0.0         # the outermost events' durations, added up
+    retrieval_s = None    # a cache read no `jax.compile` has taken yet
+
+    def __init__(self):
+        # one entry an event of JAX_SPANS begun and not ended on this
+        # thread: the trace cache's misses as it began
+        self.open: list = []
+
+
+_compiles = _ThreadCompiles()
+
+
+def _record_ended(name: str, seconds: float, args: dict) -> None:
+    """A span of the process-default tracer that ends now and began
+    `seconds` of wall time ago. A tracer on an injected clock (a test's
+    fake one) takes none: seconds of the wall do not lie on its clock, and
+    it is not read for them."""
+    tracer = get_tracer()
+    if tracer.enabled and tracer._clock is None:
+        dur_us = seconds * 1e6
+        tracer.record_span(name, tracer._now_us() - dur_us, dur_us, **args)
+
+
+_bridge_installed = False
+_trace_cache_info = None   # jax's `trace_to_jaxpr.cache_info`, at install
+
+
+def _trace_misses() -> "int | None":
+    return None if _trace_cache_info is None else _trace_cache_info().misses
+
+
+def _on_jax_start(event: str, _value: float, **_kw: Any) -> None:
+    if event in JAX_SPANS:
+        _compiles.open.append(_trace_misses())
+
+
+def _on_jax_duration(event: str, duration: float, **kw: Any) -> None:
+    """Runs on the thread that traced, lowered or compiled, as that work
+    ends: the span ends now on the tracer's clock and began `duration`
+    earlier, under the thread's active span. A jit traced inside another
+    reports inside the outer one's interval, so only an outermost event
+    adds to the thread's running total. JAX 0.9.0 also reports a trace for
+    every CALL of a jitted function inside a trace, `jnp.add` included,
+    though the call finds its jaxpr in the cache (10 us): such a nested
+    call traced nothing (the trace cache missed no more often than when it
+    began) and leaves no span."""
+    name = JAX_SPANS.get(event)
+    state = _compiles
+    if name is None:
+        if event == _CACHE_RETRIEVAL:
+            state.retrieval_s = duration
+        return
+    misses = state.open.pop() if state.open else None
+    if not state.open:
+        state.seconds += duration
+    elif (name == "jax.trace" and misses is not None
+          and misses == _trace_misses()):
+        return
+    args = {"fun_name": kw.get("fun_name", "")}
+    if name == "jax.compile":
+        retrieval, state.retrieval_s = state.retrieval_s, None
+        args.update(cache_hit=retrieval is not None,
+                    retrieval_s=retrieval or 0.0)
+    _record_ended(name, duration, args)
+
+
+def install_jax_bridge() -> bool:
+    """Register the listener pair on `jax.monitoring`, once a process
+    however often it is called; False without JAX (lazy and fail-soft, as
+    `_device_annotation` is). From then on every trace, lowering and
+    backend compile (or read of the persistent cache) is a `jax.trace`,
+    `jax.lower` or `jax.compile` span of the process-default tracer, a
+    child of the span that was active on the thread that paid for it. The
+    listeners fire only when JAX traces, lowers or compiles: a warmed shape
+    costs nothing."""
+    global _bridge_installed
+    if _bridge_installed:
+        return True
+    with _DEFAULT_LOCK:
+        if not _bridge_installed:
+            try:
+                from jax import monitoring
+
+                try:
+                    from jax._src.interpreters.partial_eval import (
+                        trace_to_jaxpr)
+
+                    global _trace_cache_info
+                    _trace_cache_info = trace_to_jaxpr.cache_info
+                except Exception:        # every reported trace is a span
+                    pass
+                monitoring.register_scalar_listener(_on_jax_start)
+                monitoring.register_event_duration_secs_listener(
+                    _on_jax_duration)
+            except Exception:
+                return False
+            _bridge_installed = True
+    return True
+
+
+def jax_compile_seconds() -> float:
+    """Seconds the bridge has seen THIS thread trace, lower and compile,
+    overlaps counted once: read it before and after a call to learn what
+    that call paid (0.0 throughout without the bridge)."""
+    return _compiles.seconds
+
+
+def record_import(module: str, started: float) -> None:
+    """A package's import as a `package.import` span: `started` is the
+    `time.monotonic()` its `__init__` took on its first line, now is its
+    last."""
+    _record_ended(IMPORT_SPAN, time.monotonic() - started, {"module": module})
