@@ -1575,7 +1575,7 @@ def serve_model(
     It stays on the handler path, and logs why, whenever the model cannot
     host a resident session.
 
-    A fitted `SARModel` delegates to `recommendation.resident
+    A fitted `SARModel` delegates to `recommendation.serving
     .serve_recommender` — same warmup/byte-identity/readback contract,
     top-k reply schema (`input_cols`/`output_col` are implied by the
     model and ignored)."""
@@ -1584,7 +1584,7 @@ def serve_model(
     from ..recommendation.sar import SARModel
 
     if isinstance(model, SARModel):
-        from ..recommendation.resident import serve_recommender
+        from ..recommendation.serving import serve_recommender
 
         return serve_recommender(model, host=host, port=port, mesh=mesh,
                                  hot_path=hot_path, **server_kw)

@@ -16,7 +16,7 @@ from .ranking import (
     RankingTrainValidationSplit,
     ranking_metrics,
 )
-from .resident import SARTopKScorer, serve_recommender
+from .resident import SARTopKScorer
 
 __all__ = [
     "RecommendationIndexer",
@@ -30,3 +30,12 @@ __all__ = [
     "ranking_metrics",
     "serve_recommender",
 ]
+
+
+def __getattr__(name):
+    # the server imports the serving package (0.09 s): who serves pays it
+    if name == "serve_recommender":
+        from .serving import serve_recommender
+
+        return serve_recommender
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

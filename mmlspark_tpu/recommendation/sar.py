@@ -11,8 +11,8 @@ matrix product + top-k.
 TPU redesign: the reference builds these with Spark groupBys, per-row UDFs
 and a breeze BlockMatrix multiply. Here the whole computation is three dense
 device ops — a scatter-add affinity build, ONE (I×U)@(U×I) matmul on the MXU
-for co-occurrence, and ONE (U×I)@(I×I) matmul + `lax.top_k` for
-recommendations.
+for co-occurrence, and ONE (U×I)@(I×I) matmul + a row's top k
+(`topk.top_k_rows`) for recommendations.
 """
 
 from __future__ import annotations
@@ -31,6 +31,7 @@ from ..core.pipeline import Estimator, Model
 from ..core.schema import Table
 from ..core.serialize import register_stage
 from ..observability.tracing import get_tracer
+from .topk import top_k_rows
 
 __all__ = ["SAR", "SARModel"]
 
@@ -45,7 +46,7 @@ def _affinity_scores(affinity, similarity):
 # A block's rows are cut inside the program (the whole resident arrays go
 # in, `start` is traced, `rows` static), so a block is one enqueue. The
 # barrier keeps the affinity rows an operand of their own: XLA then writes
-# them once, already rounded for the MXU, and the product runs at 95% of
+# them once, already rounded for the MXU, and the product runs at 93% of
 # its roofline on a v5e; cut inside the product's fusion the same rows
 # cost it 13% (PERF.md, PR 25).
 def _block_scores(affinity, similarity, start, rows):
@@ -53,16 +54,33 @@ def _block_scores(affinity, similarity, start, rows):
     return jax.lax.optimization_barrier(block) @ similarity
 
 
+def _block_rows(n_users: int, block: int) -> int:
+    """Rows EVERY block of a pass takes, at most `block`: one shape a pass
+    is one trace, lowering, cache read and first run of the block program
+    (a second, small program compiles in under the second JAX asks of an
+    executable it keeps, so every start compiled it anew). The last block
+    is cut whole too, over rows the one before it already gave; so of the
+    sizes from `block` down to half of it, in the steps of 256 rows the
+    product pads a block to on the MXU, the one a pass computes the fewest
+    rows with: 69,878 users under 4096 a block are 21 blocks of 3328, ten
+    rows twice, where 18 of 4096 would be 3,850 (PERF.md, PR 39). Fewer
+    users than a block are one short block."""
+    if n_users <= block:
+        return n_users
+    return min(range(block, block // 2, -256),
+               key=lambda rows: -(-n_users // rows) * rows)
+
+
 @partial(jax.jit, static_argnames=("rows", "k"))
 def _block_topk(affinity, similarity, start, rows, k):
-    return jax.lax.top_k(_block_scores(affinity, similarity, start, rows), k)
+    return top_k_rows(_block_scores(affinity, similarity, start, rows), k)
 
 
 @partial(jax.jit, static_argnames=("rows", "k"))
 def _block_topk_unseen(affinity, similarity, seen, start, rows, k):
     scores = _block_scores(affinity, similarity, start, rows)
     seen_rows = jax.lax.dynamic_slice_in_dim(seen, start, rows)
-    return jax.lax.top_k(jnp.where(seen_rows, -jnp.inf, scores), k)
+    return top_k_rows(jnp.where(seen_rows, -jnp.inf, scores), k)
 
 
 def _to_minutes(values, fmt: str | None) -> np.ndarray:
@@ -181,8 +199,9 @@ class SAR(Estimator):
 
 @register_stage
 class SARModel(Model):
-    """Scoring: affinity (U×I) @ similarity (I×I), top-k via lax.top_k
-    (reference SARModel.scala:95-130 BlockMatrix multiply + top-k udf)."""
+    """Scoring: affinity (U×I) @ similarity (I×I), top-k via
+    `topk.top_k_rows` (reference SARModel.scala:95-130 BlockMatrix
+    multiply + top-k udf)."""
 
     user_col = Param("user", "indexed user id column", ptype=str)
     item_col = Param("item", "indexed item id column", ptype=str)
@@ -243,16 +262,21 @@ class SARModel(Model):
         program, and enqueues the next block before it reads this one back:
         at most two blocks are in flight, so peak device memory is two
         blocks×I rather than U×I. Matmul rows and top_k are row-independent,
-        so the blocked result is byte-identical to the single big matmul."""
+        so the blocked result is byte-identical to the single big matmul.
+        Every block of a pass has ONE shape (`_block_rows`: at most
+        `user_block` rows): the last one's cut starts early enough to be
+        whole, and the rows the block before it already gave are dropped
+        on the host."""
         dev = self._device_arrays()
         n_users, n_items = self.user_affinity.shape
         k = min(k, n_items)
         block = user_block or self.USER_BLOCK
+        rows = _block_rows(n_users, block)
         mask_seen = remove_seen and dev["seen"] is not None
         tracer = get_tracer()
         with tracer.start_span(
                 "sar.recommend_all", users=n_users, items=n_items, k=k,
-                block=block, blocks=-(-n_users // block),
+                block=block, blocks=-(-n_users // rows),
                 remove_seen=mask_seen) as call:
             vals = np.empty((n_users, k), np.float64)
             idx = np.empty((n_users, k), np.int64)
@@ -263,11 +287,12 @@ class SARModel(Model):
                 # first changes no order and tells waiting from copying
                 with tracer.start_span("sar.wait"):
                     jax.block_until_ready((v, i))
-                block_bytes = v.nbytes + i.nbytes
+                # the rows this block is asked for are its last hi - lo
+                block_bytes = (hi - lo) * k * (v.itemsize + i.itemsize)
                 with tracer.start_span("sar.readback", bytes=block_bytes):
                     block_vals, block_idx = vals[lo:hi], idx[lo:hi]
-                    block_vals[...] = v
-                    block_idx[...] = i
+                    block_vals[...] = np.asarray(v)[lo - hi:]
+                    block_idx[...] = np.asarray(i)[lo - hi:]
                     # users with fewer than k unseen items: top_k still
                     # returns the -inf (seen) entries — mark them invalid
                     # (id -1) instead of leaking seen items back as
@@ -288,12 +313,12 @@ class SARModel(Model):
             # the host reads back, casts and marks
             pipeline = AsyncReadback(read_back, lag=1)
             bytes_read_back = dispatched_ahead = 0
-            for lo in range(0, n_users, block):
-                hi = min(lo + block, n_users)
+            for lo in range(0, n_users, rows):
+                hi = min(lo + rows, n_users)
                 with tracer.start_span("sar.slice", lo=lo, hi=hi):
-                    start = np.int32(lo)
+                    start = np.int32(hi - rows)
                 with tracer.start_span("sar.dispatch"):
-                    v, i = enqueue(start, hi - lo, k)
+                    v, i = enqueue(start, rows, k)
                     # the copy back starts when the block is done, under
                     # the next block's work, not when the host asks
                     v.copy_to_host_async()

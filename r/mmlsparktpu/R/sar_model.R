@@ -1,6 +1,6 @@
 #' SARModel (Model)
 #'
-#' Scoring: affinity (U×I) @ similarity (I×I), top-k via lax.top_k (reference SARModel.scala:95-130 BlockMatrix multiply + top-k udf).
+#' Scoring: affinity (U×I) @ similarity (I×I), top-k via `topk.top_k_rows` (reference SARModel.scala:95-130 BlockMatrix multiply + top-k udf).
 #'
 #' @param x a data.frame or tpu_table
 #' @param user_col indexed user id column

@@ -293,3 +293,87 @@ def test_a_kernel_is_lowered_once_a_shape_not_once_a_layer(
 
     assert pallas_bodies(1) == bodies
     assert pallas_bodies(3) == bodies
+
+
+def _sar_shapes(one_chip, users=69878, items=10677):
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return (spec((users, items)), spec((items, items)),
+            spec((users, items), jnp.bool_), spec((), jnp.int32))
+
+
+@pytest.mark.parametrize("rows", [
+    3328,       # every block of `sar_recommend_all`'s pass, the last too
+    246])       # a table of fewer users than a block: its one short block
+def test_sar_selection_kernel_compiles_and_takes_the_top_k_call(
+        one_chip, monkeypatch, rows):
+    """(rows, 10677) float32 scores, k = 10: the selection alone compiles
+    inside the default scoped VMEM (no limit is asked for) under its name,
+    and the whole `_block_topk_unseen` program, rows cut, product, mask
+    and selection, holds the kernel and no `TopK` custom call."""
+    from mmlspark_tpu.recommendation import sar, topk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shapes = _sar_shapes(one_chip)
+    items = shapes[1].shape[0]
+    assert topk.kernel_takes(rows, items, 10, jnp.float32)
+    alone = _compile(lambda s: topk.top_k_rows(s, 10),
+                     jax.ShapeDtypeStruct((rows, items), jnp.float32,
+                                          sharding=one_chip)).as_text()
+    assert "tpu_custom_call" in alone and "sar_topk_k10" in alone
+    try:
+        text = _compile(
+            lambda a, s, seen, start: sar._block_topk_unseen(
+                a, s, seen, start, rows, 10), *shapes).as_text()
+    finally:
+        sar._block_topk_unseen.clear_cache()
+    assert "sar_topk_k10" in text
+    assert 'custom_call_target="TopK"' not in text
+    # the kernel reads the scores where the product, still ending in the
+    # mask, writes them, columns-major: nothing lays them out again
+    entry = text[text.index("ENTRY"):]
+    assert re.search(rf"f32\[{items},{rows}\]\S* fusion\(.*kind=kOutput",
+                     entry)
+    assert not re.search(rf"\[({rows},{items}|{items},{rows})\]\S* "
+                         r"(copy|transpose)\(", entry)
+
+
+def test_a_pass_lowers_one_selection_body_its_last_block_included(
+        one_chip, monkeypatch):
+    """69,878 users under 4096 a block: `recommend_for_all_users` asks the
+    block program for ONE shape, 21 blocks of 3328 rows (17 of 4096 would
+    leave 246: a second program; 18 of 4096 compute 3,850 rows twice), the
+    last block's cut starting ten rows early, and the lowered text of a
+    program that holds a block and that last one holds ONE `sar_topk_k10`
+    body, the call being jitted by itself. Traced and lowered only:
+    nothing is compiled."""
+    from mmlspark_tpu.recommendation import SARModel, sar
+
+    affinity, similarity, seen, start = _sar_shapes(one_chip)
+    asked = []
+
+    def recorded(a, s, seen_, start, rows, k):
+        asked.append((int(start), rows, k))
+        return jnp.zeros((rows, k)), jnp.zeros((rows, k), jnp.int32)
+
+    model = SARModel()
+    model.user_affinity = affinity          # the loop reads its shape
+    model._device_cache = {"affinity": affinity, "similarity": similarity,
+                           "seen": seen}
+    monkeypatch.setattr(sar, "_block_topk_unseen", recorded)
+    model.recommend_for_all_users(10)
+    assert len(asked) == 21 and {a[1:] for a in asked} == {(3328, 10)}
+    assert [a[0] for a in asked[-2:]] == [19 * 3328, 69878 - 3328]
+    monkeypatch.undo()
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        text = jax.jit(lambda a, s, seen_, whole, last: (
+            sar._block_topk_unseen(a, s, seen_, whole, 3328, 10),
+            sar._block_topk_unseen(a, s, seen_, last, 3328, 10))
+        ).lower(affinity, similarity, seen, start, start).as_text()
+    finally:
+        sar._block_topk_unseen.clear_cache()
+    assert text.count("tpu_custom_call") == 1
+    assert "sar_topk_k10" in text

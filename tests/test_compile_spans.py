@@ -439,3 +439,36 @@ def test_fit_leaves_traces_under_its_epochs(tracer):
     # traces them again; printed, not asserted (S3 will change it)
     print(f"first fit under trainer.epoch: {dict(first)}; "
           f"second: {dict(fit())}")
+
+
+# -- SAR: one shape a pass ------------------------------------------------ #
+
+@pytest.mark.parametrize("remove_seen,program", [
+    (True, "_block_topk_unseen"), (False, "_block_topk")])
+def test_a_pass_over_17_blocks_and_a_tail_traces_the_block_program_once(
+        tracer, remove_seen, program):
+    """141 users in blocks of 8 are 17 whole blocks and 5 rows, as
+    `sar_recommend_all`'s 69,878 are 17 of 4096 and 246: the last block is
+    cut whole too, so the pass leaves ONE trace, lowering and compile of
+    the block program, under its first `sar.dispatch`, and a second pass
+    none."""
+    from mmlspark_tpu.recommendation import SARModel, sar
+
+    rng = np.random.default_rng(3)
+    model = SARModel()
+    model.user_affinity = rng.random((141, 20)).astype(np.float32)
+    model.item_similarity = rng.random((20, 20)).astype(np.float32)
+    model.seen = rng.random((141, 20)) < 0.3
+    getattr(sar, program).clear_cache()
+    model.recommend_for_all_users(5, remove_seen=remove_seen, user_block=8)
+    ours = {name: [s for s in named(tracer, name)
+                   if program in s.args["fun_name"]]
+            for name in JAX_NAMES}
+    assert [len(ours[name]) for name in JAX_NAMES] == [1, 1, 1]
+    first = named(tracer, "sar.dispatch")[0]
+    assert all(s.parent is first for spans in ours.values() for s in spans)
+    assert len(named(tracer, "sar.dispatch")) == 18
+    spans_so_far = len(tracer.spans())
+    model.recommend_for_all_users(5, remove_seen=remove_seen, user_block=8)
+    assert not [s for s in tracer.spans()[spans_so_far:]
+                if s.name in JAX_NAMES]

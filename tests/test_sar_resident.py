@@ -26,7 +26,7 @@ from mmlspark_tpu.io_http.gateway import ServingGateway
 from mmlspark_tpu.io_http.schema import HTTPRequestData
 from mmlspark_tpu.io_http.serving import ServingFleet, serve_model
 from mmlspark_tpu.recommendation import SAR, serve_recommender
-from mmlspark_tpu.recommendation.resident import SARHotPath
+from mmlspark_tpu.recommendation.serving import SARHotPath
 
 K = 10
 
@@ -467,7 +467,7 @@ def _mixed_fleet_factory():
     from mmlspark_tpu.gbdt.estimators import GBDTRegressor
     from mmlspark_tpu.io_http.schema import make_reply, parse_request
     from mmlspark_tpu.recommendation import SAR, SARTopKScorer
-    from mmlspark_tpu.recommendation.resident import topk_reply
+    from mmlspark_tpu.recommendation.serving import topk_reply
 
     rng = np.random.default_rng(7)
     X = rng.normal(size=(128, 4)).astype(np.float32).astype(np.float64)

@@ -132,6 +132,7 @@ def test_every_call_is_its_own_trace(model, tracer):
     (37, 8, True, False, 5),     # and by a model that holds no seen mask
     (37, 10, True, True, 50),    # k clipped to the number of items
     (37, 1, True, True, 3),      # a block a user: 36 enqueued ahead
+    (700, 600, True, True, 5),   # three blocks of 344 where 600 may be
 ])
 def test_pipelined_blocks_return_the_one_block_table(tracer, users, block,
                                                      remove_seen, with_seen,
@@ -173,3 +174,17 @@ def test_users_short_of_unseen_items_are_marked(block):
     assert (items[~short] >= 0).all() and (ratings[~short] > 0).all()
     rows = np.nonzero(~short)[0]
     assert not m.seen[rows, items[~short]].any()
+
+
+@pytest.mark.parametrize("users,block,rows", [
+    (69878, 4096, 3328),    # MovieLens-10M: 21 equal blocks, ten rows twice
+    (8192, 4096, 4096),     # users that divide: the block given
+    (8193, 4096, 2816),     # one user more: three blocks, 255 rows twice
+    (37, 8, 8),             # a block under 256 rows has one size to take
+    (37, 37, 37), (5, 4096, 5)])        # fewer users than a block: one
+def test_every_block_of_a_pass_takes_the_rows_that_waste_fewest(users, block,
+                                                                rows):
+    from mmlspark_tpu.recommendation.sar import _block_rows
+
+    assert _block_rows(users, block) == rows
+    assert rows <= block and -(-users // rows) * rows >= users
