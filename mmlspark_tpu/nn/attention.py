@@ -30,7 +30,11 @@ j // group), and none repeats K or V to do it:
 A fourth core, ``eva_attention``, reads TWO sets of keys in one softmax
 (EVA, arXiv 2302.04542): the keys of the query's own window, exactly and
 causally, and one pooled key and value (``eva_summaries``) for every chunk
-of the windows before it; the same three tiers behind one switch.
+of the windows before it; the same three tiers behind one switch. A fifth,
+``causal_attention(window=...)``, is a window that SLIDES with the query
+(query t reads keys t - window + 1 .. t): the plain flash fold over the key
+blocks a query block's band touches, both edges masked in the kernel; the
+same three tiers, the chunked one its backward.
 
 The chunked and flash tiers compute scores and the softmax accumulator in
 float32 whatever the input dtype (bf16 inputs stay bf16 through the
@@ -55,9 +59,10 @@ from ..parallel.ring_attention import (dense_attention, key_head_group,
                                        over_key_heads)
 
 __all__ = ["dense_attention", "chunked_attention", "flash_attention",
-           "flash_tiles", "causal_attention", "latent_attention",
+           "flash_tiles", "causal_attention", "band_tiles", "band_tile_pairs",
+           "latent_attention",
            "eva_summaries", "eva_attention", "rotary_in_lanes",
-           "rotary_lanes_whole", "HeadsDense", "SelfAttention"]
+           "rotary_lanes_whole", "HeadsDense", "HeadsOut", "SelfAttention"]
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/max NaN-free
 
@@ -365,35 +370,54 @@ def flash_tiles(tq: int, tk: int, dtype,
     return tile(tq), tile(tk)
 
 
+def _band_first(qi, block_q: int, block_k: int, window: int):
+    """The first key block that query block `qi` of a band reads: the one
+    holding the key `window - 1` behind the block's first query, or 0."""
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
 def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
-                block_k, num_kv, causal, tk_valid, scale):
+                block_k, num_kv, causal, tk_valid, scale, window=None,
+                key_blocks=None):
     """What every flash forward does with a score tile, over a grid of
     (row, head, query block, key block): `products()` is this step's raw
     (bq, bk) float32 products of queries and keys (over a head's channels,
     or over the latent score's two parts); the masks, the online softmax,
-    the block skips and the finalisation are here."""
+    the block skips and the finalisation are here. Told a `window` (a
+    causal band: a query reads the `window` keys that end with its own),
+    the grid's last axis is the `num_kv` blocks a query block's band can
+    touch, counted from `_band_first` (of `key_blocks` in all), and the
+    block that the band's trailing edge crosses is masked like the
+    diagonal's."""
     import jax.experimental.pallas as pl
 
     qi = pl.program_id(2)
-    kv = pl.program_id(3)
+    at = kv = pl.program_id(3)                  # the step, and its key block
+    if window is not None:
+        kv = _band_first(qi, block_q, block_k, window) + at
     # only a padded sequence needs the key mask: decided here, in Python
-    padded = tk_valid < num_kv * block_k
+    padded = tk_valid < (num_kv if window is None else key_blocks) * block_k
 
-    def scores(mask_keys: bool, mask_causal: bool):
+    def scores(mask_keys: bool, mask_causal: bool, mask_trailing=False):
         """This step's (bq, bk) score tile, and which of it counts (None:
         all of it). `mask_keys`: keys at or past `tk_valid` are padding;
-        `mask_causal`: a query sees the keys at or before it."""
+        `mask_causal`: a query sees the keys at or before it;
+        `mask_trailing`: and none `window` or more behind it."""
         s = products() * scale                                # (bq, bk)
         ok = None
-        if mask_keys or mask_causal:
+        if mask_keys or mask_causal or mask_trailing:
             kpos = kv * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
         if mask_keys:
             ok = kpos < tk_valid
-        if mask_causal:
+        if mask_causal or mask_trailing:
             qpos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
+        if mask_causal:
             ok = (qpos >= kpos) if ok is None else ok & (qpos >= kpos)
+        if mask_trailing:
+            near = qpos - kpos < window
+            ok = near if ok is None else ok & near
         if ok is not None:
             s = jnp.where(ok, s, _NEG_INF)
         return s, ok
@@ -424,23 +448,23 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
         # the softmax is whole in this tile: no running maximum, no
         # correction, no accumulator through scratch (the same numbers,
         # bit for bit, as one step of the path below)
-        s, ok = scores(padded, causal)
+        s, ok = scores(padded, causal, window is not None)
         m = s.max(-1, keepdims=True)
         write(m, *weigh(s, ok, m))
         return
 
     m_sc, l_sc, acc_sc = scratch
 
-    @pl.when(kv == 0)
+    @pl.when(at == 0)
     def _init():
         m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def step(mask_keys: bool, mask_causal: bool):
+    def step(mask_keys: bool, mask_causal: bool, mask_trailing=False):
         """One key block folded into the running max / denominator /
         accumulator."""
-        s, ok = scores(mask_keys, mask_causal)
+        s, ok = scores(mask_keys, mask_causal, mask_trailing)
         m_prev = m_sc[...]                                    # (bq, 1)
         m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
         l, pv = weigh(s, ok, m_new)
@@ -459,15 +483,30 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
         needed = kv * block_k <= qi * block_q + block_q - 1
         crosses = (kv + 1) * block_k - 1 > qi * block_q
 
-        @pl.when(needed & crosses)
-        def _diagonal():
-            step(padded, True)
+        if window is None:
+            @pl.when(needed & crosses)
+            def _diagonal():
+                step(padded, True)
 
-        @pl.when(needed & jnp.logical_not(crosses))
-        def _below():
-            step(padded, False)
+            @pl.when(needed & jnp.logical_not(crosses))
+            def _below():
+                step(padded, False)
+        else:
+            # the band's other edge: some query of the block lies `window`
+            # or more past some key of this one (blocks wholly behind the
+            # band are never reached: the axis starts at `_band_first`)
+            trails = qi * block_q + block_q - 1 - kv * block_k >= window
+            needed = needed & (kv < key_blocks)
+            for diagonal in (True, False):
+                for trailing in (True, False):
+                    pl.when(needed
+                            & (crosses if diagonal
+                               else jnp.logical_not(crosses))
+                            & (trails if trailing
+                               else jnp.logical_not(trails)))(
+                        functools.partial(step, padded, diagonal, trailing))
 
-    @pl.when(kv == num_kv - 1)
+    @pl.when(at == num_kv - 1)
     def _finalize():
         write(m_sc[...], l_sc[...], acc_sc[...])
 
@@ -503,8 +542,18 @@ def _latent_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
     _flash_fold(products, v_ref, o_ref, lse_ref, scratch, **static)
 
 
+def _band_steps(tq: int, block_q: int, block_k: int, window: int) -> int:
+    """The key blocks the widest band of a query block touches: the extent
+    of a banded forward's last grid axis (`window // block_k + 1` where the
+    tiles are equal and divide the window)."""
+    return max((qi * block_q + block_q - 1) // block_k
+               - max(qi * block_q - (window - 1), 0) // block_k + 1
+               for qi in range(-(-tq // block_q)))
+
+
 def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
-                tk, causal, scale, block_q, block_k, interpret, name=None):
+                tk, causal, scale, block_q, block_k, interpret, name=None,
+                window=None):
     """ONE Pallas forward over a grid of (row, head, query block, key
     block). `queries`, `keys` and `value` are (array, block width, at):
     `at(row, head, block along the sequence)` names the (1, positions,
@@ -513,21 +562,34 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
     an array of `out_shape` (as wide a block as the value's). -> (out in
     that shape, lse (B x H, Tq, 1) float32); `tk` is the keys' length
     before padding. `name` is the call's own in a device trace; without
-    one the innermost `jax.named_scope` around it names it."""
+    one the innermost `jax.named_scope` around it names it. With a
+    `window` (causal) the last axis is a query block's band, `_band_steps`
+    key blocks from `_band_first` on: a block wholly behind the band is
+    never named, one above the diagonal re-names the diagonal's."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
     keys = [*keys, value]
     dv = value[1]
     nq = queries[0][0].shape[1] // block_q
-    nk = keys[0][0].shape[1] // block_k
+    nk = steps = keys[0][0].shape[1] // block_k
+    band = {}
+    if window is not None:
+        steps = _band_steps(nq * block_q, block_q, block_k, window)
+        band = {"window": window, "key_blocks": nk}
 
     def query_spec(width, at):
         return pl.BlockSpec((1, block_q, width),
                             lambda b_, j, qi, kv: at(b_, j, qi))
 
     def key_spec(width, at):
-        if causal:
+        if window is not None:
+            def index(b_, j, qi, kv):
+                last = (qi * block_q + block_q - 1) // block_k
+                return at(b_, j, jnp.minimum(
+                    _band_first(qi, block_q, block_k, window) + kv,
+                    jnp.minimum(last, nk - 1)))
+        elif causal:
             # a key block above the diagonal is never computed on: name
             # the last block this query block needs instead, which is
             # already in VMEM, so that no copy is issued for the skipped
@@ -542,9 +604,9 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
 
     return pl.pallas_call(
         functools.partial(
-            kernel, block_q=block_q, block_k=block_k, num_kv=nk,
-            causal=causal, tk_valid=tk, scale=scale),
-        grid=(b, h, nq, nk),
+            kernel, block_q=block_q, block_k=block_k, num_kv=steps,
+            causal=causal, tk_valid=tk, scale=scale, **band),
+        grid=(b, h, nq, steps),
         in_specs=[query_spec(w, at) for _x, w, at in queries]
         + [key_spec(w, at) for _x, w, at in keys],
         out_specs=[
@@ -560,7 +622,7 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
             jax.ShapeDtypeStruct((b * h, nq * block_q, 1), jnp.float32),
         ],
         # one key block carries nothing from step to step
-        scratch_shapes=[] if nk == 1 else [
+        scratch_shapes=[] if steps == 1 else [
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
@@ -569,7 +631,8 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
     )(*(x for x, _w, _at in [*queries, *keys]))
 
 
-def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
+def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret,
+                   window=None, name=None):
     """Pallas forward at the given tile (a multiple of what Mosaic tiles,
     or the whole length); q, k (B, T, H, D), v (B, T, H, Dv) in, returns
     (out (B,Tq,H,Dv), lse (B,H,Tq) f32). The values may have a width of
@@ -584,7 +647,7 @@ def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
     reshapes around the call move nothing. At any other width (64, 192, a
     test's 8) q, k and v are copied head-major to (B x H, T, D) first and
     the output is copied back. A block holds the same values in the same
-    order either way."""
+    order either way. `window`, `name`: `_flash_call`'s."""
     b, _, h, d = q.shape
     hk, dv = k.shape[2], v.shape[-1]
     group = key_head_group(q, k, v)
@@ -605,7 +668,7 @@ def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret):
         jax.ShapeDtypeStruct(qf.shape[:-1] + (qf.shape[-1] // d * dv,),
                              q.dtype),
         b=b, h=h, tk=tk, causal=causal, scale=d ** -0.5, block_q=block_q,
-        block_k=block_k, interpret=interpret)
+        block_k=block_k, interpret=interpret, name=name, window=window)
     out = _heads(out[:, :tq], b, h, in_place)
     lse = lse.reshape(b, h, -1)[:, :, :tq]     # (B, H, Tq)
     return out, lse
@@ -753,10 +816,132 @@ def flash_attention(q, k, v, causal: bool = False,
                        block_k if bwd_chunk is None else bwd_chunk, interpret)
 
 
-def causal_attention(q, k, v, impl: str = "flash", **flash_options):
-    """Plain causal attention by the tier's name: "flash" (None: the
-    backward scans the keys a forward tile at a time; `flash_options` are
-    that tier's, for tests), "chunked" or "dense" (in the queries' type)."""
+def _banded_dense(q, k, v, window: int):
+    """One masked softmax over all keys (u <= t and t - u < window), in the
+    queries' type like `dense_attention`: tests, short rows."""
+    if q.shape[2] != k.shape[2]:
+        return over_key_heads(lambda q, k, v: _banded_dense(q, k, v, window),
+                              q, k, v)
+    pos = jnp.arange(q.shape[1])
+    behind = pos[:, None] - pos[None, :]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    p = jax.nn.softmax(
+        jnp.where((behind >= 0) & (behind < window), s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def _banded_chunked(q, k, v, window: int, q_chunk: int = 128):
+    """XLA, a block of queries against the keys of its band only (the
+    `window + q_chunk - 1` that end with the block's last query), float32
+    scores, never a (T, T) array. Runs on every backend (the CPU's path)
+    and is differentiable: the sliding window's backward is this tier's."""
+    b, t, h, d = q.shape
+    hk, group, f32 = k.shape[2], key_head_group(q, k, v), jnp.float32
+    q_chunk = min(q_chunk, t)
+    q, _ = _pad_seq(q, q_chunk)
+    k, _ = _pad_seq(k, q_chunk)
+    v, _ = _pad_seq(v, q_chunk)
+    padded = q.shape[1]
+    span = min(window - 1 + q_chunk, padded)
+    # query head j reads key/value head j // group: (.., hk, group, d)
+    q = q.reshape(b, padded, hk, group, d)
+
+    def some_queries(first):
+        start = jnp.clip(first + q_chunk - span, 0, padded - span)
+        qpos = (first + jnp.arange(q_chunk))[:, None]
+        kpos = (start + jnp.arange(span))[None, :]
+        ok = (qpos >= kpos) & (qpos - kpos < window)
+        kb, vb = (jax.lax.dynamic_slice_in_dim(x, start, span, 1)
+                  for x in (k, v))
+        s = jnp.einsum(
+            "bqhgd,bkhd->bhgqk",
+            jax.lax.dynamic_slice_in_dim(q, first, q_chunk, 1), kb,
+            preferred_element_type=f32) * d ** -0.5
+        # every query sees itself, so no row is empty
+        p = jax.nn.softmax(jnp.where(ok, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhgqk,bkhd->bqhgd", p, vb.astype(f32),
+                          preferred_element_type=f32)
+
+    out = jax.lax.map(some_queries, jnp.arange(0, padded, q_chunk))
+    return jnp.moveaxis(out, 0, 1).reshape(b, padded, h, v.shape[-1])[
+        :, :t].astype(q.dtype)
+
+
+# The banded Pallas forward, jitted by itself as `_eva_flash` is: traced and
+# lowered once a shape, not once a layer, so the call is named by its window
+# (`swa_attn_w4096`; a device trace's readers select `swa_attn_*`)
+@functools.partial(jax.jit, static_argnames=("window", "block_q", "block_k",
+                                             "interpret"))
+def _banded_flash(q, k, v, *, window, block_q, block_k, interpret=False):
+    return _flash_fwd_lse(q, k, v, True, block_q, block_k, interpret,
+                          window=window, name=f"swa_attn_w{window}")[0]
+
+
+def band_tile_pairs(t: int, window: int, block_q: int, block_k: int):
+    """-> (visited, needed) for one head of one row of `t` positions: the
+    (query block, key block) pairs the banded forward's grid computes on
+    at that tile, and the band's own (query, key) pairs in tiles of that
+    size. Their ratio is what the tiles' edges cost."""
+    visited = sum(
+        min((q0 + block_q - 1) // block_k, (t - 1) // block_k)
+        - max(q0 - (window - 1), 0) // block_k + 1
+        for q0 in range(0, t, block_q))
+    inside = min(window, t)
+    needed = inside * (inside + 1) / 2 + (t - inside) * window
+    return visited, needed / (block_q * block_k)
+
+
+def band_tiles(t: int, window: int, dtype):
+    """The banded forward's (block_q, block_k): `flash_tiles` told the
+    window (the largest equal tiles that divide it: 1024 x 1024 of 4096,
+    so a band is `window // block_k + 1` key blocks a query block); a
+    window no multiple of 128 divides takes the lengths' own tiles, the
+    kernel masks both edges wherever they fall."""
+    if window % 128 == 0:
+        return flash_tiles(t, t, dtype, window=window)
+    return flash_tiles(t, t, dtype)
+
+
+def causal_attention(q, k, v, impl: str = "flash", window: int | None = None,
+                     **flash_options):
+    """Causal attention by the tier's name: "flash" (None: the backward
+    scans the keys a forward tile at a time; `flash_options` are that
+    tier's, for tests), "chunked" or "dense" (in the queries' type).
+
+    With a `window` the band SLIDES with the query: query t reads keys
+    t - window + 1 .. t (its own position counts). "dense": one masked
+    softmax; "chunked": a block of queries against the keys of its band
+    (XLA, the CPU's tier, and the only one with a backward: differentiate
+    through it); "flash": the plain forward's fold over the key blocks a
+    query block's band touches (`_flash_fold`: a block wholly outside the
+    band is neither fetched nor computed, the diagonal's and the trailing
+    edge's blocks are masked in the kernel; grouped key heads by index
+    map and heads of whole lanes in place as there), FORWARD ONLY, named
+    `swa_attn_w<window>`. A row no longer than the window is plain causal
+    attention and takes that tier of it. Without a window every call is
+    what it was."""
+    if window is not None and q.shape[1] > window:
+        if impl == "dense":
+            return _banded_dense(q, k, v, window).astype(q.dtype)
+        if impl == "chunked":
+            return _banded_chunked(q, k, v, window)
+        if impl == "flash":
+            t = q.shape[1]
+            rule_q, rule_k = band_tiles(t, window, q.dtype)
+            block_q = min(flash_options.get("block_q") or rule_q, t)
+            block_k = min(flash_options.get("block_k") or rule_k, t)
+            # counted where the call is traced: the kernel is traced once
+            # a shape, this once a layer
+            get_registry().counter(
+                "mmlspark_tpu_attention_window_calls_total",
+                "sliding-window attention forward calls traced, by the "
+                "window and the tile (queries x keys)",
+                labels=("window", "tile")).labels(
+                    window=str(window), tile=f"{block_q}x{block_k}").inc()
+            _count_operands("swa", _lanes_whole(q.shape[-1], v.shape[-1]))
+            return _banded_flash(
+                q, k, v, window=window, block_q=block_q, block_k=block_k,
+                interpret=bool(flash_options.get("interpret", False)))
     if impl == "flash":
         return flash_attention(q, k, v, causal=True, bwd_chunk=None,
                                **flash_options)
@@ -1317,6 +1502,36 @@ class HeadsDense(nn.Module):
             outs.append(out.reshape(x.shape[:-1] + (self.heads, width)))
             first += width
         return tuple(outs) if self.parts else outs[0]
+
+
+class HeadsOut(nn.Module):
+    """`HeadsDense`'s way back: o (.., heads, width) projected to
+    `features` channels. Parameter-compatible with `nn.DenseGeneral(
+    features, axis=(-2, -1))` (kernel (heads, width, features), no bias,
+    its initialiser), and the same sums; but computed as ONE product of
+    (.., heads x width), the array a kernel wrote in place, by the kernel
+    as (heads x width, features). Contracted over two dimensions the TPU's
+    compiler copies that array heads-in-sublanes first: a `copy` of
+    (.., heads x width) in the compiled program, which `tests/
+    test_chipless_compile.py` holds absent (what the copy takes on the
+    chip: PERF.md section 5, PR 40)."""
+
+    features: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, o):
+        heads, width = o.shape[-2:]
+
+        def kernel_init(rng, shape, dtype=jnp.float32):
+            return nn.initializers.lecun_normal()(
+                rng, (heads * width, self.features), dtype).reshape(shape)
+
+        kernel = self.param("kernel", kernel_init,
+                            (heads, width, self.features), jnp.float32)
+        o, kernel = nn.dtypes.promote_dtype(o, kernel, dtype=self.dtype)
+        return jnp.dot(o.reshape(o.shape[:-2] + (heads * width,)),
+                       kernel.reshape(heads * width, self.features))
 
 
 class SelfAttention(nn.Module):

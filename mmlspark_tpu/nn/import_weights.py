@@ -63,6 +63,14 @@ permuted, and an `lm_head.weight` is the embedding written twice.
 nn.models.EvaDecoder. Those checkpoints multiply a norm by 1 + w
 (`norm_add_unit_offset`): 1 is added to every norm's weight at import,
 and the module's scale is what multiplies.
+
+`window_moe_decoder_spec(layer_types)` maps the `smallthinker` checkpoint
+naming (`self_attn.q_proj` .. `o_proj`; `block_sparse_moe.primary_router`
+and `block_sparse_moe.experts.<n>.gate` / `up` / `down`;
+`input_layernorm`, `post_attention_layernorm`, `norm`; `embed_tokens`,
+`lm_head`) onto nn.models.WindowMoEDecoder: a layer's attention under
+`gqa_attn_<i>` (a global layer) or `swa_attn_<i>` (a sliding one), its
+router apart from its experts under `router_<i>`. Nothing is permuted.
 """
 
 from __future__ import annotations
@@ -88,6 +96,9 @@ __all__ = [
     "HYBRID_MOE_DECODER_SPEC",
     "torch_hybrid_moe_decoder_to_flax",
     "import_torch_hybrid_moe_decoder",
+    "window_moe_decoder_spec",
+    "torch_window_moe_decoder_to_flax",
+    "import_torch_window_moe_decoder",
     "import_external_weights",
     "IMPORTERS",
 ]
@@ -771,6 +782,85 @@ def import_torch_eva_decoder(
     return _validate_and_install(bundle, variables, architecture)
 
 
+# --------------------------------------------------------------------- #
+# smallthinker naming -> nn.models.WindowMoEDecoder                      #
+# --------------------------------------------------------------------- #
+
+def window_moe_decoder_spec(layer_types) -> "list[MapRule]":
+    """The rules for a `smallthinker`-named checkpoint whose layers are
+    `layer_types` ("global" / "sliding"): a layer's attention goes to
+    `gqa_attn_<i>` or `swa_attn_<i>` by its kind, its router (the
+    `primary_router`, which reads the attention's input) to `router_<i>`
+    apart from its experts."""
+    kinds = tuple(layer_types)
+
+    def attn(m) -> str:
+        i = int(m["i"])
+        if i >= len(kinds):
+            raise ValueError(f"the checkpoint has a layer {i}; layer_types "
+                             f"names {len(kinds)}")
+        return ("swa_attn_" if kinds[i] == "sliding" else "gqa_attn_") + m["i"]
+
+    moe = _LAYER + r"block_sparse_moe\."
+    return [
+        MapRule(r"model\.embed_tokens\.weight", "params/embed/embedding"),
+        MapRule(_LAYER + r"input_layernorm\.weight",
+                r"params/ln_attn_\g<i>/scale"),
+        MapRule(_LAYER + r"post_attention_layernorm\.weight",
+                r"params/ln_mlp_\g<i>/scale"),
+        MapRule(_LAYER + r"self_attn\.(?P<p>[qkv])_proj\.weight",
+                lambda m: f"params/{attn(m)}/{m['p']}_proj/kernel",
+                _t_heads_kernel),
+        MapRule(_LAYER + r"self_attn\.o_proj\.weight",
+                lambda m: f"params/{attn(m)}/out/kernel",
+                _t_attn_out_kernel),
+        MapRule(moe + r"primary_router\.weight",
+                r"params/router_\g<i>/kernel", _t_transpose),
+        MapRule(moe + r"experts\.(?P<n>\d+)\.(?P<proj>gate|up|down)\.weight",
+                r"params/moe_\g<i>/experts_\g<proj>/\g<n>", _t_transpose),
+        MapRule(r"model\.norm\.weight", "params/ln_final/scale"),
+        MapRule(r"lm_head\.weight", "params/head_kernel", _t_transpose),
+        MapRule(r".*rotary_emb\.inv_freq", None),
+    ]
+
+
+def torch_window_moe_decoder_to_flax(
+    state_dict: Mapping[str, np.ndarray], layer_types, num_heads: int,
+    head_dim: int, experts_held: "tuple[int, int] | None" = None,
+) -> dict[str, Any]:
+    """Map a `smallthinker`-named state dict onto nn.models.
+    WindowMoEDecoder variables (untied head; rotary already in the
+    rotate-half layout; the heads' width is the config's `head_dim`, not
+    the hidden width over the heads). `experts_held` (first index, count)
+    keeps a share of the routed experts (default: all the checkpoint has),
+    while the router keeps every expert's column."""
+    out = apply_mapping_spec(
+        state_dict, window_moe_decoder_spec(layer_types),
+        {"num_heads": int(num_heads), "head_dim": int(head_dim)})
+    return _stack_experts_held(out, experts_held)
+
+
+def import_torch_window_moe_decoder(
+    path: str, architecture: str = "window_moe_decoder",
+    input_shape: tuple[int, ...] = (8,), **config,
+):
+    """Load a `smallthinker`-named checkpoint into a ready-to-serve
+    ModelBundle of the `window_moe_decoder` family. `config` is the
+    module's (`layer_types`, the head counts and width, `experts_held`,
+    ...): the checkpoint's own config.json states them, its shapes do
+    not."""
+    from .models import ModelBundle
+
+    sd = load_state_dict(path)
+    bundle = ModelBundle.init(architecture, input_shape=tuple(input_shape),
+                              seed=0, **config)
+    module = bundle.module
+    variables = torch_window_moe_decoder_to_flax(
+        sd, module.layer_types, module.num_heads, module.head_dim,
+        tuple(module.experts_held))
+    return _validate_and_install(bundle, variables, architecture)
+
+
 # architecture name -> importer; zoo.import_external dispatches here, so
 # registering a new family makes it fetchable/verifiable end to end
 IMPORTERS: "dict[str, Callable]" = {
@@ -781,6 +871,7 @@ IMPORTERS: "dict[str, Callable]" = {
     "mla_moe_decoder": import_torch_mla_moe_decoder,
     "hybrid_moe_decoder": import_torch_hybrid_moe_decoder,
     "eva_decoder": import_torch_eva_decoder,
+    "window_moe_decoder": import_torch_window_moe_decoder,
 }
 
 

@@ -301,20 +301,22 @@ def _tier(impl: str) -> str:
 
 
 def _causal_attention(q, k, v, impl: str, dtype, pooling=None,
-                      window: int = 0, chunk: int = 0):
+                      window: int = 0, chunk: int = 0, band=None):
     """A decoder's attention core: "flash" (the Pallas kernel; chunked on
     the CPU, where Mosaic cannot lower), "chunked" or "dense"; any other
     name is an error that lists these. With a `window`, a query reads its
     own window exactly and the windows before it a summary a `chunk`,
     pooled with `pooling` (phi, mu) (`attention.eva_attention`, which
-    takes plain causal attention for a row of at most one window)."""
+    takes plain causal attention for a row of at most one window). With a
+    `band`, a query reads the `band` keys that end with its own (a window
+    that slides with it: `attention.causal_attention`'s `window`)."""
     from .attention import causal_attention, eva_attention
 
     impl = _tier(impl)
     if window:
         return eva_attention(q, k, v, *pooling, window, chunk,
                              impl=impl).astype(dtype)
-    return causal_attention(q, k, v, impl).astype(dtype)
+    return causal_attention(q, k, v, impl, window=band).astype(dtype)
 
 
 class LatentAttention(nn.Module):
@@ -379,10 +381,19 @@ class LatentAttention(nn.Module):
 class GroupedQueryAttention(nn.Module):
     """Causal attention whose `num_heads` query heads share `num_kv_heads`
     key/value heads (query head j reads head j // group; K and V are never
-    repeated: `nn/attention.py`), with an RMSNorm over the channels of
-    every query and of every key head before the rotary positions (one
-    scale vector for all query heads, one for all key heads), rotary over
-    the whole head, scores over sqrt(head width). No biases."""
+    repeated: `nn/attention.py`), scores over sqrt(head width). No biases.
+    A head is `head_dim` channels wide (None: the input's width over
+    `num_heads`; with a width of its own the projections need not be
+    square: 28 heads of 128 on an input of 2560). `qk_norm`: an RMSNorm
+    over the channels of every query and of every key head before the
+    rotary positions (one scale vector for all query heads, one for all
+    key heads). `rotary`: rotary positions over the whole head (False: the
+    layer has no positional encoding). `window`: a query reads the keys
+    `window - 1` behind it to its own and none further (None: every key at
+    or before it). Heads of whole lanes (128 channels) are projected by
+    `HeadsDense`, rotated where they lie on the "flash" tier, read by the
+    kernel in place and projected back by `HeadsOut`; any other width
+    keeps the path it had."""
 
     num_heads: int
     num_kv_heads: int
@@ -390,31 +401,52 @@ class GroupedQueryAttention(nn.Module):
     eps: float = 1e-5
     impl: str = "flash"
     dtype: Any = jnp.float32
+    head_dim: int | None = None
+    qk_norm: bool = True
+    rotary: bool = True
+    window: int | None = None
 
     @nn.compact
     def __call__(self, y):
+        from .attention import HeadsDense, HeadsOut
+
         dt, d = self.dtype, y.shape[-1]
-        if d % self.num_heads or self.num_heads % self.num_kv_heads:
+        if self.num_heads % self.num_kv_heads or (
+                self.head_dim is None and d % self.num_heads):
             raise ValueError(
                 f"{self.num_heads} query heads over {self.num_kv_heads} "
                 f"key/value heads do not divide a width of {d}")
-        width = d // self.num_heads
+        width = self.head_dim or d // self.num_heads
+        lanes = width % 128 == 0
 
         def heads(n, name):
+            if lanes:
+                return HeadsDense(n, width, use_bias=False, dtype=dt,
+                                  name=name)(y)
             return nn.DenseGeneral((n, width), use_bias=False, dtype=dt,
                                    name=name)(y)
 
+        def placed(x, name):
+            if self.qk_norm:
+                x = RMSNorm(self.eps, dt, name=name)(x)
+            if self.rotary:
+                x = _rotary(x, self.rope_theta,
+                            self.impl if lanes else "dense")
+            return x
+
         with jax.named_scope("gqa.project"):
-            q = _rotary(RMSNorm(self.eps, dt, name="q_norm")(
-                heads(self.num_heads, "q_proj")), self.rope_theta)
-            k = _rotary(RMSNorm(self.eps, dt, name="k_norm")(
-                heads(self.num_kv_heads, "k_proj")), self.rope_theta)
+            q = placed(heads(self.num_heads, "q_proj"), "q_norm")
+            k = placed(heads(self.num_kv_heads, "k_proj"), "k_norm")
             v = heads(self.num_kv_heads, "v_proj")
-        # the innermost scope names the Pallas call in a device trace
+        # the innermost scope names the plain Pallas call in a device trace;
+        # the banded forward names itself (`swa_attn_w<window>`: lowered
+        # once a shape, so no layer's name is in it)
         with jax.named_scope("gqa.attend"), jax.named_scope(
                 self.name or "gqa_attn"):
-            o = _causal_attention(q, k, v, self.impl, dt)
+            o = _causal_attention(q, k, v, self.impl, dt, band=self.window)
         with jax.named_scope("gqa.project"):
+            if lanes:
+                return HeadsOut(d, dtype=dt, name="out")(o)
             return nn.DenseGeneral(d, axis=(-2, -1), use_bias=False,
                                    dtype=dt, name="out")(o)
 
@@ -517,11 +549,39 @@ class GatedFFN(nn.Module):
         return dense(y.shape[-1], name="down")(hidden)
 
 
+class Router(nn.Module):
+    """A router apart from its experts: scores over all
+    `n_routed_experts` from the input it is GIVEN, which need not be the
+    experts' (a model that routes before its attention hands it the
+    attention's normed input, so that an expert's weights can be fetched
+    while the attention computes). No selection bias, no scaling factor,
+    the weights over the picks' own sum. -> (picked (T, k) int32, weights
+    (T, k) float32) of `parallel.moe.route_top_k`, which an `ExpertLayer`
+    takes as `routed`."""
+
+    n_routed_experts: int
+    top_k: int
+    scoring: str
+
+    @nn.compact
+    def __call__(self, a):
+        from ..parallel.moe import route_top_k
+
+        d = a.shape[-1]
+        kernel = self.param("kernel", nn.initializers.normal(d ** -0.5),
+                            (d, self.n_routed_experts), jnp.float32)
+        return route_top_k(a.reshape(-1, d), kernel, None, self.top_k,
+                           scoring=self.scoring)
+
+
 class ExpertLayer(nn.Module):
     """Routed experts (the `experts_held` of `n_routed_experts`, top-k,
     dropless: `parallel.moe.moe_ffn_dropless`) plus the shared feed-forward
     every token takes, where the model has one (`n_shared_experts` 0
-    builds none). -> (output, picks (held,) int32)."""
+    builds none). The router reads the layer's own input, scored by
+    `scoring`, unless the picks come with the call (`routed`, a `Router`'s
+    output: the layer then holds no router of its own). `activation` is the
+    gate's in the routed experts. -> (output, picks (held,) int32)."""
 
     n_routed_experts: int
     experts_held: tuple
@@ -532,9 +592,11 @@ class ExpertLayer(nn.Module):
     normalise: bool = True
     dtype: Any = jnp.float32
     epsilon: float = 1e-20          # added to the sum the weights are over
+    scoring: str = "sigmoid"
+    activation: str = "silu"
 
     @nn.compact
-    def __call__(self, y):
+    def __call__(self, y, routed=None):
         from ..parallel.moe import moe_ffn_dropless
 
         d, w, held = y.shape[-1], self.width, int(self.experts_held[1])
@@ -544,23 +606,30 @@ class ExpertLayer(nn.Module):
                               shape, jnp.float32)
 
         flat = y.reshape(-1, d)
-        routed, picks = moe_ffn_dropless(
-            flat, kernel("router_kernel", (d, self.n_routed_experts), d),
+        router = bias = None
+        if routed is None:
+            router = kernel("router_kernel", (d, self.n_routed_experts), d)
             # the selection bias (a checkpoint's `e_score_correction_bias`)
-            self.param("router_bias", nn.initializers.zeros,
-                       (self.n_routed_experts,), jnp.float32),
+            bias = self.param("router_bias", nn.initializers.zeros,
+                              (self.n_routed_experts,), jnp.float32)
+        out, picks = moe_ffn_dropless(
+            flat, router, bias,
             kernel("experts_gate", (held, d, w), d),
             kernel("experts_up", (held, d, w), d),
             kernel("experts_down", (held, w, d), w),
             n_routed_experts=self.n_routed_experts,
             experts_held=tuple(self.experts_held), top_k=self.top_k,
             scaling=self.scaling, normalise=self.normalise,
-            epsilon=self.epsilon, dtype=self.dtype)
+            epsilon=self.epsilon, dtype=self.dtype, scoring=self.scoring,
+            activation=self.activation, routed=routed)
         if self.n_shared_experts:
+            if self.activation != "silu":
+                raise ValueError("the shared feed-forward is gated by silu; "
+                                 f"the experts by {self.activation!r}")
             with jax.named_scope("moe.shared"):
-                routed = routed + GatedFFN(self.n_shared_experts * w,
-                                           self.dtype, name="shared")(flat)
-        return routed.reshape(y.shape), picks
+                out = out + GatedFFN(self.n_shared_experts * w, self.dtype,
+                                     name="shared")(flat)
+        return out.reshape(y.shape), picks
 
 
 class _ScoringDecoder(nn.Module):
@@ -603,6 +672,12 @@ class _ScoringDecoder(nn.Module):
     route_epsilon = 1e-20           # added to the sum of a token's weights
     tie_embeddings = False          # the head is the embedding, transposed
     num_pred_heads = 1              # predictions a position the head makes
+    router_scoring = "sigmoid"      # or "softmax" (`route_top_k`)
+    expert_activation = "silu"      # the gate's in the routed experts
+    # what a layer's router reads: "experts" (the experts' own input) or
+    # "operator" (the operator's normed input: the picks are made before
+    # the attention runs, by a `Router` named `router_<i>`)
+    router_input = "experts"
 
     @property
     def batch_counters(self) -> tuple:
@@ -652,9 +727,16 @@ class _ScoringDecoder(nn.Module):
                          name="embed")
         h = embed(ids)
         picks = []
+        early = self.router_input == "operator"
         for i in range(self.num_layers):
             before, operator = self._operator(i)
-            h = h + operator(norm(name=before)(h))
+            a = norm(name=before)(h)
+            routed = None
+            if early and i >= self._dense_layers:
+                routed = Router(
+                    self.n_routed_experts, self.num_experts_per_tok,
+                    self.router_scoring, name=f"router_{i}")(a)
+            h = h + operator(a)
             y = norm(name=f"ln_mlp_{i}")(h)
             if i < self._dense_layers:
                 h = h + GatedFFN(self.d_ff_dense, dt, name=f"mlp_{i}")(y)
@@ -664,7 +746,8 @@ class _ScoringDecoder(nn.Module):
                     self.num_experts_per_tok, self.d_ff_expert,
                     self.n_shared_experts, self.routed_scaling_factor,
                     self.norm_topk_prob, dt, self.route_epsilon,
-                    name=f"moe_{i}")(y)
+                    self.router_scoring, self.expert_activation,
+                    name=f"moe_{i}")(y, routed)
                 h = h + out
                 picks.append(n)
         h = norm(name="ln_final")(h)
@@ -851,6 +934,96 @@ class EvaDecoder(_ScoringDecoder):
         return self._score(x)
 
 
+class WindowMoEDecoder(_ScoringDecoder):
+    """Causal decoder over token ids whose attention layers are told apart
+    by a list (the SmallThinker block, arXiv 2507.20984): `layer_types[i]`
+    is "global" (every key at or before the query, NO positional encoding)
+    or "sliding" (the `window_size` keys that end with the query's own,
+    rotary positions over the whole head); both `GroupedQueryAttention`
+    with heads of `head_dim` channels, a width of their own, and no norm
+    on a head. Every layer has routed experts gated by ReLU, no shared one
+    and no dense layer; a layer's router reads the ATTENTION's normed input
+    (`router_input`: the picks are made before the attention runs) and
+    weighs its picks by a softmax over their logits; an untied head. The
+    block loop, the head, the outputs and the share of a model a chip may
+    hold are `_ScoringDecoder`'s."""
+
+    layer_types: tuple = ("global", "sliding")
+    d_model: int = 64
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    head_dim: int = 16
+    window_size: int = 4096
+    n_routed_experts: int = 8
+    experts_held: tuple = (0, 8)    # (first index, count)
+    num_experts_per_tok: int = 2
+    d_ff_expert: int = 32
+    n_shared_experts: int = 0
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1500000.0
+    vocab_size: int = 256
+    max_len: int = 16384
+    # "flash": the Pallas kernels (chunked off-TPU), "chunked", "dense"
+    attention_impl: str = "flash"
+    head_chunk: int = 1024          # tokens of one block of the head
+    output: str = "token_logprobs"  # or "logits"
+    dtype: Any = jnp.float32
+
+    # what the family states and no configuration changes
+    router_scoring = "softmax"      # over the picked logits, no bias
+    norm_topk_prob = True           # ... which is over the picks' own sum
+    routed_scaling_factor = 1.0
+    expert_activation = "relu"
+    router_input = "operator"       # the attention's normed input
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def _dense_layers(self) -> int:
+        return 0
+
+    def _operator(self, i: int):
+        kind = self.layer_types[i]
+        if kind not in ("global", "sliding"):
+            raise ValueError(f"unknown layer type {kind!r} at layer {i}: "
+                             "'global' or 'sliding'")
+        sliding = kind == "sliding"
+        # a global layer's plain forward is named by the module, `gqa_attn_
+        # <i>`, as the grouped-query layers of `hybrid_moe_decoder` are; a
+        # sliding layer's is `swa_attn_<i>` where the row fits its window
+        # and the banded forward's own name, `swa_attn_w<window>`, past it
+        return f"ln_attn_{i}", GroupedQueryAttention(
+            self.num_heads, self.num_kv_heads, self.rope_theta,
+            self.rms_norm_eps, self.attention_impl, self.dtype,
+            head_dim=self.head_dim, qk_norm=False, rotary=sliding,
+            window=self.window_size if sliding else None,
+            name=f"swa_attn_{i}" if sliding else f"gqa_attn_{i}")
+
+    def window_tile_pairs(self, rows: int, length: int):
+        """-> (visited, needed): the (query block, key block) pairs the
+        banded forward's grid computes on for a batch of `rows` rows of
+        `length` positions, over every head and sliding layer, and the
+        pairs the band itself holds in tiles of that size; from shapes
+        alone. None where no banded kernel runs: off the "flash" tier, or
+        a row no longer than the window."""
+        from .attention import band_tile_pairs, band_tiles
+
+        if _tier(self.attention_impl) != "flash" or (
+                length <= self.window_size):
+            return None
+        visited, needed = band_tile_pairs(
+            length, self.window_size,
+            *band_tiles(length, self.window_size, self.dtype))
+        calls = rows * self.num_heads * self.layer_types.count("sliding")
+        return visited * calls, needed * calls
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        return self._score(x)
+
+
 def resnet20_cifar(num_outputs: int = 10, dtype=jnp.float32) -> ResNet:
     return ResNet(stage_sizes=(3, 3, 3), num_filters=16,
                   num_outputs=num_outputs, dtype=dtype)
@@ -872,8 +1045,9 @@ def _hashable(config: dict) -> dict:
 # references architectures by name (the reference's ModelSchema carries a
 # remote URI instead, downloader/Schema.scala:30+). Families: `mlp`,
 # `simple_cnn` and the `resnet*` over images or features; over token ids the
-# `transformer` encoder and three causal decoders on one skeleton,
-# `mla_moe_decoder`, `hybrid_moe_decoder` and `eva_decoder`.
+# `transformer` encoder and four causal decoders on one skeleton,
+# `mla_moe_decoder`, `hybrid_moe_decoder`, `eva_decoder` and
+# `window_moe_decoder`.
 ARCHITECTURES: dict[str, Callable[..., nn.Module]] = {
     "mlp": lambda **kw: MLP(**kw),
     "simple_cnn": lambda **kw: SimpleCNN(**kw),
@@ -884,6 +1058,7 @@ ARCHITECTURES: dict[str, Callable[..., nn.Module]] = {
     "mla_moe_decoder": lambda **kw: MLAMoEDecoder(**_hashable(kw)),
     "hybrid_moe_decoder": lambda **kw: HybridMoEDecoder(**_hashable(kw)),
     "eva_decoder": lambda **kw: EvaDecoder(**kw),
+    "window_moe_decoder": lambda **kw: WindowMoEDecoder(**_hashable(kw)),
 }
 
 

@@ -5,9 +5,12 @@ Like pipeline parallelism, MoE is beyond the reference's capability set
 first-class distributed story. Two layers live here.
 
 **The layer a model calls: `moe_ffn_dropless`** (`nn/models.py`'s
-`ExpertLayer`, in both decoder families). Top-k routing over ALL
+`ExpertLayer`, in three decoder families). Top-k routing over ALL
 `n_routed_experts` with sigmoid scores and a selection bias (DeepSeek-V3's
-`noaux_tc`: the bias moves the picks, never the weights), and NO capacity:
+`noaux_tc`: the bias moves the picks, never the weights) or a softmax over
+the picked logits (`route_top_k`), from the layer's own input or from picks
+made earlier (`routed`: a router that reads the attention's input), and NO
+capacity:
 every pick is computed, whatever the skew. The layer is TOLD which experts
 it holds (`experts_held`: first index and count, separately from
 `n_routed_experts`) and returns the part of the result its own experts
@@ -16,8 +19,8 @@ and two grouped products of ours (`_grouped_pallas`: the custom calls
 `ragged-dot-gated` and `ragged-dot-down`) run over them with
 `experts_gate`, `experts_up` and `experts_down` read where the parameters
 keep them. The first multiplies a tile of rows by one expert's gate AND up
-blocks, applies silu(a) * b to the two float32 sums and writes (rows, w)
-once; the second multiplies by the expert's down block and weighs each row
+blocks, applies act(a) * b (silu, or relu where the family says so) to the
+two float32 sums and writes (rows, w) once; the second multiplies by the expert's down block and weighs each row
 by its pick's weight before the one rounding. Their grid is as long as the
 tiles that the picks here touch, and the tiles come from the shapes
 (`grouped_tiles`). On the CPU, for float32 operands and for extents that
@@ -154,25 +157,45 @@ def moe_ffn_sharded(params: MoEParams, x, axis_name: str = EXPERT_AXIS,
 # --------------------------------------------------------------------- #
 
 def route_top_k(x, router, bias, top_k: int, scaling: float = 1.0,
-                normalise: bool = True, epsilon: float = 1e-20):
+                normalise: bool = True, epsilon: float = 1e-20,
+                scoring: str = "sigmoid"):
     """-> (picked (T, k) int32 expert ids, weights (T, k) float32).
 
-    Scores are sigmoid(x @ router) in float32. The `top_k` experts of a
-    token are the best of scores + bias; their weights are the SCORES at
-    those experts (the bias selects, it does not weigh), over their sum
-    (+ `epsilon`, which a checkpoint's family states) if `normalise`, times
-    `scaling`."""
+    `scoring="sigmoid"`: scores are sigmoid(x @ router) in float32. The
+    `top_k` experts of a token are the best of scores + bias; their weights
+    are the SCORES at those experts (the bias selects, it does not weigh),
+    over their sum (+ `epsilon`, which a checkpoint's family states) if
+    `normalise`, times `scaling`.
+
+    `scoring="softmax"`: the scores are the logits x @ router themselves;
+    the picks are the best of logits + bias (None: no selection bias), and
+    the weights a softmax over the PICKED logits (which is the softmax
+    over all experts, the picks kept, over their sum; no epsilon enters),
+    times `scaling`. No model here weighs by the softmax over all experts
+    left as it is, so `normalise=False` is refused."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"unknown router scoring {scoring!r}; have "
+                         "'sigmoid', 'softmax'")
+    if scoring == "softmax" and not normalise:
+        raise ValueError("softmax routing weighs its picks by a softmax "
+                         "over their logits: normalise=False is not built")
     with jax.named_scope("moe.route"):
-        scores = jax.nn.sigmoid(jnp.dot(
-            x, router.astype(x.dtype), preferred_element_type=jnp.float32))
-        _best, picked = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        scores = jnp.dot(x, router.astype(x.dtype),
+                         preferred_element_type=jnp.float32)
+        if scoring == "sigmoid":
+            scores = jax.nn.sigmoid(scores)
+        _best, picked = lax.top_k(
+            scores if bias is None else scores + bias.astype(jnp.float32),
+            top_k)
         # the scores at the picks, as a sum with zeros over the experts'
         # axis (the same bits as a gather, which XLA runs a scalar at a
         # time: 2.1 against 0.2 ms for 8 x 4096 tokens on a v5e)
         weights = jnp.where(
             picked[..., None] == jnp.arange(scores.shape[-1]),
             scores[:, None, :], 0.0).sum(-1)
-        if normalise:
+        if scoring == "softmax":
+            weights = jax.nn.softmax(weights, axis=-1)
+        elif normalise:
             weights = weights / (weights.sum(-1, keepdims=True) + epsilon)
         return picked.astype(jnp.int32), weights * scaling
 
@@ -492,14 +515,20 @@ def _grouped_plan(picks, rows: int, tm: int):
     return group, jnp.clip(tile, 0, -(-rows // tm) - 1), offsets, last[-1]
 
 
+# the gate's activation, by the name a family states
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def _grouped_pallas(xs, weights, plan, *, tm: int, tn: int, name: str,
-                    scale=None, interpret: bool = False):
+                    scale=None, activation: str = "silu",
+                    interpret: bool = False):
     """One grouped product as a Pallas call: `xs` (rows, k) in expert order
     against each expert's own (k, n) matrix of every operand in `weights`
     ((held, k, n) each, read where the parameters keep them). One operand:
-    the product, rounded once. Two: silu(xs @ first) * (xs @ second), both
-    sums and the activation in float32, rounded once. `scale` (rows, 1)
-    float32 multiplies each row before the rounding.
+    the product, rounded once. Two: act(xs @ first) * (xs @ second), both
+    sums and the activation (`activation`: "silu" or "relu") in float32,
+    rounded once. `scale` (rows, 1) float32 multiplies each row before the
+    rounding.
 
     The grid is (n // tn, visits): a step multiplies one tile of `tm` rows
     by one expert's (k, tn) blocks, the whole of k at once (no sum carried
@@ -518,6 +547,7 @@ def _grouped_pallas(xs, weights, plan, *, tm: int, tn: int, name: str,
     rows, k = xs.shape
     n, dtype = weights[0].shape[2], xs.dtype
     exact = lax.Precision.HIGHEST if dtype == jnp.float32 else None
+    act = _ACTIVATIONS[activation]
 
     def kernel(group_ref, tile_ref, offsets_ref, xs_ref, *refs):
         operands, out_ref = refs[:len(weights)], refs[-1]
@@ -527,7 +557,7 @@ def _grouped_pallas(xs, weights, plan, *, tm: int, tn: int, name: str,
         out = jnp.dot(x, operands[0][...], precision=exact,
                       preferred_element_type=jnp.float32)
         if len(operands) == 2:
-            out = jax.nn.silu(out) * jnp.dot(
+            out = act(out) * jnp.dot(
                 x, operands[1][...], precision=exact,
                 preferred_element_type=jnp.float32)
         if scale is not None:
@@ -560,24 +590,27 @@ def _grouped_pallas(xs, weights, plan, *, tm: int, tn: int, name: str,
 
 
 # jitted by itself, like the combine: one trace and lowering a shape
-@functools.partial(jax.jit, static_argnames=("first", "second", "interpret"))
+@functools.partial(jax.jit, static_argnames=("first", "second", "activation",
+                                             "interpret"))
 def _grouped_ffn(xs, gate, up, down, picks, weight_of_row, *, first, second,
-                 interpret: bool = False):
-    """weight_of_row x (silu(xs @ gate[e]) * (xs @ up[e])) @ down[e] over
-    the rows of each expert e. A stage at a tile is the Pallas call, at
-    None `lax.ragged_dot` over the parameters as they lie, rounded where
-    PR 27 rounded (each sum, the activation, the product, the weighing)."""
+                 activation: str = "silu", interpret: bool = False):
+    """weight_of_row x (act(xs @ gate[e]) * (xs @ up[e])) @ down[e] over
+    the rows of each expert e, act the gate's `activation` ("silu" or
+    "relu"). A stage at a tile is the Pallas call, at None `lax.ragged_dot`
+    over the parameters as they lie, rounded where PR 27 rounded (each sum,
+    the activation, the product, the weighing)."""
     rows, dtype = xs.shape[0], xs.dtype
     plans = {tile[0]: _grouped_plan(picks, rows, tile[0])
              for tile in (first, second) if tile}
     if first:
         act = _grouped_pallas(xs, (gate, up), plans[first[0]], tm=first[0],
                               tn=first[1], name="ragged-dot-gated",
-                              interpret=interpret)
+                              activation=activation, interpret=interpret)
     else:
         a, b = (lax.ragged_dot(xs, m, picks, preferred_element_type=dtype)
                 for m in (gate, up))
-        act = (jax.nn.silu(a.astype(jnp.float32)) * b).astype(dtype)
+        act = (_ACTIVATIONS[activation](a.astype(jnp.float32))
+               * b).astype(dtype)
     if second:
         return _grouped_pallas(act, (down,), plans[second[0]], tm=second[0],
                                tn=second[1], name="ragged-dot-down",
@@ -588,7 +621,7 @@ def _grouped_ffn(xs, gate, up, down, picks, weight_of_row, *, first, second,
 
 
 def _experts(xs, gate, up, down, picks, weight_of_row, *, tiles=None,
-             interpret: bool = False):
+             activation: str = "silu", interpret: bool = False):
     """The gated feed-forward of each held expert over its rows of the
     buffer, weighed: xs (rows, d) in expert order, picks (held,) the rows
     of each, weight_of_row (rows,) float32. -> (rows, d); the rows past
@@ -597,6 +630,9 @@ def _experts(xs, gate, up, down, picks, weight_of_row, *, tiles=None,
     On a TPU each product runs at the tile `grouped_tiles` gives it; on the
     CPU (the tests) and where it gives none, `lax.ragged_dot` does
     (`tiles` and `interpret` are a test's way to the kernel)."""
+    if activation not in _ACTIVATIONS:
+        raise ValueError(f"unknown gate activation {activation!r}; have "
+                         f"{sorted(_ACTIVATIONS)}")
     (rows, d), w = xs.shape, gate.shape[2]
     if tiles is None:
         size = xs.dtype.itemsize
@@ -611,22 +647,34 @@ def _experts(xs, gate, up, down, picks, weight_of_row, *, tiles=None,
     for stage, tile in zip(("gated", "down"), tiles):
         calls.labels(kernel="pallas" if tile else "ragged_dot", stage=stage,
                      tile="x".join(map(str, tile)) if tile else "none").inc()
+    get_registry().counter(
+        "mmlspark_tpu_moe_activation_calls_total",
+        "gated feed-forwards of the experts held traced, by the gate's "
+        "activation", labels=("activation",)).labels(
+            activation=activation).inc()
     return _grouped_ffn(xs, gate, up, down, picks, weight_of_row,
-                        first=tiles[0], second=tiles[1], interpret=interpret)
+                        first=tiles[0], second=tiles[1],
+                        activation=activation, interpret=interpret)
 
 
 def moe_ffn_dropless(x, router, bias, gate, up, down, *,
                      n_routed_experts: int, experts_held: tuple,
                      top_k: int, scaling: float = 1.0,
                      normalise: bool = True, epsilon: float = 1e-20,
-                     dtype=jnp.float32):
+                     dtype=jnp.float32, scoring: str = "sigmoid",
+                     activation: str = "silu", routed=None):
     """The routed part of an expert layer that the experts held here give.
 
     x: (T, d). router: (d, n_routed_experts); bias: (n_routed_experts,)
     selection bias. gate, up: (held, d, w); down: (held, w, d): the gated
-    feed-forwards of experts `experts_held[0]` .. `+ experts_held[1]`.
-    Routing is over all `n_routed_experts`; a pick of an expert that lies
-    elsewhere contributes nothing here (its chip adds it).
+    feed-forwards (`activation` on the gate's branch: "silu" or "relu") of
+    experts `experts_held[0]` .. `+ experts_held[1]`. Routing
+    (`route_top_k`, scores by `scoring`) is over all `n_routed_experts`; a
+    pick of an expert that lies elsewhere contributes nothing here (its
+    chip adds it). `routed` (picked (T, k), weights (T, k)): the picks of a
+    router that read ANOTHER input than the experts do, computed where the
+    model computes them (a router before the attention reads the
+    attention's input); `router` and `bias` are then not read.
 
     -> (out (T, d) `dtype`, picks (held,) int32: how many picks each held
     expert received). Dropless: there is no capacity, so a batch whose
@@ -654,8 +702,8 @@ def moe_ffn_dropless(x, router, bias, gate, up, down, *,
     if gate.shape[0] != held:
         raise ValueError(f"experts_held says {held} experts, the weights "
                          f"hold {gate.shape[0]}")
-    picked, weights = route_top_k(x, router, bias, top_k, scaling, normalise,
-                                  epsilon)
+    picked, weights = routed if routed is not None else route_top_k(
+        x, router, bias, top_k, scaling, normalise, epsilon, scoring)
 
     with jax.named_scope("moe.dispatch"):
         local = picked.reshape(-1) - first                    # (T*k,)
@@ -676,7 +724,8 @@ def moe_ffn_dropless(x, router, bias, gate, up, down, *,
             xs = x.astype(dtype)[token_of_row]                 # (rows, d)
         with jax.named_scope("moe.experts"):
             weighed = _experts(xs, gate, up, down, picks,
-                               weight_of_row[:rows])           # (rows, d)
+                               weight_of_row[:rows],
+                               activation=activation)          # (rows, d)
         with jax.named_scope("moe.combine"):
             # rows past the picks that are here hold nothing defined: the
             # Pallas combine counts only the picks' rows, XLA's adds every

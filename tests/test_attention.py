@@ -902,3 +902,214 @@ class TestFlashTilesToldTheWindow:
     def test_without_a_window_the_answers_are_the_parents(self, t, tile):
         assert flash_tiles(t, t, jnp.bfloat16) == (tile, tile)
         assert flash_tiles(t, t, jnp.bfloat16, window=None) == (tile, tile)
+
+
+# --------------------------------------------------------------------- #
+# a window that slides with the query (`causal_attention(window=...)`)   #
+# --------------------------------------------------------------------- #
+
+BAND = 16                       # the window, unless said
+BAND_LIMIT = 2e-6               # float32 against float32: the sums' order
+BAND_IMPLS = {"dense": {}, "chunked": {},
+              "flash": {"block_q": 8, "block_k": 8, "interpret": True}}
+
+
+def _band_inputs(t: int, heads=(4, 2), d: int = 8, seed: int = 0):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.normal(size=(2, t, h, d)), jnp.float32)
+                 for h in (heads[0], heads[1], heads[1]))
+
+
+def _band_by_mask(q, k, v, window: int) -> np.ndarray:
+    """One softmax over a mask built here: u <= t and t - u < window,
+    query head j reading key/value head j // group, in float64."""
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    group = q.shape[2] // k.shape[2]
+    k, v = np.repeat(k, group, 2), np.repeat(v, group, 2)
+    s = np.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    pos = np.arange(q.shape[1])
+    behind = pos[:, None] - pos[None, :]
+    s = np.where((behind >= 0) & (behind < window), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum("bhqk,bkhd->bqhd", p / p.sum(-1, keepdims=True), v)
+
+
+def _window_calls(window: int, tile: str) -> float:
+    return get_registry().counter(
+        "mmlspark_tpu_attention_window_calls_total",
+        labels=("window", "tile")).labels(window=str(window),
+                                          tile=tile).value
+
+
+class TestSlidingWindow:
+    """`causal_attention(q, k, v, impl, window=16)`: 4 query heads over 2
+    key/value heads of 8 channels unless said."""
+
+    # 12: under the window; 16: the window itself; 17: one past it; 40: two
+    # and a half; 37: no multiple of any tile
+    @pytest.mark.parametrize("impl", sorted(BAND_IMPLS))
+    @pytest.mark.parametrize("t", [12, 16, 17, 40, 37])
+    def test_three_tiers_match_the_mask(self, t, impl):
+        q, k, v = _band_inputs(t)
+        got = attention.causal_attention(q, k, v, impl, window=BAND,
+                                         **BAND_IMPLS[impl])
+        assert got.shape == q.shape and got.dtype == q.dtype
+        assert np.abs(np.asarray(got) - _band_by_mask(q, k, v, BAND)).max() \
+            < BAND_LIMIT
+
+    @pytest.mark.parametrize("impl", sorted(BAND_IMPLS))
+    def test_a_row_of_one_window_is_plain_causal_attention(self, impl):
+        q, k, v = _band_inputs(BAND)
+        got = attention.causal_attention(q, k, v, impl, window=BAND,
+                                         **BAND_IMPLS[impl])
+        plain = attention.causal_attention(q, k, v, impl, **BAND_IMPLS[impl])
+        assert np.array_equal(np.asarray(got), np.asarray(plain))
+
+    # a window that is no multiple of the tile, tiles that differ, a tile
+    # larger than the window, one tile a window, queries in one block
+    @pytest.mark.parametrize("window,tiles", [
+        (16, (8, 8)), (16, (16, 16)), (16, (16, 8)), (16, (8, 16)),
+        (20, (8, 8)), (17, (16, 16)), (24, (32, 8)), (12, (32, 32)),
+        (16, (64, 8))])
+    @pytest.mark.parametrize("t", [64, 50])
+    def test_the_interpreted_kernel_at_several_tilings(self, t, window,
+                                                       tiles):
+        q, k, v = _band_inputs(t, seed=3)
+        got = attention.causal_attention(
+            q, k, v, "flash", window=window, block_q=tiles[0],
+            block_k=tiles[1], interpret=True)
+        assert np.abs(np.asarray(got) - _band_by_mask(q, k, v, window)).max() \
+            < BAND_LIMIT
+
+    @pytest.mark.parametrize("impl", sorted(BAND_IMPLS))
+    def test_a_change_at_p_moves_nothing_before_p(self, impl):
+        q, k, v = _band_inputs(48, seed=1)
+        p = 29
+
+        def run(q, k, v):
+            return np.asarray(attention.causal_attention(
+                q, k, v, impl, window=BAND, **BAND_IMPLS[impl]))
+
+        base = run(q, k, v)
+        moved = run(q.at[:, p].add(1.0), k.at[:, p].add(1.0),
+                    v.at[:, p].add(1.0))
+        assert np.array_equal(moved[:, :p], base[:, :p])     # bit for bit
+        assert not np.array_equal(moved[:, p], base[:, p])
+
+    @pytest.mark.parametrize("impl", sorted(BAND_IMPLS))
+    def test_the_key_a_window_behind_is_unseen_and_the_one_after_seen(
+            self, impl):
+        """Window 16: query 40 reads keys 25 .. 40. Key and value 24 (16
+        behind) replaced: query 40 reads the same, bit for bit, query 39
+        does not; key 25 (15 behind) replaced: query 40 changes."""
+        q, k, v = _band_inputs(48, seed=2)
+
+        def run(k, v):
+            return np.asarray(attention.causal_attention(
+                q, k, v, impl, window=BAND, **BAND_IMPLS[impl]))
+
+        base = run(k, v)
+        unseen = run(k.at[:, 24].add(3.0), v.at[:, 24].add(3.0))
+        assert np.array_equal(unseen[:, 40:], base[:, 40:])
+        assert not np.array_equal(unseen[:, 39], base[:, 39])
+        seen = run(k.at[:, 25].add(3.0), v.at[:, 25].add(3.0))
+        assert not np.array_equal(seen[:, 40], base[:, 40])
+        assert np.array_equal(seen[:, 41:], base[:, 41:])
+
+    def test_the_published_edge_4095_behind_seen_4096_behind_not(self):
+        """The model's own window on the chunked tier (the CPU's): one
+        head, 4200 positions; query 4199 reads key 104 (4095 behind) and
+        not key 103."""
+        rng = np.random.default_rng(5)
+        q, k, v = (jnp.asarray(rng.normal(size=(1, 4200, 1, 8)), jnp.float32)
+                   for _ in range(3))
+
+        def last(k, v):
+            return np.asarray(attention.causal_attention(
+                q, k, v, "chunked", window=4096))[:, -1]
+
+        base = last(k, v)
+        assert np.array_equal(last(k.at[:, 103].add(9.0),
+                                   v.at[:, 103].add(9.0)), base)
+        assert not np.array_equal(last(k.at[:, 104].add(9.0),
+                                       v.at[:, 104].add(9.0)), base)
+
+    def test_the_chunked_tier_has_the_backward(self):
+        q, k, v = _band_inputs(40, seed=4)
+
+        def loss(tier):
+            return lambda q, k, v: (attention.causal_attention(
+                q, k, v, tier, window=BAND) ** 2).sum()
+
+        got = jax.grad(loss("chunked"), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss("dense"), argnums=(0, 1, 2))(q, k, v)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, atol=2e-5)
+
+    def test_bfloat16_heads_of_whole_lanes_in_place(self):
+        """Heads of 128 channels, bfloat16, 7 query heads to a key/value
+        head: the banded kernel reads them in place, counted so."""
+        rng = np.random.default_rng(6)
+        q = jnp.asarray(rng.normal(size=(1, 48, 7, 128)), jnp.bfloat16)
+        k, v = (jnp.asarray(rng.normal(size=(1, 48, 1, 128)), jnp.bfloat16)
+                for _ in range(2))
+        before = _operands("swa", "in_place")
+        got = attention.causal_attention(q, k, v, "flash", window=BAND,
+                                         block_q=16, block_k=16,
+                                         interpret=True)
+        assert _operands("swa", "in_place") == before + 1
+        want = _band_by_mask(q, k, v, BAND)
+        assert np.abs(np.asarray(got, np.float64) - want).max() < 0.03
+
+    def test_a_call_is_counted_by_window_and_tile_and_named_by_shape(self):
+        x = jax.ShapeDtypeStruct((2, 16384, 28, 128), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((2, 16384, 4, 128), jnp.bfloat16)
+        before = (_window_calls(4096, "1024x1024"),
+                  _flash_calls("1024x1024", True))
+
+        def banded(q, k, v):
+            return attention.causal_attention(q, k, v, "flash", window=4096)
+
+        jaxpr = str(jax.make_jaxpr(banded)(x, kv, kv))
+        assert "swa_attn_w4096" in jaxpr
+        assert _window_calls(4096, "1024x1024") == before[0] + 1
+        # the plain forward's counter counts what it counted: not this
+        # call, and a row inside the window as ever
+        assert _flash_calls("1024x1024", True) == before[1]
+        short = [jax.ShapeDtypeStruct((2, 2048, h, 128), jnp.bfloat16)
+                 for h in (28, 4, 4)]
+        jax.eval_shape(banded, *short)
+        assert _flash_calls("1024x1024", True) == before[1] + 1
+        assert _window_calls(4096, "1024x1024") == before[0] + 1
+
+    def test_the_band_is_five_key_blocks_a_query_block(self):
+        """Tiles of 1024 over a window of 4096 (`band_tiles`): the grid's
+        key axis is 5 blocks long whatever the row's length; a row of
+        16384 visits 70 block pairs a head where the band holds 56."""
+        assert attention.band_tiles(16384, 4096, jnp.bfloat16) == (1024,
+                                                                   1024)
+        assert attention.band_tiles(16384, 4096, jnp.float32) == (512, 512)
+        assert attention._band_steps(16384, 1024, 1024, 4096) == 5
+        assert attention._band_steps(8192, 1024, 1024, 4096) == 5
+        visited, needed = attention.band_tile_pairs(16384, 4096, 1024, 1024)
+        assert visited == 1 + 2 + 3 + 4 + 12 * 5 == 70
+        assert needed == pytest.approx(
+            (4096 * 4097 / 2 + 12288 * 4096) / 1024 ** 2)
+        assert 1.24 < visited / needed < 1.26
+        # a window no multiple of 128 divides: the lengths' own tiles
+        assert attention.band_tiles(4096, 1100, jnp.bfloat16) == (1024, 1024)
+
+    def test_without_a_window_the_program_is_the_parents(self):
+        """`window=None` is the call it was: the same jaxpr as the plain
+        tier's own function, for every tier."""
+        q, k, v = _band_inputs(40)
+        for impl, plain in (
+                ("dense", lambda q, k, v: dense_attention(
+                    q, k, v, causal=True).astype(q.dtype)),
+                ("chunked", lambda q, k, v: chunked_attention(
+                    q, k, v, causal=True)),
+                ("flash", lambda q, k, v: flash_attention(
+                    q, k, v, causal=True, bwd_chunk=None))):
+            got = jax.make_jaxpr(lambda q, k, v: attention.causal_attention(
+                q, k, v, impl, window=None))(q, k, v)
+            assert str(got) == str(jax.make_jaxpr(plain)(q, k, v)), impl

@@ -157,6 +157,78 @@ def test_windowed_and_summarised_attention_kernels_compile(one_chip, rows,
     assert "eva_attn_w2048c16" in text and "eva_pool_w2048c16" in text
 
 
+@pytest.mark.parametrize("length,name", [
+    (16384, "swa_attn_w4096"),
+    # a row inside the window takes the plain causal forward, named by the
+    # scope around it
+    (2048, "swa_attn_7")])
+def test_sliding_window_attention_kernel_compiles(one_chip, length, name):
+    """`smallthinker_21b_a3b.score_mixed_context`'s two batches: 28 query
+    heads over 4 key/value heads of 128 channels, bfloat16, a window of
+    4096 at the tiles the rule chooses (1024 x 1024: five key blocks a
+    query block), inside the default scoped VMEM; q, k and v read in place
+    as (B, T, heads x 128)."""
+    from mmlspark_tpu.nn.attention import causal_attention
+
+    def spec(heads):
+        return jax.ShapeDtypeStruct((2, length, heads * 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def attend(q, k, v):
+        with jax.named_scope("swa_attn_7"):
+            return causal_attention(
+                q.reshape(2, length, 28, 128), k.reshape(2, length, 4, 128),
+                v.reshape(2, length, 4, 128), "flash",
+                window=4096).reshape(2, length, 28 * 128)
+
+    text = _compile(attend, spec(28), spec(4), spec(4)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert re.search(rf"%{name}[.\d]* = ", text)
+    entry = text[text.index("ENTRY"):]
+    assert not re.search(r" (copy|transpose)\(", entry)
+
+
+@pytest.mark.parametrize("tokens", [2 * 16384, 2 * 2048])
+def test_relu_expert_layer_routed_early_compiles_to_grouped_kernels(
+        one_chip, tokens, monkeypatch):
+    """The expert layer of `smallthinker_21b_a3b.score_mixed_context` at
+    its two batches: 16 of 64 experts of width 768 on a hidden width of
+    2560, 6 picks a token made by a router that reads ANOTHER input
+    (softmax over the picked logits), the gate's activation ReLU: both
+    grouped products are the Pallas calls under the names the accepted
+    readers select, each result with the buffer's rows as its first
+    extent, in both branches of the buffer-size switch; the combine is the
+    Pallas call."""
+    from mmlspark_tpu.parallel.moe import (dropless_buffer_rows,
+                                           moe_ffn_dropless, route_top_k)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(y, a, router, gate, up, down):
+        routed = route_top_k(a, router, None, 6, scoring="softmax")
+        return moe_ffn_dropless(
+            y, None, None, gate, up, down, n_routed_experts=64,
+            experts_held=(0, 16), top_k=6, dtype=jnp.bfloat16,
+            activation="relu", routed=routed)
+
+    text = _compile(
+        layer, spec(tokens, 2560), spec(tokens, 2560),
+        spec(2560, 64, dtype=jnp.float32), spec(16, 2560, 768),
+        spec(16, 2560, 768), spec(16, 768, 2560)).as_text()
+    assert "conditional" in text
+    for rows in (dropless_buffer_rows(tokens, 6, 16, 64), tokens * 6):
+        assert re.search(rf"%ragged-dot-gated[.\d]* = bf16\[{rows},768\]",
+                         text)
+        assert re.search(rf"%ragged-dot-down[.\d]* = bf16\[{rows},2560\]",
+                         text)
+        assert f"[{rows},1536]" not in text
+    assert "[16,2560,1536]" not in text
+    assert text.count("moe_combine") >= 2
+
+
 def _entry_operations(text: str):
     """(operation, result's type, whether it is computed from the
     argument `y`) of every instruction of the optimised program's entry
@@ -186,6 +258,11 @@ LAYERS = {
     "latent_8x512": ("latent", 2048, (8, 512)),
     "encoder_32x512": ("encoder", 4096, (32, 512)),
     "encoder_32x128": ("encoder", 4096, (32, 128)),
+    # `smallthinker_21b_a3b.score_mixed_context`: 28 query heads over 4
+    # key/value heads of 128 on a hidden width of 2560
+    "sliding_2x16384": ("sliding", 2560, (2, 16384)),
+    "sliding_2x2048": ("sliding", 2560, (2, 2048)),
+    "global_2x16384": ("global", 2560, (2, 16384)),
 }
 
 # what is left, by the traces of PR 35 (PERF.md section 5): at ONE row of
@@ -203,6 +280,12 @@ def _attention_layer(kind: str, i: int = 0):
     if kind == "eva":
         return models.EvaAttention(num_heads=32, dtype=bf,
                                    name=f"eva_attn_{i}")
+    if kind in ("sliding", "global"):
+        sliding = kind == "sliding"
+        return models.GroupedQueryAttention(
+            28, 4, 1.5e6, 1e-6, "flash", bf, head_dim=128, qk_norm=False,
+            rotary=sliding, window=4096 if sliding else None,
+            name=f"swa_attn_{i}" if sliding else f"gqa_attn_{i}")
     if kind == "latent":
         return models.LatentAttention(
             num_heads=16, kv_lora_rank=512, qk_nope_head_dim=128,
@@ -277,7 +360,9 @@ def test_no_activation_is_laid_out_again_around_the_kernels(
     # rotary (q and k share it), the pooling, the attention
     ("eva", 4096, 2, 32768, 3), ("eva", 4096, 1, 4096, 3),
     # rotary of the queries' rotary channels, the latent forward
-    ("latent", 2048, 8, 4096, 2), ("latent", 2048, 8, 512, 2)])
+    ("latent", 2048, 8, 4096, 2), ("latent", 2048, 8, 512, 2),
+    # rotary (q's 28 heads and k's 4 are two shapes), the banded forward
+    ("sliding", 2560, 2, 16384, 3)])
 def test_a_kernel_is_lowered_once_a_shape_not_once_a_layer(
         one_chip, monkeypatch, kind, width, rows, length, bodies):
     """The guard PR 34 lacked (its rotary call was lowered once a tensor,

@@ -256,8 +256,8 @@ class TestLayer:
         want, picks = moe_ffn_dropless(*args, **kw)
         sound = moe._experts
 
-        def planted(xs, gate, up, down, picks, weight_of_row):
-            out = sound(xs, gate, up, down, picks, weight_of_row)
+        def planted(xs, gate, up, down, picks, weight_of_row, **kw):
+            out = sound(xs, gate, up, down, picks, weight_of_row, **kw)
             past = jnp.arange(xs.shape[0]) >= picks.sum()
             return jnp.where(past[:, None], jnp.nan, out)
 
@@ -300,3 +300,187 @@ class TestLayer:
             lambda *a: moe_ffn_dropless(*a, **kw))(*args))
         assert "8,128,512" not in text and ",512]" not in text
         assert text.count("ragged_dot") >= 3
+
+
+# --------------------------------------------------------------------- #
+# a second activation and a second scoring (the family that states them) #
+# --------------------------------------------------------------------- #
+
+def _activations(**labels):
+    return get_registry().counter(
+        "mmlspark_tpu_moe_activation_calls_total",
+        labels=("activation",)).labels(**labels).value
+
+
+class TestReluGate:
+    """relu(a) * b in the first product's epilogue and in the `ragged_dot`
+    path alike: a static argument of the same call, not a second kernel."""
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", ["an_empty_group",
+                                      "a_group_across_tiles",
+                                      "an_odd_multiple_of_128"])
+    def test_the_kernel_equals_a_dense_product_a_group(self, case, dtype):
+        rows, picks, tm, n, tn = CASES[case]
+        dtype = jnp.dtype(dtype)
+        o = _operands(rows, picks, n, dtype)
+        plan = moe._grouped_plan(o["picks"], rows, tm)
+        got = moe._grouped_pallas(
+            o["xs"], (o["gate"], o["up"]), plan, tm=tm, tn=tn,
+            name="ragged-dot-gated", activation="relu", interpret=True)
+        a, b = (_dense(o["xs"], o[m], picks) for m in ("gate", "up"))
+        want = (np.maximum(a, 0.0) * b)[:sum(picks)]
+        got = np.asarray(got, np.float32)[:sum(picks)]
+        if dtype == jnp.float32:
+            assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+        else:
+            assert (np.abs(got - want) <= _last_place(want) + SUMS).all()
+        # half the hidden values are exactly zero, which silu's never are
+        assert 0.3 < (got == 0.0).mean() < 0.7
+        silu = np.asarray(moe._grouped_pallas(
+            o["xs"], (o["gate"], o["up"]), plan, tm=tm, tn=tn,
+            name="ragged-dot-gated", interpret=True), np.float32)
+        assert (silu[:sum(picks)] == 0.0).mean() < 0.01
+
+    @pytest.mark.parametrize("path", ["ragged_dot", "kernels"])
+    def test_the_layer_with_relu_is_the_equations(self, monkeypatch, path):
+        """`moe_ffn_dropless(activation="relu")` against every expert
+        computed densely for every token and weighed by its gate, float32
+        on the CPU's path and bfloat16 through the interpreted kernels."""
+        layer = TestLayer()
+        dtype = jnp.float32 if path == "ragged_dot" else jnp.bfloat16
+        x, router, b, gate, up, down = layer._args(tokens=96)
+        kw = dict(n_routed_experts=8, experts_held=(0, 8), top_k=3,
+                  dtype=dtype, activation="relu")
+        if path == "kernels":
+            layer._as_on_the_chip(monkeypatch)
+        got, picks = moe_ffn_dropless(x, router, b, gate, up, down, **kw)
+        picked, weights = moe.route_top_k(x, router, b, 3)
+        xs, mats = x.astype(dtype), [m.astype(dtype) for m in (gate, up,
+                                                               down)]
+        want = np.zeros(x.shape, np.float32)
+        for e in range(8):
+            g = np.where(np.asarray(picked) == e, np.asarray(weights),
+                         0.0).sum(-1, keepdims=True)
+            hidden = np.maximum(np.asarray(xs @ mats[0][e], np.float32),
+                                0.0) * np.asarray(xs @ mats[1][e],
+                                                  np.float32)
+            want += g * np.asarray(
+                jnp.asarray(hidden, dtype) @ mats[2][e], np.float32)
+        assert int(picks.sum()) == 96 * 3
+        tol = 1e-4 if path == "ragged_dot" else 0.05
+        assert np.abs(np.asarray(got, np.float32) - want).max() \
+            < tol * np.abs(want).max()
+        # silu in its place is another layer
+        other, _p = moe_ffn_dropless(x, router, b, gate, up, down,
+                                     **dict(kw, activation="silu"))
+        assert np.abs(np.asarray(other, np.float32) - want).max() \
+            > 0.05 * np.abs(want).max()
+
+    def test_the_activation_is_counted_and_an_unknown_one_refused(self):
+        xs = jnp.zeros((48, 128), jnp.bfloat16)
+        w = jnp.zeros((2, 128, 256), jnp.bfloat16)
+        rest = (jnp.asarray([20, 9], jnp.int32), jnp.ones((48,), jnp.float32))
+        before = {a: _activations(activation=a) for a in ("silu", "relu")}
+        grouped = {(k, s): _counted(kernel=k, stage=s, tile="none")
+                   for k, s in (("ragged_dot", "gated"),)}
+        moe._experts(xs, w, w, w.reshape(2, 256, 128), *rest)
+        moe._experts(xs, w, w, w.reshape(2, 256, 128), *rest,
+                     activation="relu")
+        assert {a: _activations(activation=a) - before[a]
+                for a in before} == {"silu": 1, "relu": 1}
+        # the grouped calls' counter keeps its three labels
+        assert _counted(kernel="ragged_dot", stage="gated",
+                        tile="none") == grouped[("ragged_dot", "gated")] + 2
+        with pytest.raises(ValueError, match="unknown gate activation"):
+            moe._experts(xs, w, w, w.reshape(2, 256, 128), *rest,
+                         activation="gelu")
+
+    @pytest.mark.parametrize("tokens,rows", [(32768, 74240), (4096, 9728)])
+    def test_the_rule_at_experts_of_768_on_a_hidden_width_of_2560(
+            self, tokens, rows):
+        """`smallthinker_21b_a3b.score_mixed_context`'s two buffers (6
+        picks of 64, 16 held): 768 columns in two blocks of 384 (two of
+        512 would compute a third more), 2560 in one; both inside the
+        kernel's VMEM, the whole-buffer branch's rows too."""
+        assert dropless_buffer_rows(tokens, 6, 16, 64) == rows
+        for rows in (rows, tokens * 6):
+            assert grouped_tiles(rows, 2560, 768, 2, 2) == (256, 384)
+            assert grouped_tiles(rows, 768, 2560, 2, 1) == (256, 2560)
+        assert moe._grouped_bytes(256, 2560, 384, 2, 2) <= moe._GROUPED_VMEM
+        assert moe._grouped_bytes(256, 2560, 512, 2, 2) <= moe._GROUPED_VMEM
+        assert moe._grouped_bytes(256, 768, 2560, 2, 1) <= moe._GROUPED_VMEM
+
+
+class TestSoftmaxRouting:
+    def _inputs(self, seed=0, tokens=50, d=32, n=16):
+        keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+        return (jax.random.normal(keys[0], (tokens, d)),
+                jax.random.normal(keys[1], (d, n)),
+                jax.random.normal(keys[2], (n,)))
+
+    def test_softmax_over_the_picks_is_softmax_over_all_renormalised(self):
+        x, router, _b = self._inputs()
+        picked, weights = moe.route_top_k(x, router, None, 4,
+                                          scoring="softmax")
+        logits = x @ router
+        assert np.array_equal(picked, lax.top_k(logits, 4)[1])
+        over_all = jnp.take_along_axis(jax.nn.softmax(logits, -1), picked, 1)
+        np.testing.assert_allclose(
+            weights, over_all / over_all.sum(-1, keepdims=True), rtol=1e-5)
+        np.testing.assert_allclose(weights.sum(-1), 1.0, rtol=1e-6)
+        # the softmax over all experts left as it is has no model here
+        with pytest.raises(ValueError, match="normalise=False"):
+            moe.route_top_k(x, router, None, 4, normalise=False,
+                            scoring="softmax")
+        # the scaling factor multiplies the weights
+        _p, scaled = moe.route_top_k(x, router, None, 4, scaling=2.5,
+                                     scoring="softmax")
+        np.testing.assert_allclose(scaled, 2.5 * weights, rtol=1e-6)
+
+    def test_the_bias_selects_and_does_not_weigh(self):
+        x, router, _b = self._inputs(seed=1)
+        bias = jnp.zeros(16).at[3].set(50.0).at[9].set(-50.0)
+        picked, weights = moe.route_top_k(x, router, bias, 4,
+                                          scoring="softmax")
+        assert (np.asarray(picked) == 3).any(axis=1).all()
+        assert not (np.asarray(picked) == 9).any()
+        chosen = jnp.take_along_axis(x @ router, picked, 1)     # no bias
+        np.testing.assert_allclose(weights, jax.nn.softmax(chosen, -1),
+                                   rtol=1e-5)
+
+    def test_sigmoid_is_what_it_was(self):
+        """The default scoring, written out as the parent had it."""
+        x, router, bias = self._inputs(seed=2)
+        picked, weights = moe.route_top_k(x, router, bias, 4, epsilon=1e-6)
+        scores = jax.nn.sigmoid(x @ router)
+        assert np.array_equal(picked, lax.top_k(scores + bias, 4)[1])
+        chosen = jnp.take_along_axis(scores, picked, 1)
+        np.testing.assert_allclose(
+            weights, chosen / (chosen.sum(-1, keepdims=True) + 1e-6),
+            rtol=1e-6)
+        named = moe.route_top_k(x, router, bias, 4, epsilon=1e-6,
+                                scoring="sigmoid")
+        assert np.array_equal(named[1], weights)
+        assert not np.allclose(moe.route_top_k(
+            x, router, bias, 4, scoring="softmax")[1], weights, atol=1e-3)
+
+    def test_picks_made_earlier_take_the_routers_place(self):
+        """`routed=`: the layer computes with the picks it is handed (a
+        router that read another input) and reads no router of its own."""
+        layer = TestLayer()
+        x, router, b, gate, up, down = layer._args(tokens=64,
+                                                   dtype=jnp.float32)
+        other = jax.random.normal(jax.random.PRNGKey(11), x.shape)
+        kw = dict(n_routed_experts=8, experts_held=(2, 4), top_k=3)
+        routed = moe.route_top_k(other, router, None, 3, scoring="softmax")
+        got, picks = moe_ffn_dropless(x, None, None, gate[2:6], up[2:6],
+                                      down[2:6], routed=routed, **kw)
+        held = ((routed[0] >= 2) & (routed[0] < 6))
+        assert int(picks.sum()) == int(held.sum())
+        # the same picks from the layer's own router on that other input
+        own, own_picks = moe_ffn_dropless(
+            other, router, jnp.zeros(8), gate[2:6], up[2:6], down[2:6],
+            scoring="softmax", **kw)
+        assert np.array_equal(own_picks, picks)
+        assert not np.allclose(own, got, atol=1e-3)    # other experts' input
