@@ -33,8 +33,9 @@ causally, and one pooled key and value (``eva_summaries``) for every chunk
 of the windows before it; the same three tiers behind one switch. A fifth,
 ``causal_attention(window=...)``, is a window that SLIDES with the query
 (query t reads keys t - window + 1 .. t): the plain flash fold over the key
-blocks a query block's band touches, both edges masked in the kernel; the
-same three tiers, the chunked one its backward.
+blocks a query block's band touches, both edges masked in the kernel (an
+edge tile in parts, what the mask would erase whole not computed:
+`_edge_parts`); the same three tiers, the chunked one its backward.
 
 The chunked and flash tiers compute scores and the softmax accumulator in
 float32 whatever the input dtype (bf16 inputs stay bf16 through the
@@ -231,6 +232,20 @@ def _count_operands(kernel: str, in_place: bool) -> None:
             layout="in_place" if in_place else "head_major").inc()
 
 
+def _count_edge_parts(block_q: int, block_k: int, steps: int,
+                      window: int | None = None) -> None:
+    """Counted where a causal forward is traced: where the split of the
+    edge tiles engaged (`_edge_parts`)."""
+    get_registry().counter(
+        "mmlspark_tpu_attention_edge_parts_total",
+        "causal flash-attention forward calls traced (plain, latent and "
+        "banded), by the tile and by the parts an edge tile is folded in "
+        "(1: whole, masked)",
+        labels=("tile", "parts")).labels(
+            tile=f"{block_q}x{block_k}",
+            parts=str(_edge_parts(block_q, block_k, steps, window))).inc()
+
+
 # --------------------------------------------------------------------- #
 # chunked (memory-efficient, differentiable)                            #
 # --------------------------------------------------------------------- #
@@ -339,7 +354,12 @@ def flash_tiles(tq: int, tk: int, dtype,
     over 8 key/value heads, bfloat16, causal; PERF.md, PR 31) 1024 x 1024
     gives 36.8 ms a call, 512 x 2048 43.7, 512 x 1024 41.3, 2048 x 512
     59.0, 1024 x 512 69.9, and 2048 x 1024 does not fit the 16 MB: the
-    score tile, not the head, fills VMEM.
+    score tile, not the head, fills VMEM. What a causal mask's EDGE costs
+    at that tile is cut inside the step, not by a smaller tile: a tile of
+    1024 on the diagonal (or on a band's trailing edge) is folded in two
+    parts of 512 queries and computes 3/4 of itself, about 3.9 us (4.3 on
+    the trailing edge) where the whole tile masked takes 5.0 and an
+    unmasked one 4.1 (`_edge_parts`; PERF.md, PR 41).
 
     Told a `window` (`eva_attention`: a query reads the keys of its own
     window of that many positions), both tiles are the largest under the
@@ -376,19 +396,71 @@ def _band_first(qi, block_q: int, block_k: int, window: int):
     return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
 
 
+def _edge_parts(block_q: int, block_k: int, steps: int,
+                window: int | None = None) -> int:
+    """In how many parts along the queries the causal fold takes an EDGE
+    tile (the diagonal's, and a band's trailing one), from what it can see;
+    1 is the whole tile, masked. Where the tiles are equal the diagonal's
+    block holds the mask's edge corner to corner (and so does the trailing
+    block of a window that is whole tiles), so part r of n needs only the
+    keys up to (from) its own square on the edge: (n + 1) / 2n of the
+    tile's products and exponentials, `edge_tile_share`. Two parts where a
+    step is one of several (one tile a row keeps the single-step path) and
+    a part is `_PART_ROWS` rows or more: tiles of 1024. Read on a v5e
+    (PERF.md, PR 41), the kernels alone: two parts of 512 rows take 8.6%
+    off the banded forward at 2 x 16384, 3.2% off the triangle there,
+    15.4% off it at 2 x 2048 and 12.4% off the latent forward at 8 x 4096;
+    four of 256 are no faster than two and cost a start three times the
+    equations; two parts of 256 rows (tiles of 512) or of 128 LOSE 0.2 and
+    0.8% (read with the mask by absolute positions: two parts then gave
+    6.1, 2.4, 11.6 and 8.3%)."""
+    aligned = block_q == block_k and (window is None or window % block_k == 0)
+    # two parts, each whole lane blocks
+    whole = block_q % 256 == 0 and block_q // 2 >= _PART_ROWS
+    return 2 if steps > 1 and aligned and whole else 1
+
+
+# a part of fewer rows than this stays in its tile
+_PART_ROWS = 512
+
+
+def edge_tile_share(parts: int) -> float:
+    """What of an edge tile the fold computes when it takes it in `parts`
+    parts: part r of n is (r + 1) / n of the keys for 1 / n of the
+    queries."""
+    return (parts + 1) / (2 * parts)
+
+
+def _block(ref, at=None):
+    """A ref's (positions, channels) block, or the positions `at` (first,
+    how many) of it."""
+    import jax.experimental.pallas as pl
+
+    return ref[0] if at is None else ref[0, pl.ds(*at), :]
+
+
 def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
                 block_k, num_kv, causal, tk_valid, scale, window=None,
                 key_blocks=None):
     """What every flash forward does with a score tile, over a grid of
-    (row, head, query block, key block): `products()` is this step's raw
-    (bq, bk) float32 products of queries and keys (over a head's channels,
-    or over the latent score's two parts); the masks, the online softmax,
-    the block skips and the finalisation are here. Told a `window` (a
-    causal band: a query reads the `window` keys that end with its own),
-    the grid's last axis is the `num_kv` blocks a query block's band can
-    touch, counted from `_band_first` (of `key_blocks` in all), and the
-    block that the band's trailing edge crosses is masked like the
-    diagonal's."""
+    (row, head, query block, key block): `products(rows, keys)` is this
+    step's raw float32 products of queries and keys (over a head's
+    channels, or over the latent score's two parts), of the whole (bq, bk)
+    tile or of the `rows` and `keys` (first, how many) of it; the masks,
+    the online softmax, the block skips and the finalisation are here.
+    Told a `window` (a causal band: a query reads the `window` keys that
+    end with its own), the grid's last axis is the `num_kv` blocks a query
+    block's band can touch, counted from `_band_first` (of `key_blocks` in
+    all), and the block that the band's trailing edge crosses is masked
+    like the diagonal's.
+
+    An EDGE tile, where `_edge_parts` says so, is folded in parts along
+    the queries: a part's rows against the keys its mask leaves and no
+    others, so the corner of the tile that the mask would erase whole is
+    neither multiplied nor exponentiated (an erased entry gave exp(-inf) =
+    0: every row still sums over exactly the keys it saw, in another
+    order). The running maximum, sum and accumulator are a row's own, so
+    the parts touch disjoint rows of the scratch and carry nothing new."""
     import jax.experimental.pallas as pl
 
     qi = pl.program_id(2)
@@ -398,13 +470,27 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
     # only a padded sequence needs the key mask: decided here, in Python
     padded = tk_valid < (num_kv if window is None else key_blocks) * block_k
 
-    def scores(mask_keys: bool, mask_causal: bool, mask_trailing=False):
-        """This step's (bq, bk) score tile, and which of it counts (None:
-        all of it). `mask_keys`: keys at or past `tk_valid` are padding;
-        `mask_causal`: a query sees the keys at or before it;
-        `mask_trailing`: and none `window` or more behind it."""
-        s = products() * scale                                # (bq, bk)
+    def scores(mask_keys: bool, mask_causal: bool, mask_trailing=False,
+               rows=None, keys=None):
+        """This step's score tile, (bq, bk) or the `rows` and `keys` of
+        it, and which of it counts (None: all of it). `mask_keys`: keys at
+        or past `tk_valid` are padding; `mask_causal`: a query sees the
+        keys at or before it; `mask_trailing`: and none `window` or more
+        behind it."""
+        s = products(rows, keys) * scale
         ok = None
+        if rows is not None:
+            # a part of an edge tile (`fold`): the tile's corner lies on
+            # the edge, so what counts is told by the part's own place in
+            # the tile, whatever the block
+            keys_ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                          - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+            ok = (keys_ahead <= rows[0] - keys[0] if mask_causal
+                  else keys_ahead > rows[0] - keys[0])
+            if mask_keys:
+                ok = ok & (kv * block_k + keys[0] + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1) < tk_valid)
+            return jnp.where(ok, s, _NEG_INF), ok
         if mask_keys or mask_causal or mask_trailing:
             kpos = kv * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
@@ -422,17 +508,17 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
             s = jnp.where(ok, s, _NEG_INF)
         return s, ok
 
-    def weigh(s, ok, m):
-        """exp(s - m): its row sums (bq, 1) and its product with the
-        values (bq, Dv)."""
-        p = jnp.exp(s - m)                                    # (bq, bk)
+    def weigh(s, ok, m, keys=None):
+        """exp(s - m): its row sums (rows, 1) and its product with the
+        values (rows, Dv)."""
+        p = jnp.exp(s - m)
         if ok is not None:
             # masked entries must contribute 0 even when the whole row is
             # masked (then m == _NEG_INF and exp(s - m) == 1, not 0)
             p = jnp.where(ok, p, 0.0)
         pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            p.astype(v_ref.dtype), _block(v_ref, keys),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         return p.sum(-1, keepdims=True), pv
 
     def write(m, l, acc):
@@ -461,17 +547,24 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def step(mask_keys: bool, mask_causal: bool, mask_trailing=False):
-        """One key block folded into the running max / denominator /
-        accumulator."""
-        s, ok = scores(mask_keys, mask_causal, mask_trailing)
-        m_prev = m_sc[...]                                    # (bq, 1)
+    def step(mask_keys: bool, mask_causal: bool, mask_trailing=False,
+             rows=None, keys=None):
+        """One key block, or the `keys` of it for the `rows` of the query
+        block, folded into the running max / denominator / accumulator of
+        those rows."""
+        s, ok = scores(mask_keys, mask_causal, mask_trailing, rows, keys)
+        mine = ... if rows is None else (pl.ds(*rows), slice(None))
+        m_prev = m_sc[mine]                                   # (rows, 1)
         m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        l, pv = weigh(s, ok, m_new)
-        corr = jnp.exp(m_prev - m_new)                        # (bq, 1)
-        l_sc[...] = l_sc[...] * corr + l
-        acc_sc[...] = acc_sc[...] * corr + pv
-        m_sc[...] = m_new
+        if rows is not None and mask_causal and not mask_keys:
+            # every row of a part on the diagonal sees its own key: the
+            # maximum is a score, and exp(_NEG_INF - m) is 0 by itself
+            ok = None
+        l, pv = weigh(s, ok, m_new, keys)
+        corr = jnp.exp(m_prev - m_new)                        # (rows, 1)
+        l_sc[mine] = l_sc[mine] * corr + l
+        acc_sc[mine] = acc_sc[mine] * corr + pv
+        m_sc[mine] = m_new
 
     if not causal:
         step(padded, False)
@@ -482,15 +575,26 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
         # mask
         needed = kv * block_k <= qi * block_q + block_q - 1
         crosses = (kv + 1) * block_k - 1 > qi * block_q
+        parts = _edge_parts(block_q, block_k, num_kv, window)
+
+        def fold(diagonal: bool, trailing: bool = False):
+            """The step of a block by the edges that cross it. An edge
+            tile in parts: the diagonal's valid half is its lower-left
+            triangle, part r reads the keys up to its own square; the
+            trailing edge's is the upper-right one, part r reads them
+            from its own square on."""
+            if parts == 1 or diagonal == trailing:
+                return step(padded, diagonal, trailing)
+            size = block_q // parts
+            for r in range(parts):
+                step(padded, diagonal, trailing, (r * size, size),
+                     (0, (r + 1) * size) if diagonal
+                     else (r * size, block_k - r * size))
 
         if window is None:
-            @pl.when(needed & crosses)
-            def _diagonal():
-                step(padded, True)
-
-            @pl.when(needed & jnp.logical_not(crosses))
-            def _below():
-                step(padded, False)
+            pl.when(needed & crosses)(functools.partial(fold, True))
+            pl.when(needed & jnp.logical_not(crosses))(
+                functools.partial(fold, False))
         else:
             # the band's other edge: some query of the block lies `window`
             # or more past some key of this one (blocks wholly behind the
@@ -499,28 +603,33 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
             needed = needed & (kv < key_blocks)
             for diagonal in (True, False):
                 for trailing in (True, False):
+                    if parts > 1 and diagonal and trailing:
+                        # equal tiles that divide the window: the edges
+                        # are `window // block_k` blocks apart
+                        continue
                     pl.when(needed
                             & (crosses if diagonal
                                else jnp.logical_not(crosses))
                             & (trails if trailing
                                else jnp.logical_not(trails)))(
-                        functools.partial(step, padded, diagonal, trailing))
+                        functools.partial(fold, diagonal, trailing))
 
     @pl.when(at == num_kv - 1)
     def _finalize():
         write(m_sc[...], l_sc[...], acc_sc[...])
 
 
-def _qk(q_ref, k_ref):
-    """(bq, D) x (bk, D) -> (bq, bk), float32 sums."""
+def _qk(q_ref, k_ref, rows=None, keys=None):
+    """(bq, D) x (bk, D) -> (bq, bk), float32 sums; of the `rows` and
+    `keys` where told."""
     return jax.lax.dot_general(
-        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+        _block(q_ref, rows), _block(k_ref, keys), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, **static):
-    _flash_fold(lambda: _qk(q_ref, k_ref), v_ref, o_ref, lse_ref, scratch,
-                **static)
+    _flash_fold(functools.partial(_qk, q_ref, k_ref), v_ref, o_ref, lse_ref,
+                scratch, **static)
 
 
 def _latent_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
@@ -533,10 +642,10 @@ def _latent_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
     blocks both, so that the MXU sums them in ONE product's float32
     accumulator: added as two (bq, bk) tiles they cost the VPU a pass over
     the score tile, 6% of the kernel (PERF.md, PR 34)."""
-    def products():
+    def products(rows=None, keys=None):
         return jax.lax.dot_general(
-            jnp.concatenate([qn_ref[0], qr_ref[0]], -1),
-            jnp.concatenate([kn_ref[0], kr_ref[0]], -1),
+            jnp.concatenate([_block(qn_ref, rows), _block(qr_ref, rows)], -1),
+            jnp.concatenate([_block(kn_ref, keys), _block(kr_ref, keys)], -1),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
     _flash_fold(products, v_ref, o_ref, lse_ref, scratch, **static)
@@ -777,6 +886,8 @@ def _call_tiles(tq: int, tk: int, dtype, block_q, block_k, causal: bool):
         "flash-attention forward calls traced, by the tile they run at",
         labels=("tile", "causal")).labels(
             tile=f"{block_q}x{block_k}", causal=str(causal).lower()).inc()
+    if causal:
+        _count_edge_parts(block_q, block_k, -(-tk // block_k))
     return block_q, block_k
 
 
@@ -878,17 +989,28 @@ def _banded_flash(q, k, v, *, window, block_q, block_k, interpret=False):
 
 
 def band_tile_pairs(t: int, window: int, block_q: int, block_k: int):
-    """-> (visited, needed) for one head of one row of `t` positions: the
-    (query block, key block) pairs the banded forward's grid computes on
-    at that tile, and the band's own (query, key) pairs in tiles of that
-    size. Their ratio is what the tiles' edges cost."""
-    visited = sum(
-        min((q0 + block_q - 1) // block_k, (t - 1) // block_k)
-        - max(q0 - (window - 1), 0) // block_k + 1
-        for q0 in range(0, t, block_q))
+    """-> (computed, needed) for one head of one row of `t` positions: the
+    (query block, key block) tiles the banded forward COMPUTES at that
+    tile, in tiles and fractions of one (a block on the diagonal or on
+    the band's trailing edge counts `edge_tile_share` of a tile where
+    `_edge_parts` folds it in parts, a whole one where it is masked
+    whole), and the band's own (query, key) pairs in tiles of that size.
+    Their ratio is what the tiles' edges cost."""
+    steps = _band_steps(t, block_q, block_k, window)
+    share = edge_tile_share(_edge_parts(block_q, block_k, steps, window))
+    computed = 0.0
+    for q0 in range(0, t, block_q):
+        last = min((q0 + block_q - 1) // block_k, (t - 1) // block_k)
+        first = max(q0 - (window - 1), 0) // block_k
+        # the blocks ONE edge crosses (`_flash_fold`'s `crosses`, `trails`)
+        edges = sum(
+            ((kv + 1) * block_k - 1 > q0)
+            != (q0 + block_q - 1 - kv * block_k >= window)
+            for kv in range(first, last + 1))
+        computed += last - first + 1 - edges * (1 - share)
     inside = min(window, t)
     needed = inside * (inside + 1) / 2 + (t - inside) * window
-    return visited, needed / (block_q * block_k)
+    return computed, needed / (block_q * block_k)
 
 
 def band_tiles(t: int, window: int, dtype):
@@ -914,12 +1036,14 @@ def causal_attention(q, k, v, impl: str = "flash", window: int | None = None,
     (XLA, the CPU's tier, and the only one with a backward: differentiate
     through it); "flash": the plain forward's fold over the key blocks a
     query block's band touches (`_flash_fold`: a block wholly outside the
-    band is neither fetched nor computed, the diagonal's and the trailing
-    edge's blocks are masked in the kernel; grouped key heads by index
-    map and heads of whole lanes in place as there), FORWARD ONLY, named
-    `swa_attn_w<window>`. A row no longer than the window is plain causal
-    attention and takes that tier of it. Without a window every call is
-    what it was."""
+    band is neither fetched nor computed; the diagonal's and the trailing
+    edge's blocks are masked in the kernel and, where the tiles are equal
+    and divide the window, folded in parts that leave out what the mask
+    would erase whole, `_edge_parts`: `band_tile_pairs` counts what is
+    computed; grouped key heads by index map and heads of whole lanes in
+    place as there), FORWARD ONLY, named `swa_attn_w<window>`. A row no
+    longer than the window is plain causal attention and takes that tier
+    of it. Without a window every call is what it was."""
     if window is not None and q.shape[1] > window:
         if impl == "dense":
             return _banded_dense(q, k, v, window).astype(q.dtype)
@@ -938,6 +1062,8 @@ def causal_attention(q, k, v, impl: str = "flash", window: int | None = None,
                 "window and the tile (queries x keys)",
                 labels=("window", "tile")).labels(
                     window=str(window), tile=f"{block_q}x{block_k}").inc()
+            _count_edge_parts(block_q, block_k,
+                              _band_steps(t, block_q, block_k, window), window)
             _count_operands("swa", _lanes_whole(q.shape[-1], v.shape[-1]))
             return _banded_flash(
                 q, k, v, window=window, block_q=block_q, block_k=block_k,

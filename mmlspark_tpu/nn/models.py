@@ -1002,22 +1002,24 @@ class WindowMoEDecoder(_ScoringDecoder):
             name=f"swa_attn_{i}" if sliding else f"gqa_attn_{i}")
 
     def window_tile_pairs(self, rows: int, length: int):
-        """-> (visited, needed): the (query block, key block) pairs the
-        banded forward's grid computes on for a batch of `rows` rows of
-        `length` positions, over every head and sliding layer, and the
-        pairs the band itself holds in tiles of that size; from shapes
-        alone. None where no banded kernel runs: off the "flash" tier, or
-        a row no longer than the window."""
+        """-> (computed, needed): the (query block, key block) tiles the
+        banded forward computes for a batch of `rows` rows of `length`
+        positions, over every head and sliding layer, in tiles and
+        FRACTIONS of one (floats: an edge tile folded in parts counts the
+        part of it that is computed, `attention.band_tile_pairs`), and
+        the pairs the band itself holds in tiles of that size; from
+        shapes alone. None where no banded kernel runs: off the "flash"
+        tier, or a row no longer than the window."""
         from .attention import band_tile_pairs, band_tiles
 
         if _tier(self.attention_impl) != "flash" or (
                 length <= self.window_size):
             return None
-        visited, needed = band_tile_pairs(
+        computed, needed = band_tile_pairs(
             length, self.window_size,
             *band_tiles(length, self.window_size, self.dtype))
         calls = rows * self.num_heads * self.layer_types.count("sliding")
-        return visited * calls, needed * calls
+        return computed * calls, needed * calls
 
     @nn.compact
     def __call__(self, x, train: bool = False):

@@ -338,9 +338,10 @@ class DeepModelTransformer(Model):
                     root, np.stack([c[nf] for c in chunks]),
                     [rows * per_row for rows in scored])
             # a module with sliding-window layers says, from shapes alone,
-            # what the banded kernel's grid visits for a batch and what the
-            # band needs (None where no such kernel runs); only a tracer
-            # that keeps spans has a reader for it
+            # what the banded kernel computes for a batch, in tiles and
+            # fractions of one, and what the band needs (None where no such
+            # kernel runs); only a tracer that keeps spans has a reader for
+            # it
             tile_pairs = getattr(self.bundle.module, "window_tile_pairs",
                                  None) if tracer.enabled else None
             if tile_pairs is not None and x.ndim == 2:
@@ -348,7 +349,7 @@ class DeepModelTransformer(Model):
                                      for rows in scored) if p]
                 if pairs:
                     root.set(
-                        attn_window_tile_pairs=int(
+                        attn_window_tile_pairs=float(
                             sum(p[0] for p in pairs)),
                         attn_window_tile_pairs_needed=float(
                             sum(p[1] for p in pairs)))

@@ -55,6 +55,31 @@ def subprocess_env():
     return env
 
 
+def kernel_equations(jaxpr) -> list:
+    """The equations of every Pallas kernel's body under `jaxpr`, however
+    deep, a count a kernel: what a start lowers."""
+    def subjaxprs(eqn):
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else (value,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield inner
+
+    def deep(inner):
+        return sum(1 + sum(deep(sub) for sub in subjaxprs(eqn))
+                   for eqn in inner.eqns)
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(deep(eqn.params["jaxpr"]))
+        else:
+            for sub in subjaxprs(eqn):
+                found += kernel_equations(sub)
+    return found
+
+
 def at_device_shapes(pipeline_at, table, bs, shards=1):
     """`table` scored by `pipeline_at(rows)`, the same stages built to run
     `rows` at a time on one device, one batch of a fused pipeline (`bs`

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import kernel_equations
 
 from mmlspark_tpu.nn import attention
 from mmlspark_tpu.nn.attention import (
@@ -1070,9 +1071,15 @@ class TestSlidingWindow:
         def banded(q, k, v):
             return attention.causal_attention(q, k, v, "flash", window=4096)
 
+        parts = attention._edge_parts(1024, 1024, 5, 4096)
+        split = _edge_parts_calls("1024x1024", parts)
         jaxpr = str(jax.make_jaxpr(banded)(x, kv, kv))
         assert "swa_attn_w4096" in jaxpr
         assert _window_calls(4096, "1024x1024") == before[0] + 1
+        # and by the parts its edge tiles are folded in, beside the plain
+        # causal calls
+        assert parts > 1
+        assert _edge_parts_calls("1024x1024", parts) == split + 1
         # the plain forward's counter counts what it counted: not this
         # call, and a row inside the window as ever
         assert _flash_calls("1024x1024", True) == before[1]
@@ -1085,17 +1092,28 @@ class TestSlidingWindow:
     def test_the_band_is_five_key_blocks_a_query_block(self):
         """Tiles of 1024 over a window of 4096 (`band_tiles`): the grid's
         key axis is 5 blocks long whatever the row's length; a row of
-        16384 visits 70 block pairs a head where the band holds 56."""
+        16384 visits 70 block pairs a head where the band holds 56, and
+        28 of the 70 are edge tiles (16 on the diagonal, 12 on the
+        trailing edge) of which the fold computes `edge_tile_share`."""
         assert attention.band_tiles(16384, 4096, jnp.bfloat16) == (1024,
                                                                    1024)
         assert attention.band_tiles(16384, 4096, jnp.float32) == (512, 512)
         assert attention._band_steps(16384, 1024, 1024, 4096) == 5
         assert attention._band_steps(8192, 1024, 1024, 4096) == 5
-        visited, needed = attention.band_tile_pairs(16384, 4096, 1024, 1024)
-        assert visited == 1 + 2 + 3 + 4 + 12 * 5 == 70
+        computed, needed = attention.band_tile_pairs(16384, 4096, 1024, 1024)
+        share = attention.edge_tile_share(
+            attention._edge_parts(1024, 1024, 5, 4096))
+        assert share < 1 and isinstance(computed, float)
+        assert computed == 1 + 2 + 3 + 4 + 12 * 5 - (16 + 12) * (1 - share)
+        assert computed == {0.75: 63.0, 0.625: 59.5}[share]
         assert needed == pytest.approx(
             (4096 * 4097 / 2 + 12288 * 4096) / 1024 ** 2)
-        assert 1.24 < visited / needed < 1.26
+        assert computed / needed < 1.126
+        # tiles the fold leaves whole count whole: unequal ones, and equal
+        # ones a window of 1100 does not divide
+        assert attention.band_tile_pairs(16384, 4096, 1024, 512)[0] == 140
+        assert attention.band_tile_pairs(16384, 1100, 1024, 1024)[0] == (
+            1 + 2 + 14 * 3)
         # a window no multiple of 128 divides: the lengths' own tiles
         assert attention.band_tiles(4096, 1100, jnp.bfloat16) == (1024, 1024)
 
@@ -1113,3 +1131,212 @@ class TestSlidingWindow:
             got = jax.make_jaxpr(lambda q, k, v: attention.causal_attention(
                 q, k, v, impl, window=None))(q, k, v)
             assert str(got) == str(jax.make_jaxpr(plain)(q, k, v)), impl
+
+
+# --------------------------------------------------------------------- #
+# an edge tile folded in parts (`_edge_parts`)                           #
+# --------------------------------------------------------------------- #
+
+PARTS = 2
+EDGE_TILE = PARTS * attention._PART_ROWS     # the smallest tile that splits
+EDGE_LIMIT = 2e-6               # float32 against float32: the sums' order
+
+
+def _whole_tiles(monkeypatch):
+    """Every edge tile folded whole, as the parent folds it, for a
+    comparison at EQUAL tiles: the rule is by shape and the program has no
+    switch, so the test takes the rule away (and the jitted forwards'
+    cached traces with it), as `_head_major` does."""
+    monkeypatch.setattr(attention, "_edge_parts", lambda *a, **kw: 1)
+    jax.clear_caches()
+
+
+def _kernel_equations(fn, *args) -> int:
+    """The equations of the one Pallas kernel's body under `fn`."""
+    (count,) = kernel_equations(jax.make_jaxpr(fn)(*args).jaxpr)
+    return count
+
+
+def _edge_parts_calls(tile: str, parts: int) -> float:
+    return get_registry().counter(
+        "mmlspark_tpu_attention_edge_parts_total",
+        labels=("tile", "parts")).labels(tile=tile, parts=str(parts)).value
+
+
+class TestEdgeTilesInParts:
+    """Where the tiles are equal and a step is one of several, the
+    diagonal's block and the band's trailing block are folded in parts
+    along the queries, each against the keys its mask leaves: the same
+    numbers as the whole tile masked, up to the order of a float32 row
+    sum. Tiles of `EDGE_TILE`, the smallest that split, on the CPU's
+    interpreted kernel; 2 rows unless said."""
+
+    T = 2 * EDGE_TILE + EDGE_TILE // 2 + 24     # padded keys in an edge tile
+    TILES = {"block_q": EDGE_TILE, "block_k": EDGE_TILE, "interpret": True}
+    # a test-only tile choice the rule leaves whole: unequal tiles
+    UNEQUAL = {"block_q": EDGE_TILE, "block_k": EDGE_TILE // 2,
+               "interpret": True}
+
+    def test_the_rule_by_shape(self):
+        parts = attention._edge_parts
+        # the cells' tiles: plain causal, latent and the band of 4096
+        assert parts(1024, 1024, 16) == parts(1024, 1024, 4) == PARTS
+        assert parts(1024, 1024, 2) == PARTS              # rows of 2048
+        assert parts(1024, 1024, 5, window=4096) == PARTS
+        assert parts(EDGE_TILE, EDGE_TILE, 3) == PARTS
+        # one tile a row; unequal tiles; a window the tile does not divide;
+        # parts of fewer rows than a pass is worth, or of no lane blocks
+        assert parts(1024, 1024, 1) == parts(512, 512, 1) == 1
+        assert parts(1024, 512, 8) == parts(512, 1024, 4) == 1
+        assert parts(640, 640, 2, window=1100) == 1
+        assert parts(EDGE_TILE // 2, EDGE_TILE // 2, 4) == 1
+        assert parts(8, 8, 4) == parts(640, 640, 2) == 1
+        assert attention.edge_tile_share(1) == 1.0
+        assert attention.edge_tile_share(2) == 0.75
+        assert attention.edge_tile_share(4) == 0.625
+
+    @pytest.mark.parametrize("heads,d", [((2, 1), 64), ((2, 1), 128),
+                                         ((4, 2), 8)])
+    @pytest.mark.parametrize("window", [None, EDGE_TILE, 2 * EDGE_TILE])
+    def test_the_split_fold_matches_the_mask_and_the_whole_tile(
+            self, monkeypatch, window, heads, d):
+        """The plain triangle and the band, grouped key heads, heads of 64
+        and of 128 (in place), keys padded inside the last diagonal tile:
+        against one masked softmax in float64, against the whole-tile fold
+        at a test's unequal tiles, and against the whole-tile fold at the
+        SAME tiles (the rule taken away)."""
+        q, k, v = _band_inputs(self.T, heads, d, seed=7)
+        steps = -(-self.T // EDGE_TILE) if window is None else (
+            attention._band_steps(self.T, EDGE_TILE, EDGE_TILE, window))
+        assert attention._edge_parts(EDGE_TILE, EDGE_TILE, steps,
+                                     window) == PARTS
+
+        def call(q, k, v, tiles=self.TILES):
+            return attention.causal_attention(q, k, v, "flash",
+                                              window=window, **tiles)
+
+        split = np.asarray(call(q, k, v))
+        program = str(jax.make_jaxpr(call)(q, k, v))
+        want = _band_by_mask(q, k, v, window or self.T)
+        assert np.abs(split - want).max() < EDGE_LIMIT
+        assert np.abs(split - np.asarray(call(q, k, v, self.UNEQUAL))).max() \
+            < EDGE_LIMIT
+        _whole_tiles(monkeypatch)
+        assert np.abs(split - np.asarray(call(q, k, v))).max() < EDGE_LIMIT
+        # it did engage (the CPU may well sum a row to the same bits)
+        assert str(jax.make_jaxpr(call)(q, k, v)) != program
+
+    def test_the_latent_score_in_parts(self, monkeypatch):
+        t = self.T
+        operands = _latent_inputs(t, b=1, h=2, seed=8)
+
+        def call(*operands, tiles=self.TILES):
+            return attention.latent_attention(
+                *operands, block_q=tiles["block_q"],
+                block_k=tiles["block_k"], interpret=True)
+
+        split = np.asarray(call(*operands))
+        program = str(jax.make_jaxpr(call)(*operands))
+        want = np.asarray(attention.causal_attention(
+            *attention._latent_concatenated(*operands), "dense"))
+        assert np.abs(split - want).max() < 2e-5
+        assert np.abs(split - np.asarray(
+            call(*operands, tiles=self.UNEQUAL))).max() < EDGE_LIMIT
+        _whole_tiles(monkeypatch)
+        assert np.abs(split - np.asarray(call(*operands))).max() < EDGE_LIMIT
+        assert str(jax.make_jaxpr(call)(*operands)) != program
+
+    @pytest.mark.parametrize("window", [None, EDGE_TILE])
+    def test_the_log_sum_exp_is_the_whole_tiles(self, monkeypatch, window):
+        """What the backward reads: equal to float32 rounding, +inf on no
+        row (every query sees itself)."""
+        q, k, v = _band_inputs(self.T, (2, 1), 64, seed=9)
+
+        def run():
+            return attention._flash_fwd_lse(
+                q, k, v, True, EDGE_TILE, EDGE_TILE, True, window=window)
+
+        out, lse = run()
+        _whole_tiles(monkeypatch)
+        out_whole, lse_whole = run()
+        assert np.isfinite(np.asarray(lse)).all()
+        np.testing.assert_allclose(lse, lse_whole, rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(out, out_whole, atol=EDGE_LIMIT)
+
+    def test_the_gradient_through_the_split_forward_is_unchanged(
+            self, monkeypatch):
+        q, k, v = _band_inputs(self.T, (2, 1), 64, seed=10)
+
+        def grads(**tiles):
+            return jax.grad(lambda q, k, v: (flash_attention(
+                q, k, v, causal=True, bwd_chunk=128, **tiles) ** 2).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+
+        split = grads(**self.TILES)
+        dense = jax.grad(lambda q, k, v: (dense_attention(
+            q, k, v, causal=True) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+        for a, b_ in zip(dense, split):
+            np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
+        _whole_tiles(monkeypatch)
+        for a, b_ in zip(grads(**self.TILES), split):
+            np.testing.assert_allclose(a, b_, atol=2e-6, rtol=1e-5)
+
+    # where the fold is the parent's: one tile a row (the cells' rows of
+    # 512 and 1024 tokens), unequal tiles, tiles under the threshold, a
+    # window no multiple of 128 divides, and every non-causal call; the
+    # equations are the parent's kernels' at these shapes, counted on its
+    # tree (PERF.md, PR 41: 120 plain causal, 243 banded, 18 more where
+    # keys are padded)
+    @pytest.mark.parametrize("case,t,window,tiles,equations", [
+        ("one_tile", 512, None, {}, 49),
+        ("one_tile_band", 1024, 512, {"block_q": 1024, "block_k": 1024},
+         68),
+        ("unequal", 2048, None, {"block_q": 1024, "block_k": 512}, 120),
+        ("small", 512, None, {"block_q": 128, "block_k": 128}, 120),
+        ("window_1100", 2200, 1100, {}, 261),
+        ("band_unequal", 4096, 1024, {"block_q": 1024, "block_k": 512},
+         243),
+    ])
+    def test_where_the_split_does_not_engage_the_program_is_the_parents(
+            self, monkeypatch, case, t, window, tiles, equations):
+        x = jax.ShapeDtypeStruct((1, t, 2, 128), jnp.bfloat16)
+
+        def call(q, k, v):
+            return attention.causal_attention(q, k, v, "flash",
+                                              window=window, **tiles)
+
+        with_rule = str(jax.make_jaxpr(call)(x, x, x))
+        assert _kernel_equations(call, x, x, x) == equations
+        _whole_tiles(monkeypatch)
+        assert str(jax.make_jaxpr(call)(x, x, x)) == with_rule, case
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_a_call_that_is_not_causal_or_one_tile_is_bit_for_bit(
+            self, monkeypatch, causal):
+        """Run, not only traced: two key blocks not causal (no edge tile),
+        and one causal tile (`num_kv == 1`)."""
+        t = 2 * EDGE_TILE if not causal else EDGE_TILE
+        q, k, v = _band_inputs(t, (2, 1), 64, seed=11)
+
+        def run():
+            return np.asarray(flash_attention(
+                q, k, v, causal=causal, block_q=EDGE_TILE,
+                block_k=EDGE_TILE, interpret=True))
+
+        with_rule = run()
+        _whole_tiles(monkeypatch)
+        assert np.array_equal(run(), with_rule)
+
+    def test_a_traced_call_is_counted_by_tile_and_parts(self):
+        x = jax.ShapeDtypeStruct((2, 4096, 4, 128), jnp.bfloat16)
+        short = jax.ShapeDtypeStruct((2, 512, 4, 128), jnp.bfloat16)
+        before = (_edge_parts_calls("1024x1024", PARTS),
+                  _edge_parts_calls("512x512", 1))
+        jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                       x, x, x)
+        jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                       short, short, short)
+        # not causal: no edge tile, not counted
+        jax.eval_shape(flash_attention, x, x, x)
+        assert _edge_parts_calls("1024x1024", PARTS) == before[0] + 1
+        assert _edge_parts_calls("512x512", 1) == before[1] + 1

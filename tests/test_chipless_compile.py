@@ -14,6 +14,7 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from conftest import kernel_equations
 from jax.sharding import SingleDeviceSharding
 
 
@@ -378,6 +379,56 @@ def test_a_kernel_is_lowered_once_a_shape_not_once_a_layer(
 
     assert pallas_bodies(1) == bodies
     assert pallas_bodies(3) == bodies
+
+
+# (rows, tokens, query heads, key heads, channels, window), the parent's
+# count at PR 40, the budget. A cell lowers the plain fold once a LAYER
+# and shape (24 kernels in `smallthinker_21b_a3b.score_mixed_context`, 18
+# in `lfm2_8b_a1b.score_long_docs`), at 0.3 to 0.65 ms an equation on the
+# chip's host (0.83 trace and lowering together, PR 41): PR 41's edge
+# tiles in two parts add 27 to each kernel where they engage (half a
+# second of `setup_s` in the deepest cell; its budget was one) and
+# nothing where they do not
+FOLDS = {
+    "global_2x16384": ((2, 16384, 28, 4, 128, None), 120, 150),
+    "short_rows_2x2048": ((2, 2048, 28, 4, 128, None), 120, 150),
+    "lfm2_2x16384": ((2, 16384, 32, 8, 64, None), 120, 150),
+    "banded_2x16384": ((2, 16384, 28, 4, 128, 4096), 243, 260),
+    # one tile a row: the parent's kernel, to the equation
+    "lfm2_2x1024": ((2, 1024, 32, 8, 64, None), 49, 49),
+    "encoder_32x512": ((32, 512, 32, 32, 128, "not causal"), 34, 34),
+}
+
+
+@pytest.mark.parametrize("case", [*FOLDS, "latent_8x4096", "latent_8x512"])
+def test_the_folds_equations_stay_inside_what_a_start_was_budgeted(case):
+    """A later edit cannot buy speed with a start unseen: the body of
+    every flash forward the cells lower, counted at the cells' shapes
+    (traced on the CPU, nothing lowered), stays within its budget, and is
+    the parent's own where the edge tiles are not split."""
+    from mmlspark_tpu.nn import attention
+
+    bf = jnp.bfloat16
+    if case.startswith("latent"):
+        t = int(case.split("x")[1])
+        was, limit = (128, 162) if t == 4096 else (53, 53)
+        jaxpr = jax.make_jaxpr(attention.latent_attention)(
+            jax.ShapeDtypeStruct((8, t, 16, 128), bf),
+            jax.ShapeDtypeStruct((8, t, 16, 64), bf),
+            jax.ShapeDtypeStruct((8, t, 16, 256), bf),
+            jax.ShapeDtypeStruct((8, t, 64), bf))
+    else:
+        (rows, t, heads, key_heads, d, window), was, limit = FOLDS[case]
+        q = jax.ShapeDtypeStruct((rows, t, heads, d), bf)
+        k = jax.ShapeDtypeStruct((rows, t, key_heads, d), bf)
+        if window == "not causal":
+            jaxpr = jax.make_jaxpr(attention.flash_attention)(q, k, k)
+        else:
+            jaxpr = jax.make_jaxpr(
+                lambda q, k, v: attention.causal_attention(
+                    q, k, v, "flash", window=window))(q, k, k)
+    (equations,) = kernel_equations(jaxpr.jaxpr)
+    assert was <= equations <= limit
 
 
 def _sar_shapes(one_chip, users=69878, items=10677):

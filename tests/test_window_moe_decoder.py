@@ -488,6 +488,10 @@ class TestThroughTheRunner:
         # two batches of 4 rows
         assert root.args["attn_window_tile_pairs"] == 80
         assert root.args["attn_window_tile_pairs_needed"] == 32.0
+        # computed tiles and fractions of one: both floats (an edge tile
+        # folded in parts counts the part of it that is computed)
+        assert all(isinstance(root.args[key], float) for key in (
+            "attn_window_tile_pairs", "attn_window_tile_pairs_needed"))
         stage.transform(Table({"tokens": _ids(6, 9)}))
         root = [s for s in get_tracer().spans()
                 if s.name == "runner.transform"][-1]
