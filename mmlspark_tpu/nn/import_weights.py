@@ -71,6 +71,14 @@ and `block_sparse_moe.experts.<n>.gate` / `up` / `down`;
 `lm_head`) onto nn.models.WindowMoEDecoder: a layer's attention under
 `gqa_attn_<i>` (a global layer) or `swa_attn_<i>` (a sliding one), its
 router apart from its experts under `router_<i>`. Nothing is permuted.
+
+`LOOPED_DECODER_SPEC` maps the `ouro` checkpoint naming (`self_attn.q_proj`
+.. `o_proj`; `mlp.gate_proj` / `up_proj` / `down_proj`; FOUR norms a layer,
+`input_layernorm` and `input_layernorm_2` before and after the attention,
+`post_attention_layernorm` and `post_attention_layernorm_2` before and
+after the feed-forward; `norm`; `early_exit_gate` with its bias;
+`embed_tokens`, `lm_head`) onto nn.models.LoopedDecoder: one set of layers
+for all `total_ut_steps` steps. Nothing is permuted.
 """
 
 from __future__ import annotations
@@ -99,6 +107,9 @@ __all__ = [
     "window_moe_decoder_spec",
     "torch_window_moe_decoder_to_flax",
     "import_torch_window_moe_decoder",
+    "LOOPED_DECODER_SPEC",
+    "torch_looped_decoder_to_flax",
+    "import_torch_looped_decoder",
     "import_external_weights",
     "IMPORTERS",
 ]
@@ -861,6 +872,67 @@ def import_torch_window_moe_decoder(
     return _validate_and_install(bundle, variables, architecture)
 
 
+# --------------------------------------------------------------------- #
+# ouro naming -> nn.models.LoopedDecoder                                 #
+# --------------------------------------------------------------------- #
+
+# the four norms of a layer: before and after the attention, before and
+# after the feed-forward
+_OURO_NORMS = {"input_layernorm": "ln_attn", "input_layernorm_2":
+               "ln_attn_post", "post_attention_layernorm": "ln_mlp",
+               "post_attention_layernorm_2": "ln_mlp_post"}
+LOOPED_DECODER_SPEC: "list[MapRule]" = [
+    MapRule(r"model\.embed_tokens\.weight", "params/embed/embedding"),
+    MapRule(_LAYER + r"(?P<norm>(?:input|post_attention)_layernorm(?:_2)?)"
+            r"\.weight",
+            lambda m: f"params/{_OURO_NORMS[m['norm']]}_{m['i']}/scale"),
+    MapRule(_LAYER + r"self_attn\.(?P<p>[qkv])_proj\.weight",
+            r"params/gqa_attn_\g<i>/\g<p>_proj/kernel", _t_heads_kernel),
+    MapRule(_LAYER + r"self_attn\.o_proj\.weight",
+            r"params/gqa_attn_\g<i>/out/kernel", _t_attn_out_kernel),
+    MapRule(_LAYER + r"mlp\." + _FFN,
+            r"params/mlp_\g<i>/\g<proj>/kernel", _t_transpose),
+    MapRule(r"model\.norm\.weight", "params/ln_final/scale"),
+    # Linear(hidden -> 1), with its bias
+    MapRule(r"model\.early_exit_gate\.weight", "params/exit_gate/kernel",
+            _t_transpose),
+    MapRule(r"model\.early_exit_gate\.bias", "params/exit_gate/bias"),
+    MapRule(r"lm_head\.weight", "params/head_kernel", _t_transpose),
+    MapRule(r".*rotary_emb\.inv_freq", None),
+]
+
+
+def torch_looped_decoder_to_flax(
+    state_dict: Mapping[str, np.ndarray], num_heads: int, head_dim: int,
+) -> dict[str, Any]:
+    """Map an `ouro`-named state dict onto nn.models.LoopedDecoder
+    variables: ONE set of layers whatever `total_ut_steps` is (the steps
+    share it), four norms a layer, the exit gate with its bias, an untied
+    head; rotary already in the rotate-half layout. A name no rule places
+    is an error that names it."""
+    return apply_mapping_spec(state_dict, LOOPED_DECODER_SPEC, {
+        "num_heads": int(num_heads), "head_dim": int(head_dim)})
+
+
+def import_torch_looped_decoder(
+    path: str, architecture: str = "looped_decoder",
+    input_shape: tuple[int, ...] = (8,), **config,
+):
+    """Load an `ouro`-named checkpoint into a ready-to-serve ModelBundle
+    of the `looped_decoder` family. `config` is the module's (`num_layers`,
+    `total_ut_steps`, the head counts and width, ...): the checkpoint's own
+    config.json states them, its shapes do not."""
+    from .models import ModelBundle
+
+    sd = load_state_dict(path)
+    bundle = ModelBundle.init(architecture, input_shape=tuple(input_shape),
+                              seed=0, **config)
+    module = bundle.module
+    variables = torch_looped_decoder_to_flax(sd, module.num_heads,
+                                             module.head_dim)
+    return _validate_and_install(bundle, variables, architecture)
+
+
 # architecture name -> importer; zoo.import_external dispatches here, so
 # registering a new family makes it fetchable/verifiable end to end
 IMPORTERS: "dict[str, Callable]" = {
@@ -872,6 +944,7 @@ IMPORTERS: "dict[str, Callable]" = {
     "hybrid_moe_decoder": import_torch_hybrid_moe_decoder,
     "eva_decoder": import_torch_eva_decoder,
     "window_moe_decoder": import_torch_window_moe_decoder,
+    "looped_decoder": import_torch_looped_decoder,
 }
 
 

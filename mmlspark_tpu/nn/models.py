@@ -632,6 +632,24 @@ class ExpertLayer(nn.Module):
         return out.reshape(y.shape), picks
 
 
+class ExitGate(nn.Module):
+    """sigmoid(h . w + b), one number a token: a step's chance of being a
+    token's last, given that the token reached it. The product takes
+    `dtype` inputs; the sum, the bias and the sigmoid are float32."""
+
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        d = h.shape[-1]
+        kernel = self.param("kernel", nn.initializers.normal(d ** -0.5),
+                            (d, 1), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (1,), jnp.float32)
+        logit = jnp.dot(h, kernel.astype(self.dtype),
+                        preferred_element_type=jnp.float32)[..., 0]
+        return jax.nn.sigmoid(logit + bias.astype(jnp.float32))
+
+
 class _ScoringDecoder(nn.Module):
     """What the decoder families share, written once: the block loop over
     token ids (h = x + Op(RMSNorm(x)), out = h + FF(RMSNorm(h)); FF a
@@ -664,9 +682,26 @@ class _ScoringDecoder(nn.Module):
     held expert received (`batch_counters` names it for the runner, which
     reads it back with the batch).
 
+    Three seats that a family may fill and most leave empty.
+    `total_ut_steps` T > 1 runs the whole stack, the final norm included,
+    T times over the SAME parameters (h_t = RMSNorm_final(stack(h_{t-1}));
+    the next step reads h_t and so does the head): the steps are one
+    `nn.scan` with the parameters broadcast, so the lowered program holds
+    every layer once whatever T is, and nothing is sown inside it.
+    `sandwich_norms` puts an RMSNorm of its own AFTER each operator and
+    each feed-forward, before the residual add (`<norm before>_post_<i>`
+    in the tree). `exit_gate` reads every step's h_t through an `ExitGate`
+    (lambda_t, the chance of leaving at step t having reached it); the
+    module then sows `exit_pdf`, float32 (rows, length, T): p_t =
+    lambda_t prod_{j<t} (1 - lambda_j), the last step taking what is left;
+    the head reads each token's state at its exit step, the first t whose
+    running sum of p reaches `early_exit_threshold` (T where that is 1 or
+    more, as published; no step's compute is skipped either way), and
+    `loop_exit_at`, int32 (T,), counts the batch's tokens by that step.
+
     Precision: products take `dtype` inputs and accumulate in float32; the
-    router's scores, the top-k, every softmax and log-sum-exp and every
-    RMSNorm's statistics are float32."""
+    router's scores, the top-k, every softmax, sigmoid and log-sum-exp,
+    the exit distribution and every RMSNorm's statistics are float32."""
 
     # what a family may state as an attribute of its own
     route_epsilon = 1e-20           # added to the sum of a token's weights
@@ -678,12 +713,18 @@ class _ScoringDecoder(nn.Module):
     # "operator" (the operator's normed input: the picks are made before
     # the attention runs, by a `Router` named `router_<i>`)
     router_input = "experts"
+    total_ut_steps = 1              # passes of the stack, one set of weights
+    sandwich_norms = False          # an RMSNorm after each operator as well
+    exit_gate = False               # an exit distribution over the steps
+    early_exit_threshold = 1.0      # running sum of it a token leaves at
 
     @property
     def batch_counters(self) -> tuple:
         """int32 arrays sown per batch that the runner reads back beside
-        the fetched outputs (`nn/runner.py`)."""
-        return ("moe_picks",) if self.num_layers > self._dense_layers else ()
+        the fetched outputs (`nn/runner.py`, which tells them apart by
+        name)."""
+        return (("moe_picks",) if self.num_layers > self._dense_layers
+                else ()) + (("loop_exit_at",) if self.exit_gate else ())
 
     def _token_logprobs(self, h, ids, head):
         """log_softmax(h @ head)[next token] for every position but a
@@ -710,6 +751,65 @@ class _ScoringDecoder(nn.Module):
                                 target.reshape(-1, chunk)))
         return out.reshape(-1)[:n].reshape(b, t)[:, :t - 1]
 
+    def _stack(self, h, norm):
+        """One pass through the layers -> (h, [an expert layer's picks])."""
+        dt = self.dtype
+        picks = []
+        early = self.router_input == "operator"
+        for i in range(self.num_layers):
+            before, operator = self._operator(i)
+            a = norm(name=before)(h)
+            routed = None
+            if early and i >= self._dense_layers:
+                routed = Router(
+                    self.n_routed_experts, self.num_experts_per_tok,
+                    self.router_scoring, name=f"router_{i}")(a)
+            out = operator(a)
+            if self.sandwich_norms:
+                out = norm(name=f"{before[:before.rindex('_')]}_post_{i}")(
+                    out)
+            h = h + out
+            y = norm(name=f"ln_mlp_{i}")(h)
+            if i < self._dense_layers:
+                out = GatedFFN(self.d_ff_dense, dt, name=f"mlp_{i}")(y)
+            else:
+                out, n = ExpertLayer(
+                    self.n_routed_experts, tuple(self.experts_held),
+                    self.num_experts_per_tok, self.d_ff_expert,
+                    self.n_shared_experts, self.routed_scaling_factor,
+                    self.norm_topk_prob, dt, self.route_epsilon,
+                    self.router_scoring, self.expert_activation,
+                    name=f"moe_{i}")(y, routed)
+                picks.append(n)
+            if self.sandwich_norms:
+                out = norm(name=f"ln_mlp_post_{i}")(out)
+            h = h + out
+        return h, picks
+
+    def _leave(self, h, leave, states):
+        """The exit distribution from every step's lambda_t (`leave`,
+        (T, rows, length) float32), sown as `exit_pdf` with the batch's
+        `loop_exit_at` -> the state the head reads: each token's at its
+        exit step (`states`, (T, rows, length, d)), or `h`, the last
+        step's, where the threshold is 1 or more."""
+        steps = leave.shape[0]
+        # prod_{j<t} (1 - lambda_j): the chance of reaching step t
+        reach = jnp.concatenate([jnp.ones_like(leave[:1]),
+                                 jnp.cumprod(1.0 - leave, 0)[:-1]])
+        pdf = jnp.moveaxis(jnp.concatenate(
+            [(leave * reach)[:-1], reach[-1:]]), 0, -1)
+        self.sow("intermediates", "exit_pdf", pdf)
+        if states is None:
+            at = jnp.full(pdf.shape[:-1], steps - 1, jnp.int32)
+        else:
+            reached = (jnp.cumsum(pdf, -1) >= self.early_exit_threshold
+                       ).at[..., -1].set(True)
+            at = jnp.argmax(reached, -1).astype(jnp.int32)
+            h = jnp.take_along_axis(states, at[None, ..., None], 0)[0]
+        self.sow("intermediates", "loop_exit_at", (
+            at[..., None] == jnp.arange(steps)).sum((0, 1), dtype=jnp.int32))
+        return h
+
     def _score(self, x):
         """The forward every family's `__call__` is."""
         ids = x.astype(jnp.int32)
@@ -726,34 +826,42 @@ class _ScoringDecoder(nn.Module):
                          embedding_init=nn.initializers.normal(1.0),
                          name="embed")
         h = embed(ids)
-        picks = []
-        early = self.router_input == "operator"
-        for i in range(self.num_layers):
-            before, operator = self._operator(i)
-            a = norm(name=before)(h)
-            routed = None
-            if early and i >= self._dense_layers:
-                routed = Router(
-                    self.n_routed_experts, self.num_experts_per_tok,
-                    self.router_scoring, name=f"router_{i}")(a)
-            h = h + operator(a)
-            y = norm(name=f"ln_mlp_{i}")(h)
-            if i < self._dense_layers:
-                h = h + GatedFFN(self.d_ff_dense, dt, name=f"mlp_{i}")(y)
-            else:
-                out, n = ExpertLayer(
-                    self.n_routed_experts, tuple(self.experts_held),
-                    self.num_experts_per_tok, self.d_ff_expert,
-                    self.n_shared_experts, self.routed_scaling_factor,
-                    self.norm_topk_prob, dt, self.route_epsilon,
-                    self.router_scoring, self.expert_activation,
-                    name=f"moe_{i}")(y, routed)
-                h = h + out
-                picks.append(n)
-        h = norm(name="ln_final")(h)
+        steps = int(self.total_ut_steps)
+        # a token may leave before the last step: every step's state is kept
+        select = self.exit_gate and self.early_exit_threshold < 1
+
+        def step(mdl, h, _):
+            """One pass of the stack and the final norm -> (h_t, what the
+            steps stack: the picks, lambda_t and, under `select`, h_t)."""
+            h, picks = mdl._stack(h, norm)
+            h = norm(name="ln_final")(h)
+            picks = jnp.stack(picks) if picks else None
+            leave = (ExitGate(dt, name="exit_gate")(h) if mdl.exit_gate
+                     else None)
+            return h, (picks, leave, h if select else None)
+
+        if steps == 1:
+            h, (picks, leave, states) = step(self, h, None)
+            # as a scan of one step would stack them
+            leave, states = (a if a is None else a[None]
+                             for a in (leave, states))
+        else:
+            # ONE traced and lowered body for all steps, its parameters the
+            # module's own tree; nothing inside it is sown. flax traces the
+            # body a second time to find what it gives that no step changes,
+            # which only `init` needs (the parameters are made in there)
+            h, (picks, leave, states) = nn.scan(
+                step, variable_broadcast="params",
+                split_rngs={"params": False}, length=steps,
+                check_constancy_invariants=self.is_initializing())(
+                    self, h, None)
+            picks = picks if picks is None else picks.sum(0)
+        if self.exit_gate:
+            with jax.named_scope("loop.exit"):
+                h = self._leave(h, leave, states)
         self.sow("intermediates", "hidden", h)
-        if picks:
-            self.sow("intermediates", "moe_picks", jnp.stack(picks))
+        if picks is not None:
+            self.sow("intermediates", "moe_picks", picks)
         if self.tie_embeddings:
             head = embed.embedding.T.astype(dt)
         else:
@@ -1026,6 +1134,60 @@ class WindowMoEDecoder(_ScoringDecoder):
         return self._score(x)
 
 
+class LoopedDecoder(_ScoringDecoder):
+    """Causal decoder over token ids whose ONE stack of layers is run
+    `total_ut_steps` times over the same weights (the Ouro block: "Scaling
+    Latent Reasoning via Looped Language Models", arXiv 2510.25741): every
+    layer plain causal attention (`GroupedQueryAttention`: `num_heads`
+    query heads over `num_kv_heads` key/value heads of `head_dim` channels,
+    no norm on a head, rotary over the whole head, the same positions in
+    every step) and a gated feed-forward, each between TWO RMSNorms, one
+    before it and one after it before the residual add (four a layer); the
+    final norm inside the loop; a gate a step whose exit distribution is
+    sown beside the log-probabilities (`exit_pdf`, `loop_exit_at`); an
+    untied head over the state each token leaves with. No expert layer.
+    The steps, the norms' seats, the gate, the head and the outputs are
+    `_ScoringDecoder`'s."""
+
+    num_layers: int = 2
+    total_ut_steps: int = 4
+    early_exit_threshold: float = 1.0
+    d_model: int = 64
+    num_heads: int = 4
+    num_kv_heads: int = 4
+    head_dim: int = 16
+    d_ff_dense: int = 128
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1000000.0
+    vocab_size: int = 256
+    max_len: int = 65536
+    # "flash": the Pallas kernel (chunked off-TPU), "chunked", "dense"
+    attention_impl: str = "flash"
+    head_chunk: int = 1024          # tokens of one block of the head
+    output: str = "token_logprobs"  # or "logits"
+    dtype: Any = jnp.float32
+
+    # what the family states and no configuration changes
+    sandwich_norms = True
+    exit_gate = True
+
+    @property
+    def _dense_layers(self) -> int:
+        return self.num_layers
+
+    def _operator(self, i: int):
+        # named as the other families' plain causal layers are, so that a
+        # device trace tells this attention by the same name
+        return f"ln_attn_{i}", GroupedQueryAttention(
+            self.num_heads, self.num_kv_heads, self.rope_theta,
+            self.rms_norm_eps, self.attention_impl, self.dtype,
+            head_dim=self.head_dim, qk_norm=False, name=f"gqa_attn_{i}")
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        return self._score(x)
+
+
 def resnet20_cifar(num_outputs: int = 10, dtype=jnp.float32) -> ResNet:
     return ResNet(stage_sizes=(3, 3, 3), num_filters=16,
                   num_outputs=num_outputs, dtype=dtype)
@@ -1047,9 +1209,9 @@ def _hashable(config: dict) -> dict:
 # references architectures by name (the reference's ModelSchema carries a
 # remote URI instead, downloader/Schema.scala:30+). Families: `mlp`,
 # `simple_cnn` and the `resnet*` over images or features; over token ids the
-# `transformer` encoder and four causal decoders on one skeleton,
-# `mla_moe_decoder`, `hybrid_moe_decoder`, `eva_decoder` and
-# `window_moe_decoder`.
+# `transformer` encoder and five causal decoders on one skeleton,
+# `mla_moe_decoder`, `hybrid_moe_decoder`, `eva_decoder`,
+# `window_moe_decoder` and `looped_decoder`.
 ARCHITECTURES: dict[str, Callable[..., nn.Module]] = {
     "mlp": lambda **kw: MLP(**kw),
     "simple_cnn": lambda **kw: SimpleCNN(**kw),
@@ -1061,6 +1223,7 @@ ARCHITECTURES: dict[str, Callable[..., nn.Module]] = {
     "hybrid_moe_decoder": lambda **kw: HybridMoEDecoder(**_hashable(kw)),
     "eva_decoder": lambda **kw: EvaDecoder(**kw),
     "window_moe_decoder": lambda **kw: WindowMoEDecoder(**_hashable(kw)),
+    "looped_decoder": lambda **kw: LoopedDecoder(**kw),
 }
 
 

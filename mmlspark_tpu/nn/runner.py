@@ -229,6 +229,7 @@ class DeepModelTransformer(Model):
         # set_model) must not score with stale cached/cast weights
         key = (fetches, bs, self.get("use_mesh"),
                self.get("bfloat16"), id(self.bundle), fused)
+        counters = tuple(getattr(self.bundle.module, "batch_counters", ()))
         if key not in self._apply_cache:
             variables = self.bundle.variables
             if self.get("bfloat16"):
@@ -238,12 +239,12 @@ class DeepModelTransformer(Model):
                     if jnp.issubdtype(jnp.asarray(a).dtype, jnp.floating) else a,
                     variables,
                 )
-            # what a module with experts sows per batch (routing counts)
-            # rides with the streamed path's lagged readback; a module
-            # without takes the path as it was
+            # what a module sows per batch for the runner (`batch_counters`:
+            # routing counts, a looped stack's exit steps) rides with the
+            # streamed path's lagged readback; a module without takes the
+            # path as it was
             made = (self._make_apply_fused(fetches) if fused
-                    else self._make_apply(fetches, tuple(getattr(
-                        self.bundle.module, "batch_counters", ()))))
+                    else self._make_apply(fetches, counters))
             self._apply_cache[key] = (made, variables)
         apply_fn, variables = self._apply_cache[key]
 
@@ -255,7 +256,7 @@ class DeepModelTransformer(Model):
             cols = [np.asarray(o).reshape(nb * bs, *o.shape[2:])[:n] for o in outs]
         else:
             cols = self._transform_pipelined(x, bs, d, key, apply_fn, variables,
-                                             fetches)
+                                             fetches, counters)
 
         out = table
         for (col_name, fetch_name), arr in zip(fetch.items(), cols):
@@ -264,8 +265,8 @@ class DeepModelTransformer(Model):
         return out
 
     def _transform_pipelined(self, x: np.ndarray, bs: int, d: int, family,
-                             apply_fn, variables,
-                             fetches: tuple[str, ...]) -> list[np.ndarray]:
+                             apply_fn, variables, fetches: tuple[str, ...],
+                             counters: tuple[str, ...]) -> list[np.ndarray]:
         """Non-fused loop on the async data plane: prepare (slice + pad +
         upload) of minibatch N+1 overlaps device compute on N, and host
         readback lags one batch so it overlaps too. Shapes, batch order,
@@ -332,11 +333,19 @@ class DeepModelTransformer(Model):
                             jax_compile_seconds() - paid_before.pop())
                     chunks.extend(readback.push((out, m)))
             chunks.extend(readback.drain())
-            if chunks and len(chunks[0]) > nf:
+            # a batch's counters follow its fetched outputs in the module's
+            # own order and are told apart by NAME: the experts' picks go
+            # to the load's accounting, a looped stack's exit steps to the
+            # root span; a name nothing here knows is read back and left
+            counted = {name: np.stack([c[nf + i] for c in chunks])
+                       for i, name in enumerate(counters)} if chunks else {}
+            if "moe_picks" in counted:
                 per_row = int(np.prod(x.shape[1:]))
                 self._record_expert_load(
-                    root, np.stack([c[nf] for c in chunks]),
+                    root, counted["moe_picks"],
                     [rows * per_row for rows in scored])
+            if "loop_exit_at" in counted:
+                self._record_loop(root, counted["loop_exit_at"])
             # a module with sliding-window layers says, from shapes alone,
             # what the banded kernel computes for a batch, in tiles and
             # fractions of one, and what the band needs (None where no such
@@ -362,6 +371,21 @@ class DeepModelTransformer(Model):
         }
         return [np.concatenate([c[j] for c in chunks])
                 for j in range(len(fetches))]
+
+    def _record_loop(self, root, exit_at: np.ndarray) -> None:
+        """`exit_at`: int (batches, steps), each batch's tokens (padding
+        rows' included: the device computed them) by the step of the
+        looped stack they leave at. Written on the call's root span with
+        the steps and the layer passes the call ran (every batch passes
+        every layer once a step), which the registry counts too."""
+        steps = int(exit_at.shape[1])
+        passes = len(exit_at) * int(self.bundle.module.num_layers) * steps
+        get_registry().counter(
+            "mmlspark_tpu_loop_layer_passes_total",
+            "layer passes of a looped stack: batches x layers x steps",
+        ).inc(float(passes))
+        root.set(loop_steps=steps, loop_layer_passes=passes,
+                 loop_exit_at=[int(n) for n in exit_at.sum(axis=0)])
 
     def _record_expert_load(self, root, picks: np.ndarray,
                             tokens: list[int]) -> None:

@@ -513,3 +513,102 @@ def test_a_pass_lowers_one_selection_body_its_last_block_included(
         sar._block_topk_unseen.clear_cache()
     assert text.count("tpu_custom_call") == 1
     assert "sar_topk_k10" in text
+
+
+# --------------------------------------------------------------------- #
+# the looped stack (`ouro_2_6b.score_reasoning_traces`)                 #
+# --------------------------------------------------------------------- #
+
+def _looped_for_the_chip(one_chip, monkeypatch, steps, rows, length,
+                         layers=2):
+    """(function of (variables, ids), their shapes on the described chip)
+    for `layers` layers of the cell's model at its published widths,
+    bfloat16, run `steps` times, embedding to log-probabilities."""
+    from mmlspark_tpu.nn import attention, models
+
+    for mod in (models, attention):
+        monkeypatch.setattr(mod.jax, "default_backend", lambda: "tpu")
+    module = models.make_model(
+        "looped_decoder", num_layers=layers, total_ut_steps=steps,
+        d_model=2048, num_heads=16, num_kv_heads=16, head_dim=128,
+        d_ff_dense=5632, vocab_size=49152, dtype=jnp.bfloat16)
+    variables = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: module.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))
+    ids = jax.ShapeDtypeStruct((rows, length), jnp.int32, sharding=one_chip)
+
+    def forward(v, x):
+        return module.apply(v, x, capture_intermediates=True,
+                            mutable=["intermediates"])
+
+    return forward, variables, ids
+
+
+@pytest.mark.parametrize("rows,length", [(2, 8192), (2, 1024)])
+def test_a_looped_stack_lowers_every_layer_once(one_chip, monkeypatch, rows,
+                                                length):
+    """The cell's two batches at its published widths: FOUR steps lower as
+    many Pallas bodies as ONE (the steps are a scan over one body whose
+    constants are the parameters), under the name the accepted reader
+    selects; the compiled program is a loop, both sown outputs come out of
+    it, and no activation is laid out again around the kernel. Two layers
+    stand for the cell's 44: a layer more is a body more at any step
+    count."""
+    def lowered(steps):
+        fn, variables, ids = _looped_for_the_chip(one_chip, monkeypatch,
+                                                  steps, rows, length)
+        return jax.jit(fn).lower(variables, ids)
+
+    one, four = lowered(1).as_text(), lowered(4).as_text()
+    assert one.count("tpu_custom_call") == four.count("tpu_custom_call")
+    assert "stablehlo.while" in four
+    text = _compile(*_looped_for_the_chip(one_chip, monkeypatch, 4, rows,
+                                          length)).as_text()
+    for layer in (0, 1):
+        assert re.search(rf"%gqa_attn_{layer}[.\d]* = ", text)
+    assert f"f32[{rows},{length},4]" in text            # the exit distribution
+    assert f"f32[{rows},{length - 1}]" in text          # the log-probabilities
+    body = text[:text.index("ENTRY")]
+    # inside the loop's body (the embedding's lookup, before it, is not)
+    moved = [line for line in body.splitlines()
+             if re.search(r" (copy|transpose)\(", line) and "/while/" in line
+             and f"[{rows},{length}," in line.split("=")[1].split("(")[0]]
+    assert not moved, moved
+
+
+def test_the_looped_references_layer_program_fits_beside_the_trees(one_chip):
+    """`benchmark/reference/looped_decoder.py`'s largest program, one
+    layer over one row of 8192 at hidden 2048 in float32: its temporaries
+    stay under 0.35 GB (read 0.270) beside 0.27 GB of arguments, which is
+    what `check` has to find free beside the served copy and the float32
+    tree (PERF.md section 4: 0.9 GB are left at 48 layers)."""
+    import importlib.util
+    import pathlib
+
+    path = (pathlib.Path(__file__).parent.parent / "benchmark" / "reference"
+            / "looped_decoder.py")
+    spec = importlib.util.spec_from_file_location("ref_looped_chip", path)
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    config = {"model": dict(
+        num_layers=44, total_ut_steps=4, d_model=2048, num_heads=16,
+        num_kv_heads=16, head_dim=128, d_ff_dense=5632, vocab_size=49152)}
+    frozen = tuple(sorted(ref.sizes(config).items()))
+
+    def spec_of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    weights = {name: spec_of(*shape) for name, shape in dict(
+        ln_attn_scale=(2048,), wq=(2048, 16, 128), wk=(2048, 16, 128),
+        wv=(2048, 16, 128), wo=(16, 128, 2048), ln_attn_post_scale=(2048,),
+        ln_mlp_scale=(2048,), gate=(2048, 5632), up=(2048, 5632),
+        down=(5632, 2048), ln_mlp_post_scale=(2048,)).items()}
+    assert set(weights) == set(ref.LAYER_NAMES)
+    with jax.default_matmul_precision("highest"):
+        compiled = _compile(lambda h, w: ref._layer(h, w, frozen),
+                            spec_of(1, 8192, 2048), weights)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.35e9
+    assert memory.argument_size_in_bytes < 0.3e9
