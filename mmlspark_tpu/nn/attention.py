@@ -30,7 +30,10 @@ j // group), and none repeats K or V to do it:
 A fourth core, ``eva_attention``, reads TWO sets of keys in one softmax
 (EVA, arXiv 2302.04542): the keys of the query's own window, exactly and
 causally, and one pooled key and value (``eva_summaries``) for every chunk
-of the windows before it; the same three tiers behind one switch. A fifth,
+of the windows before it; the same three tiers behind one switch (its
+kernel, `_eva_kernel`, folds its edge tiles over what their masks leave
+too: the diagonal's in `_edge_parts` parts, a block of summaries that ends
+past the ones seen over a key prefix, `_edge_prefixes`). A fifth,
 ``causal_attention(window=...)``, is a window that SLIDES with the query
 (query t reads keys t - window + 1 .. t): the plain flash fold over the key
 blocks a query block's band touches, both edges masked in the kernel (an
@@ -62,7 +65,8 @@ from ..parallel.ring_attention import (dense_attention, key_head_group,
 __all__ = ["dense_attention", "chunked_attention", "flash_attention",
            "flash_tiles", "causal_attention", "band_tiles", "band_tile_pairs",
            "latent_attention",
-           "eva_summaries", "eva_attention", "rotary_in_lanes",
+           "eva_summaries", "eva_attention", "eva_tile_pairs",
+           "rotary_in_lanes",
            "rotary_lanes_whole", "HeadsDense", "HeadsOut", "SelfAttention"]
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/max NaN-free
@@ -238,9 +242,9 @@ def _count_edge_parts(block_q: int, block_k: int, steps: int,
     edge tiles engaged (`_edge_parts`)."""
     get_registry().counter(
         "mmlspark_tpu_attention_edge_parts_total",
-        "causal flash-attention forward calls traced (plain, latent and "
-        "banded), by the tile and by the parts an edge tile is folded in "
-        "(1: whole, masked)",
+        "causal flash-attention forward calls traced (plain, latent, "
+        "banded, and windowed-and-summarised), by the tile and by the "
+        "parts an edge tile is folded in (1: whole, masked)",
         labels=("tile", "parts")).labels(
             tile=f"{block_q}x{block_k}",
             parts=str(_edge_parts(block_q, block_k, steps, window))).inc()
@@ -359,7 +363,9 @@ def flash_tiles(tq: int, tk: int, dtype,
     1024 on the diagonal (or on a band's trailing edge) is folded in two
     parts of 512 queries and computes 3/4 of itself, about 3.9 us (4.3 on
     the trailing edge) where the whole tile masked takes 5.0 and an
-    unmasked one 4.1 (`_edge_parts`; PERF.md, PR 41).
+    unmasked one 4.1 (`_edge_parts`; PERF.md, PR 41), in every fold that
+    has such a tile: `_flash_fold` (plain, latent, banded) and, since PR
+    43, `_eva_kernel`.
 
     Told a `window` (`eva_attention`: a query reads the keys of its own
     window of that many positions), both tiles are the largest under the
@@ -439,6 +445,42 @@ def _block(ref, at=None):
     return ref[0] if at is None else ref[0, pl.ds(*at), :]
 
 
+def _weigh(s, ok, m, v_ref, keys=None):
+    """exp(s - m): its row sums (rows, 1) and its product with the `keys`
+    of the value block (rows, Dv)."""
+    p = jnp.exp(s - m)
+    if ok is not None:
+        # masked entries must contribute 0 even when the whole row is
+        # masked (then m == _NEG_INF and exp(s - m) == 1, not 0)
+        p = jnp.where(ok, p, 0.0)
+    pv = jax.lax.dot_general(
+        p.astype(v_ref.dtype), _block(v_ref, keys),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    return p.sum(-1, keepdims=True), pv
+
+
+def _fold_tile(s, ok, v_ref, scratch, rows=None, keys=None):
+    """The online-softmax step every fold of this module takes (the plain,
+    latent and banded forwards' `_flash_fold` and `_eva_kernel`): a float32
+    score tile `s`, masked already, of the `rows` of the query block
+    against the `keys` of a source block (first, how many; None: all),
+    folded into those rows of the running maximum, denominator and
+    accumulator (`scratch`). `ok` is what of the tile counts where a row
+    may have seen nothing yet; None where every row holds a real score, in
+    the tile or from a step before it (exp(_NEG_INF - m) is 0 by itself)."""
+    import jax.experimental.pallas as pl
+
+    m_sc, l_sc, acc_sc = scratch
+    mine = ... if rows is None else (pl.ds(*rows), slice(None))
+    m_prev = m_sc[mine]                                       # (rows, 1)
+    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+    l, pv = _weigh(s, ok, m_new, v_ref, keys)
+    corr = jnp.exp(m_prev - m_new)                            # (rows, 1)
+    l_sc[mine] = l_sc[mine] * corr + l
+    acc_sc[mine] = acc_sc[mine] * corr + pv
+    m_sc[mine] = m_new
+
+
 def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
                 block_k, num_kv, causal, tk_valid, scale, window=None,
                 key_blocks=None):
@@ -508,19 +550,6 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
             s = jnp.where(ok, s, _NEG_INF)
         return s, ok
 
-    def weigh(s, ok, m, keys=None):
-        """exp(s - m): its row sums (rows, 1) and its product with the
-        values (rows, Dv)."""
-        p = jnp.exp(s - m)
-        if ok is not None:
-            # masked entries must contribute 0 even when the whole row is
-            # masked (then m == _NEG_INF and exp(s - m) == 1, not 0)
-            p = jnp.where(ok, p, 0.0)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), _block(v_ref, keys),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return p.sum(-1, keepdims=True), pv
-
     def write(m, l, acc):
         out = acc / jnp.maximum(l, 1e-30)
         out = jnp.where(l > 0, out, 0.0)
@@ -536,7 +565,7 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
         # bit for bit, as one step of the path below)
         s, ok = scores(padded, causal, window is not None)
         m = s.max(-1, keepdims=True)
-        write(m, *weigh(s, ok, m))
+        write(m, *_weigh(s, ok, m, v_ref))
         return
 
     m_sc, l_sc, acc_sc = scratch
@@ -553,18 +582,11 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
         block, folded into the running max / denominator / accumulator of
         those rows."""
         s, ok = scores(mask_keys, mask_causal, mask_trailing, rows, keys)
-        mine = ... if rows is None else (pl.ds(*rows), slice(None))
-        m_prev = m_sc[mine]                                   # (rows, 1)
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
         if rows is not None and mask_causal and not mask_keys:
             # every row of a part on the diagonal sees its own key: the
             # maximum is a score, and exp(_NEG_INF - m) is 0 by itself
             ok = None
-        l, pv = weigh(s, ok, m_new, keys)
-        corr = jnp.exp(m_prev - m_new)                        # (rows, 1)
-        l_sc[mine] = l_sc[mine] * corr + l
-        acc_sc[mine] = acc_sc[mine] * corr + pv
-        m_sc[mine] = m_new
+        _fold_tile(s, ok, v_ref, scratch, rows, keys)
 
     if not causal:
         step(padded, False)
@@ -1295,15 +1317,58 @@ def _eva_chunked(q, k, v, kbar, vbar, window, chunk, q_chunk: int = 128):
     return jnp.moveaxis(out, 0, 1).reshape(b, -1, h, v.shape[-1])[:, :t]
 
 
+def _edge_prefixes(block_s: int, per_window: int) -> tuple[int, ...]:
+    """The key prefixes among which `_eva_kernel` folds a block of summaries
+    that ends past the ones its queries see, from what it can see; () is
+    the whole block, masked by column. A query block sees `per_window`
+    summaries a window before its own, so where a block is whole windows'
+    shares the valid part of such a block is one of its prefixes of
+    `per_window`, 2 x `per_window`, .. columns. A prefix has to be a static
+    slice, so the kernel chooses among them by `pl.when` and folds that
+    prefix alone, UNMASKED: the block is fetched whole, the products, the
+    exponentials and the values' product shrink and the mask goes. Shares
+    of whole lane blocks only: a block of 1024 with 128 summaries a window
+    has 7 (`evabyte_6_5b.score_byte_docs`' rows of 32768 bytes); a block
+    that ends with a window's share has no edge, and the tests' small
+    windows keep the whole masked block. Read on a v5e (PERF.md, PR 43),
+    2 x 32768 x 32 heads: the seven prefixes take 0.51 to 0.62 ms off a
+    call of 25.2, which is the MASK's cost (0.3 us a tile); fewer, wider
+    classes that keep the mask (halves, quarters) gain nothing, a fold's
+    cost being mostly its rows' (the running maximum and sum as columns),
+    not its columns'."""
+    if per_window % 128 or block_s % per_window:
+        return ()
+    return tuple(range(per_window, block_s, per_window))
+
+
+def _eva_steps(t: int, window: int, chunk: int, block_k: int,
+               block_s: int) -> tuple[int, int]:
+    """(key blocks of a window, blocks of the summaries a row of `t`
+    positions reads): the two runs of `_eva_kernel`'s last grid axis."""
+    summaries = (-(-t // window) - 1) * (window // chunk)
+    return window // block_k, -(-summaries // block_s)
+
+
 def _eva_kernel(q_ref, k_ref, v_ref, kb_ref, vb_ref, o_ref, m_sc, l_sc,
                 acc_sc, *, block_q, block_k, block_s, n_local, n_remote,
                 window, per_window, scale):
     """A grid of (row, head, query block, source block). A block of
     queries, which lies in ONE window, over the grid's last axis: first
-    the `n_local` key blocks of its window (those above the diagonal
-    skipped), then the `n_remote` blocks of summaries (those past the
-    `per_window` x window index that lie before it skipped), all into one
-    running maximum, denominator and accumulator."""
+    the `n_remote` blocks of summaries, the last first (those past the
+    `per_window` x window index that lie before it skipped), then the
+    `n_local` key blocks of its window, the last first (those above the
+    diagonal skipped), all into one running maximum, denominator and
+    accumulator (`_fold_tile`, the step `_flash_fold` takes). In that
+    order the steps that compute nothing come before those that do and a
+    query block's LAST step is a whole tile of its window's keys: the next
+    query block's operands are fetched under it (under a skipped step, or
+    a short one, the copy was waited for: PERF.md, PR 43).
+
+    An EDGE tile is folded only over what its mask leaves: the diagonal's
+    in parts along the queries where `_edge_parts` says so, as
+    `_flash_fold` does, and a block of summaries that ends past the ones
+    seen over a key prefix (`_edge_prefixes`). Every row still sums over
+    exactly the keys and summaries it saw."""
     import jax.experimental.pallas as pl
 
     qi, j = pl.program_id(2), pl.program_id(3)
@@ -1316,63 +1381,65 @@ def _eva_kernel(q_ref, k_ref, v_ref, kb_ref, vb_ref, o_ref, m_sc, l_sc,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def fold(keys_ref, values_ref, counts):
-        """One block of keys or summaries folded in; `counts(shape)` is
-        what of the (bq, bk) tile counts, or None for all of it."""
-        s = jax.lax.dot_general(
-            q_ref[0], keys_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        ok = None if counts is None else counts(s.shape)
-        if ok is not None:
-            s = jnp.where(ok, s, _NEG_INF)
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if ok is not None:
-            # a row with nothing yet has m_new == _NEG_INF: exp(0), not 0
-            p = jnp.where(ok, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_sc[...] = l_sc[...] * corr + p.sum(-1, keepdims=True)
-        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
-            p.astype(values_ref.dtype), values_ref[0],
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_sc[...] = m_new
-
-    # the window's own keys, causally
-    kfirst = (own * (window // block_k) + j) * block_k
-    needed = (j < n_local) & (kfirst <= first + block_q - 1)
-    crosses = kfirst + block_k - 1 > first
-
-    def causal(shape):
-        return (first + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-                >= kfirst + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
-
-    @pl.when(needed & crosses)
-    def _diagonal():
-        fold(k_ref, v_ref, causal)
-
-    @pl.when(needed & jnp.logical_not(crosses))
-    def _below():
-        fold(k_ref, v_ref, None)
+    def fold(keys_ref, values_ref, counts=None, rows=None, keys=None):
+        """One block of keys or summaries, or the `keys` of it for the
+        `rows` of the query block, folded in; `counts(shape)` is what of
+        that tile counts (None: all of it). Every masked tile holds a
+        score that counts in each of its rows (a summary seen by one query
+        of the block is seen by all; a query's own key), so a row's
+        maximum is a real score from its first tile on and a masked
+        entry's exp(_NEG_INF - m) is 0 by itself."""
+        s = _qk(q_ref, keys_ref, rows, keys) * scale
+        if counts is not None:
+            s = jnp.where(counts(s.shape), s, _NEG_INF)
+        _fold_tile(s, None, values_ref, (m_sc, l_sc, acc_sc), rows, keys)
 
     # the summaries of the windows before it
-    sfirst = (j - n_local) * block_s
+    sfirst = (n_remote - 1 - j) * block_s
     seen = own * per_window
-    reads = (j >= n_local) & (sfirst < seen)
+    reads = (j < n_remote) & (sfirst < seen)
     if per_window % block_s:
         # a block of summaries may end past the windows before this one
-        partly = sfirst + block_s > seen
-
-        @pl.when(reads & partly)
-        def _edge():
-            fold(kb_ref, vb_ref, lambda shape: sfirst
-                 + jax.lax.broadcasted_iota(jnp.int32, shape, 1) < seen)
-
+        visible = seen - sfirst
+        partly = reads & (visible < block_s)
+        prefixes = _edge_prefixes(block_s, per_window)
+        if not prefixes:
+            pl.when(partly)(functools.partial(
+                fold, kb_ref, vb_ref, lambda shape: jax.lax.broadcasted_iota(
+                    jnp.int32, shape, 1) < visible))
+        for prefix in prefixes:
+            pl.when(partly & (visible == prefix))(functools.partial(
+                fold, kb_ref, vb_ref, keys=(0, prefix)))
         reads = reads & jnp.logical_not(partly)
 
     @pl.when(reads)
     def _before():
-        fold(kb_ref, vb_ref, None)
+        fold(kb_ref, vb_ref)
+
+    # the window's own keys, causally
+    kfirst = (own * n_local + n_local + n_remote - 1 - j) * block_k
+    needed = (j >= n_remote) & (kfirst <= first + block_q - 1)
+    crosses = kfirst + block_k - 1 > first
+    parts = _edge_parts(block_q, block_k, n_local + n_remote, window)
+
+    @pl.when(needed & crosses)
+    def _diagonal():
+        if parts == 1:
+            return fold(k_ref, v_ref, lambda shape: (
+                first + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                >= kfirst + jax.lax.broadcasted_iota(jnp.int32, shape, 1)))
+        # equal tiles: the tile's corner lies on the diagonal, and part r
+        # reads the keys up to its own square
+        size = block_q // parts
+        for r in range(parts):
+            fold(k_ref, v_ref, lambda shape, r=r: (
+                jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                - jax.lax.broadcasted_iota(jnp.int32, shape, 0) <= r * size),
+                (r * size, size), (0, (r + 1) * size))
+
+    @pl.when(needed & jnp.logical_not(crosses))
+    def _below():
+        fold(k_ref, v_ref)
 
     @pl.when(j == n_local + n_remote - 1)
     def _finalize():
@@ -1421,7 +1488,8 @@ def _eva_flash(q, k, v, phi, mu, kbar=None, vbar=None, *, window, chunk,
     in), `eva_attn_*` attends. Nothing of size T x T or T x T / chunk is
     ever whole in HBM; a key block outside the query's window and a
     summary block at or past it are neither fetched (their index maps
-    name a block that is already in VMEM) nor computed on.
+    name the block the query block's next computing step reads) nor
+    computed on.
 
     q, k, v (B, T, H, D) in, (B, T, H, D) out. The layout is
     `_flash_fwd_lse`'s, by shape: heads of whole lane blocks (multiples of
@@ -1441,8 +1509,7 @@ def _eva_flash(q, k, v, phi, mu, kbar=None, vbar=None, *, window, chunk,
     windows_before = -(-t // window) - 1
     qf, kf, vf = (_pad_seq(_rows(x, in_place), max(block_q, block_k))[0]
                   for x in (q, k, v))
-    n_local = window // block_k
-    n_remote = -(-windows_before * per_window // block_s)
+    n_local, n_remote = _eva_steps(t, window, chunk, block_k, block_s)
     tag = f"w{window}c{chunk}"      # a device trace's readers select by name
 
     def like(x, positions, width):
@@ -1481,16 +1548,19 @@ def _eva_flash(q, k, v, phi, mu, kbar=None, vbar=None, *, window, chunk,
                                    in_place), block_s)[0]
                     for x in (kbar, vbar))
 
+    # a step that computes nothing names the block the next one that does
+    # will read, so that it is fetched under the last step before them
+
     def key_block(b_, j, qi, s):
         own = (qi * block_q) // window
         last = (qi * block_q + block_q - 1) // block_k     # the diagonal's
-        return at(b_, j, jnp.minimum(
-            own * n_local + jnp.minimum(s, n_local - 1), last))
+        return at(b_, j, jnp.minimum(own * n_local + jnp.clip(
+            n_local + n_remote - 1 - s, 0, n_local - 1), last))
 
     def summary_block(b_, j, qi, s):
         seen = ((qi * block_q) // window) * per_window
         last = jnp.maximum(-(-seen // block_s) - 1, 0)
-        return at(b_, j, jnp.clip(s - n_local, 0, last))
+        return at(b_, j, jnp.clip(n_remote - 1 - s, 0, last))
 
     def query_block(b_, j, qi, s):
         return at(b_, j, qi)
@@ -1515,6 +1585,41 @@ def _eva_flash(q, k, v, phi, mu, kbar=None, vbar=None, *, window, chunk,
             interpret=interpret, name=f"eva_attn_{tag}",
         )(qf, kf, vf, kbf, vbf)
     return _heads(out[:, :t], b, h, in_place)
+
+
+def eva_tile_pairs(t: int, window: int, chunk: int, block_q: int,
+                   block_k: int, block_s: int):
+    """-> (computed, needed) for one head of one row of `t` positions, in
+    tiles of block_q x block_k: what the flash tier's attention kernel
+    COMPUTES at those tiles, by the kernel's own rules (a key block at or
+    below the diagonal, the diagonal's `edge_tile_share` of one where
+    `_edge_parts` folds it in parts; a block of summaries that holds one
+    its queries see, the key prefix of `_edge_prefixes` where it ends past
+    them; a block of summaries counts block_s / block_k of a tile), and
+    the (query, key) and (query, summary) pairs the masks leave. Their
+    ratio is what the tiles' edges cost (`band_tile_pairs`' count, for
+    this kernel)."""
+    per_window = window // chunk
+    share = edge_tile_share(_edge_parts(
+        block_q, block_k, sum(_eva_steps(t, window, chunk, block_k, block_s)),
+        window))
+    prefixes = _edge_prefixes(block_s, per_window)
+    tile = max(block_q, block_k)
+    computed = 0.0
+    for first in range(0, -(-t // tile) * tile, block_q):
+        own = first // window
+        for kfirst in range(own * window, first + block_q, block_k):
+            computed += share if kfirst + block_k - 1 > first else 1.0
+        seen = own * per_window
+        for sfirst in range(0, seen, block_s):
+            columns = next((p for p in prefixes if seen - sfirst <= p),
+                           block_s)
+            computed += columns / block_k
+    whole, rest = divmod(t, window)
+    needed = (whole * window * (window + 1) / 2 + rest * (rest + 1) / 2
+              + per_window * (window * whole * (whole - 1) / 2
+                              + rest * whole))
+    return computed, needed / (block_q * block_k)
 
 
 def eva_attention(q, k, v, phi, mu, window: int, chunk: int,
@@ -1571,10 +1676,18 @@ def eva_attention(q, k, v, phi, mu, window: int, chunk: int,
     get_registry().counter(
         "mmlspark_tpu_eva_calls_total",
         "windowed-and-summarised attention forward calls traced, by the "
-        "window, the chunk and the tile (queries x keys x summaries)",
-        labels=("window", "chunk", "tile")).labels(
+        "window, the chunk, the tile (queries x keys x summaries) and the "
+        "key prefixes among which a block of summaries that ends past the "
+        "ones seen is folded (0: the whole block, masked, or no such "
+        "block)",
+        labels=("window", "chunk", "tile", "prefixes")).labels(
             window=str(window), chunk=str(chunk),
-            tile=f"{block_q}x{block_k}x{block_s}").inc()
+            tile=f"{block_q}x{block_k}x{block_s}",
+            prefixes=str(len(_edge_prefixes(block_s, window // chunk)))
+        ).inc()
+    _count_edge_parts(
+        block_q, block_k, sum(_eva_steps(t, window, chunk, block_k, block_s)),
+        window)
     _count_operands("eva", _lanes_whole(q.shape[-1], v.shape[-1]))
     return _eva_flash(q, k, v, phi, mu, *(summaries or ()), window=window,
                       chunk=chunk, block_q=block_q, block_k=block_k,

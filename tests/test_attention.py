@@ -743,11 +743,12 @@ def _eva_by_masks(q, k, v, phi, mu, window, chunk):
             kbar, vbar)
 
 
-def _eva_calls(window, chunk, tile) -> float:
+def _eva_calls(window, chunk, tile, prefixes=0) -> float:
     return get_registry().counter(
         "mmlspark_tpu_eva_calls_total",
-        labels=("window", "chunk", "tile")).labels(
-            window=str(window), chunk=str(chunk), tile=tile).value
+        labels=("window", "chunk", "tile", "prefixes")).labels(
+            window=str(window), chunk=str(chunk), tile=tile,
+            prefixes=str(prefixes)).value
 
 
 class TestEvaAttention:
@@ -1340,3 +1341,214 @@ class TestEdgeTilesInParts:
         jax.eval_shape(flash_attention, x, x, x)
         assert _edge_parts_calls("1024x1024", PARTS) == before[0] + 1
         assert _edge_parts_calls("512x512", 1) == before[1] + 1
+
+
+# --------------------------------------------------------------------- #
+# the windowed-and-summarised kernel's edge tiles (`_eva_kernel`)        #
+# --------------------------------------------------------------------- #
+
+def _whole_blocks(monkeypatch):
+    """`_whole_tiles`, and every block of summaries that ends past the ones
+    seen folded whole, masked by column, as the parent folds it."""
+    monkeypatch.setattr(attention, "_edge_prefixes", lambda *a, **kw: ())
+    _whole_tiles(monkeypatch)
+
+
+class TestEvaEdgeTiles:
+    """`_eva_kernel` folds an edge tile over what its mask leaves: the
+    diagonal's tile in `_edge_parts` parts, and a block of summaries that
+    ends past the ones its queries see over a key prefix
+    (`_edge_prefixes`). Tiles of `EDGE_TILE`, the smallest that split, a
+    window of one such tile with 128 summaries (chunks of 8), summaries in
+    blocks of 512: the prefixes are 128, 256 and 384, and window w's
+    queries see 128 x w. On the CPU's interpreted kernel; float32."""
+
+    WINDOW, CHUNK = EDGE_TILE, EDGE_TILE // 128
+    TILES = {"block_q": EDGE_TILE, "block_k": EDGE_TILE, "block_s": 512,
+             "interpret": True}
+
+    @pytest.fixture(autouse=True)
+    def _no_trace_outlives_its_rule(self):
+        """`_eva_flash` is jitted by itself: a trace made with the rule
+        taken away would serve the next test of the same shapes."""
+        yield
+        jax.clear_caches()
+
+    def call(self, q, k, v, phi, mu, **more):
+        return eva_attention(q, k, v, phi, mu, self.WINDOW, self.CHUNK,
+                             impl="flash", **self.TILES, **more)
+
+    def test_the_rules_by_shape(self):
+        prefixes = attention._edge_prefixes
+        # the cell's long rows, and this class's blocks
+        assert prefixes(1024, 128) == (128, 256, 384, 512, 640, 768, 896)
+        assert prefixes(512, 128) == (128, 256, 384)
+        # a block that ends with a window's share (the cell's rows of 4096);
+        # shares of no lane blocks; a block of broken shares; the tests'
+        assert prefixes(128, 128) == prefixes(1024, 64) == ()
+        assert prefixes(1000, 128) == prefixes(24, 8) == prefixes(3, 8) == ()
+        # the diagonal's parts are `_flash_fold`'s rule: two key blocks of
+        # the window and two of summaries are four steps
+        assert attention._edge_parts(1024, 1024, 4, 2048) == PARTS
+        assert attention._edge_parts(512, 512, 5, 2048) == 1
+
+    # the last window sees 128 x `windows` summaries: a last block of
+    # summaries that is an edge in EACH prefix class (1, 2, 3; 5: the
+    # second block's first), and one that ends where they do (4)
+    @pytest.mark.parametrize("windows", [1, 2, 3, 4, 5])
+    def test_the_cut_edges_match_the_masks_and_the_whole_tiles(
+            self, monkeypatch, windows):
+        t = windows * self.WINDOW + EDGE_TILE // 2 + 24
+        operands = _eva_inputs(t, b=1, h=2, seed=windows)
+        want, _kbar, _vbar = _eva_by_masks(*operands, self.WINDOW,
+                                           self.CHUNK)
+        cut = np.asarray(self.call(*operands))
+        program = str(jax.make_jaxpr(self.call)(*operands))
+        assert np.abs(cut - want).max() < EVA_LIMIT
+        _whole_blocks(monkeypatch)
+        assert np.abs(cut - np.asarray(self.call(*operands))).max() \
+            < EDGE_LIMIT
+        # it did engage (the CPU may well sum a row to the same bits)
+        assert str(jax.make_jaxpr(self.call)(*operands)) != program
+
+    def test_heads_of_whole_lanes_read_in_place(self, monkeypatch):
+        t = 3 * self.WINDOW
+        operands = _eva_inputs(t, b=1, h=1, d=128, seed=6)
+        before = _operands("eva", "in_place")
+        cut = np.asarray(self.call(*operands))
+        assert _operands("eva", "in_place") == before + 1
+        want, _kbar, _vbar = _eva_by_masks(*operands, self.WINDOW,
+                                           self.CHUNK)
+        assert np.abs(cut - want).max() < EVA_LIMIT
+        _whole_blocks(monkeypatch)
+        assert np.abs(cut - np.asarray(self.call(*operands))).max() \
+            < EDGE_LIMIT
+
+    # what a start lowers: the pooling's body and the attention's, at the
+    # cell's three batches (the parent's: 193 and 149) and at this class's
+    # tiles; a later prefix class or part shows here first
+    @pytest.mark.parametrize("rows,t,window,chunk,tiles,equations", [
+        (2, 32768, 2048, 16, {}, [43, 396]),
+        (2, 4096, 2048, 16, {}, [43, 181]),
+        (1, 4096, 2048, 16, {}, [43, 181]),
+        (1, 5 * EDGE_TILE, EDGE_TILE, EDGE_TILE // 128,
+         {"block_q": EDGE_TILE, "block_k": EDGE_TILE, "block_s": 512},
+         [43, 276]),
+    ])
+    def test_the_kernels_equations_are_what_a_start_was_budgeted(
+            self, monkeypatch, rows, t, window, chunk, tiles, equations):
+        x = jax.ShapeDtypeStruct((rows, t, 2, 128), jnp.bfloat16)
+        vec = jax.ShapeDtypeStruct((2, 128), jnp.float32)
+
+        def call(q, k, v, phi, mu):
+            return eva_attention(q, k, v, phi, mu, window, chunk, **tiles)
+
+        assert kernel_equations(
+            jax.make_jaxpr(call)(x, x, x, vec, vec).jaxpr) == equations
+        _whole_blocks(monkeypatch)
+        whole = kernel_equations(
+            jax.make_jaxpr(call)(x, x, x, vec, vec).jaxpr)
+        assert whole[0] == equations[0] and whole[1] < equations[1]
+
+    @pytest.mark.parametrize("planted", ["diagonal", "summaries"])
+    def test_what_the_mask_erases_is_not_read(self, monkeypatch, planted):
+        """NaN where a mask erases: keys and values in the upper corner of
+        a diagonal tile (the LAST window's, which nobody pools), summaries
+        past the prefix that a window sees. A masked entry's weight is 0,
+        and 0 x NaN in the values' product is NaN: the whole tile lets it
+        through, the cut one never multiplies it."""
+        t = 4 * self.WINDOW
+        q, k, v, phi, mu = _eva_inputs(t, b=1, h=2, seed=12)
+        summaries = eva_summaries(k, v, phi, mu, self.CHUNK)
+        nan = jnp.nan
+        if planted == "diagonal":
+            # the second half of the last tile's keys: its first half of
+            # queries lies above them
+            dirty = slice(t - EDGE_TILE // 2, t)
+            k, v = k.at[:, dirty].set(nan), v.at[:, dirty].set(nan)
+            clean = slice(0, t - EDGE_TILE // 2)
+        else:
+            # window 2's summaries, 256 .. 383: windows 1 and 2 see 128
+            # and 256 of the block they lie in, window 3 sees them
+            summaries = tuple(x.at[:, 256:384].set(nan) for x in summaries)
+            clean = slice(0, 3 * self.WINDOW)
+
+        def run():
+            return np.asarray(self.call(q, k, v, None, None,
+                                        summaries=summaries))
+
+        out = run()
+        assert np.isfinite(out[:, clean]).all()
+        assert np.isnan(out[:, clean.stop:]).all()
+        _whole_blocks(monkeypatch)
+        assert np.isnan(run()[:, clean]).any()
+
+    def test_a_traced_call_is_counted_with_its_parts_and_prefixes(self):
+        vec = jax.ShapeDtypeStruct((2, 128), jnp.float32)
+
+        def trace(t):
+            x = jax.ShapeDtypeStruct((1, t, 2, 128), jnp.bfloat16)
+            jax.eval_shape(
+                lambda q, k, v, phi, mu: eva_attention(q, k, v, phi, mu,
+                                                       2048, 16),
+                x, x, x, vec, vec)
+
+        def counts():
+            return (_eva_calls(2048, 16, "1024x1024x1024", prefixes=7),
+                    _eva_calls(2048, 16, "1024x1024x128"),
+                    _edge_parts_calls("1024x1024", PARTS))
+
+        before = counts()
+        trace(32768)
+        assert counts() == (before[0] + 1, before[1], before[2] + 1)
+        # a block of summaries that ends with a window's share has no edge
+        trace(4096)
+        assert counts() == (before[0] + 1, before[1] + 1, before[2] + 2)
+
+
+def _eva_pairs_by_masks(t, window, chunk) -> int:
+    """The (query, key) and (query, summary) pairs the two masks leave."""
+    pos = np.arange(t)
+    start = (pos // window) * window
+    local = (pos[None, :] <= pos[:, None]) & (pos[None, :] >= start[:, None])
+    remote = ((np.arange(t // chunk)[None, :] + 1) * chunk
+              <= start[:, None])
+    return int(local.sum() + remote.sum())
+
+
+class TestEvaTilePairs:
+    """`eva_tile_pairs`: what the kernel computes against what the masks
+    leave, counted without a chip."""
+
+    CELL = (2048, 16, 1024, 1024)
+
+    def test_the_cells_long_rows(self, monkeypatch):
+        computed, needed = attention.eva_tile_pairs(32768, *self.CELL, 1024)
+        # 32 diagonal tiles at 3/4, 16 whole; 16 whole blocks of
+        # summaries and 28 prefixes of 128 .. 896 columns
+        assert computed == 24 + 16 + 16 + 14
+        assert needed == pytest.approx(62, abs=0.02)
+        monkeypatch.setattr(attention, "_edge_prefixes", lambda *a: ())
+        assert attention.eva_tile_pairs(32768, *self.CELL, 1024)[0] == 84
+        monkeypatch.setattr(attention, "_edge_parts", lambda *a, **kw: 1)
+        assert attention.eva_tile_pairs(32768, *self.CELL, 1024) == (
+            92, needed)
+
+    def test_the_cells_rows_of_4096(self, monkeypatch):
+        # two windows: 4 diagonal tiles of 6, one block of 128 summaries
+        # for each of the second window's two query blocks
+        computed, needed = attention.eva_tile_pairs(4096, *self.CELL, 128)
+        assert computed == 3 + 2 + 2 * 128 / 1024
+        assert needed == pytest.approx(4.25, abs=0.01)
+        monkeypatch.setattr(attention, "_edge_parts", lambda *a, **kw: 1)
+        assert attention.eva_tile_pairs(4096, *self.CELL, 128)[0] == 6.25
+
+    @pytest.mark.parametrize("t,tiles", [
+        (96, (32, 32, 16)), (70, (16, 16, 16)), (80, (32, 16, 8)),
+        (96, (16, 32, 24)), (70, (8, 8, 3))])
+    def test_needed_is_the_masks_and_computed_covers_it(self, t, tiles):
+        computed, needed = attention.eva_tile_pairs(t, EVA_WINDOW,
+                                                    EVA_CHUNK, *tiles)
+        assert needed * tiles[0] * tiles[1] == _eva_pairs_by_masks(
+            t, EVA_WINDOW, EVA_CHUNK)
+        assert computed >= needed

@@ -156,6 +156,12 @@ def test_windowed_and_summarised_attention_kernels_compile(one_chip, rows,
         q, q, q, phi, phi).as_text()
     assert text.count("tpu_custom_call") >= 2
     assert "eva_attn_w2048c16" in text and "eva_pool_w2048c16" in text
+    # no array of T x T or T x T / 16 (told apart from the hidden width,
+    # 4096, at the long rows alone)
+    if length == 32768:
+        for found in re.findall(r"\[([\d,]+)\]", text):
+            extents = [int(n) for n in found.split(",")]
+            assert extents.count(length) + extents.count(length // 16) < 2
 
 
 @pytest.mark.parametrize("length,name", [
@@ -400,35 +406,86 @@ FOLDS = {
 }
 
 
+def _fold_jaxpr(case):
+    """The traced program of a cell's flash forward (`FOLDS`' shapes and
+    the two latent ones), as `make_jaxpr` prints it."""
+    from mmlspark_tpu.nn import attention
+
+    bf = jnp.bfloat16
+    if case.startswith("latent"):
+        t = int(case.split("x")[1])
+        return jax.make_jaxpr(attention.latent_attention)(
+            jax.ShapeDtypeStruct((8, t, 16, 128), bf),
+            jax.ShapeDtypeStruct((8, t, 16, 64), bf),
+            jax.ShapeDtypeStruct((8, t, 16, 256), bf),
+            jax.ShapeDtypeStruct((8, t, 64), bf))
+    (rows, t, heads, key_heads, d, window), _was, _limit = FOLDS[case]
+    q = jax.ShapeDtypeStruct((rows, t, heads, d), bf)
+    k = jax.ShapeDtypeStruct((rows, t, key_heads, d), bf)
+    if window == "not causal":
+        return jax.make_jaxpr(attention.flash_attention)(q, k, k)
+    return jax.make_jaxpr(lambda q, k, v: attention.causal_attention(
+        q, k, v, "flash", window=window))(q, k, k)
+
+
 @pytest.mark.parametrize("case", [*FOLDS, "latent_8x4096", "latent_8x512"])
 def test_the_folds_equations_stay_inside_what_a_start_was_budgeted(case):
     """A later edit cannot buy speed with a start unseen: the body of
     every flash forward the cells lower, counted at the cells' shapes
     (traced on the CPU, nothing lowered), stays within its budget, and is
     the parent's own where the edge tiles are not split."""
-    from mmlspark_tpu.nn import attention
-
-    bf = jnp.bfloat16
     if case.startswith("latent"):
-        t = int(case.split("x")[1])
-        was, limit = (128, 162) if t == 4096 else (53, 53)
-        jaxpr = jax.make_jaxpr(attention.latent_attention)(
-            jax.ShapeDtypeStruct((8, t, 16, 128), bf),
-            jax.ShapeDtypeStruct((8, t, 16, 64), bf),
-            jax.ShapeDtypeStruct((8, t, 16, 256), bf),
-            jax.ShapeDtypeStruct((8, t, 64), bf))
+        was, limit = (128, 162) if case.endswith("4096") else (53, 53)
     else:
-        (rows, t, heads, key_heads, d, window), was, limit = FOLDS[case]
-        q = jax.ShapeDtypeStruct((rows, t, heads, d), bf)
-        k = jax.ShapeDtypeStruct((rows, t, key_heads, d), bf)
-        if window == "not causal":
-            jaxpr = jax.make_jaxpr(attention.flash_attention)(q, k, k)
-        else:
-            jaxpr = jax.make_jaxpr(
-                lambda q, k, v: attention.causal_attention(
-                    q, k, v, "flash", window=window))(q, k, k)
+        _shape, was, limit = FOLDS[case]
+    jaxpr = _fold_jaxpr(case)
     (equations,) = kernel_equations(jaxpr.jaxpr)
     assert was <= equations <= limit
+
+
+def _parents_weigh(s, ok, m, v_ref, keys=None):
+    """PR 41's `weigh`, as `_flash_fold` held it."""
+    from mmlspark_tpu.nn.attention import _block
+
+    p = jnp.exp(s - m)
+    if ok is not None:
+        p = jnp.where(ok, p, 0.0)
+    pv = jax.lax.dot_general(
+        p.astype(v_ref.dtype), _block(v_ref, keys),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    return p.sum(-1, keepdims=True), pv
+
+
+def _parents_step(s, ok, v_ref, scratch, rows=None, keys=None):
+    """PR 41's `step` behind its score tile, as `_flash_fold` held it
+    before PR 43 lifted it out as `_fold_tile` for `_eva_kernel` to share."""
+    import jax.experimental.pallas as pl
+
+    m_sc, l_sc, acc_sc = scratch
+    mine = ... if rows is None else (pl.ds(*rows), slice(None))
+    m_prev = m_sc[mine]
+    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+    l, pv = _parents_weigh(s, ok, m_new, v_ref, keys)
+    corr = jnp.exp(m_prev - m_new)
+    l_sc[mine] = l_sc[mine] * corr + l
+    acc_sc[mine] = acc_sc[mine] * corr + pv
+    m_sc[mine] = m_new
+
+
+@pytest.mark.parametrize("case", [*FOLDS, "latent_8x4096", "latent_8x512"])
+def test_the_shared_step_leaves_the_other_folds_programs_as_they_were(
+        monkeypatch, case):
+    """`_fold_tile` serves `_eva_kernel` too since PR 43: the plain, the
+    latent and the banded forward at the other cells' shapes still trace,
+    word for word, what they traced with the step inside `_flash_fold`
+    (kept here as the parent wrote it). Traced on the CPU."""
+    from mmlspark_tpu.nn import attention
+
+    shared = str(_fold_jaxpr(case))
+    monkeypatch.setattr(attention, "_fold_tile", _parents_step)
+    monkeypatch.setattr(attention, "_weigh", _parents_weigh)
+    jax.clear_caches()          # the forwards jitted by themselves
+    assert str(_fold_jaxpr(case)) == shared
 
 
 def _sar_shapes(one_chip, users=69878, items=10677):
