@@ -21,8 +21,10 @@ j // group), and none repeats K or V to do it:
 - ``flash_attention``: a Pallas TPU kernel for the forward hot path —
   the (block_q, block_k) score tile lives only in VMEM, never HBM, with
   the online-softmax running max / denominator / accumulator carried in
-  VMEM scratch across the sequential key-block grid dimension. The tile
-  is chosen here, by `flash_tiles`, from the lengths and the dtype.
+  VMEM scratch across the sequential key-block grid dimension (the
+  maximum and the denominator lane-dense, 128 lanes a row: `_fold_tile`,
+  the one step every fold here takes). The tile is chosen here, by
+  `flash_tiles`, from the lengths and the dtype.
   DIFFERENTIABLE via `jax.custom_vjp`: the kernel also emits the per-row
   logsumexp, and the backward is the standard flash recomputation as a
   pure-XLA k-block scan (compiles on every backend; O(T) score memory).
@@ -52,6 +54,8 @@ or flash for bf16 serving.
 from __future__ import annotations
 
 import functools
+import math
+import operator
 from typing import Any
 
 import jax
@@ -250,6 +254,21 @@ def _count_edge_parts(block_q: int, block_k: int, steps: int,
             parts=str(_edge_parts(block_q, block_k, steps, window))).inc()
 
 
+def _count_fold_rows(kernel: str, block_q: int, steps: int) -> None:
+    """Counted where a flash forward is traced: in what row parts its
+    folds take a tile that nothing masks (`_row_parts`; one tile a row
+    holds no running statistics and stays whole)."""
+    parts = _row_parts(block_q) if steps > 1 else 1
+    get_registry().counter(
+        "mmlspark_tpu_attention_fold_rows_total",
+        "flash-attention forward calls traced (plain, latent, banded, and "
+        "windowed-and-summarised), by the kernel and by the rows x parts in "
+        "which a fold takes an unmasked tile (1024x1: whole; 512x2: two "
+        "halves)",
+        labels=("kernel", "rows")).labels(
+            kernel=kernel, rows=f"{block_q // parts}x{parts}").inc()
+
+
 # --------------------------------------------------------------------- #
 # chunked (memory-efficient, differentiable)                            #
 # --------------------------------------------------------------------- #
@@ -365,7 +384,13 @@ def flash_tiles(tq: int, tk: int, dtype,
     the trailing edge) where the whole tile masked takes 5.0 and an
     unmasked one 4.1 (`_edge_parts`; PERF.md, PR 41), in every fold that
     has such a tile: `_flash_fold` (plain, latent, banded) and, since PR
-    43, `_eva_kernel`.
+    43, `_eva_kernel`. Those are a call's time over its tiles. Since PR 44
+    (`_fold_tile`: the running maximum and sum lane-dense, the scale in
+    the exponent, an unmasked tile in two row halves) the triangle over
+    16384 tokens takes 3.5 us a tile where it took 4.1; by leaving kinds
+    of tile out of the kernel, an unmasked tile costs 2.4 us (3.0 before),
+    the diagonal's in two parts 2.2 (2.9), and a grid step with nothing
+    folded 0.5, the copies (PERF.md, PR 44).
 
     Told a `window` (`eva_attention`: a query reads the keys of its own
     window of that many positions), both tiles are the largest under the
@@ -430,6 +455,37 @@ def _edge_parts(block_q: int, block_k: int, steps: int,
 _PART_ROWS = 512
 
 
+def _row_parts(block_q: int) -> int:
+    """In how many parts along the queries a fold takes a tile that
+    NOTHING masks, from what it can see; 1 is the whole tile. Two halves,
+    each half's products, maximum, exponential and value product
+    independent of the other's, so that the compiler overlaps one half's
+    softmax with the other's products; by `_edge_parts`' rule, a part of
+    `_PART_ROWS` rows or more and whole lane blocks: tiles of 1024 (inputs
+    of 2 bytes). A row sums the same keys in the same order either way.
+    Read on a v5e (PERF.md, PR 44), the kernels alone: two halves take
+    4.6% off the triangle at 2 x 16384 (28 over 4 heads of 128), 3.4% off
+    the band of 4096 there, 4.5% off heads of 64, 2.5% off
+    `eva_attention` at 2 x 32768 and 1.6% off the latent forward at 8 x
+    4096."""
+    return 2 if block_q % 256 == 0 and block_q // 2 >= _PART_ROWS else 1
+
+
+def _row_halves(block_q: int) -> tuple:
+    """The rows (first, how many; None: all) of each of `_row_parts`' parts
+    of a tile that nothing masks. A Python loop over them writes the fold
+    once and applies it to each, as the edge tiles' parts are: ONE traced
+    body unrolled by the lowering (`lax.fori_loop`) schedules the same
+    bundles but costs a start more, 23 ms a plain kernel traced and lowered
+    where two bodies written out cost 3 to 9, and the latent kernel's
+    lowering 315 ms where 86 (PERF.md, PR 44)."""
+    parts = _row_parts(block_q)
+    if parts == 1:
+        return (None,)
+    size = block_q // parts
+    return tuple((r * size, size) for r in range(parts))
+
+
 def edge_tile_share(parts: int) -> float:
     """What of an edge tile the fold computes when it takes it in `parts`
     parts: part r of n is (r + 1) / n of the keys for 1 / n of the
@@ -445,10 +501,52 @@ def _block(ref, at=None):
     return ref[0] if at is None else ref[0, pl.ds(*at), :]
 
 
-def _weigh(s, ok, m, v_ref, keys=None):
-    """exp(s - m): its row sums (rows, 1) and its product with the `keys`
-    of the value block (rows, Dv)."""
-    p = jnp.exp(s - m)
+# the lanes of a vector register: the running statistics are kept that wide
+_LANES = 128
+_LOG2_E = math.log2(math.e)
+
+
+def _stat_lanes(*widths: int) -> int:
+    """How many lanes wide the running maximum and sum are kept: 128, a
+    vector register's, where the score tiles' columns are whole blocks of
+    that many (every tile `flash_tiles` chooses past 128 keys); the widest
+    block that divides them at a test's small tile."""
+    return math.gcd(_LANES, *widths)
+
+
+def _over(x, width: int):
+    """A per-row statistic held replicated across its lanes, (rows, lanes),
+    laid over `width` columns: whole registers repeated, never a (rows, 1)
+    column permuted back over the lanes. A column broadcasts by itself."""
+    lanes = x.shape[1]
+    if lanes == 1 or width == lanes:
+        return x
+    if width < lanes:
+        return x[:, :width]
+    if width % lanes:
+        return x[:, :1]
+    return jnp.tile(x, (1, width // lanes))
+
+
+def _lane_sums(p, lanes: int):
+    """p's columns added up in blocks of `lanes`: (rows, lanes) partial
+    sums a row, elementwise (no reduction across lanes), one block after
+    the other (added by halves, six equations where eight, the compiler
+    schedules a step 2% worse: PERF.md, PR 44); one lane is the row sum
+    itself."""
+    if lanes == 1:
+        return p.sum(-1, keepdims=True)
+    return functools.reduce(operator.add, jnp.split(p, p.shape[1] // lanes, 1))
+
+
+def _weigh(s, ok, m, v_ref, exponent, lanes, keys=None):
+    """exp((s - m) x scale) of RAW products s and their raw maximum m (a
+    (rows, 1) column, or (rows, lanes) replicated), as ONE multiply an
+    element: exp2 of (s - m) x `exponent`, the scale times log2 e folded in
+    Python (scale > 0, so the maximum commutes with it). -> its row sums as
+    `lanes` per-lane partial sums (rows, lanes), and its product with the
+    `keys` of the value block (rows, Dv)."""
+    p = jnp.exp2((s - _over(m, s.shape[1])) * exponent)
     if ok is not None:
         # masked entries must contribute 0 even when the whole row is
         # masked (then m == _NEG_INF and exp(s - m) == 1, not 0)
@@ -456,28 +554,36 @@ def _weigh(s, ok, m, v_ref, keys=None):
     pv = jax.lax.dot_general(
         p.astype(v_ref.dtype), _block(v_ref, keys),
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    return p.sum(-1, keepdims=True), pv
+    return _lane_sums(p, lanes), pv
 
 
-def _fold_tile(s, ok, v_ref, scratch, rows=None, keys=None):
+def _fold_tile(s, ok, v_ref, scratch, exponent, rows=None, keys=None):
     """The online-softmax step every fold of this module takes (the plain,
     latent and banded forwards' `_flash_fold` and `_eva_kernel`): a float32
-    score tile `s`, masked already, of the `rows` of the query block
+    tile `s` of RAW products (unscaled: `exponent` is the scale times
+    log2 e, `_weigh`), masked already, of the `rows` of the query block
     against the `keys` of a source block (first, how many; None: all),
     folded into those rows of the running maximum, denominator and
     accumulator (`scratch`). `ok` is what of the tile counts where a row
     may have seen nothing yet; None where every row holds a real score, in
-    the tile or from a step before it (exp(_NEG_INF - m) is 0 by itself)."""
+    the tile or from a step before it (exp(_NEG_INF - m) is 0 by itself).
+
+    The statistics are LANE-DENSE, (block_q, `_stat_lanes`) float32: the
+    running maximum (of the raw products) replicated across the lanes, the
+    running sum as per-lane partial sums that only the finalisation adds
+    up across lanes. As (block_q, 1) columns they cost a step a lane
+    permute and a cross-lane sum a row block and most of its vector
+    stores, which paced it (PERF.md, PR 44)."""
     import jax.experimental.pallas as pl
 
     m_sc, l_sc, acc_sc = scratch
     mine = ... if rows is None else (pl.ds(*rows), slice(None))
-    m_prev = m_sc[mine]                                       # (rows, 1)
+    m_prev = m_sc[mine]                                   # (rows, lanes)
     m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-    l, pv = _weigh(s, ok, m_new, v_ref, keys)
-    corr = jnp.exp(m_prev - m_new)                            # (rows, 1)
+    l, pv = _weigh(s, ok, m_new, v_ref, exponent, m_prev.shape[1], keys)
+    corr = jnp.exp2((m_prev - m_new) * exponent)          # (rows, lanes)
     l_sc[mine] = l_sc[mine] * corr + l
-    acc_sc[mine] = acc_sc[mine] * corr + pv
+    acc_sc[mine] = acc_sc[mine] * _over(corr, pv.shape[1]) + pv
     m_sc[mine] = m_new
 
 
@@ -514,12 +620,13 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
 
     def scores(mask_keys: bool, mask_causal: bool, mask_trailing=False,
                rows=None, keys=None):
-        """This step's score tile, (bq, bk) or the `rows` and `keys` of
-        it, and which of it counts (None: all of it). `mask_keys`: keys at
-        or past `tk_valid` are padding; `mask_causal`: a query sees the
-        keys at or before it; `mask_trailing`: and none `window` or more
-        behind it."""
-        s = products(rows, keys) * scale
+        """This step's tile of RAW products (the scale is in the exponent:
+        `_weigh`; `_NEG_INF` masks whatever the scale), (bq, bk) or the
+        `rows` and `keys` of it, and which of it counts (None: all of it).
+        `mask_keys`: keys at or past `tk_valid` are padding; `mask_causal`:
+        a query sees the keys at or before it; `mask_trailing`: and none
+        `window` or more behind it."""
+        s = products(rows, keys)
         ok = None
         if rows is not None:
             # a part of an edge tile (`fold`): the tile's corner lies on
@@ -550,22 +657,27 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
             s = jnp.where(ok, s, _NEG_INF)
         return s, ok
 
+    exponent = scale * _LOG2_E
+
     def write(m, l, acc):
+        """A row's raw maximum and its sum, (bq, 1) columns, and the
+        accumulator, written out."""
         out = acc / jnp.maximum(l, 1e-30)
         out = jnp.where(l > 0, out, 0.0)
         o_ref[0] = out.astype(o_ref.dtype)
-        # per-row logsumexp, the backward pass's softmax residual;
-        # +inf on fully-masked rows makes exp(s - lse) vanish there
+        # per-row logsumexp of the scaled scores, the backward pass's
+        # softmax residual; +inf on fully-masked rows makes exp(s - lse)
+        # vanish there
         lse_ref[0] = jnp.where(
-            l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), jnp.inf)
+            l > 0, m * scale + jnp.log(jnp.maximum(l, 1e-30)), jnp.inf)
 
     if num_kv == 1:
         # the softmax is whole in this tile: no running maximum, no
-        # correction, no accumulator through scratch (the same numbers,
-        # bit for bit, as one step of the path below)
+        # correction, no accumulator through scratch (the step of the
+        # path below, its exponent and its order of sums)
         s, ok = scores(padded, causal, window is not None)
         m = s.max(-1, keepdims=True)
-        write(m, *_weigh(s, ok, m, v_ref))
+        write(m, *_weigh(s, ok, m, v_ref, exponent, 1))
         return
 
     m_sc, l_sc, acc_sc = scratch
@@ -580,13 +692,19 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
              rows=None, keys=None):
         """One key block, or the `keys` of it for the `rows` of the query
         block, folded into the running max / denominator / accumulator of
-        those rows."""
+        those rows; a whole tile that nothing masks in `_row_parts`
+        parts."""
+        if rows is None and not (mask_keys or mask_causal or mask_trailing):
+            for part in _row_halves(block_q):
+                _fold_tile(products(part, None), None, v_ref, scratch,
+                           exponent, part)
+            return
         s, ok = scores(mask_keys, mask_causal, mask_trailing, rows, keys)
         if rows is not None and mask_causal and not mask_keys:
             # every row of a part on the diagonal sees its own key: the
             # maximum is a score, and exp(_NEG_INF - m) is 0 by itself
             ok = None
-        _fold_tile(s, ok, v_ref, scratch, rows, keys)
+        _fold_tile(s, ok, v_ref, scratch, exponent, rows, keys)
 
     if not causal:
         step(padded, False)
@@ -638,7 +756,20 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
 
     @pl.when(at == num_kv - 1)
     def _finalize():
-        write(m_sc[...], l_sc[...], acc_sc[...])
+        # the ONE sum across lanes a row
+        write(m_sc[:, :1], l_sc[...].sum(-1, keepdims=True), acc_sc[...])
+
+
+def _fold_scratch(block_q: int, dv: int, *key_widths: int) -> list:
+    """`_fold_tile`'s scratch: the running maximum and sum, lane-dense, and
+    the accumulator. As (block_q, 1) columns the first two were padded to
+    128 lanes already: the same VMEM."""
+    import jax.experimental.pallas.tpu as pltpu
+
+    lanes = _stat_lanes(*key_widths)
+    return [pltpu.VMEM((block_q, lanes), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32)]
 
 
 def _qk(q_ref, k_ref, rows=None, keys=None):
@@ -698,7 +829,6 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
     key blocks from `_band_first` on: a block wholly behind the band is
     never named, one above the diagonal re-names the diagonal's."""
     import jax.experimental.pallas as pl
-    import jax.experimental.pallas.tpu as pltpu
 
     keys = [*keys, value]
     dv = value[1]
@@ -742,9 +872,10 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
         + [key_spec(w, at) for _x, w, at in keys],
         out_specs=[
             query_spec(dv, out_at),
-            # lse keeps the scratch's (block_q, 1) column layout: a
-            # trailing dim equal to the array's satisfies Mosaic's block
-            # rule, and no sublane->lane relayout happens in the kernel
+            # lse is a (block_q, 1) column, a row's statistic as the
+            # finalisation's sum across lanes leaves it: a trailing dim
+            # equal to the array's satisfies Mosaic's block rule, and no
+            # sublane->lane relayout happens in the kernel
             pl.BlockSpec((1, block_q, 1),
                          lambda b_, j, qi, kv: (b_ * h + j, qi, 0)),
         ],
@@ -753,11 +884,8 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
             jax.ShapeDtypeStruct((b * h, nq * block_q, 1), jnp.float32),
         ],
         # one key block carries nothing from step to step
-        scratch_shapes=[] if steps == 1 else [
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, dv), jnp.float32),
-        ],
+        scratch_shapes=[] if steps == 1 else _fold_scratch(
+            block_q, dv, block_k),
         interpret=interpret, name=name,
     )(*(x for x, _w, _at in [*queries, *keys]))
 
@@ -897,7 +1025,8 @@ def _flash_diff_bwd(causal, block_q, block_k, bwd_chunk, interpret, res, do):
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
 
 
-def _call_tiles(tq: int, tk: int, dtype, block_q, block_k, causal: bool):
+def _call_tiles(tq: int, tk: int, dtype, block_q, block_k, causal: bool,
+                kernel: str = "flash"):
     """A forward's (block_q, block_k): `flash_tiles`' unless a test names
     one; counted where the call is traced, once a compiled shape."""
     rule_q, rule_k = flash_tiles(tq, tk, dtype)
@@ -910,6 +1039,7 @@ def _call_tiles(tq: int, tk: int, dtype, block_q, block_k, causal: bool):
             tile=f"{block_q}x{block_k}", causal=str(causal).lower()).inc()
     if causal:
         _count_edge_parts(block_q, block_k, -(-tk // block_k))
+    _count_fold_rows(kernel, block_q, -(-tk // block_k))
     return block_q, block_k
 
 
@@ -1084,8 +1214,9 @@ def causal_attention(q, k, v, impl: str = "flash", window: int | None = None,
                 "window and the tile (queries x keys)",
                 labels=("window", "tile")).labels(
                     window=str(window), tile=f"{block_q}x{block_k}").inc()
-            _count_edge_parts(block_q, block_k,
-                              _band_steps(t, block_q, block_k, window), window)
+            steps = _band_steps(t, block_q, block_k, window)
+            _count_edge_parts(block_q, block_k, steps, window)
+            _count_fold_rows("swa", block_q, steps)
             _count_operands("swa", _lanes_whole(q.shape[-1], v.shape[-1]))
             return _banded_flash(
                 q, k, v, window=window, block_q=block_q, block_k=block_k,
@@ -1222,7 +1353,8 @@ def latent_attention(q_nope, q_rope, kv, k_rope, impl: str = "flash",
             **({"interpret": True} if interpret else {}))
     t = q_nope.shape[1]
     # under the plain forward's counter too: its fold, at its tile
-    block_q, block_k = _call_tiles(t, t, q_nope.dtype, block_q, block_k, True)
+    block_q, block_k = _call_tiles(t, t, q_nope.dtype, block_q, block_k, True,
+                                   "mla")
     _count_operands("mla", True)
     return _latent_diff(q_nope, q_rope, kv, k_rope, block_q, block_k,
                         interpret)
@@ -1333,9 +1465,10 @@ def _edge_prefixes(block_s: int, per_window: int) -> tuple[int, ...]:
     windows keep the whole masked block. Read on a v5e (PERF.md, PR 43),
     2 x 32768 x 32 heads: the seven prefixes take 0.51 to 0.62 ms off a
     call of 25.2, which is the MASK's cost (0.3 us a tile); fewer, wider
-    classes that keep the mask (halves, quarters) gain nothing, a fold's
-    cost being mostly its rows' (the running maximum and sum as columns),
-    not its columns'."""
+    classes that keep the mask (halves, quarters) gained nothing then, a
+    fold's cost being mostly its rows' while the running maximum and sum
+    were (rows, 1) columns; lane-dense since PR 44, a prefix of 128
+    columns schedules a third of what it did."""
     if per_window % 128 or block_s % per_window:
         return ()
     return tuple(range(per_window, block_s, per_window))
@@ -1374,6 +1507,8 @@ def _eva_kernel(q_ref, k_ref, v_ref, kb_ref, vb_ref, o_ref, m_sc, l_sc,
     qi, j = pl.program_id(2), pl.program_id(3)
     first = qi * block_q
     own = first // window                       # this block's window
+    scratch = (m_sc, l_sc, acc_sc)
+    exponent = scale * _LOG2_E                  # on raw products: `_weigh`
 
     @pl.when(j == 0)
     def _init():
@@ -1389,10 +1524,16 @@ def _eva_kernel(q_ref, k_ref, v_ref, kb_ref, vb_ref, o_ref, m_sc, l_sc,
         of the block is seen by all; a query's own key), so a row's
         maximum is a real score from its first tile on and a masked
         entry's exp(_NEG_INF - m) is 0 by itself."""
-        s = _qk(q_ref, keys_ref, rows, keys) * scale
+        if counts is None and rows is None:
+            # a whole tile, or a key prefix of one, that nothing masks
+            for part in _row_halves(block_q):
+                _fold_tile(_qk(q_ref, keys_ref, part, keys), None,
+                           values_ref, scratch, exponent, part, keys)
+            return
+        s = _qk(q_ref, keys_ref, rows, keys)
         if counts is not None:
             s = jnp.where(counts(s.shape), s, _NEG_INF)
-        _fold_tile(s, None, values_ref, (m_sc, l_sc, acc_sc), rows, keys)
+        _fold_tile(s, None, values_ref, scratch, exponent, rows, keys)
 
     # the summaries of the windows before it
     sfirst = (n_remote - 1 - j) * block_s
@@ -1443,7 +1584,8 @@ def _eva_kernel(q_ref, k_ref, v_ref, kb_ref, vb_ref, o_ref, m_sc, l_sc,
 
     @pl.when(j == n_local + n_remote - 1)
     def _finalize():
-        o_ref[0] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
+        o_ref[0] = (acc_sc[...] / l_sc[...].sum(-1, keepdims=True)).astype(
+            o_ref.dtype)
 
 
 def _eva_pool_kernel(k_ref, v_ref, phi_ref, mu_ref, kb_ref, vb_ref, *,
@@ -1499,7 +1641,6 @@ def _eva_flash(q, k, v, phi, mu, kbar=None, vbar=None, *, window, chunk,
     any other width (a test's 8) every operand is copied head-major to
     (B x H, T, D) first and the output copied back."""
     import jax.experimental.pallas as pl
-    import jax.experimental.pallas.tpu as pltpu
 
     b, t, h, d = q.shape
     dv = v.shape[-1]
@@ -1579,9 +1720,7 @@ def _eva_flash(q, k, v, phi, mu, kbar=None, vbar=None, *, window, chunk,
                       pl.BlockSpec((1, block_s, dv), summary_block)],
             out_specs=pl.BlockSpec((1, block_q, dv), query_block),
             out_shape=like(qf, qf.shape[1], dv),
-            scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
-                            pltpu.VMEM((block_q, 1), jnp.float32),
-                            pltpu.VMEM((block_q, dv), jnp.float32)],
+            scratch_shapes=_fold_scratch(block_q, dv, block_k, block_s),
             interpret=interpret, name=f"eva_attn_{tag}",
         )(qf, kf, vf, kbf, vbf)
     return _heads(out[:, :t], b, h, in_place)
@@ -1685,9 +1824,9 @@ def eva_attention(q, k, v, phi, mu, window: int, chunk: int,
             tile=f"{block_q}x{block_k}x{block_s}",
             prefixes=str(len(_edge_prefixes(block_s, window // chunk)))
         ).inc()
-    _count_edge_parts(
-        block_q, block_k, sum(_eva_steps(t, window, chunk, block_k, block_s)),
-        window)
+    steps = sum(_eva_steps(t, window, chunk, block_k, block_s))
+    _count_edge_parts(block_q, block_k, steps, window)
+    _count_fold_rows("eva", block_q, steps)
     _count_operands("eva", _lanes_whole(q.shape[-1], v.shape[-1]))
     return _eva_flash(q, k, v, phi, mu, *(summaries or ()), window=window,
                       chunk=chunk, block_q=block_q, block_k=block_k,

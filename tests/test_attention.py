@@ -208,10 +208,12 @@ class TestFlashTiles:
         assert -(-tk // block_k) == key_blocks
         # a tile does no work the shapes rule out: no key positions where no
         # key is padding, and with one key block no correction to a running
-        # maximum (its second exponential)
+        # maximum (its second exponential; the scale is inside it, so the
+        # exponential is base 2: `_weigh`)
         kernel = str(jax.make_jaxpr(call)(*low))
         assert ("= iota[" in kernel) == masked
-        assert (kernel.count("= exp ") == 1) == (key_blocks == 1)
+        assert (kernel.count("= exp2 ") == 1) == (key_blocks == 1)
+        assert "= exp " not in kernel
 
     def test_backward_chunk_is_not_the_tile(self):
         """A caller that names nothing (`SelfAttention`) gets the rule's
@@ -1282,21 +1284,25 @@ class TestEdgeTilesInParts:
         for a, b_ in zip(grads(**self.TILES), split):
             np.testing.assert_allclose(a, b_, atol=2e-6, rtol=1e-5)
 
-    # where the fold is the parent's: one tile a row (the cells' rows of
+    # where the edge tiles stay whole: one tile a row (the cells' rows of
     # 512 and 1024 tokens), unequal tiles, tiles under the threshold, a
     # window no multiple of 128 divides, and every non-causal call; the
-    # equations are the parent's kernels' at these shapes, counted on its
-    # tree (PERF.md, PR 41: 120 plain causal, 243 banded, 18 more where
-    # keys are padded)
+    # equations are PR 41's kernels' at these shapes (49, 68, 120, 120,
+    # 261, 243: 120 plain causal, 243 banded, 18 more where keys are
+    # padded) and what PR 44's step adds to them: 1 where one tile holds
+    # no running statistics (the log-sum-exp's scale), 3 to 49 elsewhere
+    # (a fold's row sum by lanes, the maximum laid over the tile, the
+    # exponent's constant twice, less the scale's pass; a second fold for
+    # the `_row_parts` halves of a tile of 1024 rows that nothing masks)
     @pytest.mark.parametrize("case,t,window,tiles,equations", [
-        ("one_tile", 512, None, {}, 49),
+        ("one_tile", 512, None, {}, 50),
         ("one_tile_band", 1024, 512, {"block_q": 1024, "block_k": 1024},
-         68),
-        ("unequal", 2048, None, {"block_q": 1024, "block_k": 512}, 120),
-        ("small", 512, None, {"block_q": 128, "block_k": 128}, 120),
-        ("window_1100", 2200, 1100, {}, 261),
+         69),
+        ("unequal", 2048, None, {"block_q": 1024, "block_k": 512}, 161),
+        ("small", 512, None, {"block_q": 128, "block_k": 128}, 123),
+        ("window_1100", 2200, 1100, {}, 288),
         ("band_unequal", 4096, 1024, {"block_q": 1024, "block_k": 512},
-         243),
+         292),
     ])
     def test_where_the_split_does_not_engage_the_program_is_the_parents(
             self, monkeypatch, case, t, window, tiles, equations):
@@ -1425,15 +1431,17 @@ class TestEvaEdgeTiles:
             < EDGE_LIMIT
 
     # what a start lowers: the pooling's body and the attention's, at the
-    # cell's three batches (the parent's: 193 and 149) and at this class's
-    # tiles; a later prefix class or part shows here first
+    # cell's three batches (PR 42's: 193 and 149; PR 43's: 396 and 181;
+    # PR 44's lane-dense step, and every fold that nothing masks in two
+    # row halves, the seven prefixes among them: 730 and 263) and at this
+    # class's tiles; a later prefix class or part shows here first
     @pytest.mark.parametrize("rows,t,window,chunk,tiles,equations", [
-        (2, 32768, 2048, 16, {}, [43, 396]),
-        (2, 4096, 2048, 16, {}, [43, 181]),
-        (1, 4096, 2048, 16, {}, [43, 181]),
+        (2, 32768, 2048, 16, {}, [43, 730]),
+        (2, 4096, 2048, 16, {}, [43, 263]),
+        (1, 4096, 2048, 16, {}, [43, 263]),
         (1, 5 * EDGE_TILE, EDGE_TILE, EDGE_TILE // 128,
          {"block_q": EDGE_TILE, "block_k": EDGE_TILE, "block_s": 512},
-         [43, 276]),
+         [43, 454]),
     ])
     def test_the_kernels_equations_are_what_a_start_was_budgeted(
             self, monkeypatch, rows, t, window, chunk, tiles, equations):
@@ -1552,3 +1560,254 @@ class TestEvaTilePairs:
         assert needed * tiles[0] * tiles[1] == _eva_pairs_by_masks(
             t, EVA_WINDOW, EVA_CHUNK)
         assert computed >= needed
+
+
+# --------------------------------------------------------------------- #
+# the fold's statistics kept lane-dense, the scale in the exponent, an   #
+# unmasked tile in two row halves (`_fold_tile`, `_weigh`, `_row_parts`) #
+# --------------------------------------------------------------------- #
+
+def _whole_rows(monkeypatch):
+    """Every unmasked tile folded whole: `_whole_tiles`' way of taking a
+    rule by shape away for a comparison at EQUAL tiles."""
+    monkeypatch.setattr(attention, "_row_parts", lambda block_q: 1)
+    jax.clear_caches()
+
+
+def _fold_rows_calls(kernel: str, rows: str) -> float:
+    return get_registry().counter(
+        "mmlspark_tpu_attention_fold_rows_total",
+        labels=("kernel", "rows")).labels(kernel=kernel, rows=rows).value
+
+
+def _scaled_scores(q, k, causal: bool, tk: int | None = None):
+    """(B, H, Tq, Tk) float64 scores over sqrt(D), -inf where a key is
+    ahead of its query (causal) or at or past `tk` (padding)."""
+    q, k = (np.asarray(x, np.float64) for x in (q, k))
+    k = np.repeat(k, q.shape[2] // k.shape[2], 2)
+    s = np.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    qpos, kpos = np.arange(q.shape[1])[:, None], np.arange(k.shape[1])[None]
+    seen = kpos < (k.shape[1] if tk is None else tk)
+    if causal:
+        seen = seen & (kpos <= qpos)
+    return np.where(seen, s, -np.inf)
+
+
+def _log_sum_exp(s):
+    m = s.max(-1, keepdims=True)
+    return (m + np.log(np.exp(s - m).sum(-1, keepdims=True)))[..., 0]
+
+
+class TestLaneDenseFold:
+    """The running maximum and sum of a fold are (block_q, 128): the
+    maximum of the RAW products replicated across the lanes, the sum as 128
+    per-lane partial sums that the finalisation adds up; the weights are
+    exp2((s - m) x scale x log2 e). Tiles of 128 and 256 (whole lane
+    blocks, several steps a row) on the CPU's interpreted kernel, float32
+    inputs; today's tolerances."""
+
+    def test_the_rules_by_shape(self):
+        lanes, parts = attention._stat_lanes, attention._row_parts
+        # every tile the rule chooses past 128 keys; EvaByte's two sources
+        assert lanes(1024) == lanes(512) == lanes(640) == lanes(128) == 128
+        assert lanes(1024, 1024) == lanes(1024, 128) == 128
+        # a test's small tiles: the widest block that divides them
+        assert lanes(8) == 8 and lanes(64) == 64 and lanes(192) == 64
+        assert lanes(32, 16) == 16 and lanes(200) == 8
+        # an unmasked tile of 1024 in two halves of 512 rows; tiles of 512
+        # and under (float32 inputs, short rows) whole
+        assert parts(1024) == 2
+        assert parts(512) == parts(640) == parts(128) == parts(8) == 1
+
+    @pytest.mark.parametrize("heads,d", [((4, 2), 128), ((4, 1), 64)])
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("tile", [128, 256])
+    def test_the_plain_fold_matches_dense(self, heads, d, causal, tile):
+        """Heads of 128 in place and of 64 head-major, grouped key heads,
+        3 to 5 steps a row, keys padded inside the last block."""
+        rng = np.random.default_rng(d + tile)
+        q, k, v = (jnp.asarray(rng.normal(size=(1, 600, h, d)), jnp.float32)
+                   for h in (heads[0], heads[1], heads[1]))
+        got = flash_attention(q, k, v, causal=causal, block_q=tile,
+                              block_k=tile, interpret=True)
+        np.testing.assert_allclose(
+            got, dense_attention(q, k, v, causal=causal), atol=2e-5,
+            rtol=2e-5)
+
+    @pytest.mark.parametrize("window", [128, 200, 384])
+    def test_the_banded_fold_matches_the_mask(self, window):
+        q, k, v = _band_inputs(600, (4, 2), 128, seed=window)
+        got = attention.causal_attention(
+            q, k, v, "flash", window=window, block_q=128, block_k=128,
+            interpret=True)
+        assert np.abs(np.asarray(got) - _band_by_mask(q, k, v, window)
+                      ).max() < 2e-5
+
+    def test_the_latent_fold_matches_dense(self):
+        operands = _latent_inputs(600, b=1, h=2, seed=3)
+        got = attention.latent_attention(*operands, block_q=128, block_k=256,
+                                         interpret=True)
+        want = attention.causal_attention(
+            *attention._latent_concatenated(*operands), "dense")
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("d", [128, 8])
+    def test_the_windowed_and_summarised_fold_matches_the_masks(self, d):
+        """Two sources in one running maximum: keys in blocks of 128,
+        summaries in blocks of 128 (a window of 256 positions, 32 a
+        window)."""
+        t, window, chunk = 900, 256, 8
+        operands = _eva_inputs(t, b=1, h=2, d=d, seed=4)
+        want, _kbar, _vbar = _eva_by_masks(*operands, window, chunk)
+        got = eva_attention(*operands, window, chunk, impl="flash",
+                            block_q=128, block_k=128, block_s=128,
+                            interpret=True)
+        assert np.abs(np.asarray(got) - want).max() < EVA_LIMIT
+
+    @pytest.mark.parametrize("kind", ["plain", "banded", "latent", "eva"])
+    def test_two_row_halves_are_the_whole_tiles_fold_bit_for_bit(
+            self, monkeypatch, kind):
+        """A tile of 1024 that nothing masks is folded as two halves of
+        512 rows: every row against the same keys in the same order, so
+        the same bits (the CPU's product sums a row's channels alike at
+        512 and at 1024 rows). Whole tiles of keys: a padded length masks
+        its keys in every step."""
+        t = 3 * EDGE_TILE
+        if kind == "latent":
+            operands = _latent_inputs(t, b=1, h=2, seed=5)
+
+            def call():
+                return attention.latent_attention(
+                    *operands, block_q=EDGE_TILE, block_k=EDGE_TILE,
+                    interpret=True)
+        elif kind == "eva":
+            operands = _eva_inputs(3 * EDGE_TILE + 24, b=1, h=2, seed=5)
+
+            def call():
+                return eva_attention(
+                    *operands, EDGE_TILE, EDGE_TILE // 128, impl="flash",
+                    block_q=EDGE_TILE, block_k=EDGE_TILE, block_s=512,
+                    interpret=True)
+        else:
+            q, k, v = _band_inputs(t, (2, 1), 64, seed=5)
+
+            def call():
+                return attention.causal_attention(
+                    q, k, v, "flash", block_q=EDGE_TILE, block_k=EDGE_TILE,
+                    window=EDGE_TILE if kind == "banded" else None,
+                    interpret=True)
+
+        halves = np.asarray(call())
+        program = str(jax.make_jaxpr(call)())
+        _whole_rows(monkeypatch)
+        assert np.array_equal(np.asarray(call()), halves)
+        # it did engage
+        assert str(jax.make_jaxpr(call)()) != program
+        jax.clear_caches()      # the forwards jitted by themselves
+
+    @pytest.mark.parametrize("steps", [1, 2])
+    def test_a_row_of_padding_is_zero_and_a_padded_block_sums_real_keys(
+            self, steps):
+        """`_flash_fold` told that NO key is real (every row empty: output
+        0, log-sum-exp +inf, the backward's exp(s - lse) = 0) and that the
+        LAST key block holds 3 real keys (a row sums those and no
+        padding), at one key block a row and at two."""
+        block, d = 128, 64
+        tk = steps * block
+        rng = np.random.default_rng(steps)
+        q, k, v = (jnp.asarray(rng.normal(size=(1, block, 1, d)),
+                               jnp.float32),
+                   *(jnp.asarray(rng.normal(size=(1, tk, 1, d)), jnp.float32)
+                     for _ in range(2)))
+        at = attention._block_at(False, 1)
+
+        def fold(real):
+            return attention._flash_call(
+                attention._flash_kernel, [(q[:, :, 0], d, at)],
+                [(k[:, :, 0], d, at)], (v[:, :, 0], d, at), at,
+                jax.ShapeDtypeStruct((1, block, d), jnp.float32), b=1, h=1,
+                tk=real, causal=False, scale=d ** -0.5, block_q=block,
+                block_k=block, interpret=True)
+
+        out, lse = fold(0)
+        assert np.array_equal(np.asarray(out), np.zeros((1, block, d)))
+        assert np.isposinf(np.asarray(lse)).all()
+        real = tk - block + 3
+        out, lse = fold(real)
+        s = _scaled_scores(q, k, False, tk=real)
+        np.testing.assert_allclose(lse[0, :, 0], _log_sum_exp(s)[0, 0],
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(
+            out[:, :, None], dense_attention(q, k[:, :real], v[:, :real]),
+            atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_one_tile_and_two_of_half_the_size_agree(self, causal):
+        """`num_kv == 1` holds no running statistics and takes the step's
+        exponent: a row as one tile of 256 keys and as two of 128 agree to
+        float32 rounding, outputs and log-sum-exp."""
+        q, k, v = _qkv(1, 256, 256, 2, 64, seed=6)
+        one = attention._flash_fwd_lse(q, k, v, causal, 256, 256, True)
+        two = attention._flash_fwd_lse(q, k, v, causal, 256, 128, True)
+        np.testing.assert_allclose(one[0], two[0], atol=EDGE_LIMIT)
+        np.testing.assert_allclose(one[1], two[1], rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("tile", [128, 640])
+    def test_the_log_sum_exp_is_of_the_scaled_scores(self, tile):
+        """What the backward reads, m x scale + log l with m the RAW
+        maximum, against the log-sum-exp of the scores over sqrt(D) in
+        float64: over five steps, and over one."""
+        q, k, v = _qkv(1, 600, 600, 2, 128, seed=7)
+        _out, lse = attention._flash_fwd_lse(q, k, v, True, tile, tile, True)
+        np.testing.assert_allclose(
+            lse, _log_sum_exp(_scaled_scores(q, k, True)), rtol=1e-6,
+            atol=2e-6)
+
+    def test_the_gradients_through_the_lane_dense_forward_are_denses(self):
+        """The backward is the XLA recomputation from the kernel's
+        log-sum-exp: grouped heads of 128, three steps a row."""
+        q, k, v = _grouped_qkv(1, 384, 4, 2, 128, seed=8)
+
+        def loss(fn):
+            return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+        dense = jax.grad(loss(lambda q, k, v: dense_attention(
+            q, k, v, causal=True)), argnums=(0, 1, 2))(q, k, v)
+        flash = jax.grad(loss(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128,
+            interpret=True)), argnums=(0, 1, 2))(q, k, v)
+        for a, b_ in zip(dense, flash):
+            np.testing.assert_allclose(a, b_, atol=5e-5, rtol=1e-4)
+
+    def test_a_traced_fold_is_counted_by_kernel_and_row_parts(self):
+        """Once a shape, where the call is traced: tiles of 1024 (inputs
+        of 2 bytes) report two parts of 512 rows, float32 inputs (tiles of
+        512) one, one tile a row the whole tile."""
+        def counts():
+            return {key: _fold_rows_calls(*key) for key in [
+                ("flash", "512x2"), ("flash", "512x1"), ("flash", "1024x1"),
+                ("swa", "512x2"), ("mla", "512x2"), ("eva", "512x2")]}
+
+        def x(t, dtype=jnp.bfloat16, d=128):
+            return jax.ShapeDtypeStruct((1, t, 2, d), dtype)
+
+        before = counts()
+        jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                       x(4096), x(4096), x(4096))
+        jax.eval_shape(flash_attention, x(2048), x(2048), x(2048))
+        jax.eval_shape(flash_attention, *[x(2048, jnp.float32)] * 3)
+        jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                       x(1024), x(1024), x(1024))
+        jax.eval_shape(lambda q, k, v: attention.causal_attention(
+            q, k, v, "flash", window=2048), x(4096), x(4096), x(4096))
+        jax.eval_shape(
+            attention.latent_attention, x(2048), x(2048, d=64),
+            x(2048, d=256), jax.ShapeDtypeStruct((1, 2048, 64), jnp.bfloat16))
+        vec = jax.ShapeDtypeStruct((2, 128), jnp.float32)
+        jax.eval_shape(lambda q, k, v, phi, mu: eva_attention(
+            q, k, v, phi, mu, 2048, 16), x(4096), x(4096), x(4096), vec, vec)
+        after = counts()
+        assert {key: after[key] - before[key] for key in after} == {
+            ("flash", "512x2"): 2, ("flash", "512x1"): 1,
+            ("flash", "1024x1"): 1, ("swa", "512x2"): 1,
+            ("mla", "512x2"): 1, ("eva", "512x2"): 1}

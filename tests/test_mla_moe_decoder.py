@@ -621,17 +621,15 @@ class TestFlashKernel:
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_equal_widths_as_before(self, causal):
-        """Equal widths, not causal: bit for bit what the kernel returned
-        before this family. Causal: the blocks it now skips added exact
-        zeros, so the values are the same too."""
+        """Equal widths: what the kernel returned before this family, to
+        float32 rounding (bit for bit when not causal until PR 44, whose
+        step sums a row by lanes first and scales inside the exponent).
+        Causal: the blocks it now skips added exact zeros."""
         q, k, v = _qkv(64, 16, 16, seed=3)
         got = flash_attention(q, k, v, causal=causal, block_q=16,
                               block_k=16, interpret=True)
         before = _flash_pr26(q, k, v, causal, 16, 16)
-        if causal:
-            np.testing.assert_allclose(got, before, atol=1e-6)
-        else:
-            assert np.array_equal(np.asarray(got), np.asarray(before))
+        np.testing.assert_allclose(got, before, atol=1e-6)
 
 
 # --------------------------------------------------------------------- #
