@@ -1,0 +1,533 @@
+"""The fold every Pallas forward here takes, in ONE place: the tile rule
+(`flash_tiles`), the rules for the tiles a mask's edge crosses
+(`_edge_parts`, `_row_parts`), the online-softmax step (`_fold_tile`), the
+kernel body over a grid of (row, head, query block, key block)
+(`_flash_fold`), the call that builds that grid (`_flash_call`) and the
+registry's counters of which path a traced shape took. The plain, banded and
+latent cores (`flash.py`, `latent.py`) are `_flash_fold` with their own
+products; `eva.py`'s kernel takes `_fold_tile` directly. What each rule
+measured on a v5e is in PERF.md (section 6: PRs 27, 30, 31, 41, 43, 44)."""
+
+from __future__ import annotations
+
+import functools
+import math
+import operator
+
+import jax
+import jax.numpy as jnp
+
+from ...observability.metrics import get_registry
+from .layout import _NEG_INF
+
+
+def _count_operands(kernel: str, in_place: bool) -> None:
+    """Counted where a forward is traced: which path a shape took."""
+    get_registry().counter(
+        "mmlspark_tpu_attention_operands_total",
+        "attention forward calls traced, by the kernel and by how it reads "
+        "its operands: in place where the projections wrote them, or from "
+        "a head-major copy",
+        labels=("kernel", "layout")).labels(
+            kernel=kernel,
+            layout="in_place" if in_place else "head_major").inc()
+
+
+def _count_edge_parts(block_q: int, block_k: int, steps: int,
+                      window: int | None = None) -> None:
+    """Counted where a causal forward is traced: where the split of the
+    edge tiles engaged (`_edge_parts`)."""
+    get_registry().counter(
+        "mmlspark_tpu_attention_edge_parts_total",
+        "causal flash-attention forward calls traced (plain, latent, "
+        "banded, and windowed-and-summarised), by the tile and by the "
+        "parts an edge tile is folded in (1: whole, masked)",
+        labels=("tile", "parts")).labels(
+            tile=f"{block_q}x{block_k}",
+            parts=str(_edge_parts(block_q, block_k, steps, window))).inc()
+
+
+def _count_fold_rows(kernel: str, block_q: int, steps: int) -> None:
+    """Counted where a flash forward is traced: in what row parts its
+    folds take a tile that nothing masks (`_row_parts`; one tile a row
+    holds no running statistics and stays whole)."""
+    parts = _row_parts(block_q) if steps > 1 else 1
+    get_registry().counter(
+        "mmlspark_tpu_attention_fold_rows_total",
+        "flash-attention forward calls traced (plain, latent, banded, and "
+        "windowed-and-summarised), by the kernel and by the rows x parts in "
+        "which a fold takes an unmasked tile (1024x1: whole; 512x2: two "
+        "halves)",
+        labels=("kernel", "rows")).labels(
+            kernel=kernel, rows=f"{block_q // parts}x{parts}").inc()
+
+
+def flash_tiles(tq: int, tk: int, dtype,
+                window: int | None = None) -> tuple[int, int]:
+    """The (block_q, block_k) the flash forward works on, from what it can
+    see. The kernel pays a fixed cost a grid step whatever is in it, so the
+    largest tile wins; the cap is 1024 for inputs of 2 bytes and 512 for
+    float32, where a 1024 x 1024 tile passes the default 16 MB of scoped
+    VMEM (what a kernel asks beyond the default is taken from the whole
+    program). A tile is a multiple of 128, or the whole of a sequence
+    shorter than that, and is never bought with padding: the padded length
+    stays within one eighth of the length rounded up to 128 (512 -> 512,
+    514 -> 640, 1100 -> two of 640, 4096 -> 1024). The head's width plays
+    no part: the score tile, not the head, fills VMEM. What a causal
+    mask's EDGE costs at that tile is cut inside the step, not by a
+    smaller tile (`_edge_parts`). The readings: PERF.md, PRs 27, 30, 31.
+
+    Told a `window` (`eva_attention`: a query reads the keys of its own
+    window of that many positions), both tiles are the largest under the
+    cap that DIVIDE the window, so that a block of queries lies in one
+    window and a window is whole blocks of keys: 1024 x 1024 of 2048.
+    Without one the answers are what they were."""
+    cap = 1024 if jnp.dtype(dtype).itemsize <= 2 else 512
+    if window is not None:
+        if window <= 128:
+            return window, window
+        fits = [b for b in range(128, cap + 1, 128) if window % b == 0]
+        if not fits:
+            raise ValueError(
+                f"no tile of the flash forward divides a window of {window} "
+                "positions: a multiple of 128 does, or one of at most 128")
+        return max(fits), max(fits)
+
+    def padded(t, b):
+        return -(-t // b) * b
+
+    def tile(t):
+        if t <= 128:
+            return max(t, 1)
+        most = padded(t, 128) + padded(t, 128) // 8
+        return max(b for b in range(128, cap + 1, 128)
+                   if padded(t, b) <= most)
+
+    return tile(tq), tile(tk)
+
+
+def _band_first(qi, block_q: int, block_k: int, window: int):
+    """The first key block that query block `qi` of a band reads: the one
+    holding the key `window - 1` behind the block's first query, or 0."""
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def _edge_parts(block_q: int, block_k: int, steps: int,
+                window: int | None = None) -> int:
+    """In how many parts along the queries the causal fold takes an EDGE
+    tile (the diagonal's, and a band's trailing one), from what it can see;
+    1 is the whole tile, masked. Where the tiles are equal the diagonal's
+    block holds the mask's edge corner to corner (and so does the trailing
+    block of a window that is whole tiles), so part r of n needs only the
+    keys up to (from) its own square on the edge: (n + 1) / 2n of the
+    tile's products and exponentials, `edge_tile_share`. Two parts where a
+    step is one of several (one tile a row keeps the single-step path) and
+    a part is `_PART_ROWS` rows or more: tiles of 1024. More parts are no
+    faster and cost a start their equations; parts of fewer rows lose
+    (PERF.md, PR 41)."""
+    aligned = block_q == block_k and (window is None or window % block_k == 0)
+    # two parts, each whole lane blocks
+    whole = block_q % 256 == 0 and block_q // 2 >= _PART_ROWS
+    return 2 if steps > 1 and aligned and whole else 1
+
+
+# a part of fewer rows than this stays in its tile
+_PART_ROWS = 512
+
+
+def _row_parts(block_q: int) -> int:
+    """In how many parts along the queries a fold takes a tile that
+    NOTHING masks, from what it can see; 1 is the whole tile. Two halves,
+    each half's products, maximum, exponential and value product
+    independent of the other's, so that the compiler overlaps one half's
+    softmax with the other's products; by `_edge_parts`' rule, a part of
+    `_PART_ROWS` rows or more and whole lane blocks: tiles of 1024 (inputs
+    of 2 bytes). A row sums the same keys in the same order either way
+    (PERF.md, PR 44)."""
+    return 2 if block_q % 256 == 0 and block_q // 2 >= _PART_ROWS else 1
+
+
+def _row_halves(block_q: int) -> tuple:
+    """The rows (first, how many; None: all) of each of `_row_parts`' parts
+    of a tile that nothing masks. A Python loop over them writes the fold
+    once and applies it to each, as the edge tiles' parts are: ONE traced
+    body unrolled by the lowering (`lax.fori_loop`) schedules the same
+    bundles but costs a start several times the tracing and lowering
+    (PERF.md, PR 44)."""
+    parts = _row_parts(block_q)
+    if parts == 1:
+        return (None,)
+    size = block_q // parts
+    return tuple((r * size, size) for r in range(parts))
+
+
+def edge_tile_share(parts: int) -> float:
+    """What of an edge tile the fold computes when it takes it in `parts`
+    parts: part r of n is (r + 1) / n of the keys for 1 / n of the
+    queries."""
+    return (parts + 1) / (2 * parts)
+
+
+def _block(ref, at=None):
+    """A ref's (positions, channels) block, or the positions `at` (first,
+    how many) of it."""
+    import jax.experimental.pallas as pl
+
+    return ref[0] if at is None else ref[0, pl.ds(*at), :]
+
+
+# the lanes of a vector register: the running statistics are kept that wide
+_LANES = 128
+_LOG2_E = math.log2(math.e)
+
+
+def _stat_lanes(*widths: int) -> int:
+    """How many lanes wide the running maximum and sum are kept: 128, a
+    vector register's, where the score tiles' columns are whole blocks of
+    that many (every tile `flash_tiles` chooses past 128 keys); the widest
+    block that divides them at a test's small tile."""
+    return math.gcd(_LANES, *widths)
+
+
+def _over(x, width: int):
+    """A per-row statistic held replicated across its lanes, (rows, lanes),
+    laid over `width` columns: whole registers repeated, never a (rows, 1)
+    column permuted back over the lanes. A column broadcasts by itself."""
+    lanes = x.shape[1]
+    if lanes == 1 or width == lanes:
+        return x
+    if width < lanes:
+        return x[:, :width]
+    if width % lanes:
+        return x[:, :1]
+    return jnp.tile(x, (1, width // lanes))
+
+
+def _lane_sums(p, lanes: int):
+    """p's columns added up in blocks of `lanes`: (rows, lanes) partial
+    sums a row, elementwise (no reduction across lanes), one block after
+    the other (added by halves, six equations where eight, the compiler
+    schedules a step 2% worse: PERF.md, PR 44); one lane is the row sum
+    itself."""
+    if lanes == 1:
+        return p.sum(-1, keepdims=True)
+    return functools.reduce(operator.add, jnp.split(p, p.shape[1] // lanes, 1))
+
+
+def _weigh(s, ok, m, v_ref, exponent, lanes, keys=None):
+    """exp((s - m) x scale) of RAW products s and their raw maximum m (a
+    (rows, 1) column, or (rows, lanes) replicated), as ONE multiply an
+    element: exp2 of (s - m) x `exponent`, the scale times log2 e folded in
+    Python (scale > 0, so the maximum commutes with it). -> its row sums as
+    `lanes` per-lane partial sums (rows, lanes), and its product with the
+    `keys` of the value block (rows, Dv)."""
+    p = jnp.exp2((s - _over(m, s.shape[1])) * exponent)
+    if ok is not None:
+        # masked entries must contribute 0 even when the whole row is
+        # masked (then m == _NEG_INF and exp(s - m) == 1, not 0)
+        p = jnp.where(ok, p, 0.0)
+    pv = jax.lax.dot_general(
+        p.astype(v_ref.dtype), _block(v_ref, keys),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    return _lane_sums(p, lanes), pv
+
+
+def _fold_tile(s, ok, v_ref, scratch, exponent, rows=None, keys=None):
+    """The online-softmax step every fold of this package takes (the plain,
+    latent and banded forwards' `_flash_fold` and `eva._eva_kernel`): a
+    float32 tile `s` of RAW products (unscaled: `exponent` is the scale
+    times log2 e, `_weigh`), masked already, of the `rows` of the query block
+    against the `keys` of a source block (first, how many; None: all),
+    folded into those rows of the running maximum, denominator and
+    accumulator (`scratch`). `ok` is what of the tile counts where a row
+    may have seen nothing yet; None where every row holds a real score, in
+    the tile or from a step before it (exp(_NEG_INF - m) is 0 by itself).
+
+    The statistics are LANE-DENSE, (block_q, `_stat_lanes`) float32: the
+    running maximum (of the raw products) replicated across the lanes, the
+    running sum as per-lane partial sums that only the finalisation adds
+    up across lanes. As (block_q, 1) columns they cost a step a lane
+    permute and a cross-lane sum a row block and most of its vector
+    stores, which paced it (PERF.md, PR 44)."""
+    import jax.experimental.pallas as pl
+
+    m_sc, l_sc, acc_sc = scratch
+    mine = ... if rows is None else (pl.ds(*rows), slice(None))
+    m_prev = m_sc[mine]                                   # (rows, lanes)
+    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+    l, pv = _weigh(s, ok, m_new, v_ref, exponent, m_prev.shape[1], keys)
+    corr = jnp.exp2((m_prev - m_new) * exponent)          # (rows, lanes)
+    l_sc[mine] = l_sc[mine] * corr + l
+    acc_sc[mine] = acc_sc[mine] * _over(corr, pv.shape[1]) + pv
+    m_sc[mine] = m_new
+
+
+def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
+                block_k, num_kv, causal, tk_valid, scale, window=None,
+                key_blocks=None):
+    """What every flash forward does with a score tile, over a grid of
+    (row, head, query block, key block): `products(rows, keys)` is this
+    step's raw float32 products of queries and keys (over a head's
+    channels, or over the latent score's two parts), of the whole (bq, bk)
+    tile or of the `rows` and `keys` (first, how many) of it; the masks,
+    the online softmax, the block skips and the finalisation are here.
+    Told a `window` (a causal band: a query reads the `window` keys that
+    end with its own), the grid's last axis is the `num_kv` blocks a query
+    block's band can touch, counted from `_band_first` (of `key_blocks` in
+    all), and the block that the band's trailing edge crosses is masked
+    like the diagonal's.
+
+    An EDGE tile, where `_edge_parts` says so, is folded in parts along
+    the queries: a part's rows against the keys its mask leaves and no
+    others, so the corner of the tile that the mask would erase whole is
+    neither multiplied nor exponentiated (an erased entry gave exp(-inf) =
+    0: every row still sums over exactly the keys it saw, in another
+    order). The running maximum, sum and accumulator are a row's own, so
+    the parts touch disjoint rows of the scratch and carry nothing new."""
+    import jax.experimental.pallas as pl
+
+    qi = pl.program_id(2)
+    at = kv = pl.program_id(3)                  # the step, and its key block
+    if window is not None:
+        kv = _band_first(qi, block_q, block_k, window) + at
+    # only a padded sequence needs the key mask: decided here, in Python
+    padded = tk_valid < (num_kv if window is None else key_blocks) * block_k
+
+    def scores(mask_keys: bool, mask_causal: bool, mask_trailing=False,
+               rows=None, keys=None):
+        """This step's tile of RAW products (the scale is in the exponent:
+        `_weigh`; `_NEG_INF` masks whatever the scale), (bq, bk) or the
+        `rows` and `keys` of it, and which of it counts (None: all of it).
+        `mask_keys`: keys at or past `tk_valid` are padding; `mask_causal`:
+        a query sees the keys at or before it; `mask_trailing`: and none
+        `window` or more behind it."""
+        s = products(rows, keys)
+        ok = None
+        if rows is not None:
+            # a part of an edge tile (`fold`): the tile's corner lies on
+            # the edge, so what counts is told by the part's own place in
+            # the tile, whatever the block
+            keys_ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                          - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+            ok = (keys_ahead <= rows[0] - keys[0] if mask_causal
+                  else keys_ahead > rows[0] - keys[0])
+            if mask_keys:
+                ok = ok & (kv * block_k + keys[0] + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1) < tk_valid)
+            return jnp.where(ok, s, _NEG_INF), ok
+        if mask_keys or mask_causal or mask_trailing:
+            kpos = kv * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+        if mask_keys:
+            ok = kpos < tk_valid
+        if mask_causal or mask_trailing:
+            qpos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+        if mask_causal:
+            ok = (qpos >= kpos) if ok is None else ok & (qpos >= kpos)
+        if mask_trailing:
+            near = qpos - kpos < window
+            ok = near if ok is None else ok & near
+        if ok is not None:
+            s = jnp.where(ok, s, _NEG_INF)
+        return s, ok
+
+    exponent = scale * _LOG2_E
+
+    def write(m, l, acc):
+        """A row's raw maximum and its sum, (bq, 1) columns, and the
+        accumulator, written out."""
+        out = acc / jnp.maximum(l, 1e-30)
+        out = jnp.where(l > 0, out, 0.0)
+        o_ref[0] = out.astype(o_ref.dtype)
+        # per-row logsumexp of the scaled scores, the backward pass's
+        # softmax residual; +inf on fully-masked rows makes exp(s - lse)
+        # vanish there
+        lse_ref[0] = jnp.where(
+            l > 0, m * scale + jnp.log(jnp.maximum(l, 1e-30)), jnp.inf)
+
+    if num_kv == 1:
+        # the softmax is whole in this tile: no running maximum, no
+        # correction, no accumulator through scratch (the step of the
+        # path below, its exponent and its order of sums)
+        s, ok = scores(padded, causal, window is not None)
+        m = s.max(-1, keepdims=True)
+        write(m, *_weigh(s, ok, m, v_ref, exponent, 1))
+        return
+
+    m_sc, l_sc, acc_sc = scratch
+
+    @pl.when(at == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    def step(mask_keys: bool, mask_causal: bool, mask_trailing=False,
+             rows=None, keys=None):
+        """One key block, or the `keys` of it for the `rows` of the query
+        block, folded into the running max / denominator / accumulator of
+        those rows; a whole tile that nothing masks in `_row_parts`
+        parts."""
+        if rows is None and not (mask_keys or mask_causal or mask_trailing):
+            for part in _row_halves(block_q):
+                _fold_tile(products(part, None), None, v_ref, scratch,
+                           exponent, part)
+            return
+        s, ok = scores(mask_keys, mask_causal, mask_trailing, rows, keys)
+        if rows is not None and mask_causal and not mask_keys:
+            # every row of a part on the diagonal sees its own key: the
+            # maximum is a score, and exp(_NEG_INF - m) is 0 by itself
+            ok = None
+        _fold_tile(s, ok, v_ref, scratch, exponent, rows, keys)
+
+    if not causal:
+        step(padded, False)
+    else:
+        # key blocks wholly above the diagonal are skipped, not masked
+        # (their index map re-names the last block needed, so nothing is
+        # fetched for them either); blocks wholly below it need no causal
+        # mask
+        needed = kv * block_k <= qi * block_q + block_q - 1
+        crosses = (kv + 1) * block_k - 1 > qi * block_q
+        parts = _edge_parts(block_q, block_k, num_kv, window)
+
+        def fold(diagonal: bool, trailing: bool = False):
+            """The step of a block by the edges that cross it. An edge
+            tile in parts: the diagonal's valid half is its lower-left
+            triangle, part r reads the keys up to its own square; the
+            trailing edge's is the upper-right one, part r reads them
+            from its own square on."""
+            if parts == 1 or diagonal == trailing:
+                return step(padded, diagonal, trailing)
+            size = block_q // parts
+            for r in range(parts):
+                step(padded, diagonal, trailing, (r * size, size),
+                     (0, (r + 1) * size) if diagonal
+                     else (r * size, block_k - r * size))
+
+        if window is None:
+            pl.when(needed & crosses)(functools.partial(fold, True))
+            pl.when(needed & jnp.logical_not(crosses))(
+                functools.partial(fold, False))
+        else:
+            # the band's other edge: some query of the block lies `window`
+            # or more past some key of this one (blocks wholly behind the
+            # band are never reached: the axis starts at `_band_first`)
+            trails = qi * block_q + block_q - 1 - kv * block_k >= window
+            needed = needed & (kv < key_blocks)
+            for diagonal in (True, False):
+                for trailing in (True, False):
+                    if parts > 1 and diagonal and trailing:
+                        # equal tiles that divide the window: the edges
+                        # are `window // block_k` blocks apart
+                        continue
+                    pl.when(needed
+                            & (crosses if diagonal
+                               else jnp.logical_not(crosses))
+                            & (trails if trailing
+                               else jnp.logical_not(trails)))(
+                        functools.partial(fold, diagonal, trailing))
+
+    @pl.when(at == num_kv - 1)
+    def _finalize():
+        # the ONE sum across lanes a row
+        write(m_sc[:, :1], l_sc[...].sum(-1, keepdims=True), acc_sc[...])
+
+
+def _fold_scratch(block_q: int, dv: int, *key_widths: int) -> list:
+    """`_fold_tile`'s scratch: the running maximum and sum, lane-dense, and
+    the accumulator. As (block_q, 1) columns the first two were padded to
+    128 lanes already: the same VMEM."""
+    import jax.experimental.pallas.tpu as pltpu
+
+    lanes = _stat_lanes(*key_widths)
+    return [pltpu.VMEM((block_q, lanes), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32)]
+
+
+def _band_steps(tq: int, block_q: int, block_k: int, window: int) -> int:
+    """The key blocks the widest band of a query block touches: the extent
+    of a banded forward's last grid axis (`window // block_k + 1` where the
+    tiles are equal and divide the window)."""
+    return max((qi * block_q + block_q - 1) // block_k
+               - max(qi * block_q - (window - 1), 0) // block_k + 1
+               for qi in range(-(-tq // block_q)))
+
+
+def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
+                tk, causal, scale, block_q, block_k, interpret, name=None,
+                window=None):
+    """ONE Pallas forward over a grid of (row, head, query block, key
+    block). `queries`, `keys` and `value` are (array, block width, at):
+    `at(row, head, block along the sequence)` names the (1, positions,
+    width) block of that head in the array, wherever it lies; the value
+    block is the last input. The output's blocks are named by `out_at` in
+    an array of `out_shape` (as wide a block as the value's). -> (out in
+    that shape, lse (B x H, Tq, 1) float32); `tk` is the keys' length
+    before padding. `name` is the call's own in a device trace; without
+    one the innermost `jax.named_scope` around it names it. With a
+    `window` (causal) the last axis is a query block's band, `_band_steps`
+    key blocks from `_band_first` on: a block wholly behind the band is
+    never named, one above the diagonal re-names the diagonal's."""
+    import jax.experimental.pallas as pl
+
+    keys = [*keys, value]
+    dv = value[1]
+    nq = queries[0][0].shape[1] // block_q
+    nk = steps = keys[0][0].shape[1] // block_k
+    band = {}
+    if window is not None:
+        steps = _band_steps(nq * block_q, block_q, block_k, window)
+        band = {"window": window, "key_blocks": nk}
+
+    def query_spec(width, at):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda b_, j, qi, kv: at(b_, j, qi))
+
+    def key_spec(width, at):
+        if window is not None:
+            def index(b_, j, qi, kv):
+                last = (qi * block_q + block_q - 1) // block_k
+                return at(b_, j, jnp.minimum(
+                    _band_first(qi, block_q, block_k, window) + kv,
+                    jnp.minimum(last, nk - 1)))
+        elif causal:
+            # a key block above the diagonal is never computed on: name
+            # the last block this query block needs instead, which is
+            # already in VMEM, so that no copy is issued for the skipped
+            # steps
+            def index(b_, j, qi, kv):
+                last = (qi * block_q + block_q - 1) // block_k
+                return at(b_, j, jnp.minimum(kv, last))
+        else:
+            def index(b_, j, qi, kv):
+                return at(b_, j, kv)
+        return pl.BlockSpec((1, block_k, width), index)
+
+    return pl.pallas_call(
+        functools.partial(
+            kernel, block_q=block_q, block_k=block_k, num_kv=steps,
+            causal=causal, tk_valid=tk, scale=scale, **band),
+        grid=(b, h, nq, steps),
+        in_specs=[query_spec(w, at) for _x, w, at in queries]
+        + [key_spec(w, at) for _x, w, at in keys],
+        out_specs=[
+            query_spec(dv, out_at),
+            # lse is a (block_q, 1) column, a row's statistic as the
+            # finalisation's sum across lanes leaves it: a trailing dim
+            # equal to the array's satisfies Mosaic's block rule, and no
+            # sublane->lane relayout happens in the kernel
+            pl.BlockSpec((1, block_q, 1),
+                         lambda b_, j, qi, kv: (b_ * h + j, qi, 0)),
+        ],
+        out_shape=[
+            out_shape,
+            jax.ShapeDtypeStruct((b * h, nq * block_q, 1), jnp.float32),
+        ],
+        # one key block carries nothing from step to step
+        scratch_shapes=[] if steps == 1 else _fold_scratch(
+            block_q, dv, block_k),
+        interpret=interpret, name=name,
+    )(*(x for x, _w, _at in [*queries, *keys]))

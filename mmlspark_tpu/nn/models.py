@@ -23,6 +23,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability.metrics import get_registry
+from ..observability.tracing import get_tracer
+from . import attention
+
 __all__ = [
     "MLP",
     "SimpleCNN",
@@ -192,9 +196,9 @@ class TransformerEncoder(nn.Module):
     vocab_size: int = 0             # >0: int token inputs, embed; 0: project
     max_len: int = 512
     dropout_rate: float = 0.0
-    # attention core (nn/attention.py): "dense" (reference math),
+    # attention core (nn/attention/): "dense" (reference math),
     # "chunked" (O(T) online-softmax scan), "flash" (Pallas TPU kernel,
-    # differentiable via custom_vjp; falls back to chunked off-TPU).
+    # differentiable via custom_vjp; the chunked tier on the CPU).
     # Param trees are identical across impls, so a model trained with one
     # loads and serves with any other.
     attention_impl: str = "dense"
@@ -237,9 +241,7 @@ class TransformerEncoder(nn.Module):
                     name=f"attn_{i}",
                 )(y)
             else:
-                from .attention import SelfAttention
-
-                y = SelfAttention(
+                y = attention.SelfAttention(
                     num_heads=self.num_heads, dtype=self.dtype,
                     impl=self.attention_impl, name=f"attn_{i}",
                 )(y, train=train)
@@ -269,54 +271,6 @@ class RMSNorm(nn.Module):
         y = x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True)
                                 + self.eps)
         return (y * scale.astype(jnp.float32)).astype(self.dtype)
-
-
-def _rotary(x, theta: float, impl: str = "dense"):
-    """Rotary positions 0 .. T-1 on the channels of x (B, T, heads, c),
-    rotate-half layout (channel i pairs with channel i + c/2), computed in
-    float32. A checkpoint with the interleaved layout is permuted at import
-    (`import_weights.MLA_MOE_DECODER_SPEC`). Told that the "flash" tier
-    reads x, heads of whole lanes are rotated where they lie
-    (`attention.rotary_in_lanes`: the same numbers, no relayout before the
-    kernel)."""
-    from .attention import (rotary_cos_sin, rotary_in_lanes,
-                            rotary_lanes_whole)
-
-    if _tier(impl) == "flash" and rotary_lanes_whole(*x.shape[2:]):
-        return rotary_in_lanes(x, theta)
-    half = x.shape[-1] // 2
-    cos, sin = (table[:, None, :]
-                for table in rotary_cos_sin(x.shape[1], half, theta))
-    a = x[..., :half].astype(jnp.float32)
-    b = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           -1).astype(x.dtype)
-
-
-def _tier(impl: str) -> str:
-    """The attention tier that runs: "flash" is the Pallas kernels, and
-    the chunked tier on the CPU, where Mosaic cannot lower."""
-    return ("chunked" if impl == "flash" and jax.default_backend() == "cpu"
-            else impl)
-
-
-def _causal_attention(q, k, v, impl: str, dtype, pooling=None,
-                      window: int = 0, chunk: int = 0, band=None):
-    """A decoder's attention core: "flash" (the Pallas kernel; chunked on
-    the CPU, where Mosaic cannot lower), "chunked" or "dense"; any other
-    name is an error that lists these. With a `window`, a query reads its
-    own window exactly and the windows before it a summary a `chunk`,
-    pooled with `pooling` (phi, mu) (`attention.eva_attention`, which
-    takes plain causal attention for a row of at most one window). With a
-    `band`, a query reads the `band` keys that end with its own (a window
-    that slides with it: `attention.causal_attention`'s `window`)."""
-    from .attention import causal_attention, eva_attention
-
-    impl = _tier(impl)
-    if window:
-        return eva_attention(q, k, v, *pooling, window, chunk,
-                             impl=impl).astype(dtype)
-    return causal_attention(q, k, v, impl, window=band).astype(dtype)
 
 
 class LatentAttention(nn.Module):
@@ -350,29 +304,32 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, y):
-        from .attention import HeadsDense, latent_attention
-
         dt, heads, lat = self.dtype, self.num_heads, self.kv_lora_rank
         nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                           self.v_head_dim)
         d = y.shape[-1]
         with jax.named_scope("mla.project"):
-            q_nope, q_rope = HeadsDense(
+            q_nope, q_rope = attention.HeadsDense(
                 heads, nope + rope, use_bias=False, parts=(nope, rope),
                 dtype=dt, name="q_proj")(y)
-            q_rope = _rotary(q_rope, self.rope_theta, self.impl)
+            q_rope = attention.rotary_positions(q_rope, self.rope_theta,
+                                                self.impl)
             kv = nn.Dense(lat + rope, use_bias=False, dtype=dt,
                           name="kv_a_proj")(y)
-            k_pe = _rotary(kv[:, :, None, lat:], self.rope_theta)[:, :, 0]
+            # the one rotary key is a slice of a plain product, no head a
+            # `HeadsDense` wrote
+            k_pe = attention.rotary_xla(kv[:, :, None, lat:],
+                                        self.rope_theta)[:, :, 0]
             c = RMSNorm(self.eps, dt, name="kv_a_norm")(kv[..., :lat])
-            kvb = HeadsDense(heads, nope + vd, use_bias=False, dtype=dt,
-                             name="kv_b_proj")(c)
+            kvb = attention.HeadsDense(heads, nope + vd, use_bias=False,
+                                       dtype=dt, name="kv_b_proj")(c)
         # the innermost scope names the plain flash call in a device trace;
         # the latent forward of whole lanes names itself (`mla_attn_n<nope>
         # r<rope>`: lowered once a shape, so no layer's name is in it)
         with jax.named_scope("mla.attend"), jax.named_scope(self.name):
-            o = latent_attention(q_nope, q_rope, kvb, k_pe,
-                                 _tier(self.impl)).astype(dt)
+            o = attention.latent_attention(
+                q_nope, q_rope, kvb, k_pe,
+                attention.tier(self.impl)).astype(dt)
         with jax.named_scope("mla.project"):
             return nn.DenseGeneral(d, axis=(-2, -1), use_bias=False,
                                    dtype=dt, name="out")(o)
@@ -381,7 +338,7 @@ class LatentAttention(nn.Module):
 class GroupedQueryAttention(nn.Module):
     """Causal attention whose `num_heads` query heads share `num_kv_heads`
     key/value heads (query head j reads head j // group; K and V are never
-    repeated: `nn/attention.py`), scores over sqrt(head width). No biases.
+    repeated: `nn/attention/`), scores over sqrt(head width). No biases.
     A head is `head_dim` channels wide (None: the input's width over
     `num_heads`; with a width of its own the projections need not be
     square: 28 heads of 128 on an input of 2560). `qk_norm`: an RMSNorm
@@ -390,10 +347,10 @@ class GroupedQueryAttention(nn.Module):
     key heads). `rotary`: rotary positions over the whole head (False: the
     layer has no positional encoding). `window`: a query reads the keys
     `window - 1` behind it to its own and none further (None: every key at
-    or before it). Heads of whole lanes (128 channels) are projected by
-    `HeadsDense`, rotated where they lie on the "flash" tier, read by the
-    kernel in place and projected back by `HeadsOut`; any other width
-    keeps the path it had."""
+    or before it). How the heads are projected, rotated and projected back
+    is the package's rule by shape (`attention.head_projection`,
+    `rotary_heads`, `out_projection`): heads of whole lanes stay where the
+    kernel reads them in place."""
 
     num_heads: int
     num_kv_heads: int
@@ -408,8 +365,6 @@ class GroupedQueryAttention(nn.Module):
 
     @nn.compact
     def __call__(self, y):
-        from .attention import HeadsDense, HeadsOut
-
         dt, d = self.dtype, y.shape[-1]
         if self.num_heads % self.num_kv_heads or (
                 self.head_dim is None and d % self.num_heads):
@@ -417,21 +372,15 @@ class GroupedQueryAttention(nn.Module):
                 f"{self.num_heads} query heads over {self.num_kv_heads} "
                 f"key/value heads do not divide a width of {d}")
         width = self.head_dim or d // self.num_heads
-        lanes = width % 128 == 0
 
         def heads(n, name):
-            if lanes:
-                return HeadsDense(n, width, use_bias=False, dtype=dt,
-                                  name=name)(y)
-            return nn.DenseGeneral((n, width), use_bias=False, dtype=dt,
-                                   name=name)(y)
+            return attention.head_projection(n, width, dt, name)(y)
 
         def placed(x, name):
             if self.qk_norm:
                 x = RMSNorm(self.eps, dt, name=name)(x)
             if self.rotary:
-                x = _rotary(x, self.rope_theta,
-                            self.impl if lanes else "dense")
+                x = attention.rotary_heads(x, self.rope_theta, self.impl)
             return x
 
         with jax.named_scope("gqa.project"):
@@ -443,12 +392,10 @@ class GroupedQueryAttention(nn.Module):
         # once a shape, so no layer's name is in it)
         with jax.named_scope("gqa.attend"), jax.named_scope(
                 self.name or "gqa_attn"):
-            o = _causal_attention(q, k, v, self.impl, dt, band=self.window)
+            o = attention.decoder_attention(q, k, v, self.impl, dt,
+                                            band=self.window)
         with jax.named_scope("gqa.project"):
-            if lanes:
-                return HeadsOut(d, dtype=dt, name="out")(o)
-            return nn.DenseGeneral(d, axis=(-2, -1), use_bias=False,
-                                   dtype=dt, name="out")(o)
+            return attention.out_projection(d, width, dt, "out")(o)
 
 
 class EvaAttention(nn.Module):
@@ -460,7 +407,7 @@ class EvaAttention(nn.Module):
     and `mu` (added to the pooled key), float32. Query t in window
     w = t // window_size attends, in ONE softmax, to the keys of window w
     at or before it and to the summaries of every chunk of windows 0 ..
-    w - 1 (`nn/attention.py` `eva_summaries`, `eva_attention`). Heads of
+    w - 1 (`nn/attention/eva.py` `eva_summaries`, `eva_attention`). Heads of
     whole lanes (128 channels) on the "flash" tier are projected, rotated
     and attended to as (B, T, heads x width) arrays, nothing laid out
     again between the projections and the output projection."""
@@ -474,8 +421,6 @@ class EvaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, y):
-        from .attention import HeadsDense
-
         dt, d = self.dtype, y.shape[-1]
         if d % self.num_heads:
             raise ValueError(f"{self.num_heads} heads do not divide a "
@@ -483,20 +428,23 @@ class EvaAttention(nn.Module):
         width = d // self.num_heads
 
         def heads(name):
-            return HeadsDense(self.num_heads, width, use_bias=False,
-                              dtype=dt, name=name)(y)
+            return attention.HeadsDense(self.num_heads, width, use_bias=False,
+                                        dtype=dt, name=name)(y)
+
+        def placed(name):
+            return attention.rotary_positions(heads(name), self.rope_theta,
+                                              self.impl)
 
         with jax.named_scope("eva.project"):
-            q = _rotary(heads("q_proj"), self.rope_theta, self.impl)
-            k = _rotary(heads("k_proj"), self.rope_theta, self.impl)
-            v = heads("v_proj")
+            q, k, v = placed("q_proj"), placed("k_proj"), heads("v_proj")
         phi, mu = (self.param(name, nn.initializers.normal(1.0),
                               (self.num_heads, width), jnp.float32)
                    for name in ("phi", "mu"))
         # `eva.summarise` and `eva.attend` are opened inside
         with jax.named_scope(self.name or "eva_attn"):
-            o = _causal_attention(q, k, v, self.impl, dt, (phi, mu),
-                                  self.window_size, self.chunk_size)
+            o = attention.decoder_attention(
+                q, k, v, self.impl, dt, (phi, mu), self.window_size,
+                self.chunk_size)
         with jax.named_scope("eva.project"):
             return nn.DenseGeneral(d, axis=(-2, -1), use_bias=False,
                                    dtype=dt, name="out")(o)
@@ -680,7 +628,8 @@ class _ScoringDecoder(nn.Module):
     rows of the embedding and of the head held. Per batch the module also
     sows `moe_picks`, int32 (expert layers, experts held): the picks each
     held expert received (`batch_counters` names it for the runner, which
-    reads it back with the batch).
+    reads it back with the batch and hands a call's counters to
+    `call_span_arguments`).
 
     Three seats that a family may fill and most leave empty.
     `total_ut_steps` T > 1 runs the whole stack, the final norm included,
@@ -721,10 +670,81 @@ class _ScoringDecoder(nn.Module):
     @property
     def batch_counters(self) -> tuple:
         """int32 arrays sown per batch that the runner reads back beside
-        the fetched outputs (`nn/runner.py`, which tells them apart by
-        name)."""
+        the fetched outputs (`nn/runner.py`) and hands, stacked over a
+        call's batches, to `call_span_arguments`."""
         return (("moe_picks",) if self.num_layers > self._dense_layers
                 else ()) + (("loop_exit_at",) if self.exit_gate else ())
+
+    def call_span_arguments(self, counted: dict, scored: list,
+                            row_shape: tuple) -> dict:
+        """What a call reports about itself -> the arguments of its root
+        span (`runner.transform`); the registry's counters are added to
+        here. `counted`: `batch_counters`' arrays by name, each stacked over
+        the call's batches; `scored`: the rows each batch scored, padding
+        included (the device computed them); `row_shape`: a row's shape. A
+        counter's arrays are told apart by NAME, and a name nothing here
+        knows is left."""
+        arguments = {}
+        if "moe_picks" in counted:
+            per_row = int(np.prod(row_shape))
+            arguments.update(self._expert_load(
+                counted["moe_picks"], [rows * per_row for rows in scored]))
+        if "loop_exit_at" in counted:
+            arguments.update(self._loop_passes(counted["loop_exit_at"]))
+        return arguments
+
+    def _loop_passes(self, exit_at: np.ndarray) -> dict:
+        """`exit_at`: int (batches, steps), each batch's tokens (padding
+        rows' included) by the step of the looped stack they leave at. ->
+        the span's arguments: that, with the steps and the layer passes the
+        call ran (every batch passes every layer once a step), which the
+        registry counts too."""
+        steps = int(exit_at.shape[1])
+        passes = len(exit_at) * int(self.num_layers) * steps
+        get_registry().counter(
+            "mmlspark_tpu_loop_layer_passes_total",
+            "layer passes of a looped stack: batches x layers x steps",
+        ).inc(float(passes))
+        return dict(loop_steps=steps, loop_layer_passes=passes,
+                    loop_exit_at=[int(n) for n in exit_at.sum(axis=0)])
+
+    def _expert_load(self, picks: np.ndarray, tokens: list) -> dict:
+        """`picks`: int (batches, expert layers, experts held), the picks
+        each held expert received in each batch, padding rows' included;
+        `tokens`: each batch's tokens. Added to the registry's counters by
+        layer -> the span's arguments, with the (layer, batch) pairs whose
+        picks outgrew the expert layer's dispatch buffer
+        (`moe_ffn_dropless` then runs the whole T x k)."""
+        from ..parallel.moe import dropless_buffer_rows
+
+        k, held = self.num_experts_per_tok, picks.shape[2]
+        whole = np.asarray(tokens) * k
+        buffer = np.asarray([dropless_buffer_rows(
+            t, k, held, self.n_routed_experts) for t in tokens])
+        outgrown = ((buffer < whole) & (picks.sum(axis=2).T >= buffer)).sum(
+            axis=1)                                        # by layer
+        registry = get_registry()
+        held_total = registry.counter(
+            "mmlspark_tpu_moe_picks_held_total",
+            "picks routed to the experts this module holds, by expert layer",
+            labels=("layer",))
+        whole_total = registry.counter(
+            "mmlspark_tpu_moe_whole_buffer_total",
+            "batches whose picks outgrew the dispatch buffer, by expert layer",
+            labels=("layer",))
+        call = picks.sum(axis=0)                           # (layers, held)
+        for layer, here in enumerate(call.sum(axis=1)):
+            held_total.labels(layer=layer).inc(float(here))
+            whole_total.labels(layer=layer).inc(float(outgrown[layer]))
+        return dict(
+            moe_picks=int(whole.sum() * picks.shape[1]),
+            moe_picks_held=int(call.sum()),
+            moe_whole_buffer=int(outgrown.sum()),
+            # the busiest held expert of a layer over that layer's mean,
+            # the largest over the layers
+            moe_load_max_over_mean=float(
+                (call.max(axis=1) / np.maximum(call.mean(axis=1), 1e-30))
+                .max()))
 
     def _token_logprobs(self, h, ids, head):
         """log_softmax(h @ head)[next token] for every position but a
@@ -810,8 +830,9 @@ class _ScoringDecoder(nn.Module):
             at[..., None] == jnp.arange(steps)).sum((0, 1), dtype=jnp.int32))
         return h
 
-    def _score(self, x):
-        """The forward every family's `__call__` is."""
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        """The forward of every family."""
         ids = x.astype(jnp.int32)
         if ids.ndim != 2:
             raise ValueError("the decoder takes (rows, length) token ids, "
@@ -929,10 +950,6 @@ class MLAMoEDecoder(_ScoringDecoder):
             self.rms_norm_eps, self.attention_impl, self.dtype,
             name=f"mla_attn_{i}")
 
-    @nn.compact
-    def __call__(self, x, train: bool = False):
-        return self._score(x)
-
 
 class HybridMoEDecoder(_ScoringDecoder):
     """Causal decoder over token ids whose layers are told apart by a
@@ -995,10 +1012,6 @@ class HybridMoEDecoder(_ScoringDecoder):
                              "'conv' or 'full_attention'")
         return f"ln_op_{i}", operator
 
-    @nn.compact
-    def __call__(self, x, train: bool = False):
-        return self._score(x)
-
 
 class EvaDecoder(_ScoringDecoder):
     """Causal decoder over bytes (EvaByte): every layer `EvaAttention`
@@ -1036,10 +1049,6 @@ class EvaDecoder(_ScoringDecoder):
             self.num_heads, self.window_size, self.chunk_size,
             self.rope_theta, self.attention_impl, self.dtype,
             name=f"eva_attn_{i}")
-
-    @nn.compact
-    def __call__(self, x, train: bool = False):
-        return self._score(x)
 
 
 class WindowMoEDecoder(_ScoringDecoder):
@@ -1118,20 +1127,32 @@ class WindowMoEDecoder(_ScoringDecoder):
         the pairs the band itself holds in tiles of that size; from
         shapes alone. None where no banded kernel runs: off the "flash"
         tier, or a row no longer than the window."""
-        from .attention import band_tile_pairs, band_tiles
-
-        if _tier(self.attention_impl) != "flash" or (
+        if attention.tier(self.attention_impl) != "flash" or (
                 length <= self.window_size):
             return None
-        computed, needed = band_tile_pairs(
+        computed, needed = attention.band_tile_pairs(
             length, self.window_size,
-            *band_tiles(length, self.window_size, self.dtype))
+            *attention.band_tiles(length, self.window_size, self.dtype))
         calls = rows * self.num_heads * self.layer_types.count("sliding")
         return computed * calls, needed * calls
 
-    @nn.compact
-    def __call__(self, x, train: bool = False):
-        return self._score(x)
+    def call_span_arguments(self, counted: dict, scored: list,
+                            row_shape: tuple) -> dict:
+        """The family's own beside the skeleton's: what the banded kernel
+        computed over the call and what the band needed
+        (`window_tile_pairs`, summed over the batches). Only a tracer that
+        keeps spans has a reader for it, only rows of token ids have a
+        length, and only where that kernel ran."""
+        arguments = super().call_span_arguments(counted, scored, row_shape)
+        if get_tracer().enabled and len(row_shape) == 1:
+            pairs = [p for p in (self.window_tile_pairs(rows, row_shape[0])
+                                 for rows in scored) if p]
+            if pairs:
+                arguments.update(
+                    attn_window_tile_pairs=float(sum(p[0] for p in pairs)),
+                    attn_window_tile_pairs_needed=float(
+                        sum(p[1] for p in pairs)))
+        return arguments
 
 
 class LoopedDecoder(_ScoringDecoder):
@@ -1182,10 +1203,6 @@ class LoopedDecoder(_ScoringDecoder):
             self.num_heads, self.num_kv_heads, self.rope_theta,
             self.rms_norm_eps, self.attention_impl, self.dtype,
             head_dim=self.head_dim, qk_norm=False, name=f"gqa_attn_{i}")
-
-    @nn.compact
-    def __call__(self, x, train: bool = False):
-        return self._score(x)
 
 
 def resnet20_cifar(num_outputs: int = 10, dtype=jnp.float32) -> ResNet:

@@ -27,7 +27,6 @@ from ..core.params import Param
 from ..core.pipeline import Model
 from ..core.schema import SCORE_KIND, Table
 from ..core.serialize import register_stage
-from ..observability.metrics import get_registry
 from ..observability.tracing import get_tracer, jax_compile_seconds
 from ..parallel.mesh import DATA_AXIS, get_mesh
 from .models import ModelBundle
@@ -109,9 +108,9 @@ class DeepModelTransformer(Model):
 
     def _forward_fn(self, fetches: tuple[str, ...],
                     counters: tuple[str, ...] = ()):
-        """`counters`: int32 arrays the module sows per batch (a module with
-        experts: `batch_counters`), returned after the fetched outputs as
-        they are. Only the streamed path asks for them."""
+        """`counters`: int32 arrays the module sows per batch
+        (`batch_counters`), returned after the fetched outputs as they are.
+        Only the streamed path asks for them."""
         bundle = self.bundle
         module = bundle.module
         need_caps = bool(counters) or any(
@@ -148,18 +147,21 @@ class DeepModelTransformer(Model):
 
         return forward
 
+    def _jit(self, fn, *lead):
+        """`fn(variables, x)` jitted; under a mesh the variables replicated
+        and x sharded over the data axis, after its `lead` axes."""
+        if not self.get("use_mesh"):
+            return jax.jit(fn)
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        mesh = get_mesh()
+        repl = NamedSharding(mesh, P())
+        data = NamedSharding(mesh, P(*lead, DATA_AXIS))
+        return jax.jit(fn, in_shardings=(repl, data), out_shardings=repl)
+
     def _make_apply(self, fetches: tuple[str, ...],
                     counters: tuple[str, ...] = ()):
-        forward = self._forward_fn(fetches, counters)
-        if self.get("use_mesh"):
-            mesh = get_mesh()
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            repl = NamedSharding(mesh, P())
-            data = NamedSharding(mesh, P(DATA_AXIS))
-            return jax.jit(forward, in_shardings=(repl, data),
-                           out_shardings=repl)
-        return jax.jit(forward)
+        return self._jit(self._forward_fn(fetches, counters))
 
     def _make_apply_fused(self, fetches: tuple[str, ...]):
         """Jit of scan(forward) over (nb, bs, ...) — whole table, one dispatch."""
@@ -172,15 +174,7 @@ class DeepModelTransformer(Model):
             _, outs = jax.lax.scan(body, 0, xall)
             return outs                                # tuple of (nb, bs, ...)
 
-        if self.get("use_mesh"):
-            mesh = get_mesh()
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            repl = NamedSharding(mesh, P())
-            data = NamedSharding(mesh, P(None, DATA_AXIS))
-            return jax.jit(scanned, in_shardings=(repl, data),
-                           out_shardings=repl)
-        return jax.jit(scanned)
+        return self._jit(scanned, None)
 
     def _transform(self, table: Table) -> Table:
         if self.bundle is None:
@@ -231,18 +225,11 @@ class DeepModelTransformer(Model):
                self.get("bfloat16"), id(self.bundle), fused)
         counters = tuple(getattr(self.bundle.module, "batch_counters", ()))
         if key not in self._apply_cache:
-            variables = self.bundle.variables
-            if self.get("bfloat16"):
-                # cast weights ONCE; per-call casting would re-upload them
-                variables = jax.tree.map(
-                    lambda a: a.astype(jnp.bfloat16)
-                    if jnp.issubdtype(jnp.asarray(a).dtype, jnp.floating) else a,
-                    variables,
-                )
-            # what a module sows per batch for the runner (`batch_counters`:
-            # routing counts, a looped stack's exit steps) rides with the
-            # streamed path's lagged readback; a module without takes the
-            # path as it was
+            # weights cast ONCE; per-call casting would re-upload them
+            variables = self._device_variables()
+            # what a module sows per batch for the runner (`batch_counters`)
+            # rides with the streamed path's lagged readback; a module
+            # without takes the path as it was
             made = (self._make_apply_fused(fetches) if fused
                     else self._make_apply(fetches, counters))
             self._apply_cache[key] = (made, variables)
@@ -334,34 +321,12 @@ class DeepModelTransformer(Model):
                     chunks.extend(readback.push((out, m)))
             chunks.extend(readback.drain())
             # a batch's counters follow its fetched outputs in the module's
-            # own order and are told apart by NAME: the experts' picks go
-            # to the load's accounting, a looped stack's exit steps to the
-            # root span; a name nothing here knows is read back and left
+            # order; what they say of the call is the module's to tell
             counted = {name: np.stack([c[nf + i] for c in chunks])
                        for i, name in enumerate(counters)} if chunks else {}
-            if "moe_picks" in counted:
-                per_row = int(np.prod(x.shape[1:]))
-                self._record_expert_load(
-                    root, counted["moe_picks"],
-                    [rows * per_row for rows in scored])
-            if "loop_exit_at" in counted:
-                self._record_loop(root, counted["loop_exit_at"])
-            # a module with sliding-window layers says, from shapes alone,
-            # what the banded kernel computes for a batch, in tiles and
-            # fractions of one, and what the band needs (None where no such
-            # kernel runs); only a tracer that keeps spans has a reader for
-            # it
-            tile_pairs = getattr(self.bundle.module, "window_tile_pairs",
-                                 None) if tracer.enabled else None
-            if tile_pairs is not None and x.ndim == 2:
-                pairs = [p for p in (tile_pairs(rows, x.shape[1])
-                                     for rows in scored) if p]
-                if pairs:
-                    root.set(
-                        attn_window_tile_pairs=float(
-                            sum(p[0] for p in pairs)),
-                        attn_window_tile_pairs_needed=float(
-                            sum(p[1] for p in pairs)))
+            report = getattr(self.bundle.module, "call_span_arguments", None)
+            if report is not None:
+                root.set(**report(counted, scored, x.shape[1:]))
         self.last_pipeline_stats = {
             **prefetch.stats,
             "overlap_fraction": prefetch.overlap_fraction(),
@@ -372,67 +337,12 @@ class DeepModelTransformer(Model):
         return [np.concatenate([c[j] for c in chunks])
                 for j in range(len(fetches))]
 
-    def _record_loop(self, root, exit_at: np.ndarray) -> None:
-        """`exit_at`: int (batches, steps), each batch's tokens (padding
-        rows' included: the device computed them) by the step of the
-        looped stack they leave at. Written on the call's root span with
-        the steps and the layer passes the call ran (every batch passes
-        every layer once a step), which the registry counts too."""
-        steps = int(exit_at.shape[1])
-        passes = len(exit_at) * int(self.bundle.module.num_layers) * steps
-        get_registry().counter(
-            "mmlspark_tpu_loop_layer_passes_total",
-            "layer passes of a looped stack: batches x layers x steps",
-        ).inc(float(passes))
-        root.set(loop_steps=steps, loop_layer_passes=passes,
-                 loop_exit_at=[int(n) for n in exit_at.sum(axis=0)])
-
-    def _record_expert_load(self, root, picks: np.ndarray,
-                            tokens: list[int]) -> None:
-        """`picks`: int (batches, expert layers, experts held), the picks
-        each held expert received in each batch, padding rows' included
-        (the device computed them); `tokens`: each batch's tokens. Added to
-        the registry's counters by layer and written on the call's root
-        span, with the (layer, batch) pairs whose picks outgrew the expert
-        layer's dispatch buffer (`moe_ffn_dropless` then runs the whole
-        T x k)."""
-        from ..parallel.moe import dropless_buffer_rows
-
-        module = self.bundle.module
-        k, held = module.num_experts_per_tok, picks.shape[2]
-        whole = np.asarray(tokens) * k
-        buffer = np.asarray([dropless_buffer_rows(
-            t, k, held, module.n_routed_experts) for t in tokens])
-        outgrown = ((buffer < whole) & (picks.sum(axis=2).T >= buffer)).sum(
-            axis=1)                                        # by layer
-        registry = get_registry()
-        held_total = registry.counter(
-            "mmlspark_tpu_moe_picks_held_total",
-            "picks routed to the experts this module holds, by expert layer",
-            labels=("layer",))
-        whole_total = registry.counter(
-            "mmlspark_tpu_moe_whole_buffer_total",
-            "batches whose picks outgrew the dispatch buffer, by expert layer",
-            labels=("layer",))
-        call = picks.sum(axis=0)                           # (layers, held)
-        for layer, here in enumerate(call.sum(axis=1)):
-            held_total.labels(layer=layer).inc(float(here))
-            whole_total.labels(layer=layer).inc(float(outgrown[layer]))
-        root.set(
-            moe_picks=int(whole.sum() * picks.shape[1]),
-            moe_picks_held=int(call.sum()),
-            moe_whole_buffer=int(outgrown.sum()),
-            # the busiest held expert of a layer over that layer's mean,
-            # the largest over the layers
-            moe_load_max_over_mean=float(
-                (call.max(axis=1) / np.maximum(call.mean(axis=1), 1e-30))
-                .max()))
-
     # -- fusion --------------------------------------------------------- #
 
     def _device_variables(self):
-        """The bundle's variables as the fusion kernel's device-resident
-        params (bfloat16-cast once here, mirroring _apply_cache)."""
+        """The bundle's variables as a compiled program takes them
+        (`_apply_cache`, the fusion kernel's device-resident params):
+        cast to bfloat16 where the stage says so."""
         variables = self.bundle.variables
         if self.get("bfloat16"):
             variables = jax.tree.map(
@@ -581,12 +491,10 @@ class DeepModelTransformer(Model):
 
     def _save_state(self) -> dict[str, Any]:
         import base64
-        import io
+        import tempfile
 
         if self.bundle is None:
             return {}
-        import tempfile, os
-
         with tempfile.NamedTemporaryFile(delete=False) as fh:
             tmp = fh.name
         try:
@@ -599,7 +507,6 @@ class DeepModelTransformer(Model):
 
     def _load_state(self, state: dict[str, Any]) -> None:
         import base64
-        import os
         import tempfile
 
         if not state.get("bundle"):

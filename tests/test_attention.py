@@ -258,7 +258,7 @@ class TestFlashTiles:
 
         want = jax.value_and_grad(loss(dense))(variables, x)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(attention, "flash_attention", recorded)
+        monkeypatch.setattr(attention.flash, "flash_attention", recorded)
         got = jax.value_and_grad(loss(SelfAttention(
             num_heads=2, impl="flash")))(variables, x)
         assert seen == [{"causal": False}]
@@ -304,6 +304,21 @@ class TestSelfAttentionModule:
         x = jnp.zeros((1, 4, 8), jnp.float32)
         with pytest.raises(ValueError, match="unknown attention impl"):
             mod.init(jax.random.PRNGKey(0), x)
+
+
+@pytest.mark.parametrize("impl,backend,runs", [
+    ("flash", "cpu", "chunked"), ("flash", "tpu", "flash"),
+    ("chunked", "tpu", "chunked"), ("dense", "cpu", "dense"),
+    ("sparse", "tpu", None)])
+def test_the_tier_that_runs_has_one_owner(monkeypatch, impl, backend, runs):
+    """`attention.tier`: what a model's name for a tier means on this
+    backend, and the one refusal of a name that is none."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if runs is None:
+        with pytest.raises(ValueError, match="'flash', 'chunked', 'dense'"):
+            attention.tier(impl)
+    else:
+        assert attention.tier(impl) == runs
 
 
 # --------------------------------------------------------------------- #
@@ -472,7 +487,8 @@ def _head_major(monkeypatch):
     """The copy path at ANY width, for a comparison: the rule is by shape
     and the program has no switch, so the test takes the rule away (and
     the jitted forwards' cached traces with it)."""
-    monkeypatch.setattr(attention, "_lanes_whole", lambda *widths: False)
+    monkeypatch.setattr(attention.layout, "_lanes_whole",
+                        lambda *widths: False)
     jax.clear_caches()
 
 
@@ -576,7 +592,7 @@ class TestOperandsInPlace:
         got = attention.latent_attention(*parts, block_q=tile, block_k=tile,
                                          interpret=True)
         assert _operands("mla", "in_place") == before + 1
-        q, k, v = attention._latent_concatenated(*parts)
+        q, k, v = attention.latent._latent_concatenated(*parts)
         assert q.shape[-1] == k.shape[-1] == 192 and v.shape[-1] == 128
         want = flash_attention(q, k, v, causal=True, block_q=tile,
                                block_k=tile, interpret=True)
@@ -603,7 +619,7 @@ class TestOperandsInPlace:
             *a, block_q=8, block_k=8, interpret=True)),
             argnums=(0, 1, 2, 3))(*parts)
         want = jax.grad(loss(lambda *a: flash_attention(
-            *attention._latent_concatenated(*a), causal=True, block_q=8,
+            *attention.latent._latent_concatenated(*a), causal=True, block_q=8,
             block_k=8, bwd_chunk=8, interpret=True)),
             argnums=(0, 1, 2, 3))(*parts)
         assert [g.shape for g in got] == [p.shape for p in parts]
@@ -621,8 +637,9 @@ class TestOperandsInPlace:
         got = attention.latent_attention(*parts, impl=impl, interpret=True)
         assert _operands("mla", "head_major") == before + (impl == "flash")
         np.testing.assert_allclose(
-            got, dense_attention(*attention._latent_concatenated(*parts),
-                                 causal=True), atol=2e-5)
+            got, dense_attention(
+                *attention.latent._latent_concatenated(*parts), causal=True),
+            atol=2e-5)
 
     # blocks of 16 positions: 40 pad to 48, 300 to 304, float32 in steps of 8
     @pytest.mark.parametrize("shape,dtype", [
@@ -631,9 +648,9 @@ class TestOperandsInPlace:
         ((1, 32, 2, 64), jnp.float32)])
     def test_rotary_in_lanes_is_the_models_rotary(self, monkeypatch, shape,
                                                   dtype):
-        from mmlspark_tpu.nn.models import _rotary
+        from mmlspark_tpu.nn.attention import rotary_xla as _rotary
 
-        monkeypatch.setattr(attention, "_ROTARY_ROWS", 16)
+        monkeypatch.setattr(attention.rotary, "_ROTARY_ROWS", 16)
         x = jnp.asarray(np.random.default_rng(1).normal(size=shape), dtype)
         got = attention.rotary_in_lanes(x, 1e5, interpret=True)
         want = _rotary(x, 1e5)
@@ -654,9 +671,9 @@ class TestOperandsInPlace:
         layer's q and k, and every layer's) share ONE trace of the kernel,
         whose grid walks the lane blocks a block of positions."""
         traced = []
-        kernel = attention._rotary_kernel
+        kernel = attention.rotary._rotary_kernel
         monkeypatch.setattr(
-            attention, "_rotary_kernel",
+            attention.rotary, "_rotary_kernel",
             lambda *refs, **static: (traced.append(static),
                                      kernel(*refs, **static))[1])
         x = jnp.ones((1, 32, 4, 128), jnp.bfloat16)
@@ -1074,7 +1091,7 @@ class TestSlidingWindow:
         def banded(q, k, v):
             return attention.causal_attention(q, k, v, "flash", window=4096)
 
-        parts = attention._edge_parts(1024, 1024, 5, 4096)
+        parts = attention.fold._edge_parts(1024, 1024, 5, 4096)
         split = _edge_parts_calls("1024x1024", parts)
         jaxpr = str(jax.make_jaxpr(banded)(x, kv, kv))
         assert "swa_attn_w4096" in jaxpr
@@ -1101,11 +1118,11 @@ class TestSlidingWindow:
         assert attention.band_tiles(16384, 4096, jnp.bfloat16) == (1024,
                                                                    1024)
         assert attention.band_tiles(16384, 4096, jnp.float32) == (512, 512)
-        assert attention._band_steps(16384, 1024, 1024, 4096) == 5
-        assert attention._band_steps(8192, 1024, 1024, 4096) == 5
+        assert attention.fold._band_steps(16384, 1024, 1024, 4096) == 5
+        assert attention.fold._band_steps(8192, 1024, 1024, 4096) == 5
         computed, needed = attention.band_tile_pairs(16384, 4096, 1024, 1024)
-        share = attention.edge_tile_share(
-            attention._edge_parts(1024, 1024, 5, 4096))
+        share = attention.fold.edge_tile_share(
+            attention.fold._edge_parts(1024, 1024, 5, 4096))
         assert share < 1 and isinstance(computed, float)
         assert computed == 1 + 2 + 3 + 4 + 12 * 5 - (16 + 12) * (1 - share)
         assert computed == {0.75: 63.0, 0.625: 59.5}[share]
@@ -1141,7 +1158,7 @@ class TestSlidingWindow:
 # --------------------------------------------------------------------- #
 
 PARTS = 2
-EDGE_TILE = PARTS * attention._PART_ROWS     # the smallest tile that splits
+EDGE_TILE = PARTS * attention.fold._PART_ROWS   # the smallest tile that splits
 EDGE_LIMIT = 2e-6               # float32 against float32: the sums' order
 
 
@@ -1150,7 +1167,7 @@ def _whole_tiles(monkeypatch):
     comparison at EQUAL tiles: the rule is by shape and the program has no
     switch, so the test takes the rule away (and the jitted forwards'
     cached traces with it), as `_head_major` does."""
-    monkeypatch.setattr(attention, "_edge_parts", lambda *a, **kw: 1)
+    monkeypatch.setattr(attention.fold, "_edge_parts", lambda *a, **kw: 1)
     jax.clear_caches()
 
 
@@ -1181,7 +1198,7 @@ class TestEdgeTilesInParts:
                "interpret": True}
 
     def test_the_rule_by_shape(self):
-        parts = attention._edge_parts
+        parts = attention.fold._edge_parts
         # the cells' tiles: plain causal, latent and the band of 4096
         assert parts(1024, 1024, 16) == parts(1024, 1024, 4) == PARTS
         assert parts(1024, 1024, 2) == PARTS              # rows of 2048
@@ -1194,9 +1211,9 @@ class TestEdgeTilesInParts:
         assert parts(640, 640, 2, window=1100) == 1
         assert parts(EDGE_TILE // 2, EDGE_TILE // 2, 4) == 1
         assert parts(8, 8, 4) == parts(640, 640, 2) == 1
-        assert attention.edge_tile_share(1) == 1.0
-        assert attention.edge_tile_share(2) == 0.75
-        assert attention.edge_tile_share(4) == 0.625
+        assert attention.fold.edge_tile_share(1) == 1.0
+        assert attention.fold.edge_tile_share(2) == 0.75
+        assert attention.fold.edge_tile_share(4) == 0.625
 
     @pytest.mark.parametrize("heads,d", [((2, 1), 64), ((2, 1), 128),
                                          ((4, 2), 8)])
@@ -1210,8 +1227,8 @@ class TestEdgeTilesInParts:
         SAME tiles (the rule taken away)."""
         q, k, v = _band_inputs(self.T, heads, d, seed=7)
         steps = -(-self.T // EDGE_TILE) if window is None else (
-            attention._band_steps(self.T, EDGE_TILE, EDGE_TILE, window))
-        assert attention._edge_parts(EDGE_TILE, EDGE_TILE, steps,
+            attention.fold._band_steps(self.T, EDGE_TILE, EDGE_TILE, window))
+        assert attention.fold._edge_parts(EDGE_TILE, EDGE_TILE, steps,
                                      window) == PARTS
 
         def call(q, k, v, tiles=self.TILES):
@@ -1241,7 +1258,7 @@ class TestEdgeTilesInParts:
         split = np.asarray(call(*operands))
         program = str(jax.make_jaxpr(call)(*operands))
         want = np.asarray(attention.causal_attention(
-            *attention._latent_concatenated(*operands), "dense"))
+            *attention.latent._latent_concatenated(*operands), "dense"))
         assert np.abs(split - want).max() < 2e-5
         assert np.abs(split - np.asarray(
             call(*operands, tiles=self.UNEQUAL))).max() < EDGE_LIMIT
@@ -1256,7 +1273,7 @@ class TestEdgeTilesInParts:
         q, k, v = _band_inputs(self.T, (2, 1), 64, seed=9)
 
         def run():
-            return attention._flash_fwd_lse(
+            return attention.flash._flash_fwd_lse(
                 q, k, v, True, EDGE_TILE, EDGE_TILE, True, window=window)
 
         out, lse = run()
@@ -1356,7 +1373,7 @@ class TestEdgeTilesInParts:
 def _whole_blocks(monkeypatch):
     """`_whole_tiles`, and every block of summaries that ends past the ones
     seen folded whole, masked by column, as the parent folds it."""
-    monkeypatch.setattr(attention, "_edge_prefixes", lambda *a, **kw: ())
+    monkeypatch.setattr(attention.eva, "_edge_prefixes", lambda *a, **kw: ())
     _whole_tiles(monkeypatch)
 
 
@@ -1385,7 +1402,7 @@ class TestEvaEdgeTiles:
                              impl="flash", **self.TILES, **more)
 
     def test_the_rules_by_shape(self):
-        prefixes = attention._edge_prefixes
+        prefixes = attention.eva._edge_prefixes
         # the cell's long rows, and this class's blocks
         assert prefixes(1024, 128) == (128, 256, 384, 512, 640, 768, 896)
         assert prefixes(512, 128) == (128, 256, 384)
@@ -1395,8 +1412,8 @@ class TestEvaEdgeTiles:
         assert prefixes(1000, 128) == prefixes(24, 8) == prefixes(3, 8) == ()
         # the diagonal's parts are `_flash_fold`'s rule: two key blocks of
         # the window and two of summaries are four steps
-        assert attention._edge_parts(1024, 1024, 4, 2048) == PARTS
-        assert attention._edge_parts(512, 512, 5, 2048) == 1
+        assert attention.fold._edge_parts(1024, 1024, 4, 2048) == PARTS
+        assert attention.fold._edge_parts(512, 512, 5, 2048) == 1
 
     # the last window sees 128 x `windows` summaries: a last block of
     # summaries that is an edge in EACH prefix class (1, 2, 3; 5: the
@@ -1536,9 +1553,9 @@ class TestEvaTilePairs:
         # summaries and 28 prefixes of 128 .. 896 columns
         assert computed == 24 + 16 + 16 + 14
         assert needed == pytest.approx(62, abs=0.02)
-        monkeypatch.setattr(attention, "_edge_prefixes", lambda *a: ())
+        monkeypatch.setattr(attention.eva, "_edge_prefixes", lambda *a: ())
         assert attention.eva_tile_pairs(32768, *self.CELL, 1024)[0] == 84
-        monkeypatch.setattr(attention, "_edge_parts", lambda *a, **kw: 1)
+        monkeypatch.setattr(attention.fold, "_edge_parts", lambda *a, **kw: 1)
         assert attention.eva_tile_pairs(32768, *self.CELL, 1024) == (
             92, needed)
 
@@ -1548,7 +1565,7 @@ class TestEvaTilePairs:
         computed, needed = attention.eva_tile_pairs(4096, *self.CELL, 128)
         assert computed == 3 + 2 + 2 * 128 / 1024
         assert needed == pytest.approx(4.25, abs=0.01)
-        monkeypatch.setattr(attention, "_edge_parts", lambda *a, **kw: 1)
+        monkeypatch.setattr(attention.fold, "_edge_parts", lambda *a, **kw: 1)
         assert attention.eva_tile_pairs(4096, *self.CELL, 128)[0] == 6.25
 
     @pytest.mark.parametrize("t,tiles", [
@@ -1570,7 +1587,7 @@ class TestEvaTilePairs:
 def _whole_rows(monkeypatch):
     """Every unmasked tile folded whole: `_whole_tiles`' way of taking a
     rule by shape away for a comparison at EQUAL tiles."""
-    monkeypatch.setattr(attention, "_row_parts", lambda block_q: 1)
+    monkeypatch.setattr(attention.fold, "_row_parts", lambda block_q: 1)
     jax.clear_caches()
 
 
@@ -1607,7 +1624,7 @@ class TestLaneDenseFold:
     inputs; today's tolerances."""
 
     def test_the_rules_by_shape(self):
-        lanes, parts = attention._stat_lanes, attention._row_parts
+        lanes, parts = attention.fold._stat_lanes, attention.fold._row_parts
         # every tile the rule chooses past 128 keys; EvaByte's two sources
         assert lanes(1024) == lanes(512) == lanes(640) == lanes(128) == 128
         assert lanes(1024, 1024) == lanes(1024, 128) == 128
@@ -1648,7 +1665,7 @@ class TestLaneDenseFold:
         got = attention.latent_attention(*operands, block_q=128, block_k=256,
                                          interpret=True)
         want = attention.causal_attention(
-            *attention._latent_concatenated(*operands), "dense")
+            *attention.latent._latent_concatenated(*operands), "dense")
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
     @pytest.mark.parametrize("d", [128, 8])
@@ -1719,11 +1736,11 @@ class TestLaneDenseFold:
                                jnp.float32),
                    *(jnp.asarray(rng.normal(size=(1, tk, 1, d)), jnp.float32)
                      for _ in range(2)))
-        at = attention._block_at(False, 1)
+        at = attention.layout._block_at(False, 1)
 
         def fold(real):
-            return attention._flash_call(
-                attention._flash_kernel, [(q[:, :, 0], d, at)],
+            return attention.fold._flash_call(
+                attention.flash._flash_kernel, [(q[:, :, 0], d, at)],
                 [(k[:, :, 0], d, at)], (v[:, :, 0], d, at), at,
                 jax.ShapeDtypeStruct((1, block, d), jnp.float32), b=1, h=1,
                 tk=real, causal=False, scale=d ** -0.5, block_q=block,
@@ -1747,8 +1764,8 @@ class TestLaneDenseFold:
         exponent: a row as one tile of 256 keys and as two of 128 agree to
         float32 rounding, outputs and log-sum-exp."""
         q, k, v = _qkv(1, 256, 256, 2, 64, seed=6)
-        one = attention._flash_fwd_lse(q, k, v, causal, 256, 256, True)
-        two = attention._flash_fwd_lse(q, k, v, causal, 256, 128, True)
+        one = attention.flash._flash_fwd_lse(q, k, v, causal, 256, 256, True)
+        two = attention.flash._flash_fwd_lse(q, k, v, causal, 256, 128, True)
         np.testing.assert_allclose(one[0], two[0], atol=EDGE_LIMIT)
         np.testing.assert_allclose(one[1], two[1], rtol=1e-6, atol=1e-6)
 
@@ -1758,7 +1775,8 @@ class TestLaneDenseFold:
         maximum, against the log-sum-exp of the scores over sqrt(D) in
         float64: over five steps, and over one."""
         q, k, v = _qkv(1, 600, 600, 2, 128, seed=7)
-        _out, lse = attention._flash_fwd_lse(q, k, v, True, tile, tile, True)
+        _out, lse = attention.flash._flash_fwd_lse(q, k, v, True, tile, tile,
+                                                   True)
         np.testing.assert_allclose(
             lse, _log_sum_exp(_scaled_scores(q, k, True)), rtol=1e-6,
             atol=2e-6)

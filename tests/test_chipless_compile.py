@@ -346,9 +346,9 @@ def _layers_for_the_chip(one_chip, monkeypatch, kind, width, rows, length,
 
     from mmlspark_tpu.nn import attention, models
 
-    # the modules ask the backend which tier runs; here it is the CPU's
-    for mod in (models, attention):
-        monkeypatch.setattr(mod.jax, "default_backend", lambda: "tpu")
+    # the package asks the backend which tier runs; here it is the CPU's
+    monkeypatch.setattr(attention.layout.jax, "default_backend",
+                        lambda: "tpu")
 
     class Stack(nn.Module):
         @nn.compact
@@ -494,7 +494,7 @@ def _parents_step(s, ok, v_ref, scratch, exponent, rows=None, keys=None):
     it). The outputs are the parent's; the log-sum-exp is not compared
     (the finalisation scales this maximum again)."""
     import jax.experimental.pallas as pl
-    from mmlspark_tpu.nn.attention import _block
+    from mmlspark_tpu.nn.attention.fold import _block
 
     m_sc, l_sc, acc_sc = scratch
     mine = ... if rows is None else (pl.ds(*rows), slice(None))
@@ -561,7 +561,7 @@ def test_the_lane_dense_step_gives_the_column_steps_outputs(
 
     call = _small_fold(case)
     lane_dense = np.asarray(call().astype(jnp.float32))
-    monkeypatch.setattr(attention, "_fold_tile", _parents_step)
+    monkeypatch.setattr(attention.fold, "_fold_tile", _parents_step)
     jax.clear_caches()          # the forwards jitted by themselves
     columns = np.asarray(call().astype(jnp.float32))
     jax.clear_caches()
@@ -666,8 +666,8 @@ def _looped_for_the_chip(one_chip, monkeypatch, steps, rows, length,
     bfloat16, run `steps` times, embedding to log-probabilities."""
     from mmlspark_tpu.nn import attention, models
 
-    for mod in (models, attention):
-        monkeypatch.setattr(mod.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(attention.layout.jax, "default_backend",
+                        lambda: "tpu")
     module = models.make_model(
         "looped_decoder", num_layers=layers, total_ut_steps=steps,
         d_model=2048, num_heads=16, num_kv_heads=16, head_dim=128,

@@ -80,7 +80,7 @@ def _interpreted_flash(monkeypatch):
     interpreted, at tiles small enough to cross."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(
-        attention, "eva_attention",
+        attention.eva, "eva_attention",
         lambda *a, **kw: eva_attention(*a, block_q=16, block_k=16,
                                        block_s=8, interpret=True, **kw))
 
@@ -189,7 +189,7 @@ class TestATermLeftOutFails:
         core = attention.eva_attention
 
         def plant(faulty):
-            monkeypatch.setattr(attention, "eva_attention", faulty)
+            monkeypatch.setattr(attention.eva, "eva_attention", faulty)
 
         if fault == "no_mu":
             plant(lambda q, k, v, phi, mu, *a, **kw: core(

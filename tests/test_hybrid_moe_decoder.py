@@ -77,7 +77,7 @@ def _interpreted_flash(monkeypatch):
     interpreted, at tiles small enough to cross."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(
-        attention, "flash_attention",
+        attention.flash, "flash_attention",
         lambda q, k, v, **kw: flash_attention(
             q, k, v, block_q=16, block_k=16, interpret=True, **kw))
 
@@ -123,7 +123,7 @@ class TestModuleAgainstReference:
     def test_the_two_families_share_one_skeleton(self):
         """The block loop, the chunked head and the counters are written
         once: neither family overrides them."""
-        for name in ("_score", "_token_logprobs", "batch_counters"):
+        for name in ("__call__", "_token_logprobs", "batch_counters"):
             owners = {vars(cls).get(name) for cls in (MLAMoEDecoder,
                                                       HybridMoEDecoder)}
             assert owners == {None}, name

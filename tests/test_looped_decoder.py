@@ -207,7 +207,7 @@ class TestModuleAgainstReference:
         one fills the seats and the others leave them empty."""
         families = (MLAMoEDecoder, HybridMoEDecoder, EvaDecoder,
                     WindowMoEDecoder, LoopedDecoder)
-        for name in ("_score", "_stack", "_leave", "_token_logprobs",
+        for name in ("__call__", "_stack", "_leave", "_token_logprobs",
                      "batch_counters"):
             assert {vars(cls).get(name) for cls in families} == {None}
         module = make_model(FAMILY, **MODEL)
@@ -243,7 +243,7 @@ class TestTheStepsAreOneBody:
         sound = attention.causal_attention
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         monkeypatch.setattr(
-            attention, "causal_attention",
+            attention.flash, "causal_attention",
             lambda q, k, v, impl="flash", window=None: sound(
                 q, k, v, impl, window=window, block_q=8, block_k=8,
                 interpret=True))

@@ -714,7 +714,7 @@ class TestWeightImport:
         """Interleaved rotary on a checkpoint's columns and rotate-half
         rotary on the imported ones give the same query-key products."""
         from mmlspark_tpu.nn.import_weights import _rope_to_halves
-        from mmlspark_tpu.nn.models import _rotary
+        from mmlspark_tpu.nn.attention import rotary_xla as _rotary
 
         rng = np.random.default_rng(0)
         q, k = rng.normal(size=(2, 1, 6, 1, 8)).astype(np.float32)

@@ -89,7 +89,7 @@ def _interpreted_flash(monkeypatch):
     sound = attention.causal_attention
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(
-        attention, "causal_attention",
+        attention.flash, "causal_attention",
         lambda q, k, v, impl="flash", window=None: sound(
             q, k, v, impl, window=window, block_q=8, block_k=8,
             interpret=True))
@@ -165,7 +165,7 @@ class TestModuleAgainstReference:
         """The block loop, the chunked head and the counters are written
         once: no family overrides them; what this one states is attributes
         (`nn/runner.py` reads `batch_counters` as before)."""
-        for name in ("_score", "_token_logprobs", "batch_counters"):
+        for name in ("__call__", "_token_logprobs", "batch_counters"):
             assert {vars(cls).get(name) for cls in (
                 MLAMoEDecoder, HybridMoEDecoder, WindowMoEDecoder)} == {None}
         module = make_model(FAMILY, **MODEL)
@@ -627,7 +627,7 @@ def test_causal_attention_is_the_modules_door():
     """The module reaches the banded core through `causal_attention`'s
     `window` and nothing else."""
     q = jax.random.normal(jax.random.PRNGKey(0), (1, 24, 2, 8))
-    got = models._causal_attention(q, q, q, "dense", jnp.float32, band=8)
+    got = attention.decoder_attention(q, q, q, "dense", jnp.float32, band=8)
     want = causal_attention(q, q, q, "dense", window=8)
     assert np.array_equal(np.asarray(got), np.asarray(want))
     with pytest.raises(ValueError, match="unknown router scoring"):
