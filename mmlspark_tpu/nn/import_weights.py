@@ -79,6 +79,17 @@ router apart from its experts under `router_<i>`. Nothing is permuted.
 after the feed-forward; `norm`; `early_exit_gate` with its bias;
 `embed_tokens`, `lm_head`) onto nn.models.LoopedDecoder: one set of layers
 for all `total_ut_steps` steps. Nothing is permuted.
+
+`SSM_HYBRID_DECODER_SPEC` maps the `falcon_h1` checkpoint naming (a layer's
+state-space mixer under `mamba.`: `in_proj` with its columns [z | x B C |
+dt] as they lie, `conv1d.weight` (channels, 1, taps) and `conv1d.bias`,
+`dt_bias`, `A_log`, `D`, `norm`, `out_proj`; `self_attn.q_proj` ..
+`o_proj`; `feed_forward.gate_proj` / `up_proj` / `down_proj`;
+`input_layernorm`, `pre_ff_layernorm`, `final_layernorm`; `embed_tokens`,
+`lm_head`) onto nn.models.SSMHybridDecoder: a layer's mixers under
+`ssm_<i>` and `gqa_attn_<i>`. The family's fixed multipliers are the
+module's configuration, not weights (`mup_vector` is dropped). Nothing is
+permuted.
 """
 
 from __future__ import annotations
@@ -110,6 +121,9 @@ __all__ = [
     "LOOPED_DECODER_SPEC",
     "torch_looped_decoder_to_flax",
     "import_torch_looped_decoder",
+    "SSM_HYBRID_DECODER_SPEC",
+    "torch_ssm_hybrid_decoder_to_flax",
+    "import_torch_ssm_hybrid_decoder",
     "import_external_weights",
     "IMPORTERS",
 ]
@@ -933,6 +947,76 @@ def import_torch_looped_decoder(
     return _validate_and_install(bundle, variables, architecture)
 
 
+# --------------------------------------------------------------------- #
+# falcon_h1 naming -> nn.models.SSMHybridDecoder                         #
+# --------------------------------------------------------------------- #
+
+_MAMBA = _LAYER + r"mamba\."
+SSM_HYBRID_DECODER_SPEC: "list[MapRule]" = [
+    MapRule(r"model\.embed_tokens\.weight", "params/embed/embedding"),
+    MapRule(_LAYER + r"input_layernorm\.weight", r"params/ln_op_\g<i>/scale"),
+    MapRule(_LAYER + r"pre_ff_layernorm\.weight",
+            r"params/ln_mlp_\g<i>/scale"),
+    # the projection's columns as the checkpoint lays them: [z | x B C | dt]
+    MapRule(_MAMBA + r"in_proj\.weight",
+            r"params/ssm_\g<i>/in_proj/kernel", _t_transpose),
+    MapRule(_MAMBA + r"conv1d\.weight", r"params/ssm_\g<i>/conv_kernel",
+            _t_taps),
+    MapRule(_MAMBA + r"conv1d\.bias", r"params/ssm_\g<i>/conv_bias"),
+    MapRule(_MAMBA + r"(?P<v>dt_bias|A_log|D)", r"params/ssm_\g<i>/\g<v>"),
+    MapRule(_MAMBA + r"norm\.weight", r"params/ssm_\g<i>/norm_scale"),
+    MapRule(_MAMBA + r"out_proj\.weight",
+            r"params/ssm_\g<i>/out_proj/kernel", _t_transpose),
+    # the fixed multipliers are the configuration's, not weights: a
+    # checkpoint that writes the vector of them writes what the module holds
+    MapRule(r".*mup_vector", None),
+    MapRule(_LAYER + r"self_attn\.(?P<p>[qkv])_proj\.weight",
+            r"params/gqa_attn_\g<i>/\g<p>_proj/kernel", _t_heads_kernel),
+    MapRule(_LAYER + r"self_attn\.o_proj\.weight",
+            r"params/gqa_attn_\g<i>/out/kernel", _t_attn_out_kernel),
+    MapRule(_LAYER + r"feed_forward\." + _FFN,
+            r"params/mlp_\g<i>/\g<proj>/kernel", _t_transpose),
+    MapRule(r"model\.final_layernorm\.weight", "params/ln_final/scale"),
+    MapRule(r"lm_head\.weight", "params/head_kernel", _t_transpose),
+    MapRule(r".*rotary_emb\.inv_freq", None),
+]
+
+
+def torch_ssm_hybrid_decoder_to_flax(
+    state_dict: Mapping[str, np.ndarray], num_heads: int, head_dim: int,
+) -> dict[str, Any]:
+    """Map a `falcon_h1`-named state dict onto nn.models.SSMHybridDecoder
+    variables: every layer a state-space mixer (`mamba.*`: the input
+    projection's columns [z | x B C | dt] as they lie, the depthwise taps
+    with the last meeting the newest token, the vectors a head) beside the
+    attention's four matrices; an untied head; rotary already in the
+    rotate-half layout. The family's multipliers are not in a state dict:
+    the module's configuration states them. A name no rule places is an
+    error that names it."""
+    return apply_mapping_spec(state_dict, SSM_HYBRID_DECODER_SPEC, {
+        "num_heads": int(num_heads), "head_dim": int(head_dim)})
+
+
+def import_torch_ssm_hybrid_decoder(
+    path: str, architecture: str = "ssm_hybrid_decoder",
+    input_shape: tuple[int, ...] = (8,), **config,
+):
+    """Load a `falcon_h1`-named checkpoint into a ready-to-serve
+    ModelBundle of the `ssm_hybrid_decoder` family. `config` is the
+    module's (`num_layers`, the heads' and the scan's counts and widths,
+    every multiplier, ...): the checkpoint's own config.json states them,
+    its shapes do not."""
+    from .models import ModelBundle
+
+    sd = load_state_dict(path)
+    bundle = ModelBundle.init(architecture, input_shape=tuple(input_shape),
+                              seed=0, **config)
+    module = bundle.module
+    variables = torch_ssm_hybrid_decoder_to_flax(sd, module.num_heads,
+                                                 module.head_dim)
+    return _validate_and_install(bundle, variables, architecture)
+
+
 # architecture name -> importer; zoo.import_external dispatches here, so
 # registering a new family makes it fetchable/verifiable end to end
 IMPORTERS: "dict[str, Callable]" = {
@@ -945,6 +1029,7 @@ IMPORTERS: "dict[str, Callable]" = {
     "eva_decoder": import_torch_eva_decoder,
     "window_moe_decoder": import_torch_window_moe_decoder,
     "looped_decoder": import_torch_looped_decoder,
+    "ssm_hybrid_decoder": import_torch_ssm_hybrid_decoder,
 }
 
 

@@ -25,7 +25,7 @@ import numpy as np
 
 from ..observability.metrics import get_registry
 from ..observability.tracing import get_tracer
-from . import attention
+from . import attention, scan
 
 __all__ = [
     "MLP",
@@ -39,6 +39,8 @@ __all__ = [
     "GroupedQueryAttention",
     "EvaAttention",
     "ShortConv",
+    "StateSpaceMixer",
+    "SSMHybridDecoder",
     "ExpertLayer",
     "GatedFFN",
     "RMSNorm",
@@ -257,6 +259,15 @@ class TransformerEncoder(nn.Module):
         return nn.Dense(self.num_outputs, dtype=jnp.float32, name="head")(pooled)
 
 
+def _times(x, multiplier: float):
+    """x under a model's fixed scalar: the product in float32, rounded once
+    to x's type; a multiplier of 1 is no operation at all, so a family
+    without multipliers lowers to what it did."""
+    if multiplier == 1.0:
+        return x
+    return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
+
+
 class RMSNorm(nn.Module):
     """x / rms(x) * scale: the statistics and the product in float32."""
 
@@ -350,7 +361,9 @@ class GroupedQueryAttention(nn.Module):
     or before it). How the heads are projected, rotated and projected back
     is the package's rule by shape (`attention.head_projection`,
     `rotary_heads`, `out_projection`): heads of whole lanes stay where the
-    kernel reads them in place."""
+    kernel reads them in place. `key_multiplier`: a fixed scalar on the key
+    projection's output (a family trained under fixed multipliers; 1 is no
+    operation at all)."""
 
     num_heads: int
     num_kv_heads: int
@@ -362,6 +375,7 @@ class GroupedQueryAttention(nn.Module):
     qk_norm: bool = True
     rotary: bool = True
     window: int | None = None
+    key_multiplier: float = 1.0
 
     @nn.compact
     def __call__(self, y):
@@ -385,7 +399,8 @@ class GroupedQueryAttention(nn.Module):
 
         with jax.named_scope("gqa.project"):
             q = placed(heads(self.num_heads, "q_proj"), "q_norm")
-            k = placed(heads(self.num_kv_heads, "k_proj"), "k_norm")
+            k = placed(_times(heads(self.num_kv_heads, "k_proj"),
+                              self.key_multiplier), "k_norm")
             v = heads(self.num_kv_heads, "v_proj")
         # the innermost scope names the plain Pallas call in a device trace;
         # the banded forward names itself (`swa_attn_w<window>`: lowered
@@ -450,6 +465,18 @@ class EvaAttention(nn.Module):
                                    dtype=dt, name="out")(o)
 
 
+def _causal_taps(z, kernel):
+    """c[t] = sum_j kernel[:, j] z[t - (taps - 1) + j] a channel, z zero
+    before a row's first token: a depthwise causal convolution as shifted
+    multiply-adds (the last tap meets the newest token, as torch's Conv1d
+    lays them). z (B, T, C), padded in its own type and read in float32;
+    kernel (C, taps) float32."""
+    taps, t = kernel.shape[1], z.shape[1]
+    z = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(kernel[:, j] * z[:, j:j + t].astype(jnp.float32)
+               for j in range(taps))
+
+
 class ShortConv(nn.Module):
     """The gated short convolution (LFM2, arXiv 2511.23404, section 2):
     [B, C, u] = y W_in; z = B * u; c[t] = sum_j w[:, j] z[t - (taps-1) + j]
@@ -464,7 +491,7 @@ class ShortConv(nn.Module):
 
     @nn.compact
     def __call__(self, y):
-        dt, (_b, t, d) = self.dtype, y.shape
+        dt, d = self.dtype, y.shape[-1]
         with jax.named_scope("conv.project"):
             gated = nn.Dense(3 * d, use_bias=False, dtype=dt,
                              name="in_proj")(y)
@@ -475,26 +502,116 @@ class ShortConv(nn.Module):
             gate_in, gate_out, u = (
                 gated[..., i * d:(i + 1) * d].astype(jnp.float32)
                 for i in range(3))
-            z = jnp.pad(gate_in * u, ((0, 0), (self.taps - 1, 0), (0, 0)))
-            c = sum(kernel[:, j] * z[:, j:j + t] for j in range(self.taps))
-            mixed = (gate_out * c).astype(dt)
+            mixed = (gate_out * _causal_taps(gate_in * u, kernel)).astype(dt)
         with jax.named_scope("conv.project"):
             return nn.Dense(d, use_bias=False, dtype=dt,
                             name="out_proj")(mixed)
 
 
+class StateSpaceMixer(nn.Module):
+    """The Mamba-2 mixer (arXiv 2405.21060, as Falcon-H1 runs it): ONE
+    input projection, no bias, to [z | x B C | dt]: a gate of `num_heads` x
+    `head_dim` channels, the scan's input of as many with `n_groups` groups
+    of `d_state` channels each for B and for C, and a step a head, each part
+    under its own fixed scalar (`projection_multipliers`, over z, x, B, C,
+    dt in that order); [x B C] through a depthwise causal convolution of
+    `conv_taps` taps with a bias and a SiLU, zero before a row's first
+    token; dt_j = softplus(dt_j + dt_bias_j), A_j = -exp(A_log_j); the
+    selective scan (`nn/scan.py`: S_t = exp(dt_t A) S_{t-1} + dt_t x_t
+    B_t^T, y_t = S_t C_t + D x_t, head j reading group j // (heads /
+    groups), no state across rows); y * silu(z) through an RMSNorm in
+    `n_groups` groups of channels with one weight a channel (the gate
+    BEFORE the norm); the output projection, no bias.
+
+    The convolution writes (B, T, channels) and the scan's kernel tier reads
+    a head's x and a group's B and C out of that array in place; the step,
+    the decays and the state are float32. `scan_name` is the kernel's own in
+    a device trace."""
+
+    num_heads: int
+    head_dim: int
+    n_groups: int
+    d_state: int
+    conv_taps: int = 4
+    projection_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+    scan_name: str = "ssd_scan"
+
+    @nn.compact
+    def __call__(self, y):
+        f32, dt_, d = jnp.float32, self.dtype, y.shape[-1]
+        heads, groups, state = self.num_heads, self.n_groups, self.d_state
+        inner, bc = heads * self.head_dim, groups * state
+        mixed = inner + 2 * bc
+        if heads % groups or inner % groups:
+            raise ValueError(f"{groups} groups do not divide {heads} heads "
+                             f"of {self.head_dim} channels")
+        m_z, m_x, m_b, m_c, m_dt = self.projection_multipliers
+        with jax.named_scope("ssm.project"):
+            p = nn.Dense(inner + mixed + heads, use_bias=False, dtype=dt_,
+                         name="in_proj")(y)
+
+        def vector(name, init, n):
+            return self.param(name, init, (n,), jnp.float32)
+
+        taps = self.param("conv_kernel",
+                          nn.initializers.normal(self.conv_taps ** -0.5),
+                          (mixed, self.conv_taps), jnp.float32)
+        conv_bias = vector("conv_bias", nn.initializers.zeros, mixed)
+        dt_bias = vector("dt_bias", nn.initializers.zeros, heads)
+        a_log = vector("A_log", nn.initializers.zeros, heads)
+        skip = vector("D", nn.initializers.ones, heads)
+        gate_scale = vector("norm_scale", nn.initializers.ones, inner)
+        with jax.named_scope("ssm.conv"):
+            # each part under its multiplier, a channel's in one vector; a
+            # channel's scalar commutes with its own taps, so it scales
+            # them: conv(m x) = (m taps) x, and the projection's slice is
+            # read once, as it lies
+            by_channel = jnp.concatenate([
+                jnp.full((inner,), m_x, f32), jnp.full((bc,), m_b, f32),
+                jnp.full((bc,), m_c, f32)])
+            xbc = nn.silu(_causal_taps(
+                p[..., inner:inner + mixed], taps * by_channel[:, None])
+                + conv_bias).astype(dt_)
+        with jax.named_scope("ssm.scan"):
+            step = jax.nn.softplus(
+                p[..., inner + mixed:].astype(f32) * m_dt + dt_bias)
+            scanned = scan.selective_scan(
+                xbc, step, -jnp.exp(a_log), skip, heads=heads,
+                width=self.head_dim, groups=groups, state=state,
+                name=self.scan_name)
+        with jax.named_scope("ssm.gate"):
+            gated = scanned.astype(f32) * nn.silu(
+                p[..., :inner].astype(f32) * m_z)
+            grouped = gated.reshape(*gated.shape[:-1], groups,
+                                    inner // groups)
+            normed = grouped * jax.lax.rsqrt(
+                (grouped * grouped).mean(-1, keepdims=True) + self.eps)
+            normed = (normed.reshape(gated.shape) * gate_scale).astype(dt_)
+        with jax.named_scope("ssm.project"):
+            return nn.Dense(d, use_bias=False, dtype=dt_,
+                            name="out_proj")(normed)
+
+
 class GatedFFN(nn.Module):
-    """down(silu(gate y) * up y), no biases."""
+    """down(silu(gate y) * up y), no biases. `multipliers` (gate, down):
+    fixed scalars on the gate's pre-activation and on the output, m_down *
+    down(silu(m_gate * gate y) * up y); (1, 1) is no operation at all."""
 
     width: int
     dtype: Any = jnp.float32
+    multipliers: tuple = (1.0, 1.0)
 
     @nn.compact
     def __call__(self, y):
         dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
-        hidden = nn.silu(dense(self.width, name="gate")(y).astype(
-            jnp.float32)).astype(self.dtype) * dense(self.width, name="up")(y)
-        return dense(y.shape[-1], name="down")(hidden)
+        m_gate, m_down = self.multipliers
+        gate = _times(dense(self.width, name="gate")(y).astype(jnp.float32),
+                      m_gate)
+        hidden = nn.silu(gate).astype(self.dtype) * dense(
+            self.width, name="up")(y)
+        return _times(dense(y.shape[-1], name="down")(hidden), m_down)
 
 
 class Router(nn.Module):
@@ -631,6 +748,11 @@ class _ScoringDecoder(nn.Module):
     reads it back with the batch and hands a call's counters to
     `call_span_arguments`).
 
+    A family trained under fixed scalars states them
+    (`embedding_multiplier` on the embedding's rows, `mlp_multipliers` on a
+    dense feed-forward's gate and output, `lm_head_multiplier` on the logits);
+    at 1 none of them is an operation.
+
     Three seats that a family may fill and most leave empty.
     `total_ut_steps` T > 1 runs the whole stack, the final norm included,
     T times over the SAME parameters (h_t = RMSNorm_final(stack(h_{t-1}));
@@ -662,6 +784,10 @@ class _ScoringDecoder(nn.Module):
     # "operator" (the operator's normed input: the picks are made before
     # the attention runs, by a `Router` named `router_<i>`)
     router_input = "experts"
+    # a family trained under fixed scalars states them; 1 is no operation
+    embedding_multiplier = 1.0      # on the embedding's rows
+    lm_head_multiplier = 1.0        # on the logits
+    mlp_multipliers = (1.0, 1.0)    # a dense feed-forward's (gate, down)
     total_ut_steps = 1              # passes of the stack, one set of weights
     sandwich_norms = False          # an RMSNorm after each operator as well
     exit_gate = False               # an exit distribution over the steps
@@ -746,6 +872,11 @@ class _ScoringDecoder(nn.Module):
                 (call.max(axis=1) / np.maximum(call.mean(axis=1), 1e-30))
                 .max()))
 
+    def _logits(self, h, head):
+        """h @ head in float32, under the family's scalar on the logits."""
+        return _times(jnp.dot(h, head, preferred_element_type=jnp.float32),
+                      self.lm_head_multiplier)
+
     def _token_logprobs(self, h, ids, head):
         """log_softmax(h @ head)[next token] for every position but a
         row's last, `head_chunk` tokens at a time."""
@@ -762,7 +893,7 @@ class _ScoringDecoder(nn.Module):
 
         def one(xs):
             hc, tc = xs
-            logits = jnp.dot(hc, head, preferred_element_type=jnp.float32)
+            logits = self._logits(hc, head)
             lse = jax.nn.logsumexp(logits, axis=-1)
             picked = jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
             return picked - lse
@@ -791,7 +922,9 @@ class _ScoringDecoder(nn.Module):
             h = h + out
             y = norm(name=f"ln_mlp_{i}")(h)
             if i < self._dense_layers:
-                out = GatedFFN(self.d_ff_dense, dt, name=f"mlp_{i}")(y)
+                out = GatedFFN(self.d_ff_dense, dt,
+                               tuple(self.mlp_multipliers),
+                               name=f"mlp_{i}")(y)
             else:
                 out, n = ExpertLayer(
                     self.n_routed_experts, tuple(self.experts_held),
@@ -846,7 +979,7 @@ class _ScoringDecoder(nn.Module):
         embed = nn.Embed(self.vocab_size, d, dtype=dt,
                          embedding_init=nn.initializers.normal(1.0),
                          name="embed")
-        h = embed(ids)
+        h = _times(embed(ids), self.embedding_multiplier)
         steps = int(self.total_ut_steps)
         # a token may leave before the last step: every step's state is kept
         select = self.exit_gate and self.early_exit_threshold < 1
@@ -897,7 +1030,7 @@ class _ScoringDecoder(nn.Module):
                 if self.num_pred_heads > 1 else head)
             self.sow("intermediates", "token_logprobs", logprobs)
             if self.output == "logits":
-                logits = jnp.dot(h, head, preferred_element_type=jnp.float32)
+                logits = self._logits(h, head)
                 return logits if self.num_pred_heads == 1 else logits.reshape(
                     *logits.shape[:2], self.num_pred_heads, self.vocab_size)
         if self.output != "token_logprobs":
@@ -1205,6 +1338,106 @@ class LoopedDecoder(_ScoringDecoder):
             head_dim=self.head_dim, qk_norm=False, name=f"gqa_attn_{i}")
 
 
+class SSMHybridDecoder(_ScoringDecoder):
+    """Causal decoder over token ids whose every layer runs TWO mixers side
+    by side on the same normed input (the Falcon-H1 block): a Mamba-2
+    state-space mixer (`StateSpaceMixer`: `ssm_heads` heads of
+    `ssm_head_dim` channels, `ssm_groups` groups of `ssm_state` state
+    channels, a convolution of `conv_taps` taps) and grouped-query
+    attention (`num_heads` query heads over `num_kv_heads` key/value heads
+    of `head_dim` channels, no norm on a head, rotary over the whole head),
+    h <- h + m_ssm_out SSM(m_ssm_in x) + m_attn_out Attn(m_attn_in x), then
+    a gated feed-forward; all of it under the family's fixed scalars: on
+    the embedding, the key projection, both mixers' inputs and outputs, the
+    five parts of the state-space projection (`ssm_multipliers`: z, x, B, C,
+    dt), the feed-forward's gate and output (`mlp_multipliers`) and the
+    logits. No expert layer; an untied head. The scan's tier is
+    `scan.tier`'s to pick (the backend and the widths), no option here. The
+    block loop, the final norm, the head and the outputs are
+    `_ScoringDecoder`'s."""
+
+    num_layers: int = 2
+    d_model: int = 64
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    head_dim: int = 16
+    ssm_heads: int = 4
+    ssm_head_dim: int = 16
+    ssm_groups: int = 2
+    ssm_state: int = 32
+    conv_taps: int = 4
+    d_ff_dense: int = 128
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 1e11
+    embedding_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: tuple = (1.0, 1.0)
+    lm_head_multiplier: float = 1.0
+    vocab_size: int = 256
+    max_len: int = 32768
+    # "flash": the Pallas kernel (chunked off-TPU), "chunked", "dense"
+    attention_impl: str = "flash"
+    head_chunk: int = 1024          # tokens of one block of the head
+    output: str = "token_logprobs"  # or "logits"
+    dtype: Any = jnp.float32
+
+    @property
+    def _dense_layers(self) -> int:
+        return self.num_layers
+
+    def _operator(self, i: int):
+        dt = self.dtype
+        ssm = StateSpaceMixer(
+            self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
+            self.ssm_state, self.conv_taps, tuple(self.ssm_multipliers),
+            self.rms_norm_eps, dt, f"ssd_scan_{i}", name=f"ssm_{i}")
+        # named as the other families' plain causal layers are, so that a
+        # device trace tells this attention by the same name
+        attend = GroupedQueryAttention(
+            self.num_heads, self.num_kv_heads, self.rope_theta,
+            self.rms_norm_eps, self.attention_impl, dt,
+            head_dim=self.head_dim, qk_norm=False,
+            key_multiplier=self.key_multiplier, name=f"gqa_attn_{i}")
+
+        def both(x):
+            """The two branches' sum, each under its scalars, in float32
+            and rounded once."""
+            f32 = jnp.float32
+            state_space = ssm(_times(x, self.ssm_in_multiplier))
+            attended = attend(_times(x, self.attention_in_multiplier))
+            return (state_space.astype(f32) * self.ssm_out_multiplier
+                    + attended.astype(f32) * self.attention_out_multiplier
+                    ).astype(dt)
+
+        return f"ln_op_{i}", both
+
+    def call_span_arguments(self, counted: dict, scored: list,
+                            row_shape: tuple) -> dict:
+        """The family's own beside the skeleton's: `ssd_steps`, the chunks
+        the call's scans stepped through (rows x heads x chunks, padding
+        rows' included, over the layers and the batches), which the
+        registry counts too. It follows from the batches' shapes, so it is
+        reckoned here and nothing is read back for it; only rows of token
+        ids have a length."""
+        arguments = super().call_span_arguments(counted, scored, row_shape)
+        if len(row_shape) == 1:
+            total = self.num_layers * sum(
+                scan.scan_steps(rows, row_shape[0], self.ssm_heads)
+                for rows in scored)
+            get_registry().counter(
+                "mmlspark_tpu_ssd_steps_total",
+                "chunks a state-space scan stepped through: rows x heads "
+                "x chunks, over the layers and the batches",
+            ).inc(float(total))
+            arguments["ssd_steps"] = total
+        return arguments
+
+
 def resnet20_cifar(num_outputs: int = 10, dtype=jnp.float32) -> ResNet:
     return ResNet(stage_sizes=(3, 3, 3), num_filters=16,
                   num_outputs=num_outputs, dtype=dtype)
@@ -1226,9 +1459,9 @@ def _hashable(config: dict) -> dict:
 # references architectures by name (the reference's ModelSchema carries a
 # remote URI instead, downloader/Schema.scala:30+). Families: `mlp`,
 # `simple_cnn` and the `resnet*` over images or features; over token ids the
-# `transformer` encoder and five causal decoders on one skeleton,
+# `transformer` encoder and six causal decoders on one skeleton,
 # `mla_moe_decoder`, `hybrid_moe_decoder`, `eva_decoder`,
-# `window_moe_decoder` and `looped_decoder`.
+# `window_moe_decoder`, `looped_decoder` and `ssm_hybrid_decoder`.
 ARCHITECTURES: dict[str, Callable[..., nn.Module]] = {
     "mlp": lambda **kw: MLP(**kw),
     "simple_cnn": lambda **kw: SimpleCNN(**kw),
@@ -1241,6 +1474,7 @@ ARCHITECTURES: dict[str, Callable[..., nn.Module]] = {
     "eva_decoder": lambda **kw: EvaDecoder(**kw),
     "window_moe_decoder": lambda **kw: WindowMoEDecoder(**_hashable(kw)),
     "looped_decoder": lambda **kw: LoopedDecoder(**kw),
+    "ssm_hybrid_decoder": lambda **kw: SSMHybridDecoder(**_hashable(kw)),
 }
 
 

@@ -752,3 +752,129 @@ def test_the_looped_references_layer_program_fits_beside_the_trees(one_chip):
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 0.35e9
     assert memory.argument_size_in_bytes < 0.3e9
+
+
+# --------------------------------------------------------------------- #
+# the state-space hybrid (`falcon_h1_34b.score_long_context`)           #
+# --------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("length", [32768, 2048])
+def test_state_space_scan_kernel_compiles_at_the_cells_shapes(one_chip,
+                                                              length):
+    """`ssd_scan_<i>` at the published widths, a batch of one row: 32 heads
+    of 128 channels, 2 groups of 256 state channels, read in place from the
+    convolution's (1, T, 5120) array, a group's 16 heads a grid step; their
+    states, (16, 256, 128) float32, are the kernel's one scratch (2 MB) and
+    nothing the size of a state leaves it."""
+    from mmlspark_tpu.nn import scan
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def scanned(xbc, dt, a, d):
+        return scan.ssd_kernel(xbc, dt, a, d, heads=32, width=128, groups=2,
+                               state=256, name="ssd_scan_3")
+
+    shapes = (spec((1, length, 5120), jnp.bfloat16),
+              spec((1, length, 32), jnp.float32), spec((32,), jnp.float32),
+              spec((32,), jnp.float32))
+    kernel = str(jax.make_jaxpr(scanned)(*shapes))
+    assert "Ref<vmem>{f32[16,256,128]}" in kernel
+    compiled = _compile(scanned, *shapes)
+    text = compiled.as_text()
+    assert re.search(r"%ssd_scan_3[.\d]* = ", text)
+    assert f"bf16[1,{length},4096]" in text
+    # the running decays in both layouts and the padded step: a few
+    # float32 numbers a token a head, and no copy of x, B or C
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 32 * length
+
+
+def test_state_space_scan_kernel_is_differentiated_for_the_chip(one_chip):
+    """`jax.grad` through `ssd_scan_<i>` compiles for the described v5e at
+    the published widths: the forward is the kernel, by name, and the
+    backward the plain tier's chunks in XLA (no Pallas backward exists to
+    fail)."""
+    from mmlspark_tpu.nn import scan
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(xbc, dt, a, d):
+        return scan.ssd_kernel(xbc, dt, a, d, heads=32, width=128, groups=2,
+                               state=256, name="ssd_scan_0").astype(
+                                   jnp.float32).sum()
+
+    shapes = (spec((1, 2048, 5120), jnp.bfloat16),
+              spec((1, 2048, 32), jnp.float32), spec((32,), jnp.float32),
+              spec((32,), jnp.float32))
+    both = _compile(jax.value_and_grad(loss, (0, 1, 2, 3)), *shapes)
+    text = both.as_text()
+    assert re.search(r"%ssd_scan_0[.\d]* = ", text)
+    _value, grads = both.out_info
+    assert [g.shape for g in grads] == [s.shape for s in shapes]
+
+
+@pytest.mark.parametrize("length", [32768, 2048])
+def test_grouped_query_attention_compiles_at_five_heads_a_group(one_chip,
+                                                                length):
+    """`gqa_attn_<i>` as the state-space hybrid's cell runs it: 20 query
+    heads over 4 key/value heads of 128 channels, in place, a batch of one
+    row."""
+    from mmlspark_tpu.nn.attention import flash_attention
+
+    bf = jnp.bfloat16
+    q = jax.ShapeDtypeStruct((1, length, 20, 128), bf, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, length, 4, 128), bf, sharding=one_chip)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    kernel = str(jax.make_jaxpr(attend)(q, k, k))
+    # a key/value head serves five query heads by the index map: K and V
+    # go in as they lie, four heads of them
+    assert f"bf16[1,{length},512]" in kernel
+    assert "tpu_custom_call" in _compile(attend, q, k, k).as_text()
+
+
+def test_the_state_space_hybrid_lowers_both_kernels_a_layer_by_name(
+        one_chip, monkeypatch):
+    """Two layers of the cell's model at its published widths, a row of
+    2048 tokens, embedding to log-probabilities: every layer's scan and
+    attention are in the compiled program under the names the readers
+    select, and no activation of the convolution's or the scan's width is
+    laid out again around the scan."""
+    from mmlspark_tpu.nn import attention, models
+
+    monkeypatch.setattr(attention.layout.jax, "default_backend",
+                        lambda: "tpu")
+    module = models.make_model(
+        "ssm_hybrid_decoder", num_layers=2, d_model=5120, num_heads=20,
+        num_kv_heads=4, head_dim=128, ssm_heads=32, ssm_head_dim=128,
+        ssm_groups=2, ssm_state=256, d_ff_dense=21504, vocab_size=32640,
+        key_multiplier=0.011048543456039804, ssm_in_multiplier=0.25,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+        lm_head_multiplier=0.0078125, dtype=jnp.bfloat16)
+    variables = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: module.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))
+    ids = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_chip)
+
+    def forward(v, x):
+        _out, state = module.apply(v, x, capture_intermediates=True,
+                                   mutable=["intermediates"])
+        return state["intermediates"]["token_logprobs"][0]
+
+    text = _compile(forward, variables, ids).as_text()
+    for layer in (0, 1):
+        assert re.search(rf"%ssd_scan_{layer}[.\d]* = ", text)
+        assert re.search(rf"%gqa_attn_{layer}[.\d]* = ", text)
+    assert "f32[1,2047]" in text
+    moved = [line for line in text[text.index("ENTRY"):].splitlines()
+             if re.search(r" (copy|transpose)\(", line)
+             and re.search(r"\[1,2048,(5120|4096|9248)\]",
+                           line.split("=")[1].split("(")[0])]
+    assert not moved, moved
