@@ -180,6 +180,11 @@ def _call_tiles(tq: int, tk: int, dtype, block_q, block_k, causal: bool,
     if causal:
         fold._count_edge_parts(block_q, block_k, -(-tk // block_k))
     fold._count_fold_rows(kernel, block_q, -(-tk // block_k))
+    # the plain forward by its mask: a decoder's `gqa_attn_<i>`, an
+    # encoder's `attn_<i>`
+    fold._count_grid_steps(
+        kernel if kernel != "flash" else "gqa" if causal else "attn",
+        tq, tk, block_q, block_k, causal)
     return block_q, block_k
 
 
@@ -310,6 +315,8 @@ def causal_attention(q, k, v, impl: str = "flash", window: int | None = None,
             steps = fold._band_steps(t, block_q, block_k, window)
             fold._count_edge_parts(block_q, block_k, steps, window)
             fold._count_fold_rows("swa", block_q, steps)
+            fold._count_grid_steps("swa", t, t, block_q, block_k, True,
+                                   window)
             fold._count_operands(
                 "swa", layout._lanes_whole(q.shape[-1], v.shape[-1]))
             return _banded_flash(

@@ -1,15 +1,18 @@
 """The fold every Pallas forward here takes, in ONE place: the tile rule
 (`flash_tiles`), the rules for the tiles a mask's edge crosses
 (`_edge_parts`, `_row_parts`), the online-softmax step (`_fold_tile`), the
-kernel body over a grid of (row, head, query block, key block)
-(`_flash_fold`), the call that builds that grid (`_flash_call`) and the
+kernel body of a grid step, one (query block, key block) pair of a row and
+head (`_flash_fold`), the list of the pairs that fold something
+(`_fold_steps`), the call whose grid walks it (`_flash_call`) and the
 registry's counters of which path a traced shape took. The plain, banded and
 latent cores (`flash.py`, `latent.py`) are `_flash_fold` with their own
 products; `eva.py`'s kernel takes `_fold_tile` directly. What each rule
-measured on a v5e is in PERF.md (section 6: PRs 27, 30, 31, 41, 43, 44)."""
+measured on a v5e is in PERF.md (section 6: PRs 27, 30, 31, 41, 43, 44,
+47)."""
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 import operator
@@ -106,10 +109,10 @@ def flash_tiles(tq: int, tk: int, dtype,
     return tile(tq), tile(tk)
 
 
-def _band_first(qi, block_q: int, block_k: int, window: int):
+def _band_first(qi: int, block_q: int, block_k: int, window: int) -> int:
     """The first key block that query block `qi` of a band reads: the one
     holding the key `window - 1` behind the block's first query, or 0."""
-    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+    return max(qi * block_q - (window - 1), 0) // block_k
 
 
 def _edge_parts(block_q: int, block_k: int, steps: int,
@@ -263,19 +266,23 @@ def _fold_tile(s, ok, v_ref, scratch, exponent, rows=None, keys=None):
 
 
 def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
-                block_k, num_kv, causal, tk_valid, scale, window=None,
-                key_blocks=None):
-    """What every flash forward does with a score tile, over a grid of
-    (row, head, query block, key block): `products(rows, keys)` is this
-    step's raw float32 products of queries and keys (over a head's
-    channels, or over the latent score's two parts), of the whole (bq, bk)
-    tile or of the `rows` and `keys` (first, how many) of it; the masks,
-    the online softmax, the block skips and the finalisation are here.
-    Told a `window` (a causal band: a query reads the `window` keys that
-    end with its own), the grid's last axis is the `num_kv` blocks a query
-    block's band can touch, counted from `_band_first` (of `key_blocks` in
-    all), and the block that the band's trailing edge crosses is masked
-    like the diagonal's.
+                block_k, num_kv, key_blocks, causal, tk_valid, scale,
+                window=None, walk=None):
+    """What every flash forward does with a score tile, a grid step a
+    (query block, key block) pair that folds something (`_fold_steps`):
+    `products(rows, keys)` is this step's raw float32 products of queries
+    and keys (over a head's channels, or over the latent score's two
+    parts), of the whole (bq, bk) tile or of the `rows` and `keys` (first,
+    how many) of it; the masks, the online softmax and the finalisation
+    are here. `walk` is the scalar-prefetch ref of the steps the grid's
+    last axis takes (`_flash_call`): -1, the steps' query blocks, -1, their
+    key blocks; a step is its query block's first where the entry before
+    its own differs, and its last where the one after does. A call of ONE
+    key block has none: the grid's last two axes are the query block and
+    that block. `num_kv` is the most key blocks a query block reads. Told a
+    `window` (a causal band: a query reads the `window` keys that end with
+    its own, of `key_blocks` blocks in all), the block that the band's
+    trailing edge crosses is masked like the diagonal's.
 
     An EDGE tile, where `_edge_parts` says so, is folded in parts along
     the queries: a part's rows against the keys its mask leaves and no
@@ -286,12 +293,13 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
     the parts touch disjoint rows of the scratch and carry nothing new."""
     import jax.experimental.pallas as pl
 
-    qi = pl.program_id(2)
-    at = kv = pl.program_id(3)                  # the step, and its key block
-    if window is not None:
-        kv = _band_first(qi, block_q, block_k, window) + at
+    if walk is None:
+        qi, kv = pl.program_id(2), pl.program_id(3)
+    else:
+        at, n = pl.program_id(2), walk.shape[0] // 2 - 1
+        qi, kv = walk[1 + at], walk[n + 2 + at]
     # only a padded sequence needs the key mask: decided here, in Python
-    padded = tk_valid < (num_kv if window is None else key_blocks) * block_k
+    padded = tk_valid < key_blocks * block_k
 
     def scores(mask_keys: bool, mask_causal: bool, mask_trailing=False,
                rows=None, keys=None):
@@ -357,7 +365,7 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
 
     m_sc, l_sc, acc_sc = scratch
 
-    @pl.when(at == 0)
+    @pl.when(walk[at] != qi)                  # its query block's first step
     def _init():
         m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[...] = jnp.zeros_like(l_sc)
@@ -384,11 +392,8 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
     if not causal:
         step(padded, False)
     else:
-        # key blocks wholly above the diagonal are skipped, not masked
-        # (their index map re-names the last block needed, so nothing is
-        # fetched for them either); blocks wholly below it need no causal
-        # mask
-        needed = kv * block_k <= qi * block_q + block_q - 1
+        # a key block wholly above the diagonal, or wholly behind a band,
+        # is no step (`_fold_steps`); one wholly inside needs no mask
         crosses = (kv + 1) * block_k - 1 > qi * block_q
         parts = _edge_parts(block_q, block_k, num_kv, window)
 
@@ -407,29 +412,25 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
                      else (r * size, block_k - r * size))
 
         if window is None:
-            pl.when(needed & crosses)(functools.partial(fold, True))
-            pl.when(needed & jnp.logical_not(crosses))(
-                functools.partial(fold, False))
+            pl.when(crosses)(functools.partial(fold, True))
+            pl.when(jnp.logical_not(crosses))(functools.partial(fold, False))
         else:
             # the band's other edge: some query of the block lies `window`
-            # or more past some key of this one (blocks wholly behind the
-            # band are never reached: the axis starts at `_band_first`)
+            # or more past some key of this one
             trails = qi * block_q + block_q - 1 - kv * block_k >= window
-            needed = needed & (kv < key_blocks)
             for diagonal in (True, False):
                 for trailing in (True, False):
                     if parts > 1 and diagonal and trailing:
                         # equal tiles that divide the window: the edges
                         # are `window // block_k` blocks apart
                         continue
-                    pl.when(needed
-                            & (crosses if diagonal
-                               else jnp.logical_not(crosses))
+                    pl.when((crosses if diagonal
+                             else jnp.logical_not(crosses))
                             & (trails if trailing
                                else jnp.logical_not(trails)))(
                         functools.partial(fold, diagonal, trailing))
 
-    @pl.when(at == num_kv - 1)
+    @pl.when(walk[at + 2] != qi)              # its query block's last step
     def _finalize():
         # the ONE sum across lanes a row
         write(m_sc[:, :1], l_sc[...].sum(-1, keepdims=True), acc_sc[...])
@@ -448,86 +449,159 @@ def _fold_scratch(block_q: int, dv: int, *key_widths: int) -> list:
 
 
 def _band_steps(tq: int, block_q: int, block_k: int, window: int) -> int:
-    """The key blocks the widest band of a query block touches: the extent
-    of a banded forward's last grid axis (`window // block_k + 1` where the
-    tiles are equal and divide the window)."""
-    return max((qi * block_q + block_q - 1) // block_k
-               - max(qi * block_q - (window - 1), 0) // block_k + 1
-               for qi in range(-(-tq // block_q)))
+    """The key blocks the widest band of a query block touches
+    (`window // block_k + 1` where the tiles are equal and divide the
+    window): the longest run of one query block in `_fold_steps`' list,
+    what `_edge_parts` and `band_tile_pairs` take for a banded forward's
+    steps a query block."""
+    return max(collections.Counter(
+        qi for qi, _kv in _fold_steps(tq, tq, block_q, block_k, True,
+                                      window)).values())
+
+
+@functools.lru_cache(maxsize=None)
+def _fold_steps(tq: int, tk: int, block_q: int, block_k: int, causal: bool,
+                window: int | None = None) -> tuple:
+    """The (query block, key block) pairs of a forward that FOLD something,
+    in the order the grid walks them: query blocks ascending, and of a
+    query block its key blocks ascending from the first its mask leaves (0;
+    with a `window`, `_band_first`) to the last (every one; causal, the
+    diagonal's). Without a mask that is the whole rectangle; a causal row
+    of 16384 in tiles of 1024 has 136 of its square's 256, its band of
+    4096 has 70."""
+    nk = -(-tk // block_k)
+    pairs = []
+    for qi in range(-(-tq // block_q)):
+        first = 0 if window is None else _band_first(
+            qi, block_q, block_k, window)
+        last = min((qi * block_q + block_q - 1) // block_k,
+                   nk - 1) if causal else nk - 1
+        if first > last:
+            raise ValueError(
+                f"query block {qi} of {tq} positions reads none of {tk} keys "
+                f"behind a window of {window}")
+        pairs += [(qi, kv) for kv in range(first, last + 1)]
+    return tuple(pairs)
+
+
+def _count_grid_steps(kernel: str, tq: int, tk: int, block_q: int,
+                      block_k: int, causal: bool,
+                      window: int | None = None) -> None:
+    """Counted where a flash forward is traced: the grid steps a head of a
+    row takes (`_fold_steps`) beside those of the whole rectangle of (query
+    block, key block) pairs, the square or a band's `_band_steps` a query
+    block. Equal where nothing is masked."""
+    nq = -(-tq // block_q)
+    steps = -(-tk // block_k) if window is None else _band_steps(
+        tq, block_q, block_k, window)
+    counter = get_registry().counter(
+        "mmlspark_tpu_flash_grid_steps_total",
+        "grid steps a head of a row takes in the flash-attention forward "
+        "calls traced, by the kernel (gqa: plain causal; attn: plain, no "
+        "mask; swa: banded; mla: latent) and by kind: visited (the steps "
+        "that fold something, which the grid walks) and square (every "
+        "query block against every key block, or against its band's most)",
+        labels=("kernel", "kind"))
+    counter.labels(kernel=kernel, kind="visited").inc(
+        len(_fold_steps(tq, tk, block_q, block_k, causal, window)))
+    counter.labels(kernel=kernel, kind="square").inc(nq * steps)
 
 
 def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
                 tk, causal, scale, block_q, block_k, interpret, name=None,
                 window=None):
-    """ONE Pallas forward over a grid of (row, head, query block, key
-    block). `queries`, `keys` and `value` are (array, block width, at):
-    `at(row, head, block along the sequence)` names the (1, positions,
-    width) block of that head in the array, wherever it lies; the value
-    block is the last input. The output's blocks are named by `out_at` in
-    an array of `out_shape` (as wide a block as the value's). -> (out in
-    that shape, lse (B x H, Tq, 1) float32); `tk` is the keys' length
-    before padding. `name` is the call's own in a device trace; without
-    one the innermost `jax.named_scope` around it names it. With a
-    `window` (causal) the last axis is a query block's band, `_band_steps`
-    key blocks from `_band_first` on: a block wholly behind the band is
-    never named, one above the diagonal re-names the diagonal's."""
+    """ONE Pallas forward over a grid of (row, head, step), a step a
+    (query block, key block) pair that folds something (`_fold_steps`).
+    `queries`, `keys` and `value` are (array, block width, at): `at(row,
+    head, block along the sequence)` names the (1, positions, width) block
+    of that head in the array, wherever it lies; the value block is the
+    last input. The output's blocks are named by `out_at` in an array of
+    `out_shape` (as wide a block as the value's). -> (out in that shape,
+    lse (B x H, Tq, 1) float32); `tk` is the keys' length before padding.
+    `name` is the call's own in a device trace; without one the innermost
+    `jax.named_scope` around it names it.
+
+    The grid's last axis walks the list, read from ONE scalar-prefetch
+    operand (`_flash_fold`'s `walk`), so a block above the diagonal or
+    behind a band is no step at all, and a query block's last step, the
+    one under which the next block's copies are issued, is one that
+    computes. A call of ONE key block holds no list: its grid is (row,
+    head, query block, 1), with no operand added."""
     import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+    import numpy as np
 
     keys = [*keys, value]
     dv = value[1]
     nq = queries[0][0].shape[1] // block_q
     nk = steps = keys[0][0].shape[1] // block_k
-    band = {}
     if window is not None:
         steps = _band_steps(nq * block_q, block_q, block_k, window)
-        band = {"window": window, "key_blocks": nk}
+    static = dict(block_q=block_q, block_k=block_k, num_kv=steps,
+                  key_blocks=nk, causal=causal, tk_valid=tk, scale=scale,
+                  window=window)
+    if nk > 1:
+        qs, ks = np.asarray(_fold_steps(
+            nq * block_q, nk * block_k, block_q, block_k, causal, window)).T
+        n = len(qs)
+        # ONE operand, both tables end to end: as several operands they
+        # moved the latent call's arrays to positions where the compiler
+        # no longer wrote the projections in the call's layout and copied
+        # q_nope and kv before every call (PERF.md, PR 47)
+        walk = (np.concatenate([[-1], qs, [-1], ks]).astype(np.int32),)
+        grid = (b, h, n)
+
+        def query_block(at, walk):
+            return walk[1 + at]
+
+        def key_block(at, walk):
+            return walk[n + 2 + at]
+
+        def body(walk, *refs):
+            kernel(*refs, walk=walk, **static)
+    else:
+        walk, grid = (), (b, h, nq, 1)
+        body = functools.partial(kernel, **static)
+
+        def query_block(qi, _kv):
+            return qi
+
+        def key_block(_qi, kv):
+            return kv
 
     def query_spec(width, at):
-        return pl.BlockSpec((1, block_q, width),
-                            lambda b_, j, qi, kv: at(b_, j, qi))
+        return pl.BlockSpec(
+            (1, block_q, width),
+            lambda b_, j, *step: at(b_, j, query_block(*step)))
 
     def key_spec(width, at):
-        if window is not None:
-            def index(b_, j, qi, kv):
-                last = (qi * block_q + block_q - 1) // block_k
-                return at(b_, j, jnp.minimum(
-                    _band_first(qi, block_q, block_k, window) + kv,
-                    jnp.minimum(last, nk - 1)))
-        elif causal:
-            # a key block above the diagonal is never computed on: name
-            # the last block this query block needs instead, which is
-            # already in VMEM, so that no copy is issued for the skipped
-            # steps
-            def index(b_, j, qi, kv):
-                last = (qi * block_q + block_q - 1) // block_k
-                return at(b_, j, jnp.minimum(kv, last))
-        else:
-            def index(b_, j, qi, kv):
-                return at(b_, j, kv)
-        return pl.BlockSpec((1, block_k, width), index)
+        return pl.BlockSpec(
+            (1, block_k, width),
+            lambda b_, j, *step: at(b_, j, key_block(*step)))
+
+    def lse_at(b_, j, qi):
+        return b_ * h + j, qi, 0
 
     return pl.pallas_call(
-        functools.partial(
-            kernel, block_q=block_q, block_k=block_k, num_kv=steps,
-            causal=causal, tk_valid=tk, scale=scale, **band),
-        grid=(b, h, nq, steps),
-        in_specs=[query_spec(w, at) for _x, w, at in queries]
-        + [key_spec(w, at) for _x, w, at in keys],
-        out_specs=[
-            query_spec(dv, out_at),
-            # lse is a (block_q, 1) column, a row's statistic as the
-            # finalisation's sum across lanes leaves it: a trailing dim
-            # equal to the array's satisfies Mosaic's block rule, and no
-            # sublane->lane relayout happens in the kernel
-            pl.BlockSpec((1, block_q, 1),
-                         lambda b_, j, qi, kv: (b_ * h + j, qi, 0)),
-        ],
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(walk), grid=grid,
+            in_specs=[query_spec(w, at) for _x, w, at in queries]
+            + [key_spec(w, at) for _x, w, at in keys],
+            out_specs=[
+                query_spec(dv, out_at),
+                # lse is a (block_q, 1) column, a row's statistic as the
+                # finalisation's sum across lanes leaves it: a trailing dim
+                # equal to the array's satisfies Mosaic's block rule, and
+                # no sublane->lane relayout happens in the kernel
+                query_spec(1, lse_at),
+            ],
+            # one key block carries nothing from step to step
+            scratch_shapes=[] if steps == 1 else _fold_scratch(
+                block_q, dv, block_k)),
         out_shape=[
             out_shape,
             jax.ShapeDtypeStruct((b * h, nq * block_q, 1), jnp.float32),
         ],
-        # one key block carries nothing from step to step
-        scratch_shapes=[] if steps == 1 else _fold_scratch(
-            block_q, dv, block_k),
         interpret=interpret, name=name,
-    )(*(x for x, _w, _at in [*queries, *keys]))
+    )(*walk, *(x for x, _w, _at in [*queries, *keys]))

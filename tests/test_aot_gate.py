@@ -42,9 +42,17 @@ class TestAotGate:
 
         calls = []
 
-        def recording_pallas_call(kernel, *, out_shape, in_specs, out_specs,
-                                  **_kw):
+        def recording_pallas_call(kernel, *, out_shape, in_specs=None,
+                                  out_specs=None, grid_spec=None, **_kw):
+            prefetched = 0
+            if grid_spec is not None:
+                # the specs inside a grid spec; its scalar-prefetch
+                # operands come first and have no block
+                in_specs, out_specs = grid_spec.in_specs, grid_spec.out_specs
+                prefetched = grid_spec.num_scalar_prefetch
+
             def run(*args):
+                args = args[prefetched:]
                 outs_list = (list(out_shape)
                              if isinstance(out_shape, (list, tuple))
                              else [out_shape])

@@ -393,24 +393,31 @@ class TestGroupedQueryHeads:
             np.asarray(call(q, jnp.repeat(k, group, 2),
                             jnp.repeat(v, group, 2))))
 
+    @pytest.mark.parametrize("tile", [16, 32])
     @pytest.mark.parametrize("d", [64, 128])
     @pytest.mark.parametrize("group", [1, 4])
-    def test_key_and_value_operands_keep_their_own_heads(self, group, d):
+    def test_key_and_value_operands_keep_their_own_heads(self, group, d,
+                                                         tile):
         q, k, v = _grouped_qkv(2, 32, 8, group, d)
         eqn = _pallas_equation(
-            lambda q, k, v: flash_attention(q, k, v, block_q=16, block_k=16,
-                                            interpret=True), q, k, v)
+            lambda q, k, v: flash_attention(q, k, v, block_q=tile,
+                                            block_k=tile, interpret=True),
+            q, k, v)
+        # two key blocks: the walk comes first
+        walked = eqn.params["grid_mapping"].num_index_operands
+        assert walked == (tile == 16)
         # 64 channels: head-major rows; 128: the arrays as they lie
-        assert [x.aval.shape for x in eqn.invars] == (
+        assert [x.aval.shape for x in eqn.invars[walked:]] == (
             [(16, 32, 64), (16 // group, 32, 64), (16 // group, 32, 64)]
             if d == 64 else
             [(2, 32, 8 * 128)] + [(2, 32, 8 // group * 128)] * 2)
         # what an index map computes: in place nothing (it names its grid
-        # indices), head-major the row b x H + j; grouped heads add the one
+        # indices, or reads the step's block from the walk: an add and a
+        # load), head-major the row b x H + j; grouped heads add the one
         # division
         maps = eqn.params["grid_mapping"].block_mappings
         computed = [len(m.index_map_jaxpr.jaxpr.eqns) for m in maps[:3]]
-        row = 0 if d == 128 else 2
+        row = (0 if d == 128 else 2) + 2 * walked
         assert computed == [row, row + (group > 1), row + (group > 1)]
 
     def test_grouped_gradients_match_dense(self):
@@ -606,7 +613,8 @@ class TestOperandsInPlace:
             *a, block_q=tile, block_k=tile, interpret=True)
         assert not [s for s in _transposed_lengths(fn, *parts) if t in s]
         eqn = _pallas_equation(fn, *parts)
-        assert [x.aval.shape[-1] for x in eqn.invars] == [
+        # (behind the walk's table where a row is several tiles)
+        assert [x.aval.shape[-1] for x in eqn.invars if x.aval.ndim == 3] == [
             4 * 128, 4 * 64, 4 * 256, 2 * 128, 4 * 256]
 
     def test_split_latent_gradient_equals_todays(self):
@@ -1310,16 +1318,20 @@ class TestEdgeTilesInParts:
     # no running statistics (the log-sum-exp's scale), 3 to 49 elsewhere
     # (a fold's row sum by lanes, the maximum laid over the tile, the
     # exponent's constant twice, less the scale's pass; a second fold for
-    # the `_row_parts` halves of a tile of 1024 rows that nothing masks)
+    # the `_row_parts` halves of a tile of 1024 rows that nothing masks);
+    # PR 47's walk of a list takes 1 from a causal kernel of several key
+    # blocks and 21 from a band's (`needed` and its conjunctions gone, three
+    # offsets into the walk's table come), and 16 from a band of one tile
+    # (its one key block is block 0: no first block to reckon)
     @pytest.mark.parametrize("case,t,window,tiles,equations", [
         ("one_tile", 512, None, {}, 50),
         ("one_tile_band", 1024, 512, {"block_q": 1024, "block_k": 1024},
-         69),
-        ("unequal", 2048, None, {"block_q": 1024, "block_k": 512}, 161),
-        ("small", 512, None, {"block_q": 128, "block_k": 128}, 123),
-        ("window_1100", 2200, 1100, {}, 288),
+         53),
+        ("unequal", 2048, None, {"block_q": 1024, "block_k": 512}, 160),
+        ("small", 512, None, {"block_q": 128, "block_k": 128}, 122),
+        ("window_1100", 2200, 1100, {}, 267),
         ("band_unequal", 4096, 1024, {"block_q": 1024, "block_k": 512},
-         292),
+         271),
     ])
     def test_where_the_split_does_not_engage_the_program_is_the_parents(
             self, monkeypatch, case, t, window, tiles, equations):
@@ -1829,3 +1841,230 @@ class TestLaneDenseFold:
             ("flash", "512x2"): 2, ("flash", "512x1"): 1,
             ("flash", "1024x1"): 1, ("swa", "512x2"): 1,
             ("mla", "512x2"): 1, ("eva", "512x2"): 1}
+
+
+STEP_TILE = 128                 # whole lane blocks: the statistics lane-dense
+# the forwards `TestFoldSteps` runs: channels a head (the latent one's are
+# `_latent_inputs`')
+STEP_KINDS = {
+    "plain": 128, "heads_of_64": 64, "band_of_tiles": 128, "band_of_200": 64,
+    "latent": None}
+
+
+def _grid_steps(kernel: str) -> tuple:
+    counter = get_registry().counter(
+        "mmlspark_tpu_flash_grid_steps_total", labels=("kernel", "kind"))
+    return tuple(counter.labels(kernel=kernel, kind=kind).value
+                 for kind in ("visited", "square"))
+
+
+def _by_one_softmax(q, k, v, window=None):
+    """(out (B, T, H, Dv), log-sum-exp (B, H, T)) of causal attention as
+    ONE softmax over masked float64 scores, a band where told."""
+    s = _scaled_scores(q, k, True)
+    if window is not None:
+        pos = np.arange(s.shape[-1])
+        s = np.where(pos[:, None] - pos[None, :] < window, s, -np.inf)
+    v = np.repeat(np.asarray(v, np.float64), q.shape[2] // v.shape[2], 2)
+    lse = _log_sum_exp(s)
+    return np.einsum("bhqk,bkhd->bqhd", np.exp(s - lse[..., None]), v), lse
+
+
+class TestFoldSteps:
+    """A forward of several key blocks walks the LIST of the (query block,
+    key block) pairs that fold something, read from a scalar-prefetch
+    operand, not the square: the folds of a query block and their order
+    are what they were, so are the numbers. Tiles of `STEP_TILE` on the
+    CPU's interpreted kernel, float32 inputs; today's tolerances."""
+
+    @pytest.mark.parametrize("shape,steps", [
+        ((16384, 1024, 1024, None), 136), ((8192, 1024, 1024, None), 36),
+        ((32768, 1024, 1024, None), 528), ((4096, 1024, 1024, None), 10),
+        ((2048, 1024, 1024, None), 3), ((1024, 1024, 1024, None), 1),
+        ((16384, 1024, 1024, 4096), 70), ((16384, 1024, 1024, 1024), 31),
+        # tiles that do not divide the length, unequal tiles, a window no
+        # tile divides
+        ((2600, 1024, 1024, None), 6), ((600, 128, 256, None), 9),
+        ((600, 256, 128, None), 11), ((2600, 640, 640, 1100), 12),
+        ((600, 128, 128, 200), 12)])
+    def test_the_list_is_the_pairs_that_fold_in_todays_order(self, shape,
+                                                             steps):
+        t, block_q, block_k, window = shape
+        pairs = attention.fold._fold_steps(t, t, block_q, block_k, True,
+                                           window)
+        assert len(pairs) == steps
+        assert list(pairs) == sorted(pairs)  # query blocks, then key blocks
+        nq, nk = -(-t // block_q), -(-t // block_k)
+        for qi in range(nq):
+            mine = [kv for q_, kv in pairs if q_ == qi]
+            # from the first block the mask leaves to the diagonal's
+            first = 0 if window is None else (
+                max(qi * block_q - window + 1, 0) // block_k)
+            last = min((qi * block_q + block_q - 1) // block_k, nk - 1)
+            assert mine == list(range(first, last + 1))
+        if window is not None:
+            # what `band_tile_pairs` visits (an edge tile whole: its share
+            # of a tile taken back out)
+            share = attention.fold.edge_tile_share(attention.fold._edge_parts(
+                block_q, block_k,
+                attention.fold._band_steps(t, block_q, block_k, window),
+                window))
+            computed, _needed = attention.flash.band_tile_pairs(
+                t, window, block_q, block_k)
+            if share == 1.0:
+                assert len(pairs) == computed
+            assert len(pairs) <= nq * attention.fold._band_steps(
+                t, block_q, block_k, window)
+
+    def test_no_mask_is_the_whole_rectangle(self):
+        pairs = attention.fold._fold_steps(600, 900, 128, 256, False)
+        assert list(pairs) == [
+            (qi, kv) for qi in range(5) for kv in range(4)]
+
+    # (the latent forward's heads share ONE rotary key, no key head)
+    @pytest.mark.parametrize("kind,grouped", [
+        (kind, grouped) for kind in STEP_KINDS for grouped in (False, True)
+        if not (kind == "latent" and grouped)])
+    @pytest.mark.parametrize("padded", [False, True])
+    @pytest.mark.parametrize("blocks", [1, 2, 3, 5])
+    def test_the_walked_fold_matches_one_softmax(self, kind, grouped, blocks,
+                                                 padded):
+        """The plain causal forward (heads of 128 in place, heads of 64
+        head-major), the band (a window of whole tiles, four of them at
+        five key blocks as 4096 is of 1024; a window of 200, which no
+        multiple of 128 divides) and the latent forward at 1, 2, 3 and 5
+        key blocks, the keys padded inside the last block or not, grouped
+        key heads or not: output and log-sum-exp."""
+        t = blocks * STEP_TILE - (37 if padded else 0)
+        tiles = (STEP_TILE, STEP_TILE, True)
+        window = {"band_of_tiles": STEP_TILE * (4 if blocks == 5 else 1),
+                  "band_of_200": 200}.get(kind)
+        if kind == "latent":
+            operands = _latent_inputs(t, b=1, h=2, seed=blocks)
+            got = attention.latent._latent_fwd_lse(*operands, *tiles)
+            q, k, v = attention.latent._latent_concatenated(*operands)
+        else:
+            q, k, v = _grouped_qkv(1, t, 4, 2 if grouped else 1,
+                                   STEP_KINDS[kind], seed=blocks)
+            got = attention.flash._flash_fwd_lse(q, k, v, True, *tiles,
+                                                 window=window)
+        out, lse = _by_one_softmax(q, k, v, window)
+        np.testing.assert_allclose(got[0], out, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(got[1], lse, rtol=1e-6, atol=2e-6)
+
+    def test_a_band_of_4096_in_tiles_of_1024_matches_the_dense_tier(self):
+        """The cell's own window and tiles at five key blocks, the keys
+        padded inside the last: 15 of 25 pairs, the edge tiles in parts."""
+        t, window = 5 * 1024 - 40, 4096
+        q, k, v = _band_inputs(t, (2, 1), 64, seed=9)
+        q, k, v = q[:1], k[:1], v[:1]
+        got = attention.causal_attention(q, k, v, "flash", window=window,
+                                         block_q=1024, block_k=1024,
+                                         interpret=True)
+        want = attention.causal_attention(q, k, v, "dense", window=window)
+        assert len(attention.fold._fold_steps(t, t, 1024, 1024, True,
+                                              window)) == 15
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("call,prefetched", [
+        ("not causal one tile", 0), ("one tile", 0), ("one key block", 0),
+        ("not causal", 1), ("causal", 1), ("banded", 1), ("latent", 1),
+        ("latent one tile", 0)])
+    def test_only_a_call_of_several_key_blocks_brings_the_walk(
+            self, call, prefetched):
+        """A call of ONE key block (the encoder's 512 tokens, a decoder's
+        row of one tile) is the parent's: a grid of (row, head, query
+        block, 1) and no operand beside the arrays. Every call of several
+        key blocks takes ONE operand more, the walk (-1, the steps' query
+        blocks, -1, their key blocks), and a grid of (row, head, step): a
+        causal call, a band and the latent forward of the pairs their
+        masks leave, a call without a mask of its whole rectangle."""
+        x = jax.ShapeDtypeStruct((2, 600, 2, 128), jnp.float32)
+        tiles = {"block_q": 128, "block_k": 128, "interpret": True}
+        if call.startswith("latent"):
+            one = call.endswith("one tile")
+            t = 128 if one else 600
+            shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in (
+                (1, t, 2, 128), (1, t, 2, 64), (1, t, 2, 256), (1, t, 64))]
+            eqn = _pallas_equation(
+                lambda *o: attention.latent_attention(
+                    *o, block_q=128, block_k=128, interpret=True), *shapes)
+            arrays, steps = 5, (1 if one else 15)      # kv twice: k and v
+        else:
+            if call.endswith("one tile"):
+                tiles.update(block_q=640, block_k=640)
+            if call == "one key block":
+                tiles.update(block_k=640)
+            if call == "banded":
+                fn = lambda q, k, v: attention.causal_attention(  # noqa: E731
+                    q, k, v, "flash", window=200, **tiles)
+            else:
+                fn = lambda q, k, v: flash_attention(             # noqa: E731
+                    q, k, v, causal=not call.startswith("not causal"),
+                    **tiles)
+            eqn = _pallas_equation(fn, x, x, x)
+            arrays = 3
+            steps = {"causal": 15, "banded": 12, "not causal": 25}.get(call)
+        mapping = eqn.params["grid_mapping"]
+        assert mapping.num_index_operands == prefetched
+        assert len(eqn.invars) == arrays + prefetched
+        if prefetched:
+            assert mapping.grid[2:] == (steps,)
+            walk = eqn.invars[0].aval
+            assert walk.shape == (2 * steps + 2,) and walk.dtype == jnp.int32
+        else:
+            assert mapping.grid[3:] == (1,)
+
+    @pytest.mark.parametrize("window", [1, 2, 129])
+    def test_a_band_of_one_key_block_a_query_block_still_walks(self, window):
+        """A window of ONE key: every query block reads its own diagonal
+        block and no other, so the most a query block reads is one block
+        (no scratch, the one-tile step) of several: the walk names it."""
+        t = 3 * STEP_TILE
+        q, k, v = _grouped_qkv(1, t, 2, 1, 128, seed=window)
+        got = attention.flash._flash_fwd_lse(
+            q, k, v, True, STEP_TILE, STEP_TILE, True, window=window)
+        out, lse = _by_one_softmax(q, k, v, window)
+        np.testing.assert_allclose(got[0], out, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(got[1], lse, rtol=1e-6, atol=2e-6)
+
+    @pytest.mark.parametrize("blocks", [(2, 3), (3, 2), (5, 5)])
+    @pytest.mark.parametrize("padded", [False, True])
+    def test_the_walked_rectangle_matches_one_softmax(self, blocks, padded):
+        """A forward without a mask of several key blocks walks its whole
+        rectangle from the list: queries and keys of different lengths, the
+        keys padded inside the last block or not."""
+        tq, tk = (n * STEP_TILE for n in blocks)
+        tk -= 37 if padded else 0
+        q, _k, _v = _grouped_qkv(1, tq, 4, 2, 128, seed=tq)
+        _q, k, v = _grouped_qkv(1, tk, 4, 2, 128, seed=tk + 1)
+        got = attention.flash._flash_fwd_lse(q, k, v, False, STEP_TILE,
+                                             STEP_TILE, True)
+        want = attention.dense_attention(
+            q, jnp.repeat(k, 2, 2), jnp.repeat(v, 2, 2))
+        np.testing.assert_allclose(got[0], want, atol=2e-5, rtol=2e-5)
+
+    def test_a_traced_forward_counts_the_steps_it_walks(self):
+        """Once a traced call, beside the edge parts' counter: the steps a
+        head of a row visits and those of the square (a band: of its
+        rectangle), by kernel."""
+        def x(t, d=128, h=2):
+            return jax.ShapeDtypeStruct((1, t, h, d), jnp.bfloat16)
+
+        kernels = ("gqa", "attn", "swa", "mla")
+        before = {kernel: _grid_steps(kernel) for kernel in kernels}
+        for t in (16384, 1024):
+            jax.eval_shape(lambda q, k, v: attention.causal_attention(
+                q, k, v, "flash"), x(t), x(t), x(t))
+        jax.eval_shape(flash_attention, x(512), x(512), x(512))
+        jax.eval_shape(lambda q, k, v: attention.causal_attention(
+            q, k, v, "flash", window=4096), x(16384), x(16384), x(16384))
+        jax.eval_shape(
+            attention.latent_attention, x(4096), x(4096, 64), x(4096, 256),
+            jax.ShapeDtypeStruct((1, 4096, 64), jnp.bfloat16))
+        after = {kernel: _grid_steps(kernel) for kernel in kernels}
+        assert {kernel: tuple(a - b for a, b in zip(after[kernel],
+                                                    before[kernel]))
+                for kernel in kernels} == {
+            "gqa": (136 + 1, 256 + 1), "attn": (1, 1), "swa": (70, 80),
+            "mla": (10, 16)}

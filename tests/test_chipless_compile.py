@@ -298,6 +298,10 @@ LAYERS = {
     "eva_1x4096": ("eva", 4096, (1, 4096)),
     "latent_8x4096": ("latent", 2048, (8, 4096)),
     "latent_8x512": ("latent", 2048, (8, 512)),
+    # TWO layers: with the walk's tables as four operands of the call the
+    # compiler wrote the second layer's q_nope and kv positions-minor and
+    # copied both before the kernel (PR 47: 0.6 ms a long layer on the chip)
+    "latent_8x4096_two_layers": ("latent", 2048, (8, 4096), 2),
     "encoder_32x512": ("encoder", 4096, (32, 512)),
     "encoder_32x128": ("encoder", 4096, (32, 128)),
     # `smallthinker_21b_a3b.score_mixed_context`: 28 query heads over 4
@@ -305,6 +309,8 @@ LAYERS = {
     "sliding_2x16384": ("sliding", 2560, (2, 16384)),
     "sliding_2x2048": ("sliding", 2560, (2, 2048)),
     "global_2x16384": ("global", 2560, (2, 16384)),
+    "sliding_2x16384_two_layers": ("sliding", 2560, (2, 16384), 2),
+    "global_2x16384_two_layers": ("global", 2560, (2, 16384), 2),
 }
 
 # what is left, by the traces of PR 35 (PERF.md section 5): at ONE row of
@@ -333,8 +339,19 @@ def _attention_layer(kind: str, i: int = 0):
             num_heads=16, kv_lora_rank=512, qk_nope_head_dim=128,
             qk_rope_head_dim=64, v_head_dim=128, rope_theta=50000.0,
             dtype=bf, name=f"mla_attn_{i}")
+    if kind in GROUPED:
+        heads, key_heads, head = GROUPED[kind]
+        return models.GroupedQueryAttention(
+            heads, key_heads, 1e6, 1e-6, "flash", bf, head_dim=head,
+            name=f"gqa_attn_{i}")
     return attention.SelfAttention(num_heads=32, dtype=bf, impl="flash",
                                    name=f"attn_{i}")
+
+
+# the other three decoder cells' grouped-query layers: query heads, key
+# heads, channels a head (`lfm2_8b_a1b`, `ouro_2_6b`, `falcon_h1_34b`)
+GROUPED = {"lfm2": (32, 8, 64), "ouro": (16, 16, 128),
+           "falcon": (20, 4, 128)}
 
 
 def _layers_for_the_chip(one_chip, monkeypatch, kind, width, rows, length,
@@ -377,9 +394,9 @@ def test_no_activation_is_laid_out_again_around_the_kernels(
     calls the optimised program holds no copy, transpose or reshape of an
     array with the batch's extents (PERF.md, PR 34: a projection to four
     dimensions is written positions-minor and copied; XLA's rotary too)."""
-    kind, width, (rows, length) = LAYERS[case]
+    kind, width, (rows, length), *layers = LAYERS[case]
     text = _compile(*_layers_for_the_chip(one_chip, monkeypatch, kind, width,
-                                          rows, length)).as_text()
+                                          rows, length, *layers)).as_text()
     assert "tpu_custom_call" in text
 
     # an activation at least as large as the smallest the kernels read:
@@ -396,6 +413,55 @@ def test_no_activation_is_laid_out_again_around_the_kernels(
              if op in ("copy", "transpose", "reshape") and activation
              and large(kind_)]
     assert moved == KNOWN_MOVES.get(case, []), moved
+
+
+@pytest.mark.parametrize("kind,width,rows,length,kernel,visited,square", [
+    # the five decoder cells' long batches: the triangle of a square, the
+    # band of its rectangle
+    ("lfm2", 2048, 2, 16384, "gqa", 136, 256),
+    ("global", 2560, 2, 16384, "gqa", 136, 256),
+    ("sliding", 2560, 2, 16384, "swa", 70, 80),
+    ("ouro", 2048, 2, 8192, "gqa", 36, 64),
+    ("falcon", 5120, 1, 32768, "gqa", 528, 1024),
+    ("latent", 2048, 8, 4096, "mla", 10, 16),
+    # their short ones: rows of 2048 are three steps of four, a sliding
+    # layer's row inside its window is plain causal; one tile is one step
+    ("global", 2560, 2, 2048, "gqa", 3, 4),
+    ("sliding", 2560, 2, 2048, "gqa", 3, 4),
+    ("falcon", 5120, 1, 2048, "gqa", 3, 4),
+    ("lfm2", 2048, 2, 1024, "gqa", 1, 1),
+    ("ouro", 2048, 2, 1024, "gqa", 1, 1),
+    ("latent", 2048, 8, 512, "mla", 1, 1),
+    # the encoder's rectangle is all steps that compute
+    ("encoder", 4096, 32, 512, "attn", 1, 1),
+    ("encoder", 4096, 32, 128, "attn", 1, 1)])
+def test_a_causal_forward_of_several_key_blocks_walks_fewer_steps(
+        one_chip, monkeypatch, kind, width, rows, length, kernel, visited,
+        square):
+    """`mmlspark_tpu_flash_grid_steps_total` where a cell's attention
+    layer is traced at the cell's own batch: a head of a row visits fewer
+    steps than the square (the band's rectangle) holds in every causal
+    kernel of several key blocks, and every one of them where the list is
+    the rectangle (one tile a row; the encoder). Traced only."""
+    from mmlspark_tpu.observability.metrics import get_registry
+
+    counter = get_registry().counter(
+        "mmlspark_tpu_flash_grid_steps_total", labels=("kernel", "kind"))
+
+    def read():
+        return {(k, kind_): counter.labels(kernel=k, kind=kind_).value
+                for k in ("gqa", "swa", "mla", "attn")
+                for kind_ in ("visited", "square")}
+
+    # the parameters' shapes come from a trace of their own, at 8 tokens
+    layer = _layers_for_the_chip(one_chip, monkeypatch, kind, width, rows,
+                                 length)
+    before = read()
+    jax.eval_shape(*layer)
+    moved = {key: value - before[key] for key, value in read().items()
+             if value != before[key]}
+    assert moved == {(kernel, "visited"): visited, (kernel, "square"): square}
+    assert (visited < square) == (length > 1024)
 
 
 @pytest.mark.parametrize("kind,width,rows,length,bodies", [
