@@ -25,6 +25,13 @@ def _qk(q_ref, k_ref, rows=None, keys=None):
         (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
+def _heads_a_step(q, k, v, block_q: int, block_k: int) -> int:
+    """`fold.heads_a_step` of a call's operands (B, T, H, D) at its tile."""
+    return fold.heads_a_step(
+        key_head_group(q, k, v), -(-k.shape[1] // block_k), block_q, block_k,
+        q.shape[-1], v.shape[-1], q.dtype.itemsize)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, **static):
     fold._flash_fold(functools.partial(_qk, q_ref, k_ref), v_ref, o_ref,
                      lse_ref, scratch, **static)
@@ -46,7 +53,14 @@ def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret,
     reshapes around the call move nothing. At any other width (64, 192, a
     test's 8) q, k and v are copied head-major to (B x H, T, D) first and
     the output is copied back. A block holds the same values in the same
-    order either way. `window`, `name`: `fold._flash_call`'s."""
+    order either way. `window`, `name`: `fold._flash_call`'s.
+
+    A grid step is a (query block, key block) pair of `heads` query heads
+    of ONE key/value head (`fold.heads_a_step`, from the shapes: 1 where a
+    row is one key block or heads share nothing): their q, output and lse
+    blocks are `heads` consecutive rows of the head-major arrays' axis 0,
+    or `heads` consecutive lane blocks in place, and the key and value
+    block is fetched once for them."""
     b, _, h, d = q.shape
     hk, dv = k.shape[2], v.shape[-1]
     group = key_head_group(q, k, v)
@@ -55,11 +69,17 @@ def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret,
     qf, tq = layout._pad_seq(layout._rows(q, in_place), block_q)
     kf, tk = layout._pad_seq(layout._rows(k, in_place), block_k)
     vf, _ = layout._pad_seq(layout._rows(v, in_place), block_k)
-    at, key_at = layout._block_at(in_place, h), layout._block_at(in_place, hk)
+    # a grid step folds `heads` query heads of one key/value head
+    heads = _heads_a_step(q, k, v, block_q, block_k)
+    at = layout._block_at(in_place, h // heads)
+    key_at = layout._block_at(in_place, hk)
 
-    # query head j reads key/value head j // group
+    # the `heads` query heads of step j read key/value head j // steps, of
+    # the `steps` a group takes (one: the step is the key head's own)
+    steps = group // heads
+
     def key_head_at(b_, j, i):
-        return key_at(b_, j if group == 1 else j // group, i)
+        return key_at(b_, j if steps == 1 else j // steps, i)
 
     out, lse = fold._flash_call(
         _flash_kernel, [(qf, d, at)], [(kf, d, key_head_at)],
@@ -67,7 +87,8 @@ def _flash_fwd_lse(q, k, v, causal, block_q, block_k, interpret,
         jax.ShapeDtypeStruct(qf.shape[:-1] + (qf.shape[-1] // d * dv,),
                              q.dtype),
         b=b, h=h, tk=tk, causal=causal, scale=d ** -0.5, block_q=block_q,
-        block_k=block_k, interpret=interpret, name=name, window=window)
+        block_k=block_k, interpret=interpret, name=name, window=window,
+        heads=heads, in_place=in_place)
     out = layout._heads(out[:, :tq], b, h, in_place)
     lse = lse.reshape(b, h, -1)[:, :, :tq]     # (B, H, Tq)
     return out, lse
@@ -197,7 +218,10 @@ def flash_attention(q, k, v, causal: bool = False,
     scan driven by the kernel's saved logsumexp. Same contract as
     `dense_attention`, grouped-query heads included (k and v with a
     divisor of q's heads): q, k (B, T, H, D), v (B, T, H, Dv) in, (B, T,
-    H, Dv) out. Heads whose D and Dv are multiples of 128 are read and
+    H, Dv) out; where a row has several key blocks a grid step folds
+    several query heads of a key/value head against the key block fetched
+    once (`fold.heads_a_step`; `mmlspark_tpu_flash_heads_a_step_total` says
+    how many). Heads whose D and Dv are multiples of 128 are read and
     written in place, as blocks of the (B, T, H x D) arrays around the
     call; any other width pays a head-major copy of q, k and v in and of
     the output back (`_flash_fwd_lse`; the registry's
@@ -219,6 +243,9 @@ def flash_attention(q, k, v, causal: bool = False,
             "key/value heads, by the heads a key/value head serves",
             labels=("group", "tile")).labels(
                 group=str(group), tile=f"{block_q}x{block_k}").inc()
+    fold._count_heads_a_step(
+        "gqa" if causal else "attn", group,
+        _heads_a_step(q, k, v, block_q, block_k))
     fold._count_operands(
         "flash", layout._lanes_whole(q.shape[-1], v.shape[-1]))
     return _flash_diff(q, k, v, causal, block_q, block_k,
@@ -317,6 +344,9 @@ def causal_attention(q, k, v, impl: str = "flash", window: int | None = None,
             fold._count_fold_rows("swa", block_q, steps)
             fold._count_grid_steps("swa", t, t, block_q, block_k, True,
                                    window)
+            fold._count_heads_a_step(
+                "swa", key_head_group(q, k, v),
+                _heads_a_step(q, k, v, block_q, block_k))
             fold._count_operands(
                 "swa", layout._lanes_whole(q.shape[-1], v.shape[-1]))
             return _banded_flash(

@@ -1,14 +1,15 @@
 """The fold every Pallas forward here takes, in ONE place: the tile rule
-(`flash_tiles`), the rules for the tiles a mask's edge crosses
+(`flash_tiles`), the rule for the query heads a grid step folds
+(`heads_a_step`), the rules for the tiles a mask's edge crosses
 (`_edge_parts`, `_row_parts`), the online-softmax step (`_fold_tile`), the
-kernel body of a grid step, one (query block, key block) pair of a row and
-head (`_flash_fold`), the list of the pairs that fold something
-(`_fold_steps`), the call whose grid walks it (`_flash_call`) and the
-registry's counters of which path a traced shape took. The plain, banded and
-latent cores (`flash.py`, `latent.py`) are `_flash_fold` with their own
-products; `eva.py`'s kernel takes `_fold_tile` directly. What each rule
-measured on a v5e is in PERF.md (section 6: PRs 27, 30, 31, 41, 43, 44,
-47)."""
+kernel body of a (query block, key block) pair of a row and head
+(`_flash_fold`), the list of the pairs that fold something (`_fold_steps`),
+the call whose grid walks it, a step a pair of one head or of several that
+share their keys (`_flash_call`), and the registry's counters of which path a
+traced shape took. The plain, banded and latent cores (`flash.py`,
+`latent.py`) are `_flash_fold` with their own products; `eva.py`'s kernel
+takes `_fold_tile` directly. What each rule measured on a v5e is in PERF.md
+(section 6: PRs 27, 30, 31, 41, 43, 44, 47, 48)."""
 
 from __future__ import annotations
 
@@ -107,6 +108,66 @@ def flash_tiles(tq: int, tk: int, dtype,
                    if padded(t, b) <= most)
 
     return tile(tq), tile(tk)
+
+
+# What a step of several heads may keep in VMEM by `_step_bytes`' count, and
+# what such a call STATES as its scoped VMEM: twice the 16 MB a custom call
+# gets without asking. A key head's whole group needs 16.5 MB (4 heads of 64)
+# to 27.5 MB (7 of 128) by the compiler's count, 2.6 MB more with a padded
+# tail, and 5 and 7 have no divisor between. What a call states beyond the
+# default is taken from the whole program (its neighbours keep fewer operands
+# in VMEM): read on a v5e in the three grouped cells, that costs 0.04 to
+# 0.2% of a call where the kernels return 0.7 to 3.0% (PERF.md, PR 48; a
+# call that took 64 MB had cost its neighbours 8%: PR 28). A constant, not a
+# parameter; a call of one head a step states nothing.
+_STEP_VMEM = 32 * 1024 * 1024
+
+
+def _step_bytes(heads: int, block_q: int, block_k: int, d: int, dv: int,
+                itemsize: int) -> int:
+    """VMEM a grid step of `_flash_call` holds at `heads` query heads a
+    step, counted as `parallel.moe._grouped_bytes` counts: every block in
+    two slots (the pipeline fetches the next step's while this one folds),
+    an array's last dimension padded to the 128 lanes it occupies ((block_q,
+    64) and lse's (block_q, 1) are stored 128 wide). A head: its q and
+    output blocks, its lse column, its running maximum, sum and accumulator
+    (`_fold_scratch`): 3.5 MB at tiles of 1024. The step, once: the key and
+    value blocks, and three float32 tiles of the `_row_parts` part in flight
+    (the scores, which the compiler spills, their exponentials, and a
+    mask's positions where a padded tail or an edge needs one): 7 MB. The
+    chip's compiler counts 5.95, 9.45 and 16.45 MB at 1, 2 and 4 heads of
+    64 and 6.45 and 27.45 at 1 and 7 of 128, 2.6 MB more where the keys'
+    tail is padded, and at float32 heads of 256 in tiles of 512 0.2 MB over
+    this count less a tile (compiled for a described v5e, PR 48): this
+    count is 1.5 to 4.5 MB over it, never under."""
+    def lanes(width):
+        return -(-width // _LANES) * _LANES
+
+    a_head = (2 * block_q * (lanes(d) + lanes(dv)) * itemsize
+              + 2 * block_q * _LANES * 4
+              + block_q * (2 * _LANES + lanes(dv)) * 4)
+    shared = (2 * block_k * (lanes(d) + lanes(dv)) * itemsize
+              + 3 * block_q // _row_parts(block_q) * block_k * 4)
+    return heads * a_head + shared
+
+
+def heads_a_step(group: int, key_blocks: int, block_q: int, block_k: int,
+                 d: int, dv: int, itemsize: int) -> int:
+    """How many query heads of a key/value head ONE grid step of the flash
+    forward folds, from what the call's shapes show: the largest divisor of
+    the `group` (query heads a key/value head) whose blocks and statistics
+    fit `_STEP_VMEM` (`_step_bytes`): the whole group in the cells that have
+    one (4 heads of 64, 5 and 7 of 128). The heads of a step share the fetch
+    of the key and value blocks, which a kernel that reads them in place, in
+    strided pieces, does not hide under its folds (6% of it), and the step's
+    own cost (PERF.md, PR 48). 1, the program of one head a step, where
+    heads share nothing (`group` 1) and where a row is ONE key block, which
+    carries nothing from step to step."""
+    if group == 1 or key_blocks == 1:
+        return 1
+    return max(g for g in range(1, group + 1) if group % g == 0 and (
+        g == 1 or _step_bytes(g, block_q, block_k, d, dv,
+                              itemsize) <= _STEP_VMEM))
 
 
 def _band_first(qi: int, block_q: int, block_k: int, window: int) -> int:
@@ -267,7 +328,7 @@ def _fold_tile(s, ok, v_ref, scratch, exponent, rows=None, keys=None):
 
 def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
                 block_k, num_kv, key_blocks, causal, tk_valid, scale,
-                window=None, walk=None):
+                window=None, walk=None, step=None):
     """What every flash forward does with a score tile, a grid step a
     (query block, key block) pair that folds something (`_fold_steps`):
     `products(rows, keys)` is this step's raw float32 products of queries
@@ -276,10 +337,11 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
     how many) of it; the masks, the online softmax and the finalisation
     are here. `walk` is the scalar-prefetch ref of the steps the grid's
     last axis takes (`_flash_call`): -1, the steps' query blocks, -1, their
-    key blocks; a step is its query block's first where the entry before
-    its own differs, and its last where the one after does. A call of ONE
-    key block has none: the grid's last two axes are the query block and
-    that block. `num_kv` is the most key blocks a query block reads. Told a
+    key blocks; `step` is this one's place in it (the grid's last axis); a
+    step is its query block's first where the entry before its own differs,
+    and its last where the one after does. A call of ONE key block has
+    none: the grid's last two axes are the query block and that block.
+    `num_kv` is the most key blocks a query block reads. Told a
     `window` (a causal band: a query reads the `window` keys that end with
     its own, of `key_blocks` blocks in all), the block that the band's
     trailing edge crosses is masked like the diagonal's.
@@ -296,7 +358,7 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
     if walk is None:
         qi, kv = pl.program_id(2), pl.program_id(3)
     else:
-        at, n = pl.program_id(2), walk.shape[0] // 2 - 1
+        at, n = step, walk.shape[0] // 2 - 1
         qi, kv = walk[1 + at], walk[n + 2 + at]
     # only a padded sequence needs the key mask: decided here, in Python
     padded = tk_valid < key_blocks * block_k
@@ -436,16 +498,52 @@ def _flash_fold(products, v_ref, o_ref, lse_ref, scratch, *, block_q,
         write(m_sc[:, :1], l_sc[...].sum(-1, keepdims=True), acc_sc[...])
 
 
-def _fold_scratch(block_q: int, dv: int, *key_widths: int) -> list:
+def _fold_scratch(block_q: int, dv: int, *key_widths: int,
+                  heads: int = 1) -> list:
     """`_fold_tile`'s scratch: the running maximum and sum, lane-dense, and
     the accumulator. As (block_q, 1) columns the first two were padded to
-    128 lanes already: the same VMEM."""
+    128 lanes already: the same VMEM. A step of several `heads`
+    (`heads_a_step`) holds a set a head, along a leading axis."""
     import jax.experimental.pallas.tpu as pltpu
 
     lanes = _stat_lanes(*key_widths)
-    return [pltpu.VMEM((block_q, lanes), jnp.float32),
-            pltpu.VMEM((block_q, lanes), jnp.float32),
-            pltpu.VMEM((block_q, dv), jnp.float32)]
+    lead = () if heads == 1 else (heads,)
+    return [pltpu.VMEM(lead + (block_q, lanes), jnp.float32),
+            pltpu.VMEM(lead + (block_q, lanes), jnp.float32),
+            pltpu.VMEM(lead + (block_q, dv), jnp.float32)]
+
+
+class _HeadOf:
+    """Head `head` of a grid step's block of several heads (`_flash_call`),
+    read and written as the bodies read and write one head's: the (1,
+    positions, width) block that is row `head` of the step's axis 0 or, told
+    `lanes`, those lanes of its last axis; not a `block`, a head's
+    statistics, (positions, width) at `head` of axis 0. Indexed THROUGH, no
+    view of the ref (`ref.at[...]`): Mosaic slices a memref in whole tiles,
+    and a head of 64 channels, or lse's one column, is less than a tile's
+    128 lanes."""
+
+    def __init__(self, ref, head, lanes=None, block=True):
+        self.ref, self.dtype = ref, ref.dtype
+        self.head, self.lanes, self.block = head, lanes, block
+
+    @property
+    def shape(self):
+        return self.ref.shape[1:]              # of a head's statistics
+
+    def _at(self, idx):
+        idx = () if idx is ... else idx if isinstance(idx, tuple) else (idx,)
+        # a block's own leading index, 0 of 1, becomes the head's
+        idx = (self.head, *idx[self.block:])
+        if self.lanes is not None:
+            idx = (idx[0], idx[1] if len(idx) > 1 else slice(None), self.lanes)
+        return idx
+
+    def __getitem__(self, idx):
+        return self.ref[self._at(idx)]
+
+    def __setitem__(self, idx, value):
+        self.ref[self._at(idx)] = value
 
 
 def _band_steps(tq: int, block_q: int, block_k: int, window: int) -> int:
@@ -507,9 +605,22 @@ def _count_grid_steps(kernel: str, tq: int, tk: int, block_q: int,
     counter.labels(kernel=kernel, kind="square").inc(nq * steps)
 
 
+def _count_heads_a_step(kernel: str, group: int, heads: int) -> None:
+    """Counted where a flash forward is traced, beside its grid steps: how
+    many query heads ONE grid step folds (`heads_a_step`)."""
+    get_registry().counter(
+        "mmlspark_tpu_flash_heads_a_step_total",
+        "flash-attention forward calls traced, by the kernel (gqa, attn, "
+        "swa, mla), by the query heads a key/value head serves and by the "
+        "heads of it ONE grid step folds against a key block fetched once "
+        "(1: a head a step)",
+        labels=("kernel", "group", "heads")).labels(
+            kernel=kernel, group=str(group), heads=str(heads)).inc()
+
+
 def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
                 tk, causal, scale, block_q, block_k, interpret, name=None,
-                window=None):
+                window=None, heads=1, in_place=True):
     """ONE Pallas forward over a grid of (row, head, step), a step a
     (query block, key block) pair that folds something (`_fold_steps`).
     `queries`, `keys` and `value` are (array, block width, at): `at(row,
@@ -526,7 +637,16 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
     behind a band is no step at all, and a query block's last step, the
     one under which the next block's copies are issued, is one that
     computes. A call of ONE key block holds no list: its grid is (row,
-    head, query block, 1), with no operand added."""
+    head, query block, 1), with no operand added.
+
+    `heads` (`heads_a_step`: query heads that share ONE key/value head) makes
+    the grid (row, h / heads, step): a step folds its pair for each of
+    `heads` consecutive query heads, one after the other, against the key
+    and value blocks fetched ONCE. `at` and `out_at` are then told the
+    step's place among the h / heads and name the block of ALL its heads:
+    `heads` rows of axis 0 where the array is head-major, `heads` lane
+    blocks of the last (`in_place`). A head's own q, output, lse and
+    statistics are views of the step's; the body is the one head's."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
     import numpy as np
@@ -549,7 +669,7 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
         # no longer wrote the projections in the call's layout and copied
         # q_nope and kv before every call (PERF.md, PR 47)
         walk = (np.concatenate([[-1], qs, [-1], ks]).astype(np.int32),)
-        grid = (b, h, n)
+        grid = (b, h // heads, n)
 
         def query_block(at, walk):
             return walk[1 + at]
@@ -557,8 +677,11 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
         def key_block(at, walk):
             return walk[n + 2 + at]
 
+        def fold_head(step, walk, *refs):
+            kernel(*refs, walk=walk, step=step, **static)
+
         def body(walk, *refs):
-            kernel(*refs, walk=walk, **static)
+            fold_head(pl.program_id(2), walk, *refs)
     else:
         walk, grid = (), (b, h, nq, 1)
         body = functools.partial(kernel, **static)
@@ -569,9 +692,45 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
         def key_block(_qi, kv):
             return kv
 
-    def query_spec(width, at):
+    if heads > 1:
+        widths = [w for _x, w, _at in queries]
+
+        def block_of(a, ref, width):
+            """Head `a`'s (1, positions, width) block of the step's."""
+            if in_place:
+                return _HeadOf(ref, 0, pl.ds(pl.multiple_of(a * width, width),
+                                             width))
+            return _HeadOf(ref, a)
+
+        def body(walk, *refs):
+            # ONE traced body under a loop over the step's heads: written
+            # out a head it schedules the same bundles a head, costs a
+            # start 3 to 9 times the compile (5.8 to 17 s a kernel) and ran
+            # the banded kernel of 7 heads a step 50% SLOWER (PERF.md, PR
+            # 48). The step is read here: interpreted, a loop's body knows
+            # no grid
+            step = pl.program_id(2)
+            qs, rest = refs[:len(queries)], refs[len(queries):]
+            shared, (o, lse, *scratch) = rest[:len(keys)], rest[len(keys):]
+
+            def one_head(a, carry):
+                fold_head(
+                    step, walk,
+                    *map(functools.partial(block_of, a), qs, widths),
+                    *shared, block_of(a, o, dv), _HeadOf(lse, a),
+                    *(_HeadOf(ref, a, block=False) for ref in scratch))
+                return carry
+
+            jax.lax.fori_loop(0, heads, one_head, 0)
+
+    stated = {} if heads == 1 else dict(
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_STEP_VMEM))
+    # a step's block of `heads` heads: rows of axis 0, or lane blocks
+    lead, wide = (1, heads) if in_place else (heads, 1)
+
+    def query_spec(width, at, lead=lead, wide=wide):
         return pl.BlockSpec(
-            (1, block_q, width),
+            (lead, block_q, wide * width),
             lambda b_, j, *step: at(b_, j, query_block(*step)))
 
     def key_spec(width, at):
@@ -580,7 +739,7 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
             lambda b_, j, *step: at(b_, j, key_block(*step)))
 
     def lse_at(b_, j, qi):
-        return b_ * h + j, qi, 0
+        return b_ * (h // heads) + j, qi, 0
 
     return pl.pallas_call(
         body,
@@ -594,14 +753,14 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
                 # finalisation's sum across lanes leaves it: a trailing dim
                 # equal to the array's satisfies Mosaic's block rule, and
                 # no sublane->lane relayout happens in the kernel
-                query_spec(1, lse_at),
+                query_spec(1, lse_at, heads, 1),
             ],
             # one key block carries nothing from step to step
             scratch_shapes=[] if steps == 1 else _fold_scratch(
-                block_q, dv, block_k)),
+                block_q, dv, block_k, heads=heads)),
         out_shape=[
             out_shape,
             jax.ShapeDtypeStruct((b * h, nq * block_q, 1), jnp.float32),
         ],
-        interpret=interpret, name=name,
+        interpret=interpret, name=name, **stated,
     )(*walk, *(x for x, _w, _at in [*queries, *keys]))

@@ -153,6 +153,7 @@ def latent_attention(q_nope, q_rope, kv, k_rope, impl: str = "flash",
     # under the plain forward's counter too: its fold, at its tile
     block_q, block_k = flash._call_tiles(t, t, q_nope.dtype, block_q,
                                          block_k, True, "mla")
+    fold._count_heads_a_step("mla", 1, 1)     # each head its own keys
     fold._count_operands("mla", True)
     return _latent_diff(q_nope, q_rope, kv, k_rope, block_q, block_k,
                         interpret)

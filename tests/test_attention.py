@@ -414,11 +414,108 @@ class TestGroupedQueryHeads:
         # what an index map computes: in place nothing (it names its grid
         # indices, or reads the step's block from the walk: an add and a
         # load), head-major the row b x H + j; grouped heads add the one
-        # division
+        # division where a grid step is ONE query head (one key block), and
+        # nothing where it is the key head's whole group (`heads_a_step`)
         maps = eqn.params["grid_mapping"].block_mappings
         computed = [len(m.index_map_jaxpr.jaxpr.eqns) for m in maps[:3]]
         row = (0 if d == 128 else 2) + 2 * walked
-        assert computed == [row, row + (group > 1), row + (group > 1)]
+        divided = group > 1 and not walked
+        assert computed == [row, row + divided, row + divided]
+        # the step's blocks: a group's query heads, ONE key/value head
+        heads = group if walked else 1
+        assert eqn.params["grid_mapping"].grid[1] == 8 // heads
+        assert [tuple(x.block_size for x in m.block_shape)
+                for m in maps[:3]] == [
+            (heads, tile, 64) if d == 64 else (1, tile, heads * 128),
+            (1, tile, d), (1, tile, d)]
+
+    @pytest.mark.parametrize("window", [None, 24])
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("group", [2, 4])
+    def test_a_groups_heads_in_one_step_equal_a_head_a_step_bit_for_bit(
+            self, monkeypatch, group, d, window):
+        """Several key blocks (40 tokens in tiles of 16: two whole tiles
+        and a padded third), plain causal and banded, head-major (64) and
+        in place (128): a step that folds the group's heads one after the
+        other against the key block fetched once gives every head the folds
+        it had, in their order."""
+        q, k, v = _grouped_qkv(2, 40, 8, group, d, seed=group + d,
+                               dtype=jnp.bfloat16)
+        call = lambda: np.asarray(attention.causal_attention(  # noqa: E731
+            q, k, v, "flash", window=window, block_q=16, block_k=16,
+            interpret=True).astype(jnp.float32))
+        steps = []
+        real = attention.fold._flash_call
+        monkeypatch.setattr(
+            attention.fold, "_flash_call",
+            lambda *a, **kw: steps.append(kw["heads"]) or real(*a, **kw))
+        grouped = call()
+        monkeypatch.setattr(attention.fold, "heads_a_step",
+                            lambda *a, **kw: 1)
+        jax.clear_caches()
+        assert np.array_equal(grouped, call())
+        assert steps == [group, 1]
+
+    @pytest.mark.parametrize("group,blocks,d,heads", [
+        # LFM2's long rows: 32 over 8 heads of 64, 16 key blocks; rows of
+        # 2048 in tiles of 1024
+        (4, 16, 64, 4), (4, 2, 64, 4),
+        # SmallThinker's 28 over 4 and Falcon-H1's 20 over 4, of 128: the
+        # whole group (7 and 5 have no divisor between), inside the 32 MB
+        # the call states
+        (7, 16, 128, 7), (5, 32, 128, 5),
+        # a group of 16 passes it whole: its largest divisor that fits
+        (16, 16, 128, 4), (8, 16, 64, 4), (6, 16, 128, 6),
+        # Ouro's 16 over 16: nothing to share
+        (1, 8, 128, 1),
+        # ONE key block carries nothing from step to step: the program of
+        # a head a step, whatever the group
+        (4, 1, 64, 1), (7, 1, 128, 1)])
+    def test_the_rule_of_heads_a_step_at_the_cells_shapes(
+            self, group, blocks, d, heads):
+        assert attention.fold.heads_a_step(
+            group, blocks, 1024, 1024, d, d, 2) == heads
+
+    def test_a_step_of_several_heads_is_counted_inside_the_stated_vmem(self):
+        """The count a head (q, output, lse and the statistics, lane
+        padded, two slots a block) and a step's own, at tiles of 1024: 3.5
+        MB a head beside 7 MB, never under what the chip's compiler counts
+        (`tests/test_chipless_compile.py` holds the limit at the cells'
+        shapes and with a padded tail)."""
+        count = attention.fold._step_bytes
+        mb = 2 ** 20
+        assert count(1, 1024, 1024, 64, 64, 2) == 10.5 * mb
+        assert count(4, 1024, 1024, 64, 64, 2) == 21 * mb
+        assert count(7, 1024, 1024, 128, 128, 2) == 31.5 * mb
+        assert count(8, 1024, 1024, 128, 128, 2) > attention.fold._STEP_VMEM
+        # small tiles (a test's): every divisor fits
+        assert attention.fold.heads_a_step(4, 3, 16, 16, 64, 64, 4) == 4
+
+    def test_heads_a_step_are_counted_by_kernel_group_and_width(self):
+        def counted(kernel, group, heads):
+            return get_registry().counter(
+                "mmlspark_tpu_flash_heads_a_step_total",
+                labels=("kernel", "group", "heads")).labels(
+                    kernel=kernel, group=str(group), heads=str(heads)).value
+
+        before = (counted("gqa", 4, 4), counted("gqa", 4, 1),
+                  counted("swa", 7, 7), counted("attn", 1, 1))
+        q = jax.ShapeDtypeStruct((1, 2048, 8, 64), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((1, 2048, 2, 64), jnp.bfloat16)
+        jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                       q, kv, kv)
+        # one key block: a head a step
+        jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                       *(jax.ShapeDtypeStruct((1, 1024) + x.shape[2:],
+                                              x.dtype) for x in (q, kv, kv)))
+        wide = jax.ShapeDtypeStruct((1, 8192, 7, 128), jnp.bfloat16)
+        one = jax.ShapeDtypeStruct((1, 8192, 1, 128), jnp.bfloat16)
+        jax.eval_shape(lambda q, k, v: attention.causal_attention(
+            q, k, v, "flash", window=4096), wide, one, one)
+        jax.eval_shape(flash_attention, one, one, one)
+        assert (counted("gqa", 4, 4), counted("gqa", 4, 1),
+                counted("swa", 7, 7), counted("attn", 1, 1)) == tuple(
+                    n + 1 for n in before)
 
     def test_grouped_gradients_match_dense(self):
         q, k, v = _grouped_qkv(1, 24, 4, 2, 8, seed=3)

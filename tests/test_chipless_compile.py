@@ -212,7 +212,7 @@ def test_plain_causal_fold_compiles_with_lane_dense_statistics(
     scoped VMEM (the chip's compiler refuses a kernel past it). The
     banded, latent and windowed-and-summarised folds share the step and
     are compiled above at their cells' shapes."""
-    from mmlspark_tpu.nn.attention import flash_attention
+    from mmlspark_tpu.nn.attention import flash, flash_attention
 
     bf = jnp.bfloat16
     q = jax.ShapeDtypeStruct((rows, length, heads, width), bf,
@@ -223,11 +223,102 @@ def test_plain_causal_fold_compiles_with_lane_dense_statistics(
     def attend(q, k, v):
         return flash_attention(q, k, v, causal=True)
 
+    # a step of several query heads holds a set of statistics a head
+    a_step = flash._heads_a_step(q, k, k, 1024, 1024)
+    assert a_step == heads // key_heads
+    stat = "1024,128" if a_step == 1 else f"{a_step},1024,128"
     kernel = str(jax.make_jaxpr(attend)(q, k, k))
-    assert kernel.count("Ref<vmem>{f32[1024,128]}") >= (
+    assert kernel.count(f"Ref<vmem>{{f32[{stat}]}}") >= (
         3 if width == 128 else 2)
     assert "Ref<vmem>{f32[1024,1]}" not in kernel
     assert "tpu_custom_call" in _compile(attend, q, k, k).as_text()
+
+
+def test_a_groups_query_heads_lower_in_one_grid_step_inside_the_scoped_vmem(
+        one_chip):
+    """`lfm2_8b_a1b.score_long_docs`' long batch (2 x 16384, 32 query heads
+    over 8 key/value heads of 64, head-major) at the rule's heads a step,
+    a key head's whole group of 4: the grid is (row, 8, the 136 steps that
+    fold), a step's q, output and lse blocks are 4 rows of axis 0, its key
+    and value blocks ONE head's, and the chip's compiler takes the kernel
+    inside the scoped VMEM the call states (`fold._STEP_VMEM`, 32 MB: a
+    whole group passes the 16 MB a call gets without asking by 0.45 MB).
+    The counter says what the rule chose."""
+    from mmlspark_tpu.nn import attention
+    from mmlspark_tpu.observability.metrics import get_registry
+
+    bf = jnp.bfloat16
+    q = jax.ShapeDtypeStruct((2, 16384, 32, 64), bf, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((2, 16384, 8, 64), bf, sharding=one_chip)
+
+    def attend(q, k, v):
+        return attention.flash_attention(q, k, v, causal=True)
+
+    counter = get_registry().counter(
+        "mmlspark_tpu_flash_heads_a_step_total",
+        labels=("kernel", "group", "heads")).labels(
+            kernel="gqa", group="4", heads="4")
+    before = counter.value
+    (call,) = [e for e in _deep_equations(jax.make_jaxpr(attend)(q, k, k))
+               if e.primitive.name == "pallas_call"]
+    assert counter.value == before + 1
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == (2, 8, 136)
+    assert [tuple(x.block_size for x in m.block_shape)
+            for m in mapping.block_mappings] == [
+        (4, 1024, 64), (1, 1024, 64), (1, 1024, 64), (4, 1024, 64),
+        (4, 1024, 1)]
+    stated = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    assert stated == attention.fold._STEP_VMEM == 32 << 20
+    assert attention.fold._step_bytes(4, 1024, 1024, 64, 64, 2) <= stated
+    assert "tpu_custom_call" in _compile(attend, q, k, k).as_text()
+
+
+@pytest.mark.parametrize("heads,key_heads,width,dtype,length,window,a_step", [
+    # a padded tail (3000 in tiles of 1024: the key mask's tile on top) at
+    # the three grouped cells' heads, and the band
+    (32, 8, 64, jnp.bfloat16, 3000, None, 4),
+    (28, 4, 128, jnp.bfloat16, 3000, None, 7),
+    (20, 4, 128, jnp.bfloat16, 3000, None, 5),
+    (28, 4, 128, jnp.bfloat16, 9000, 1024, 7),
+    # float32 heads of 256 in tiles of 512: a whole group of 8 was refused
+    # by 0.2 MB at a count of two tiles a step; three hold it to 4
+    (16, 2, 256, jnp.float32, 3000, None, 4),
+    # a group of 16: its largest divisor that fits
+    (16, 1, 128, jnp.bfloat16, 5000, None, 4)])
+def test_the_rule_of_heads_a_step_stays_inside_the_vmem_the_call_states(
+        one_chip, heads, key_heads, width, dtype, length, window, a_step):
+    """`fold.heads_a_step` is a count made here, not the compiler's: where
+    it errs it must err towards fewer heads, because a kernel past the
+    scoped VMEM its call states is refused at a start. The shapes where the
+    count came closest, compiled for the chip."""
+    from mmlspark_tpu.nn import attention
+
+    q = jax.ShapeDtypeStruct((1, length, heads, width), dtype,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, length, key_heads, width), dtype,
+                             sharding=one_chip)
+    tile = (attention.flash.band_tiles(length, window, dtype) if window
+            else attention.flash_tiles(length, length, dtype))
+    assert attention.flash._heads_a_step(q, k, k, *tile) == a_step
+    compiled = _compile(lambda q, k, v: attention.causal_attention(
+        q, k, v, "flash", window=window), q, k, k)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _deep_equations(closed):
+    """Every equation under a traced program, however deep."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (tuple, list))
+                            else (value,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from walk(inner)
+
+    return list(walk(closed.jaxpr))
 
 
 @pytest.mark.parametrize("tokens", [2 * 16384, 2 * 2048])
@@ -311,6 +402,12 @@ LAYERS = {
     "global_2x16384": ("global", 2560, (2, 16384)),
     "sliding_2x16384_two_layers": ("sliding", 2560, (2, 16384), 2),
     "global_2x16384_two_layers": ("global", 2560, (2, 16384), 2),
+    # `lfm2_8b_a1b.score_long_docs`: 32 query heads over 8 key/value heads
+    # of 64 on a hidden width of 2048, head-major, a grid step two query
+    # heads of a group (PR 48); two layers, for what an operand's new shape
+    # can move from the second layer on (PR 47)
+    "lfm2_2x16384": ("lfm2", 2048, (2, 16384)),
+    "lfm2_2x16384_two_layers": ("lfm2", 2048, (2, 16384), 2),
 }
 
 # what is left, by the traces of PR 35 (PERF.md section 5): at ONE row of
@@ -318,6 +415,11 @@ LAYERS = {
 # two dimensions and copies the kernel's output heads-in-sublanes first,
 # 0.1 ms a layer (0.03% of EvaByte's call); at two rows it does not
 KNOWN_MOVES = {"eva_1x4096": [("copy", "bf16[512,8,32,128]")]}
+# heads of 64 are no whole lane blocks: q is copied head-major in and the
+# output back, a layer (ROADMAP D17); the parent's program holds the same
+_HEAD_MAJOR = [("copy", "bf16[2,32,16384,64]")] * 2
+KNOWN_MOVES["lfm2_2x16384"] = _HEAD_MAJOR
+KNOWN_MOVES["lfm2_2x16384_two_layers"] = 2 * _HEAD_MAJOR
 
 
 def _attention_layer(kind: str, i: int = 0):
@@ -503,9 +605,13 @@ def test_a_kernel_is_lowered_once_a_shape_not_once_a_layer(
 # latent forward are jitted by themselves, lowered once a SHAPE: 69 and
 # 61 more, once
 FOLDS = {
-    "global_2x16384": ((2, 16384, 28, 4, 128, None), 147, 207),
-    "short_rows_2x2048": ((2, 2048, 28, 4, 128, None), 147, 207),
-    "lfm2_2x16384": ((2, 16384, 32, 8, 64, None), 147, 208),
+    # a grid step a key head's whole group of query heads (PR 48): ONE
+    # traced body under a loop over the step's heads, three to six
+    # equations (the step read once, the loop, a head's place in the
+    # step's blocks); written out a head it was 825 and 1,415
+    "global_2x16384": ((2, 16384, 28, 4, 128, None), 147, 211),
+    "short_rows_2x2048": ((2, 2048, 28, 4, 128, None), 147, 211),
+    "lfm2_2x16384": ((2, 16384, 32, 8, 64, None), 147, 211),
     "banded_2x16384": ((2, 16384, 28, 4, 128, 4096), 256, 330),
     # one tile a row: the parent's kernel and the log-sum-exp's scale
     "lfm2_2x1024": ((2, 1024, 32, 8, 64, None), 50, 50),
