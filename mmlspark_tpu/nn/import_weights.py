@@ -90,6 +90,15 @@ dt] as they lie, `conv1d.weight` (channels, 1, taps) and `conv1d.bias`,
 `ssm_<i>` and `gqa_attn_<i>`. The family's fixed multipliers are the
 module's configuration, not weights (`mup_vector` is dropped). Nothing is
 permuted.
+
+`decoder_hybrid_decoder_spec` maps a `phi4flash`-style naming onto
+nn.models.DecoderHybridDecoder. The checkpoint's own tensor names could not
+be re-read when this was written (no network): the names EXPECTED are listed
+in `torch_decoder_hybrid_decoder_to_flax` as assumed, every layer's operator
+under `attn.` whatever its kind, and what a name means follows from the
+layer's index (`models.hybrid_layer_kinds`). A fused `Wqkv` is split into q,
+k and v and the feed-forward's `fc1` into gate and up. No published file has
+been loaded through it.
 """
 
 from __future__ import annotations
@@ -124,6 +133,9 @@ __all__ = [
     "SSM_HYBRID_DECODER_SPEC",
     "torch_ssm_hybrid_decoder_to_flax",
     "import_torch_ssm_hybrid_decoder",
+    "decoder_hybrid_decoder_spec",
+    "torch_decoder_hybrid_decoder_to_flax",
+    "import_torch_decoder_hybrid_decoder",
     "import_external_weights",
     "IMPORTERS",
 ]
@@ -1017,6 +1029,150 @@ def import_torch_ssm_hybrid_decoder(
     return _validate_and_install(bundle, variables, architecture)
 
 
+# --------------------------------------------------------------------- #
+# phi4flash-style naming -> nn.models.DecoderHybridDecoder               #
+# --------------------------------------------------------------------- #
+
+def decoder_hybrid_decoder_spec(num_layers: int) -> "list[MapRule]":
+    """The rules for a model of `num_layers` layers, after the fused tensors
+    are split (`torch_decoder_hybrid_decoder_to_flax`): a layer's operator
+    lies under `attn.` whatever it is, so the target follows from the
+    layer's kind by its index."""
+    from .models import hybrid_layer_kinds
+
+    kinds = hybrid_layer_kinds(num_layers)
+
+    def under(rest: str):
+        def target(m):
+            i = int(m["i"])
+            kind = kinds[i]
+            if kind.startswith("mamba"):
+                home = "mamba"
+            elif kind == "gmu":
+                home = "gmu"
+            else:
+                home = "diff_swa" if kind == "sliding" else "diff_attn"
+            return f"params/{home}_{i}/" + m.expand(rest)
+        return target
+
+    attn = _LAYER + r"attn\."
+    return [
+        MapRule(r"model\.embed_tokens\.weight", "params/embed/embedding"),
+        MapRule(_LAYER + r"input_layernorm\.weight",
+                r"params/ln_op_\g<i>/scale"),
+        MapRule(_LAYER + r"input_layernorm\.bias",
+                r"params/ln_op_\g<i>/bias"),
+        MapRule(_LAYER + r"post_attention_layernorm\.weight",
+                r"params/ln_mlp_\g<i>/scale"),
+        MapRule(_LAYER + r"post_attention_layernorm\.bias",
+                r"params/ln_mlp_\g<i>/bias"),
+        MapRule(_LAYER + r"mlp\." + _FFN,
+                r"params/mlp_\g<i>/\g<proj>/kernel", _t_transpose),
+        # a Mamba mixer's and a gated memory unit's projections
+        MapRule(attn + r"(?P<p>in|x|out)_proj\.weight",
+                under(r"\g<p>_proj/kernel"), _t_transpose),
+        MapRule(attn + r"conv1d\.weight", under("conv_kernel"), _t_taps),
+        MapRule(attn + r"conv1d\.bias", under("conv_bias")),
+        MapRule(attn + r"dt_proj\.weight", under("dt_kernel"), _t_transpose),
+        MapRule(attn + r"dt_proj\.bias", under("dt_bias")),
+        MapRule(attn + r"(?P<v>A_log|D)", under(r"\g<v>")),
+        # differential attention, Wqkv split into its parts
+        MapRule(attn + r"(?P<p>[qkv])_proj\.weight",
+                under(r"\g<p>_proj/kernel"), _t_transpose),
+        MapRule(attn + r"(?P<p>[qkv])_proj\.bias",
+                under(r"\g<p>_proj/bias")),
+        MapRule(attn + r"o_proj\.weight", under("out/kernel"), _t_transpose),
+        MapRule(attn + r"o_proj\.bias", under("out/bias")),
+        MapRule(attn + r"inner_cross_attn\.(?P<v>lambda_[qk][12])",
+                under(r"\g<v>")),
+        MapRule(attn + r"inner_cross_attn\.subln\.weight",
+                under("norm_scale")),
+        MapRule(r"model\.final_layernorm\.weight", "params/ln_final/scale"),
+        MapRule(r"model\.final_layernorm\.bias", "params/ln_final/bias"),
+        # tied: a checkpoint that writes the head writes the embedding
+        MapRule(r"lm_head\.weight", None),
+    ]
+
+
+def torch_decoder_hybrid_decoder_to_flax(
+    state_dict: Mapping[str, np.ndarray], num_layers: int, d_model: int,
+) -> dict[str, Any]:
+    """Map a `phi4flash`-style state dict onto
+    nn.models.DecoderHybridDecoder variables. The names it EXPECTS are
+    ASSUMED (from the published modeling file as remembered, not re-read: no
+    network), under `model.layers.<i>.`:
+
+    - `input_layernorm`, `post_attention_layernorm` (`weight`, `bias`);
+      `mlp.fc1.weight` (2 x ff, d), its FIRST half the gate, and
+      `mlp.fc2.weight` (d, ff);
+    - a Mamba layer: `attn.in_proj.weight` (2 x inner, d: [x | z]),
+      `attn.conv1d.weight` (inner, 1, taps) and `.bias`, `attn.x_proj.weight`
+      (rank + 2 x state, inner: [dt | B | C]), `attn.dt_proj.weight` (inner,
+      rank) and `.bias`, `attn.A_log`, `attn.D`, `attn.out_proj.weight`;
+    - a gated memory unit: `attn.in_proj.weight` (inner, d),
+      `attn.out_proj.weight` (d, inner);
+    - differential attention: `attn.Wqkv.weight` (d + 2 x kv, d: [q | k |
+      v]) and `.bias` (a cross layer's holds the queries alone: (d, d)),
+      `attn.out_proj.weight` and `.bias`,
+      `attn.inner_cross_attn.lambda_q1` .. `lambda_k2`,
+      `attn.inner_cross_attn.subln.weight`;
+
+    and `model.embed_tokens.weight`, `model.final_layernorm` (`weight`,
+    `bias`), `lm_head.weight` (tied: dropped). The fused tensors are split
+    here, then `decoder_hybrid_decoder_spec` places every name; a name no
+    rule places is an error that names it."""
+    from .models import hybrid_layer_kinds
+
+    kinds = hybrid_layer_kinds(num_layers)
+    layer = re.compile(_LAYER + r"(?P<rest>.+)")
+    split: dict[str, np.ndarray] = {}
+    for name, value in state_dict.items():
+        m = layer.fullmatch(name)
+        rest = m["rest"] if m else ""
+        attends = bool(m) and kinds[int(m["i"])] in (
+            "sliding", "full_keeps", "cross")
+        stem = name[:len(name) - len(rest)]
+        if rest in ("attn.Wqkv.weight", "attn.Wqkv.bias"):
+            kind = rest.rsplit(".", 1)[1]
+            kv = (value.shape[0] - d_model) // 2
+            for part, lo, hi in (("q", 0, d_model),
+                                 ("k", d_model, d_model + kv),
+                                 ("v", d_model + kv, d_model + 2 * kv)):
+                if hi > lo:
+                    split[f"{stem}attn.{part}_proj.{kind}"] = value[lo:hi]
+        elif attends and rest.startswith("attn.out_proj."):
+            split[f"{stem}attn.o_proj.{rest.rsplit('.', 1)[1]}"] = value
+        elif rest == "mlp.fc1.weight":
+            half = value.shape[0] // 2
+            split[f"{stem}mlp.gate_proj.weight"] = value[:half]
+            split[f"{stem}mlp.up_proj.weight"] = value[half:]
+        elif rest == "mlp.fc2.weight":
+            split[f"{stem}mlp.down_proj.weight"] = value
+        else:
+            split[name] = value
+    return apply_mapping_spec(split, decoder_hybrid_decoder_spec(num_layers))
+
+
+def import_torch_decoder_hybrid_decoder(
+    path: str, architecture: str = "decoder_hybrid_decoder",
+    input_shape: tuple[int, ...] = (8,), **config,
+):
+    """Load a `phi4flash`-style checkpoint into a ready-to-serve ModelBundle
+    of the `decoder_hybrid_decoder` family. `config` is the module's
+    (`num_layers`, the widths, ...). The names expected are assumed
+    (`torch_decoder_hybrid_decoder_to_flax`); no published file has been
+    loaded through this."""
+    from .models import ModelBundle
+
+    sd = load_state_dict(path)
+    bundle = ModelBundle.init(architecture, input_shape=tuple(input_shape),
+                              seed=0, **config)
+    module = bundle.module
+    variables = torch_decoder_hybrid_decoder_to_flax(
+        sd, module.num_layers, module.d_model)
+    return _validate_and_install(bundle, variables, architecture)
+
+
 # architecture name -> importer; zoo.import_external dispatches here, so
 # registering a new family makes it fetchable/verifiable end to end
 IMPORTERS: "dict[str, Callable]" = {
@@ -1030,6 +1186,7 @@ IMPORTERS: "dict[str, Callable]" = {
     "window_moe_decoder": import_torch_window_moe_decoder,
     "looped_decoder": import_torch_looped_decoder,
     "ssm_hybrid_decoder": import_torch_ssm_hybrid_decoder,
+    "decoder_hybrid_decoder": import_torch_decoder_hybrid_decoder,
 }
 
 

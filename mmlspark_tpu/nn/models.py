@@ -41,9 +41,14 @@ __all__ = [
     "ShortConv",
     "StateSpaceMixer",
     "SSMHybridDecoder",
+    "SelectiveMixer",
+    "GatedMemoryUnit",
+    "DifferentialAttention",
+    "DecoderHybridDecoder",
     "ExpertLayer",
     "GatedFFN",
     "RMSNorm",
+    "LayerNorm",
     "resnet20_cifar",
     "resnet50",
     "ARCHITECTURES",
@@ -282,6 +287,26 @@ class RMSNorm(nn.Module):
         y = x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True)
                                 + self.eps)
         return (y * scale.astype(jnp.float32)).astype(self.dtype)
+
+
+class LayerNorm(nn.Module):
+    """(x - mean(x)) / sqrt(var(x) + eps) * scale + bias: the statistics and
+    the products in float32 (`RMSNorm`'s signature, a family's other kind
+    of norm)."""
+
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale, bias = (self.param(name, init, (x.shape[-1],), jnp.float32)
+                       for name, init in (("scale", nn.initializers.ones),
+                                          ("bias", nn.initializers.zeros)))
+        x32 = x.astype(jnp.float32)
+        centred = x32 - x32.mean(-1, keepdims=True)
+        y = centred * jax.lax.rsqrt(
+            (centred * centred).mean(-1, keepdims=True) + self.eps)
+        return (y * scale + bias).astype(self.dtype)
 
 
 class LatentAttention(nn.Module):
@@ -594,6 +619,147 @@ class StateSpaceMixer(nn.Module):
                             name="out_proj")(normed)
 
 
+class SelectiveMixer(nn.Module):
+    """The Mamba-1 mixer (arXiv 2312.00752): [x | z] = y W_in, `inner`
+    channels each, no bias; x through a depthwise causal convolution of
+    `conv_taps` taps with a bias and a SiLU, zero before a row's first
+    token; [r | B | C] = x W_x, `dt_rank` + 2 x `d_state` channels, no
+    bias; dt = softplus(r W_dt + dt_bias) a CHANNEL, float32; A =
+    -exp(A_log), (inner, d_state); the selective scan whose decay is a
+    (channel, state) pair (`nn/scan.py`'s second form: S_t[c, n] =
+    exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] B_t[n] x_t[c], y_t[c] =
+    sum_n C_t[n] S_t[c, n] + D[c] x_t[c], B and C shared by all channels,
+    no state across rows); (y * silu(z)) W_out, no bias. -> (the output,
+    y): the scan's output with the skip, BEFORE the gate and the output
+    projection, is what a layer may keep for a later layer's
+    `GatedMemoryUnit`. `scan_name` is the kernel's own in a device trace."""
+
+    inner: int
+    dt_rank: int
+    d_state: int = 16
+    conv_taps: int = 4
+    dtype: Any = jnp.float32
+    scan_name: str = "sel_scan"
+
+    @nn.compact
+    def __call__(self, y):
+        f32, dt_, d = jnp.float32, self.dtype, y.shape[-1]
+        inner, rank, state = self.inner, self.dt_rank, self.d_state
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=dt_)
+        with jax.named_scope("selscan.project"):
+            p = dense(2 * inner, name="in_proj")(y)
+        taps = self.param("conv_kernel",
+                          nn.initializers.normal(self.conv_taps ** -0.5),
+                          (inner, self.conv_taps), f32)
+        conv_bias = self.param("conv_bias", nn.initializers.zeros, (inner,),
+                               f32)
+        dt_kernel = self.param("dt_kernel",
+                               nn.initializers.normal(rank ** -0.5),
+                               (rank, inner), f32)
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (inner,), f32)
+        a_log = self.param("A_log", nn.initializers.zeros, (inner, state),
+                           f32)
+        skip = self.param("D", nn.initializers.ones, (inner,), f32)
+        with jax.named_scope("selscan.conv"):
+            x = nn.silu(_causal_taps(p[..., :inner], taps)
+                        + conv_bias).astype(dt_)
+        with jax.named_scope("selscan.project"):
+            rbc = dense(rank + 2 * state, name="x_proj")(x)
+            step = jax.nn.softplus(jnp.dot(
+                rbc[..., :rank], dt_kernel.astype(dt_),
+                preferred_element_type=f32) + dt_bias)
+        with jax.named_scope("selscan.scan"):
+            scanned = scan.channel_scan(
+                x, step, -jnp.exp(a_log), rbc[..., rank:rank + state],
+                rbc[..., rank + state:], skip, name=self.scan_name)
+        with jax.named_scope("selscan.gate"):
+            gated = (scanned.astype(f32)
+                     * nn.silu(p[..., inner:].astype(f32))).astype(dt_)
+        with jax.named_scope("selscan.project"):
+            return dense(d, name="out_proj")(gated), scanned
+
+
+class GatedMemoryUnit(nn.Module):
+    """(silu(y W_1) * memory) W_2, no bias: a projection of the layer's
+    input gates ANOTHER layer's array at the same row and position
+    (`memory`, (B, T, inner): the scan output a `SelectiveMixer` kept; the
+    gated memory unit of arXiv 2507.06607). The gate is float32, rounded
+    once."""
+
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y, memory):
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        gate = dense(memory.shape[-1], name="in_proj")(y)
+        with jax.named_scope("gmu.gate"):
+            gated = (nn.silu(gate.astype(jnp.float32))
+                     * memory.astype(jnp.float32)).astype(self.dtype)
+        return dense(y.shape[-1], name="out_proj")(gated)
+
+
+class DifferentialAttention(nn.Module):
+    """Causal differential attention (arXiv 2410.05258; the core and its
+    layout are `nn/attention/diff.py`'s): `num_heads` query heads over
+    `num_kv_heads` key/value heads of d / num_heads channels, heads 2j and
+    2j + 1 a pair; a pair's two softmaxes over one value twice a head wide,
+    o_j = (P1 - lambda P2) v, lambda = exp(lq1 . lk1) - exp(lq2 . lk2) +
+    lambda_init with four learned vectors a layer and lambda_init = 0.8 -
+    0.6 exp(-0.3 `depth`); o_j through an RMSNorm a pair (ONE weight of
+    twice a head's channels a layer) and (1 - lambda_init); the output
+    projection. Biases on the projections; no positional encoding.
+    `window`: a query reads the `window` keys that end with its own (None:
+    every key at or before it). `cross`: the layer owns a query and an
+    output projection, its lambda vectors and its norm's weight, and NO key
+    or value projection: it reads the `keys` another layer made. -> (the
+    output, the keys and values as this layer read them: `diff.key_pairs`'
+    layout, which a layer may keep for such layers). The softmaxes, lambda
+    and the norm's statistics are float32."""
+
+    num_heads: int
+    num_kv_heads: int
+    depth: int = 0
+    window: int | None = None
+    cross: bool = False
+    eps: float = 1e-5
+    impl: str = "flash"
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y, keys=None):
+        f32, dt, d = jnp.float32, self.dtype, y.shape[-1]
+        if d % self.num_heads or self.num_heads % self.num_kv_heads or (
+                self.num_kv_heads % 2):
+            raise ValueError(
+                f"{self.num_heads} query heads over {self.num_kv_heads} "
+                f"key/value heads are no pairs that divide a width of {d}")
+        width = d // self.num_heads
+        dense = functools.partial(nn.Dense, dtype=dt)
+        with jax.named_scope("diff.project"):
+            q = dense(d, name="q_proj")(y)
+            if not self.cross:
+                wide = self.num_kv_heads * width
+                keys = attention.key_pairs(dense(wide, name="k_proj")(y),
+                                           dense(wide, name="v_proj")(y),
+                                           self.num_kv_heads)
+        lq1, lk1, lq2, lk2 = (
+            self.param(name, nn.initializers.normal(0.1), (width,), f32)
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"))
+        scale = self.param("norm_scale", nn.initializers.ones, (2 * width,),
+                           f32)
+        first = attention.diff.lambda_init(self.depth)
+        lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + first
+        with jax.named_scope("diff.attend"):
+            o = attention.differential_attention(
+                q, keys, lam, attention.tier(self.impl), self.window,
+                self.name or "diff_attn")
+        with jax.named_scope("diff.norm"):
+            o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + self.eps)
+            o = (o * (scale * (1.0 - first))).astype(dt)
+        with jax.named_scope("diff.project"):
+            return dense(d, name="out")(o.reshape(*o.shape[:2], d)), keys
+
+
 class GatedFFN(nn.Module):
     """down(silu(gate y) * up y), no biases. `multipliers` (gate, down):
     fixed scalars on the gate's pre-activation and on the output, m_down *
@@ -770,9 +936,16 @@ class _ScoringDecoder(nn.Module):
     more, as published; no step's compute is skipped either way), and
     `loop_exit_at`, int32 (T,), counts the batch's tokens by that step.
 
+    Two more. `norm_kind` "layer" makes every norm of the skeleton, the
+    final one included, a `LayerNorm` with a weight and a bias.
+    `layers_share`: a layer may KEEP arrays for later layers and a later
+    layer's operator may READ them: `_stack` carries a dict of what was
+    kept, an operator is called `operator(a, kept)` and returns (its
+    output, {name: array} to keep), and nothing is copied a layer.
+
     Precision: products take `dtype` inputs and accumulate in float32; the
     router's scores, the top-k, every softmax, sigmoid and log-sum-exp,
-    the exit distribution and every RMSNorm's statistics are float32."""
+    the exit distribution and every norm's statistics are float32."""
 
     # what a family may state as an attribute of its own
     route_epsilon = 1e-20           # added to the sum of a token's weights
@@ -792,6 +965,12 @@ class _ScoringDecoder(nn.Module):
     sandwich_norms = False          # an RMSNorm after each operator as well
     exit_gate = False               # an exit distribution over the steps
     early_exit_threshold = 1.0      # running sum of it a token leaves at
+    # every norm's kind: "rms" (`RMSNorm`, eps `rms_norm_eps`) or "layer"
+    # (`LayerNorm`, a weight and a bias, eps `layer_norm_eps`)
+    norm_kind = "rms"
+    # layers keep arrays for later layers: an operator is then called with
+    # what was kept so far and returns (its output, what it keeps)
+    layers_share = False
 
     @property
     def batch_counters(self) -> tuple:
@@ -906,6 +1085,7 @@ class _ScoringDecoder(nn.Module):
         """One pass through the layers -> (h, [an expert layer's picks])."""
         dt = self.dtype
         picks = []
+        shared = {}         # what layers kept for later ones, by name
         early = self.router_input == "operator"
         for i in range(self.num_layers):
             before, operator = self._operator(i)
@@ -915,7 +1095,11 @@ class _ScoringDecoder(nn.Module):
                 routed = Router(
                     self.n_routed_experts, self.num_experts_per_tok,
                     self.router_scoring, name=f"router_{i}")(a)
-            out = operator(a)
+            if self.layers_share:
+                out, keeps = operator(a, shared)
+                shared = {**shared, **keeps}
+            else:
+                out = operator(a)
             if self.sandwich_norms:
                 out = norm(name=f"{before[:before.rindex('_')]}_post_{i}")(
                     out)
@@ -975,7 +1159,9 @@ class _ScoringDecoder(nn.Module):
                 f"sequence length {ids.shape[1]} exceeds max_len="
                 f"{self.max_len}; raise max_len in the model config")
         dt, d = self.dtype, self.d_model
-        norm = functools.partial(RMSNorm, self.rms_norm_eps, dt)
+        norm = (functools.partial(LayerNorm, self.layer_norm_eps, dt)
+                if self.norm_kind == "layer"
+                else functools.partial(RMSNorm, self.rms_norm_eps, dt))
         embed = nn.Embed(self.vocab_size, d, dtype=dt,
                          embedding_init=nn.initializers.normal(1.0),
                          name="embed")
@@ -1184,6 +1370,39 @@ class EvaDecoder(_ScoringDecoder):
             name=f"eva_attn_{i}")
 
 
+def _band_tile_pairs(impl: str, dtype, window: int, length: int, calls: int):
+    """-> (computed, needed): the (query block, key block) tiles the banded
+    forward computes for `calls` forwards (rows x heads x banded layers) of
+    `length` positions behind a band of `window` keys, in tiles and
+    FRACTIONS of one (floats: an edge tile folded in parts counts the part
+    of it that is computed, `attention.band_tile_pairs`), and the pairs the
+    band itself holds in tiles of that size; from shapes alone. None where
+    no banded kernel runs: off the "flash" tier, or a row no longer than the
+    window."""
+    if attention.tier(impl) != "flash" or length <= window:
+        return None
+    computed, needed = attention.band_tile_pairs(
+        length, window, *attention.band_tiles(length, window, dtype))
+    return computed * calls, needed * calls
+
+
+def _band_tile_arguments(module, scored: list, row_shape: tuple) -> dict:
+    """What a family with banded layers adds to a call's root span: what the
+    banded kernel computed over the call and what the band needed
+    (`module.window_tile_pairs`, summed over the batches). Only a tracer
+    that keeps spans has a reader for it, only rows of token ids have a
+    length, and only where that kernel ran."""
+    if not get_tracer().enabled or len(row_shape) != 1:
+        return {}
+    pairs = [p for p in (module.window_tile_pairs(rows, row_shape[0])
+                         for rows in scored) if p]
+    if not pairs:
+        return {}
+    return dict(
+        attn_window_tile_pairs=float(sum(p[0] for p in pairs)),
+        attn_window_tile_pairs_needed=float(sum(p[1] for p in pairs)))
+
+
 class WindowMoEDecoder(_ScoringDecoder):
     """Causal decoder over token ids whose attention layers are told apart
     by a list (the SmallThinker block, arXiv 2507.20984): `layer_types[i]`
@@ -1252,39 +1471,18 @@ class WindowMoEDecoder(_ScoringDecoder):
             name=f"swa_attn_{i}" if sliding else f"gqa_attn_{i}")
 
     def window_tile_pairs(self, rows: int, length: int):
-        """-> (computed, needed): the (query block, key block) tiles the
-        banded forward computes for a batch of `rows` rows of `length`
-        positions, over every head and sliding layer, in tiles and
-        FRACTIONS of one (floats: an edge tile folded in parts counts the
-        part of it that is computed, `attention.band_tile_pairs`), and
-        the pairs the band itself holds in tiles of that size; from
-        shapes alone. None where no banded kernel runs: off the "flash"
-        tier, or a row no longer than the window."""
-        if attention.tier(self.attention_impl) != "flash" or (
-                length <= self.window_size):
-            return None
-        computed, needed = attention.band_tile_pairs(
-            length, self.window_size,
-            *attention.band_tiles(length, self.window_size, self.dtype))
-        calls = rows * self.num_heads * self.layer_types.count("sliding")
-        return computed * calls, needed * calls
+        """`_band_tile_pairs` for a batch of `rows` rows of `length`
+        positions, over every head and sliding layer."""
+        return _band_tile_pairs(
+            self.attention_impl, self.dtype, self.window_size, length,
+            rows * self.num_heads * self.layer_types.count("sliding"))
 
     def call_span_arguments(self, counted: dict, scored: list,
                             row_shape: tuple) -> dict:
-        """The family's own beside the skeleton's: what the banded kernel
-        computed over the call and what the band needed
-        (`window_tile_pairs`, summed over the batches). Only a tracer that
-        keeps spans has a reader for it, only rows of token ids have a
-        length, and only where that kernel ran."""
+        """The family's own beside the skeleton's: the banded kernel's
+        tiles (`_band_tile_arguments`)."""
         arguments = super().call_span_arguments(counted, scored, row_shape)
-        if get_tracer().enabled and len(row_shape) == 1:
-            pairs = [p for p in (self.window_tile_pairs(rows, row_shape[0])
-                                 for rows in scored) if p]
-            if pairs:
-                arguments.update(
-                    attn_window_tile_pairs=float(sum(p[0] for p in pairs)),
-                    attn_window_tile_pairs_needed=float(
-                        sum(p[1] for p in pairs)))
+        arguments.update(_band_tile_arguments(self, scored, row_shape))
         return arguments
 
 
@@ -1438,6 +1636,149 @@ class SSMHybridDecoder(_ScoringDecoder):
         return arguments
 
 
+def hybrid_layer_kinds(layers: int) -> tuple:
+    """Which operator each layer of a decoder-hybrid-decoder has, a function
+    of the depth alone (a multiple of 4): the first half alternates "mamba"
+    and "sliding" (differential attention behind a window); layer N / 2 is
+    "mamba_keeps" (its scan output is kept), layer N / 2 + 1 "full_keeps"
+    (full causal differential attention whose keys and values are kept);
+    after them "gmu" (reads the scan output) and "cross" (reads the keys and
+    values) alternate."""
+    if layers < 4 or layers % 4:
+        raise ValueError(
+            f"a decoder-hybrid-decoder has a multiple of 4 layers, not "
+            f"{layers}: its halves alternate two kinds of layer each")
+    half = layers // 2
+    first = ("mamba", "sliding") * (half // 2)
+    return first + ("mamba_keeps", "full_keeps") + ("gmu", "cross") * (
+        half // 2 - 1)
+
+
+class DecoderHybridDecoder(_ScoringDecoder):
+    """Causal decoder over token ids whose SECOND half reads what two layers
+    of it made (the SambaY shape of arXiv 2507.06607, as Phi-4-mini-flash
+    runs it; `hybrid_layer_kinds` is the published rule): the first half
+    alternates a Mamba-1 mixer (`SelectiveMixer`: `mamba_inner` channels,
+    `mamba_state` states, a step of rank `mamba_dt_rank`) and differential
+    attention behind a window of `window_size` keys (`DifferentialAttention`,
+    `num_heads` over `num_kv_heads` heads in pairs); layer N / 2 is a Mamba
+    mixer whose scan output M is KEPT, layer N / 2 + 1 full causal
+    differential attention whose keys and values are KEPT; every later
+    even layer is a `GatedMemoryUnit` over M, every later odd one
+    differential attention whose queries are its own and whose keys and
+    values are layer N / 2 + 1's (no key or value projection in its tree,
+    nothing copied a layer). Every layer then a gated feed-forward. LayerNorm
+    with a bias as every norm, biases on the attention's projections, no
+    positional encoding, a tied head, no multiplier. Scoring runs every
+    layer on every position. The scan's tier is `nn/scan.py`'s rule to pick.
+    The block loop, the kept arrays' seat, the head and the outputs are
+    `_ScoringDecoder`'s."""
+
+    num_layers: int = 8
+    d_model: int = 64
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    mamba_inner: int = 128
+    mamba_state: int = 16
+    mamba_dt_rank: int = 4
+    conv_taps: int = 4
+    window_size: int = 512
+    d_ff_dense: int = 128
+    layer_norm_eps: float = 1e-5
+    vocab_size: int = 256
+    tie_embeddings: bool = True
+    max_len: int = 32768
+    # "flash": the Pallas kernels (chunked off-TPU), "chunked", "dense"
+    attention_impl: str = "flash"
+    head_chunk: int = 1024          # tokens of one block of the head
+    output: str = "token_logprobs"  # or "logits"
+    dtype: Any = jnp.float32
+
+    # what the family states and no configuration changes
+    norm_kind = "layer"
+    layers_share = True
+
+    @property
+    def _dense_layers(self) -> int:
+        return self.num_layers
+
+    def _operator(self, i: int):
+        kind, dt = hybrid_layer_kinds(self.num_layers)[i], self.dtype
+        if kind in ("mamba", "mamba_keeps"):
+            mixer = SelectiveMixer(
+                self.mamba_inner, self.mamba_dt_rank, self.mamba_state,
+                self.conv_taps, dt, f"sel_scan_{i}", name=f"mamba_{i}")
+
+            def operator(a, kept):
+                out, scanned = mixer(a)
+                return out, ({"memory": scanned} if kind == "mamba_keeps"
+                             else {})
+        elif kind == "gmu":
+            unit = GatedMemoryUnit(dt, name=f"gmu_{i}")
+
+            def operator(a, kept):
+                return unit(a, kept["memory"]), {}
+        else:
+            # a device trace tells the layers by these names: the plain
+            # causal calls `diff_attn_<i>`; a sliding layer's `diff_swa_<i>`
+            # where the row fits its window and the banded forward's own
+            # name, `diff_swa_w<window>`, past it
+            sliding = kind == "sliding"
+            attend = DifferentialAttention(
+                self.num_heads, self.num_kv_heads, i,
+                self.window_size if sliding else None, kind == "cross",
+                self.layer_norm_eps, self.attention_impl, dt,
+                name=f"diff_swa_{i}" if sliding else f"diff_attn_{i}")
+
+            def operator(a, kept):
+                out, keys = attend(a, kept["keys"] if kind == "cross"
+                                   else None)
+                return out, ({"keys": keys} if kind == "full_keeps" else {})
+        return f"ln_op_{i}", operator
+
+    def window_tile_pairs(self, rows: int, length: int):
+        """`_band_tile_pairs` for a batch of `rows` rows of `length`
+        positions over the sliding layers: two softmaxes a pair of heads,
+        so `num_heads` forwards a row a layer."""
+        return _band_tile_pairs(
+            self.attention_impl, self.dtype, self.window_size, length,
+            rows * self.num_heads * hybrid_layer_kinds(
+                self.num_layers).count("sliding"))
+
+    def call_span_arguments(self, counted: dict, scored: list,
+                            row_shape: tuple) -> dict:
+        """The family's own beside the skeleton's, each reckoned from the
+        batches' shapes (nothing is read back) and counted by the registry
+        too: `sel_scan_steps`, the grid steps of the call's scans (rows x
+        channel blocks x chunks, padding rows' included, over the Mamba
+        layers and the batches); `shared_reads`, the (layer, batch) pairs
+        that read an array another layer kept; and, where a tracer keeps
+        spans and the banded kernel ran, its tiles as `window_moe_decoder`
+        writes them. Only rows of token ids have a length."""
+        arguments = super().call_span_arguments(counted, scored, row_shape)
+        if len(row_shape) != 1:
+            return arguments
+        kinds = hybrid_layer_kinds(self.num_layers)
+        mixers = sum(kind.startswith("mamba") for kind in kinds)
+        steps = mixers * sum(
+            scan.sel_scan_steps(rows, row_shape[0], self.mamba_inner)
+            for rows in scored)
+        reads = (kinds.count("gmu") + kinds.count("cross")) * len(scored)
+        registry = get_registry()
+        registry.counter(
+            "mmlspark_tpu_sel_scan_steps_total",
+            "grid steps of a channel-decay selective scan: rows x channel "
+            "blocks x chunks, over the layers and the batches",
+        ).inc(float(steps))
+        registry.counter(
+            "mmlspark_tpu_shared_reads_total",
+            "(layer, batch) pairs that read an array an earlier layer kept",
+        ).inc(float(reads))
+        arguments.update(sel_scan_steps=steps, shared_reads=reads)
+        arguments.update(_band_tile_arguments(self, scored, row_shape))
+        return arguments
+
+
 def resnet20_cifar(num_outputs: int = 10, dtype=jnp.float32) -> ResNet:
     return ResNet(stage_sizes=(3, 3, 3), num_filters=16,
                   num_outputs=num_outputs, dtype=dtype)
@@ -1459,9 +1800,10 @@ def _hashable(config: dict) -> dict:
 # references architectures by name (the reference's ModelSchema carries a
 # remote URI instead, downloader/Schema.scala:30+). Families: `mlp`,
 # `simple_cnn` and the `resnet*` over images or features; over token ids the
-# `transformer` encoder and six causal decoders on one skeleton,
+# `transformer` encoder and seven causal decoders on one skeleton,
 # `mla_moe_decoder`, `hybrid_moe_decoder`, `eva_decoder`,
-# `window_moe_decoder`, `looped_decoder` and `ssm_hybrid_decoder`.
+# `window_moe_decoder`, `looped_decoder`, `ssm_hybrid_decoder` and
+# `decoder_hybrid_decoder`.
 ARCHITECTURES: dict[str, Callable[..., nn.Module]] = {
     "mlp": lambda **kw: MLP(**kw),
     "simple_cnn": lambda **kw: SimpleCNN(**kw),
@@ -1475,6 +1817,7 @@ ARCHITECTURES: dict[str, Callable[..., nn.Module]] = {
     "window_moe_decoder": lambda **kw: WindowMoEDecoder(**_hashable(kw)),
     "looped_decoder": lambda **kw: LoopedDecoder(**kw),
     "ssm_hybrid_decoder": lambda **kw: SSMHybridDecoder(**_hashable(kw)),
+    "decoder_hybrid_decoder": lambda **kw: DecoderHybridDecoder(**kw),
 }
 
 

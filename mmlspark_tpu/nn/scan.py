@@ -30,6 +30,31 @@ rule (`tier`), as `attention/layout.py` has it for attention:
 
 Precision: the four products of a chunk take the inputs' type and add up in
 float32; decays, their running sums, every exp and the state are float32.
+
+A SECOND form, Mamba-1's (arXiv 2312.00752): the decay is a (channel, state)
+pair, the step a channel's own, B and C shared by ALL channels:
+
+    S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] B_t[n] x_t[c]
+    y_t[c]    = sum_n C_t[n] S_t[c, n] + D[c] x_t[c]
+
+x (B, T, C); dt (B, T, C) float32, after its softplus; A (C, N) negative; B,
+C (B, T, N); D (C,). exp(a_t - a_s) does not factor out of C B^T here, so
+there is no (Q, Q) product form: the work is elementwise, the vector unit's.
+The same two tiers behind the same rule (`channel_scan`):
+
+- "plain" (`sel_plain`): a `lax.scan` over chunks of Q tokens that carries S
+  (B, C, N), the chunk's tokens one after the other inside it; every
+  backend, differentiable (a chunk is recomputed in the backward, so what
+  is saved is S a chunk), never a (T, C, N) array.
+- "kernel" (`sel_kernel`): ONE Pallas call over a grid of (row, channel
+  block, chunk), the chunk axis sequential, S of the block's channels in
+  VMEM scratch from chunk to chunk, channels on lanes and the N states on
+  sublanes; x, dt, B, C read and y written once. Channels in whole lane
+  blocks and states in whole sublanes only. Its backward is the plain
+  tier's.
+
+Everything of the second form is float32 but x as it is read and y as it is
+written.
 """
 
 from __future__ import annotations
@@ -289,3 +314,194 @@ def selective_scan(xbc, dt, a, d, *, heads: int, width: int, groups: int,
                           groups=groups, state=state, name=name)
     return _plain_flat(xbc, dt, a, d, heads=heads, width=width,
                        groups=groups, state=state)
+
+
+# ---------------------------------------------------------------------------
+# The second form: a decay a (channel, state) pair (Mamba-1)
+
+SEL_BLOCK = 512     # channels of a grid step of the kernel tier, at most
+SEL_UNROLL = 8      # tokens written out a pass of the kernel's loop
+
+
+def sel_block(channels: int) -> int:
+    """Channels a grid step of the kernel tier takes: the most whole lane
+    blocks, at most `SEL_BLOCK`, that divide `channels` (512 of 5120)."""
+    return max(n for n in range(128, min(SEL_BLOCK, channels) + 1, 128)
+               if channels % n == 0)
+
+
+def sel_tier(channels: int, state: int) -> str:
+    """`tier`'s answer for the second form: the kernel where the channels
+    are whole lane blocks and the states whole sublanes."""
+    return "kernel" if tier(channels) == "kernel" and state % 8 == 0 \
+        else "plain"
+
+
+def sel_plain(x, dt, a, bm, cm, d, chunk: int = CHUNK):
+    """The plain tier of the second form -> y (B, T, C) in x's type."""
+    f32 = jnp.float32
+    b, t, c = x.shape
+    n = a.shape[1]
+    a32 = a.astype(f32)
+    padded = [_pad_chunks(v, chunk) for v in (x, dt.astype(f32), bm, cm)]
+    nc = padded[0].shape[1] // chunk
+    # (chunks, tokens of a chunk, B, .)
+    chunks = tuple(jnp.moveaxis(
+        v.reshape(b, nc, chunk, v.shape[-1]), 0, 2) for v in padded)
+
+    def token(state, now):
+        x_t, dt_t, b_t, c_t = now                  # (B,C) (B,C) (B,N) (B,N)
+        fed = (dt_t * x_t.astype(f32))[..., None] * b_t.astype(f32)[:, None]
+        state = jnp.exp(dt_t[..., None] * a32) * state + fed
+        return state, (state * c_t.astype(f32)[:, None]).sum(-1)
+
+    # a chunk is recomputed in the backward: what is saved is S a chunk
+    @jax.checkpoint
+    def one(state, xs):
+        return jax.lax.scan(token, state, xs)
+
+    _, y = jax.lax.scan(one, jnp.zeros((b, c, n), f32), chunks)
+    y = jnp.moveaxis(y.reshape(nc * chunk, b, c), 0, 1)[:, :t]
+    return (y + d.astype(f32) * x.astype(f32)).astype(x.dtype)
+
+
+def _sel_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, state_ref,
+                x32_ref, y32_ref, *, chunk, unroll):
+    """One chunk of one block of channels of one row: the tokens one after
+    the other, S (N, channels) float32 carried in the loop and, from chunk
+    to chunk, in scratch. x and y go through float32 copies of the block, so
+    that a token's row is read and written as 32-bit sublanes; B and C come
+    as (N, Q) tiles, states on sublanes, and token t's column is a masked
+    sum across lanes (`_column`)."""
+    import jax.experimental.pallas as pl
+
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    a, skip = a_ref[...], d_ref[...]                 # (N, C), (1, C)
+    bt, ct = b_ref[0, 0], c_ref[0, 0]                # (N, Q)
+    x32_ref[...] = x_ref[0].astype(f32)
+
+    def token(t, state):
+        """S after token `t` of the chunk, whose y is written."""
+        row = pl.ds(t, 1)
+        dt, x = dt_ref[0, row, :], x32_ref[row, :]   # (1, C)
+        keep = jnp.exp(jnp.broadcast_to(dt, a.shape) * a)
+        fed = jnp.broadcast_to(dt * x, a.shape) * jnp.broadcast_to(
+            _column(bt, t), a.shape)
+        state = keep * state + fed
+        y = jnp.sum(state * jnp.broadcast_to(_column(ct, t), a.shape),
+                    axis=0, keepdims=True)
+        y32_ref[row, :] = y + skip * x
+        return state
+
+    def some(g, state):
+        # `unroll` tokens written out a pass of the loop (the lowering
+        # unrolls a loop whole or not at all)
+        for j in range(unroll):
+            state = token(g * unroll + j, state)
+        return state
+
+    state_ref[...] = jax.lax.fori_loop(0, chunk // unroll, some,
+                                       state_ref[...])
+    y_ref[0] = y32_ref[...].astype(y_ref.dtype)
+
+
+# Jitted by itself, as `_ssd_flat` is: under its own name in a device trace
+# (`sel_scan_<i>`; the readers select the prefix)
+@functools.partial(jax.jit, static_argnames=("name", "interpret"))
+def _sel_flat(x, dt, a, bm, cm, d, name, interpret=False):
+    import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    f32 = jnp.float32
+    b, t, c = x.shape
+    n = a.shape[1]
+    block = sel_block(c)
+    x = _pad_chunks(x, CHUNK)
+    dt = _pad_chunks(dt.astype(f32), CHUNK)    # 0 past the row: S stays
+    tp = x.shape[1]
+    nc = tp // CHUNK
+
+    def tiles(m):
+        """(B, T, N) -> a chunk's (N, Q) tile: (B, chunks, N, Q)."""
+        return jnp.swapaxes(_pad_chunks(m.astype(f32), CHUNK).reshape(
+            b, nc, CHUNK, n), 2, 3)
+
+    def tokens(r, j, k):
+        return r, k, j
+
+    def channels(r, j, k):
+        return 0, j
+
+    def states(r, j, k):
+        return r, k, 0, 0
+
+    y = pl.pallas_call(
+        functools.partial(_sel_kernel, chunk=CHUNK, unroll=SEL_UNROLL),
+        grid=(b, c // block, nc),
+        in_specs=[
+            pl.BlockSpec((1, CHUNK, block), tokens),
+            pl.BlockSpec((1, CHUNK, block), tokens),
+            pl.BlockSpec((n, block), channels),
+            pl.BlockSpec((1, 1, n, CHUNK), states),
+            pl.BlockSpec((1, 1, n, CHUNK), states),
+            pl.BlockSpec((1, block), channels),
+        ],
+        out_specs=pl.BlockSpec((1, CHUNK, block), tokens),
+        out_shape=jax.ShapeDtypeStruct((b, tp, c), x.dtype),
+        scratch_shapes=[pltpu.VMEM((n, block), f32),
+                        pltpu.VMEM((CHUNK, block), f32),
+                        pltpu.VMEM((CHUNK, block), f32)],
+        interpret=interpret, name=name,
+    )(x, dt, a.astype(f32).T, tiles(bm), tiles(cm), d.astype(f32)[None])
+    return y[:, :t]
+
+
+# forward only, as `_ssd_diff`: differentiated, the plain tier's backward
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _sel_diff(x, dt, a, bm, cm, d, name, interpret):
+    return _sel_flat(x, dt, a, bm, cm, d, name, interpret)
+
+
+def _sel_diff_fwd(x, dt, a, bm, cm, d, name, interpret):
+    return (_sel_flat(x, dt, a, bm, cm, d, name, interpret),
+            (x, dt, a, bm, cm, d))
+
+
+def _sel_diff_bwd(_name, _interpret, given, dy):
+    _y, back = jax.vjp(sel_plain, *given)
+    return back(dy)
+
+
+_sel_diff.defvjp(_sel_diff_fwd, _sel_diff_bwd)
+
+
+def sel_kernel(x, dt, a, bm, cm, d, *, name: str = "sel_scan",
+               interpret: bool = False):
+    """The kernel tier of the second form (`_sel_flat`, differentiable
+    through the plain tier); `interpret=True` runs it on the CPU for
+    tests."""
+    if x.shape[-1] % 128 or a.shape[1] % 8:
+        raise ValueError(
+            f"the scan kernel takes channels in whole lane blocks and states "
+            f"in whole sublanes, not {x.shape[-1]} and {a.shape[1]}")
+    return _sel_diff(x, dt, a, bm, cm, d, name, interpret)
+
+
+def sel_scan_steps(rows: int, length: int, channels: int) -> int:
+    """Grid steps the kernel tier of the second form takes for `rows` rows
+    of `length` tokens: rows x channel blocks x chunks."""
+    return rows * (channels // sel_block(channels)) * -(-length // CHUNK)
+
+
+def channel_scan(x, dt, a, bm, cm, d, *, name: str = "sel_scan"):
+    """The one entry a Mamba-1 mixer calls, on the tier `sel_tier` picks:
+    x (B, T, C), dt (B, T, C) float32, a (C, N), bm, cm (B, T, N), d (C,)
+    -> y (B, T, C) in x's type."""
+    if sel_tier(x.shape[-1], a.shape[1]) == "kernel":
+        return sel_kernel(x, dt, a, bm, cm, d, name=name)
+    return sel_plain(x, dt, a, bm, cm, d)

@@ -1050,3 +1050,113 @@ def test_the_state_space_hybrid_lowers_both_kernels_a_layer_by_name(
              and re.search(r"\[1,2048,(5120|4096|9248)\]",
                            line.split("=")[1].split("(")[0])]
     assert not moved, moved
+
+
+# --------------------------------------------------------------------- #
+# the decoder-hybrid-decoder (`phi4_mini_flash.score_long_traces`)      #
+# --------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("length", [32768, 4096])
+def test_channel_decay_scan_kernel_compiles_at_the_cells_shapes(one_chip,
+                                                                length):
+    """`sel_scan_<i>` at the published widths, a batch of one row: 5120
+    channels in ten blocks of 512 lanes, 16 states on sublanes; the block's
+    state, (16, 512) float32, is scratch, and nothing of (tokens, channels,
+    states) is in the compiled program."""
+    from mmlspark_tpu.nn import scan
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def scanned(x, dt, a, bm, cm, d):
+        return scan.sel_kernel(x, dt, a, bm, cm, d, name="sel_scan_8")
+
+    shapes = (spec((1, length, 5120), jnp.bfloat16),
+              spec((1, length, 5120), jnp.float32),
+              spec((5120, 16), jnp.float32),
+              spec((1, length, 16), jnp.bfloat16),
+              spec((1, length, 16), jnp.bfloat16), spec((5120,), jnp.float32))
+    kernel = str(jax.make_jaxpr(scanned)(*shapes))
+    assert "Ref<vmem>{f32[16,512]}" in kernel
+    compiled = _compile(scanned, *shapes)
+    text = compiled.as_text()
+    assert re.search(r"%sel_scan_8[.\d]* = ", text)
+    assert f"bf16[1,{length},5120]" in text
+    assert not re.search(rf"\[(1,)?{length},5120,16\]|\[(1,)?{length},16,5120\]",
+                         text)
+    # B and C as (16, Q) tiles and A transposed: a few numbers a token, and
+    # no copy of x, dt or y
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 * length
+
+
+@pytest.mark.parametrize("length", [32768, 4096])
+@pytest.mark.parametrize("window,name", [(None, "diff_attn_9"),
+                                         (512, "diff_swa_w512")])
+def test_differential_forwards_compile_at_the_cells_shapes(one_chip, length,
+                                                           window, name):
+    """Both softmaxes of a differential layer at the published widths (40
+    query over 20 key/value heads of 64: 20 pairs over 10, scores 64 wide,
+    values 128), plain causal and behind the band of 512, under the names
+    the readers select."""
+    from mmlspark_tpu.nn.attention import diff
+
+    def spec(width):
+        return jax.ShapeDtypeStruct((1, length, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def attend(q, k, v):
+        return diff.differential_attention(
+            q, diff.key_pairs(k, v, 20), 0.5, "flash", window, "diff_attn_9")
+
+    text = _compile(attend, spec(2560), spec(1280), spec(1280)).as_text()
+    assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 2
+    assert f"f32[1,{length},20,128]" in text
+
+
+def test_the_decoder_hybrid_decoder_lowers_its_kernels_by_name(one_chip,
+                                                               monkeypatch):
+    """Eight layers of the cell's model at its published widths (every kind
+    of layer), a row of 4096 tokens, embedding to log-probabilities: every
+    Mamba layer's scan and every differential layer's forwards are in the
+    compiled program under the names the readers select; layer 5's keys and
+    values are projected ONCE and the cross layer lays out none (the three
+    self layers' pair-major copies are the only ones); nothing of (tokens,
+    channels, states) exists."""
+    from mmlspark_tpu.nn import attention, models
+
+    monkeypatch.setattr(attention.layout.jax, "default_backend",
+                        lambda: "tpu")
+    module = models.make_model(
+        "decoder_hybrid_decoder", num_layers=8, d_model=2560, num_heads=40,
+        num_kv_heads=20, mamba_inner=5120, mamba_state=16, mamba_dt_rank=160,
+        window_size=512, d_ff_dense=10240, vocab_size=200064,
+        dtype=jnp.bfloat16)
+    variables = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: module.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))
+    ids = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+
+    def forward(v, x):
+        _out, state = module.apply(v, x, capture_intermediates=True,
+                                   mutable=["intermediates"])
+        return state["intermediates"]["token_logprobs"][0]
+
+    traced = str(jax.make_jaxpr(forward)(variables, ids))
+    # keys and values: three self layers project them, the cross layer not
+    assert traced.count("bf16[1,4096,1280] = dot_general") == 2 * 3
+    text = _compile(forward, variables, ids).as_text()
+    for layer in (0, 2, 4):
+        assert re.search(rf"%sel_scan_{layer}[.\d]* = ", text)
+    for layer in (5, 7):
+        assert len(re.findall(rf"%diff_attn_{layer}[.\d]* = ", text)) == 2
+    assert len(re.findall(r"%diff_swa_w512[.\d]* = ", text)) == 2 * 2
+    assert "f32[1,4095]" in text
+    entry = text[text.index("ENTRY"):].splitlines()
+    laid_out = [line for line in entry
+                if re.search(r" (copy|transpose)\(", line)
+                and re.search(r"bf16\[1,4096,10,(2,64|128)\]",
+                              line.split("=")[1].split("(")[0])]
+    assert len(laid_out) == 2 * 3, laid_out
+    assert not re.search(r"\[(1,)?4096,5120,16\]|\[(1,)?4096,16,5120\]", text)

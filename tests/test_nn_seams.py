@@ -9,6 +9,7 @@ import re
 
 import jax
 import numpy as np
+import pytest
 
 from mmlspark_tpu.core.schema import Table
 from mmlspark_tpu.nn import attention, models
@@ -84,3 +85,43 @@ def test_what_a_module_reports_lands_on_the_calls_root_span(monkeypatch):
     assert root.args["marker"] == [{}, [4, 2], (5,)]
     plain = _root_after_a_call(monkeypatch, MLP)
     assert set(plain.args) == {"rows", "batch_size"}
+
+
+# What the six families that keep nothing were BEFORE the skeleton gained its
+# seat for kept arrays and its second kind of norm (read on the parent tree
+# of PR 49 and on the change, equal to the last digit): the leaves and the
+# parameters of the default tree, the equations of the traced forward and the
+# sum of the log-probabilities of 2 seeded rows of 24 tokens.
+BEFORE_THE_SEAT = {
+    "mla_moe_decoder": (28, 147336, 485, -275.55962586402893),
+    "hybrid_moe_decoder": (23, 119848, 364, -1830.429235458374),
+    "eva_decoder": (25, 266816, 348, -284.443870306015),
+    "window_moe_decoder": (23, 156992, 452, -271.880491733551),
+    "looped_decoder": (27, 115329, 397, -272.39950346946716),
+    "ssm_hybrid_decoder": (37, 150360, 681, -285.7063875198364),
+}
+
+
+@pytest.mark.parametrize("family", sorted(BEFORE_THE_SEAT))
+def test_a_family_that_keeps_nothing_is_what_it_was(family):
+    """The seat for arrays a layer keeps and the norm's kind are a family's
+    own to state: the six that state neither trace to the equations they
+    traced to, over the tree they had, and score what they scored; and the
+    runner learned no word of the family that does."""
+    leaves, params, equations, total = BEFORE_THE_SEAT[family]
+    module = models.make_model(family)
+    assert (module.layers_share, module.norm_kind) == (False, "rms")
+    ids = jax.numpy.asarray(np.random.default_rng(0).integers(
+        0, 200, (2, 24)), jax.numpy.int32)
+    variables = module.init(jax.random.PRNGKey(0), ids)
+    tree = jax.tree.leaves(variables["params"])
+    assert (len(tree), sum(a.size for a in tree)) == (leaves, params)
+    traced = str(jax.make_jaxpr(lambda v, x: module.apply(
+        v, x, capture_intermediates=True, mutable=["intermediates"]))(
+            variables, ids))
+    assert traced.count(" = ") == equations
+    assert float(np.asarray(module.apply(variables, ids), np.float64).sum()
+                 ) == pytest.approx(total, rel=1e-5)
+    runner = (NN / "runner.py").read_text().lower()
+    for word in ("sel_scan", "shared_reads", "kept", "hybrid", "gmu"):
+        assert word not in runner, word
