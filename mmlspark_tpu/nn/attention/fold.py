@@ -16,12 +16,12 @@ from __future__ import annotations
 import collections
 import functools
 import math
-import operator
 
 import jax
 import jax.numpy as jnp
 
 from ...observability.metrics import get_registry
+from ..lanes import LANES, STEP_VMEM, lane_sums, over
 from .layout import _NEG_INF
 
 
@@ -110,19 +110,6 @@ def flash_tiles(tq: int, tk: int, dtype,
     return tile(tq), tile(tk)
 
 
-# What a step of several heads may keep in VMEM by `_step_bytes`' count, and
-# what such a call STATES as its scoped VMEM: twice the 16 MB a custom call
-# gets without asking. A key head's whole group needs 16.5 MB (4 heads of 64)
-# to 27.5 MB (7 of 128) by the compiler's count, 2.6 MB more with a padded
-# tail, and 5 and 7 have no divisor between. What a call states beyond the
-# default is taken from the whole program (its neighbours keep fewer operands
-# in VMEM): read on a v5e in the three grouped cells, that costs 0.04 to
-# 0.2% of a call where the kernels return 0.7 to 3.0% (PERF.md, PR 48; a
-# call that took 64 MB had cost its neighbours 8%: PR 28). A constant, not a
-# parameter; a call of one head a step states nothing.
-_STEP_VMEM = 32 * 1024 * 1024
-
-
 def _step_bytes(heads: int, block_q: int, block_k: int, d: int, dv: int,
                 itemsize: int) -> int:
     """VMEM a grid step of `_flash_call` holds at `heads` query heads a
@@ -141,11 +128,11 @@ def _step_bytes(heads: int, block_q: int, block_k: int, d: int, dv: int,
     this count less a tile (compiled for a described v5e, PR 48): this
     count is 1.5 to 4.5 MB over it, never under."""
     def lanes(width):
-        return -(-width // _LANES) * _LANES
+        return -(-width // LANES) * LANES
 
     a_head = (2 * block_q * (lanes(d) + lanes(dv)) * itemsize
-              + 2 * block_q * _LANES * 4
-              + block_q * (2 * _LANES + lanes(dv)) * 4)
+              + 2 * block_q * LANES * 4
+              + block_q * (2 * LANES + lanes(dv)) * 4)
     shared = (2 * block_k * (lanes(d) + lanes(dv)) * itemsize
               + 3 * block_q // _row_parts(block_q) * block_k * 4)
     return heads * a_head + shared
@@ -156,7 +143,7 @@ def heads_a_step(group: int, key_blocks: int, block_q: int, block_k: int,
     """How many query heads of a key/value head ONE grid step of the flash
     forward folds, from what the call's shapes show: the largest divisor of
     the `group` (query heads a key/value head) whose blocks and statistics
-    fit `_STEP_VMEM` (`_step_bytes`): the whole group in the cells that have
+    fit `STEP_VMEM` (`_step_bytes`): the whole group in the cells that have
     one (4 heads of 64, 5 and 7 of 128). The heads of a step share the fetch
     of the key and value blocks, which a kernel that reads them in place, in
     strided pieces, does not hide under its folds (6% of it), and the step's
@@ -167,7 +154,7 @@ def heads_a_step(group: int, key_blocks: int, block_q: int, block_k: int,
         return 1
     return max(g for g in range(1, group + 1) if group % g == 0 and (
         g == 1 or _step_bytes(g, block_q, block_k, d, dv,
-                              itemsize) <= _STEP_VMEM))
+                              itemsize) <= STEP_VMEM))
 
 
 def _band_first(qi: int, block_q: int, block_k: int, window: int) -> int:
@@ -240,8 +227,6 @@ def _block(ref, at=None):
     return ref[0] if at is None else ref[0, pl.ds(*at), :]
 
 
-# the lanes of a vector register: the running statistics are kept that wide
-_LANES = 128
 _LOG2_E = math.log2(math.e)
 
 
@@ -250,32 +235,7 @@ def _stat_lanes(*widths: int) -> int:
     vector register's, where the score tiles' columns are whole blocks of
     that many (every tile `flash_tiles` chooses past 128 keys); the widest
     block that divides them at a test's small tile."""
-    return math.gcd(_LANES, *widths)
-
-
-def _over(x, width: int):
-    """A per-row statistic held replicated across its lanes, (rows, lanes),
-    laid over `width` columns: whole registers repeated, never a (rows, 1)
-    column permuted back over the lanes. A column broadcasts by itself."""
-    lanes = x.shape[1]
-    if lanes == 1 or width == lanes:
-        return x
-    if width < lanes:
-        return x[:, :width]
-    if width % lanes:
-        return x[:, :1]
-    return jnp.tile(x, (1, width // lanes))
-
-
-def _lane_sums(p, lanes: int):
-    """p's columns added up in blocks of `lanes`: (rows, lanes) partial
-    sums a row, elementwise (no reduction across lanes), one block after
-    the other (added by halves, six equations where eight, the compiler
-    schedules a step 2% worse: PERF.md, PR 44); one lane is the row sum
-    itself."""
-    if lanes == 1:
-        return p.sum(-1, keepdims=True)
-    return functools.reduce(operator.add, jnp.split(p, p.shape[1] // lanes, 1))
+    return math.gcd(LANES, *widths)
 
 
 def _weigh(s, ok, m, v_ref, exponent, lanes, keys=None):
@@ -285,7 +245,7 @@ def _weigh(s, ok, m, v_ref, exponent, lanes, keys=None):
     Python (scale > 0, so the maximum commutes with it). -> its row sums as
     `lanes` per-lane partial sums (rows, lanes), and its product with the
     `keys` of the value block (rows, Dv)."""
-    p = jnp.exp2((s - _over(m, s.shape[1])) * exponent)
+    p = jnp.exp2((s - over(m, s.shape[1])) * exponent)
     if ok is not None:
         # masked entries must contribute 0 even when the whole row is
         # masked (then m == _NEG_INF and exp(s - m) == 1, not 0)
@@ -293,7 +253,7 @@ def _weigh(s, ok, m, v_ref, exponent, lanes, keys=None):
     pv = jax.lax.dot_general(
         p.astype(v_ref.dtype), _block(v_ref, keys),
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    return _lane_sums(p, lanes), pv
+    return lane_sums(p, lanes), pv
 
 
 def _fold_tile(s, ok, v_ref, scratch, exponent, rows=None, keys=None):
@@ -322,7 +282,7 @@ def _fold_tile(s, ok, v_ref, scratch, exponent, rows=None, keys=None):
     l, pv = _weigh(s, ok, m_new, v_ref, exponent, m_prev.shape[1], keys)
     corr = jnp.exp2((m_prev - m_new) * exponent)          # (rows, lanes)
     l_sc[mine] = l_sc[mine] * corr + l
-    acc_sc[mine] = acc_sc[mine] * _over(corr, pv.shape[1]) + pv
+    acc_sc[mine] = acc_sc[mine] * over(corr, pv.shape[1]) + pv
     m_sc[mine] = m_new
 
 
@@ -724,7 +684,7 @@ def _flash_call(kernel, queries, keys, value, out_at, out_shape, *, b, h,
             jax.lax.fori_loop(0, heads, one_head, 0)
 
     stated = {} if heads == 1 else dict(
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_STEP_VMEM))
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=STEP_VMEM))
     # a step's block of `heads` heads: rows of axis 0, or lane blocks
     lead, wide = (1, heads) if in_place else (heads, 1)
 

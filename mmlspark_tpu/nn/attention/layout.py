@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-_NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/max NaN-free
+from ..lanes import NEG_INF as _NEG_INF  # noqa: F401  (the cores import it from here)
 
 
 def _known(impl: str) -> str:

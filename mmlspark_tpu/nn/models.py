@@ -25,7 +25,7 @@ import numpy as np
 
 from ..observability.metrics import get_registry
 from ..observability.tracing import get_tracer
-from . import attention, scan
+from . import attention, loglik, scan
 
 __all__ = [
     "MLP",
@@ -893,10 +893,10 @@ class _ScoringDecoder(nn.Module):
     mixer with the name of the norm before it.
 
     Scoring output: the module returns, and sows as `token_logprobs`, each
-    next token's log-probability, (rows, length - 1): the head runs over
-    `head_chunk` tokens at a time with that chunk's log-sum-exp, and only
-    the target's log-probability is kept, so the (rows x length x
-    vocabulary) logits are never whole in memory. `output="logits"`
+    next token's log-probability, (rows, length - 1): the head (`loglik`)
+    folds tiles of `head_chunk` tokens against the vocabulary and keeps the
+    target's log-probability alone, in ONE kernel or a chunk at a time, so
+    the (rows x length x vocabulary) logits never exist. `output="logits"`
     returns them instead (short rows, tests). A family whose head makes
     several predictions a position states `num_pred_heads`: the head's
     kernel is (d, num_pred_heads x vocab_size), prediction p in columns
@@ -1056,30 +1056,19 @@ class _ScoringDecoder(nn.Module):
         return _times(jnp.dot(h, head, preferred_element_type=jnp.float32),
                       self.lm_head_multiplier)
 
-    def _token_logprobs(self, h, ids, head):
+    def _token_logprobs(self, h, ids, head, embedding=None):
         """log_softmax(h @ head)[next token] for every position but a
-        row's last, `head_chunk` tokens at a time."""
+        row's last: `loglik.token_logprobs`, ONE Pallas call over all the
+        batch's tokens where its rule takes the shapes, else `head_chunk`
+        tokens at a time. `embedding`: (V, d), where the head is tied to
+        it."""
         b, t, d = h.shape
-        n = b * t
-        flat = h.reshape(n, d)
         # the last position of a row scores a target that is cut off below
-        target = jnp.concatenate([ids[:, 1:], ids[:, :1]], 1).reshape(n)
-        chunk = min(self.head_chunk, n)
-        pad = (-n) % chunk
-        if pad:
-            flat = jnp.pad(flat, ((0, pad), (0, 0)))
-            target = jnp.pad(target, (0, pad))
-
-        def one(xs):
-            hc, tc = xs
-            logits = self._logits(hc, head)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            picked = jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
-            return picked - lse
-
-        out = jax.lax.map(one, (flat.reshape(-1, chunk, d),
-                                target.reshape(-1, chunk)))
-        return out.reshape(-1)[:n].reshape(b, t)[:, :t - 1]
+        target = jnp.concatenate([ids[:, 1:], ids[:, :1]], 1).reshape(b * t)
+        out = loglik.token_logprobs(
+            h.reshape(b * t, d), target, head, embedding=embedding,
+            multiplier=self.lm_head_multiplier, chunk=self.head_chunk)
+        return out.reshape(b, t)[:, :t - 1]
 
     def _stack(self, h, norm):
         """One pass through the layers -> (h, [an expert layer's picks])."""
@@ -1202,8 +1191,12 @@ class _ScoringDecoder(nn.Module):
         self.sow("intermediates", "hidden", h)
         if picks is not None:
             self.sow("intermediates", "moe_picks", picks)
+        embedding = None
         if self.tie_embeddings:
-            head = embed.embedding.T.astype(dt)
+            # the kernel reads it where the parameters keep it, (V, d);
+            # only XLA's path and the logits read the transpose
+            embedding = embed.embedding.astype(dt)
+            head = embedding.T
         else:
             head = self.param(
                 "head_kernel", nn.initializers.normal(d ** -0.5),
@@ -1213,7 +1206,7 @@ class _ScoringDecoder(nn.Module):
             # the next token is prediction 0's columns
             logprobs = self._token_logprobs(
                 h, ids, head[:, :self.vocab_size]
-                if self.num_pred_heads > 1 else head)
+                if self.num_pred_heads > 1 else head, embedding)
             self.sow("intermediates", "token_logprobs", logprobs)
             if self.output == "logits":
                 logits = self._logits(h, head)

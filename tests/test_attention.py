@@ -487,7 +487,7 @@ class TestGroupedQueryHeads:
         assert count(1, 1024, 1024, 64, 64, 2) == 10.5 * mb
         assert count(4, 1024, 1024, 64, 64, 2) == 21 * mb
         assert count(7, 1024, 1024, 128, 128, 2) == 31.5 * mb
-        assert count(8, 1024, 1024, 128, 128, 2) > attention.fold._STEP_VMEM
+        assert count(8, 1024, 1024, 128, 128, 2) > attention.fold.STEP_VMEM
         # small tiles (a test's): every divisor fits
         assert attention.fold.heads_a_step(4, 3, 16, 16, 64, 64, 4) == 4
 

@@ -241,7 +241,7 @@ def test_a_groups_query_heads_lower_in_one_grid_step_inside_the_scoped_vmem(
     a key head's whole group of 4: the grid is (row, 8, the 136 steps that
     fold), a step's q, output and lse blocks are 4 rows of axis 0, its key
     and value blocks ONE head's, and the chip's compiler takes the kernel
-    inside the scoped VMEM the call states (`fold._STEP_VMEM`, 32 MB: a
+    inside the scoped VMEM the call states (`lanes.STEP_VMEM`, 32 MB: a
     whole group passes the 16 MB a call gets without asking by 0.45 MB).
     The counter says what the rule chose."""
     from mmlspark_tpu.nn import attention
@@ -269,7 +269,7 @@ def test_a_groups_query_heads_lower_in_one_grid_step_inside_the_scoped_vmem(
         (4, 1024, 64), (1, 1024, 64), (1, 1024, 64), (4, 1024, 64),
         (4, 1024, 1)]
     stated = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
-    assert stated == attention.fold._STEP_VMEM == 32 << 20
+    assert stated == attention.fold.STEP_VMEM == 32 << 20
     assert attention.fold._step_bytes(4, 1024, 1024, 64, 64, 2) <= stated
     assert "tpu_custom_call" in _compile(attend, q, k, k).as_text()
 
@@ -1160,3 +1160,92 @@ def test_the_decoder_hybrid_decoder_lowers_its_kernels_by_name(one_chip,
                               line.split("=")[1].split("(")[0])]
     assert len(laid_out) == 2 * 3, laid_out
     assert not re.search(r"\[(1,)?4096,5120,16\]|\[(1,)?4096,16,5120\]", text)
+
+
+# the five decoder cells whose head the rule takes: the long batch's tokens,
+# d, the vocabulary held, tied, `lm_head_multiplier`
+HEADS = {
+    "phi4_mini_flash": (32768, 2560, 200064, True, 1.0),
+    "lfm2_8b_a1b": (32768, 2048, 65536, True, 1.0),
+    "moonlight_16b_a3b": (32768, 2048, 40960, False, 1.0),
+    "smallthinker_21b_a3b": (32768, 2560, 37984, False, 1.0),
+    "falcon_h1_34b": (32768, 5120, 32640, False, 0.0078125),
+}
+
+
+@pytest.mark.parametrize("cell", list(HEADS))
+def test_loglik_head_compiles_at_the_cells_head_shapes(one_chip, cell):
+    """`loglik_head` at the tile `head_tiles` gives each cell's head: the
+    chip's compiler takes the kernel inside the VMEM the rule counted (a
+    call whose count passes the 16 MB a custom call gets states
+    `lanes.STEP_VMEM`, another states nothing), the grid walks the vocabulary
+    last, and a tied head's operand is the embedding as it lies, (V, d)."""
+    from mmlspark_tpu.nn import loglik
+
+    tokens, d, vocab, tied, multiplier = HEADS[cell]
+    bf = jnp.bfloat16
+    tm, tn = tiles = loglik.head_tiles(tokens, d, vocab, 2)
+    counted = loglik._head_bytes(tm, tn, d, 2)
+    assert counted <= loglik.STEP_VMEM
+    flat = jax.ShapeDtypeStruct((tokens, d), bf, sharding=one_chip)
+    target = jax.ShapeDtypeStruct((tokens,), jnp.int32, sharding=one_chip)
+    head = jax.ShapeDtypeStruct((vocab, d) if tied else (d, vocab), bf,
+                                sharding=one_chip)
+
+    def score(flat, target, head):
+        return loglik.loglik_head(flat, target, head, tied=tied, tiles=tiles,
+                                  multiplier=multiplier)
+
+    (call,) = [e for e in _deep_equations(jax.make_jaxpr(score)(
+        flat, target, head)) if e.primitive.name == "pallas_call"]
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == (tokens // tm, -(-vocab // tn))
+    assert [tuple(x.block_size for x in m.block_shape)
+            for m in mapping.block_mappings] == [
+        (tm, 128), (tm, d), (tn, d) if tied else (d, tn), (tm, 1)]
+    stated = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    assert stated == (loglik.STEP_VMEM if counted > 16 << 20 else None)
+    text = _compile(score, flat, target, head).as_text()
+    assert "tpu_custom_call" in text
+    assert f"[{tm},{vocab}]" not in text
+    if tied:
+        assert f"[{d},{vocab}]" not in text
+
+
+@pytest.mark.parametrize("cell,rows,length", [
+    ("lfm2_8b_a1b", 2, 16384), ("phi4_mini_flash", 1, 32768),
+    ("lfm2_8b_a1b", 2, 1024), ("phi4_mini_flash", 1, 4096)])
+def test_a_tied_decoder_holds_no_logits_and_no_copy_of_its_embedding(
+        one_chip, monkeypatch, cell, rows, length):
+    """A decoder with a tied head at a tied cell's width and vocabulary,
+    one layer, ids to log-probabilities, compiled for the chip at the
+    cell's batches: the head is the ONE call `loglik_head`, and the
+    optimised program holds no float32 array of (tokens of a tile, V), in
+    any order, and no array of (d, V): the embedding is read where it
+    lies."""
+    from mmlspark_tpu.nn import models
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _tokens, d, vocab, _tied, _multiplier = HEADS[cell]
+    module = models.make_model(
+        "hybrid_moe_decoder", d_model=d, num_heads=d // 64, num_kv_heads=8,
+        layer_types=("conv",), num_dense_layers=1, d_ff_dense=1024,
+        vocab_size=vocab, max_len=length, dtype=jnp.bfloat16)
+    assert module.tie_embeddings
+    variables = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: module.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))
+    ids = jax.ShapeDtypeStruct((rows, length), jnp.int32, sharding=one_chip)
+    text = _compile(lambda v, x: module.apply(v, x), variables, ids).as_text()
+    assert len(re.findall(r"%loglik_head[.\d]* = ", text)) == 1
+    # the call sits inside the decoder's `jax.named_scope("loglik.head")`
+    assert re.search(r'%loglik_head[.\d]* = .*op_name="[^"]*/loglik\.head/',
+                     text)
+    assert f"bf16[{vocab},{d}]" in text
+    shapes = set(re.findall(r"\w+\[[\d,]+\]", text))
+    assert not [s for s in shapes if re.fullmatch(
+        rf"f32\[(\d+,)?(\d+,{vocab}|{vocab},\d+)\]", s)], shapes
+    assert not [s for s in shapes if re.fullmatch(
+        rf"\w+\[{d},{vocab}\]", s)], shapes
