@@ -35,6 +35,8 @@ orchestrator processes that must never initialize jax).
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import math
 import queue
 import threading
@@ -50,6 +52,9 @@ __all__ = ["Prefetcher", "AsyncReadback", "ShapeBucketer", "ExecutableCache",
 # --------------------------------------------------------------------- #
 # Prefetcher                                                            #
 # --------------------------------------------------------------------- #
+
+_NO_SPAN = contextlib.nullcontext()
+
 
 class _End:
     """Queue sentinel (private class, never a legal prepared item)."""
@@ -78,10 +83,19 @@ class Prefetcher:
     `overlap_fraction()` estimates how much of the host-side prepare cost
     was hidden behind the consumer's own work: 1.0 means the consumer
     never waited, 0.0 means fully serial (always 0.0 at depth 0).
+
+    Spans, where the caller hands over its own (`span=`, on `tracer=`), an
+    item: `<name>.feed_wait` around the consumer's wait for it (the queue's
+    `get` alone: what `stats["wait_seconds"]` times) and `<name>.prepare`
+    around `prepare(item)` on the thread that prepares, both children of
+    `span` (at depth 0 the second nests in the first), argument `item` (its
+    index). The last wait finds the end's mark. Given no span, a prefetcher
+    records nothing, whatever is active around it.
     """
 
     def __init__(self, items: Iterable[Any], prepare: Callable[[Any], Any],
-                 depth: int = 2, name: str = "prefetch"):
+                 depth: int = 2, name: str = "prefetch",
+                 span: Any = None, tracer: Any = None):
         self._items = items
         self._prepare = prepare
         self.depth = max(int(depth), 0)
@@ -90,6 +104,8 @@ class Prefetcher:
         self._queue: "queue.Queue | None" = None
         self._thread: "threading.Thread | None" = None
         self._stop = threading.Event()
+        self._tracer = tracer
+        self._parent = span            # the caller's; None: no spans
 
     def overlap_fraction(self) -> float:
         prep = self.stats["prepare_seconds"]
@@ -119,12 +135,21 @@ class Prefetcher:
         except Exception:
             return None, None
 
+    def _span(self, phase: str, index: int, parent: Any):
+        if self._parent is None:
+            return _NO_SPAN
+        return self._tracer.start_span(f"{self.name}.{phase}", parent=parent,
+                                       item=index)
+
     # -- synchronous path (depth 0) ------------------------------------- #
 
     def _iter_sync(self) -> Iterator[Any]:
-        for item in self._items:
+        for index, item in enumerate(self._items):
             t0 = time.perf_counter()
-            out = self._prepare(item)
+            # serial: the wait IS the preparing, whose span nests in it
+            with self._span("feed_wait", index, self._parent), \
+                    self._span("prepare", index, None):
+                out = self._prepare(item)
             dt = time.perf_counter() - t0
             # serial: every prepare second is also a consumer-wait second
             self.stats["prepare_seconds"] += dt
@@ -137,12 +162,15 @@ class Prefetcher:
     def _worker(self) -> None:
         q = self._queue
         try:
-            for item in self._items:
+            for index, item in enumerate(self._items):
                 if self._stop.is_set():
                     return
                 t0 = time.perf_counter()
                 try:
-                    out = self._prepare(item)
+                    # this thread has no active span: the caller's is
+                    # the parent, so the span is never parentless
+                    with self._span("prepare", index, self._parent):
+                        out = self._prepare(item)
                 except BaseException as e:  # noqa: BLE001 — re-raised at consumer
                     q.put(_Raised(e))
                     return
@@ -164,10 +192,11 @@ class Prefetcher:
         self._thread.start()
         g_depth, g_overlap = self._gauges()
         try:
-            while True:
-                t0 = time.perf_counter()
-                got = self._queue.get()
-                self.stats["wait_seconds"] += time.perf_counter() - t0
+            for index in itertools.count():
+                with self._span("feed_wait", index, self._parent):
+                    t0 = time.perf_counter()
+                    got = self._queue.get()
+                    self.stats["wait_seconds"] += time.perf_counter() - t0
                 if got is _End:
                     return
                 if isinstance(got, _Raised):

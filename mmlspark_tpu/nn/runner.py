@@ -15,7 +15,7 @@ replicated.
 from __future__ import annotations
 
 import os
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +43,20 @@ def _fetch_from_intermediates(state: dict, path: str):
     if isinstance(node, (tuple, list)):
         node = node[0]
     return node
+
+
+class _Call(NamedTuple):
+    """What a `_transform` settles before it touches a batch."""
+
+    x: np.ndarray            # the column as one host array
+    bs: int                  # rows a batch, rounded up to the mesh
+    d: int                   # data-axis shards
+    fused: bool              # one dispatch for the whole table
+    family: tuple            # the apply cache's key
+    apply_fn: Any
+    variables: Any
+    fetch: dict
+    counters: tuple[str, ...]
 
 
 @register_stage
@@ -94,7 +108,9 @@ class DeepModelTransformer(Model):
     _outbytes_cache: dict | None = None
     _exec_cache: ExecutableCache | None = None
     #: stats from the most recent pipelined (non-fused) _transform:
-    #: prepare/wait seconds, overlap_fraction, executable-cache counters
+    #: prepare/wait seconds, overlap_fraction, executable-cache counters.
+    #: A call's totals only: the per-batch view is the `runner.*` spans'
+    #: (observability/tracing.py), so this dict need not grow
     last_pipeline_stats: dict | None = None
 
     def set_model(self, bundle: ModelBundle) -> "DeepModelTransformer":
@@ -179,6 +195,28 @@ class DeepModelTransformer(Model):
     def _transform(self, table: Table) -> Table:
         if self.bundle is None:
             raise ValueError("DeepModelTransformer has no model; call set_model()")
+        tracer = get_tracer()
+        if self.get("fused_dispatch"):
+            # the one-dispatch path opens no `runner.*` span; a table its
+            # budget sends to the streamed loop gets its root span here,
+            # after the stacking
+            call = self._settle(table)
+            if call.fused:
+                return self._score(table, call, None)
+            with tracer.start_span("runner.transform") as root:
+                return self._score(table, call, root)
+        # streamed by the stage's own setting: the call's root span opens
+        # at the entry, over the column's stacking too
+        with tracer.start_span("runner.transform") as root:
+            with tracer.start_span("runner.stack") as span:
+                call = self._settle(table)
+                span.set(bytes=int(call.x.nbytes))
+            return self._score(table, call, root)
+
+    def _settle(self, table: Table) -> _Call:
+        """The column made one host array, the batch size, whether the
+        fused path's budget takes the table, and the jitted forward from
+        the apply cache."""
         col = table[self.get("input_col")]
         x = np.stack(col) if isinstance(col, list) else np.asarray(col)
         n = x.shape[0]
@@ -234,35 +272,49 @@ class DeepModelTransformer(Model):
                     else self._make_apply(fetches, counters))
             self._apply_cache[key] = (made, variables)
         apply_fn, variables = self._apply_cache[key]
+        return _Call(x, bs, d, fused, key, apply_fn, variables, fetch,
+                     counters)
 
-        if fused:
+    def _score(self, table: Table, call: _Call, root) -> Table:
+        """`root`: a streamed call's `runner.transform` span; the fused
+        one-dispatch path has none."""
+        if call.fused:
+            x, bs, n = call.x, call.bs, call.x.shape[0]
+            pad = (-n) % bs
             if pad:
                 x = np.concatenate([x, np.repeat(x[-1:], pad, axis=0)])
             nb = len(x) // bs
-            outs = apply_fn(variables, jnp.asarray(x.reshape(nb, bs, *x.shape[1:])))
+            outs = call.apply_fn(
+                call.variables, jnp.asarray(x.reshape(nb, bs, *x.shape[1:])))
             cols = [np.asarray(o).reshape(nb * bs, *o.shape[2:])[:n] for o in outs]
         else:
-            cols = self._transform_pipelined(x, bs, d, key, apply_fn, variables,
-                                             fetches, counters)
+            cols = self._transform_pipelined(call, root)
 
         out = table
-        for (col_name, fetch_name), arr in zip(fetch.items(), cols):
+        for (col_name, fetch_name), arr in zip(call.fetch.items(), cols):
             kind = "probability" if fetch_name == "probability" else "raw_prediction"
             out = out.with_column(col_name, arr, meta={SCORE_KIND: kind})
         return out
 
-    def _transform_pipelined(self, x: np.ndarray, bs: int, d: int, family,
-                             apply_fn, variables, fetches: tuple[str, ...],
-                             counters: tuple[str, ...]) -> list[np.ndarray]:
+    def _transform_pipelined(self, call: _Call, root) -> list[np.ndarray]:
         """Non-fused loop on the async data plane: prepare (slice + pad +
         upload) of minibatch N+1 overlaps device compute on N, and host
         readback lags one batch so it overlaps too. Shapes, batch order,
-        and per-row outputs are identical at every prefetch depth."""
+        and per-row outputs are identical at every prefetch depth.
+
+        Every phase of a batch is a span under the call's root (the table
+        in observability/tracing.py's docstring): the prefetcher's
+        `runner.feed_wait` and `runner.prepare` with `runner.upload` inside
+        it, and under `runner.step`, `runner.dispatch`, then `runner.wait`
+        and `runner.readback` of the batch before."""
+        x, bs, family, variables = call.x, call.bs, call.family, call.variables
+        fetches, counters = tuple(call.fetch.values()), call.counters
         n = x.shape[0]
-        bucketer = (ShapeBucketer(bs, shards=d)
+        bucketer = (ShapeBucketer(bs, shards=call.d)
                     if self.get("shape_buckets") else None)
         if self._exec_cache is None:
             self._exec_cache = ExecutableCache()
+        tracer = get_tracer()
 
         def prepare(i: int):
             chunk = x[i:i + bs]
@@ -274,19 +326,36 @@ class DeepModelTransformer(Model):
                     [chunk, np.repeat(chunk[-1:], bs - m, axis=0)])
             else:
                 padded = chunk
-            return jnp.asarray(padded), m
+            # the prefetcher's `runner.prepare` is the active span here, on
+            # whichever thread prepares
+            around = tracer.current_span()
+            if around is not None:
+                around.set(rows=m, padded=int(padded.shape[0]),
+                           bytes=int(padded.nbytes))
+            with tracer.start_span("runner.upload", bytes=int(padded.nbytes)):
+                return jnp.asarray(padded), m
 
         prefetch = Prefetcher(range(0, n, bs), prepare,
                               depth=int(self.get("prefetch_depth")),
-                              name="runner")
-        # fetch = block on the device result and slice the padding off
-        # (a batch's counters, which follow the fetched outputs, have no
-        # rows to slice); lag 1 keeps batch N-1's readback behind batch
-        # N's dispatch
+                              name="runner", span=root, tracer=tracer)
+        # fetch = block on the device result, copy it and slice the padding
+        # off (a batch's counters, which follow the fetched outputs, have
+        # no rows to slice); lag 1 keeps batch N-1's readback behind batch
+        # N's dispatch. The wait stands before the copies, which blocked
+        # there anyway: no value and no order changes
         nf = len(fetches)
-        readback = AsyncReadback(
-            lambda om: tuple(np.asarray(a)[:om[1]] for a in om[0][:nf])
-            + tuple(np.asarray(a) for a in om[0][nf:]), lag=1)
+
+        def fetch(parked):
+            out, m, batch = parked
+            with tracer.start_span("runner.wait", batch=batch):
+                jax.block_until_ready(out)
+            with tracer.start_span("runner.readback", batch=batch) as span:
+                if tracer.enabled:      # the sum is the span's alone
+                    span.set(bytes=sum(int(a.nbytes) for a in out))
+                return (tuple(np.asarray(a)[:m] for a in out[:nf])
+                        + tuple(np.asarray(a) for a in out[nf:]))
+
+        readback = AsyncReadback(fetch, lag=1)
         chunks: list[tuple[np.ndarray, ...]] = []
         scored: list[int] = []          # rows a batch scored, padding too
         paid_before: list[float] = []
@@ -297,36 +366,36 @@ class DeepModelTransformer(Model):
             # bridge sees of that on this thread is the entry's compile
             # seconds
             paid_before.append(jax_compile_seconds())
-            return apply_fn
+            return call.apply_fn
 
-        tracer = get_tracer()
-        with tracer.start_span("runner.transform", rows=n,
-                               batch_size=bs) as root:
-            for xb, m in prefetch:
-                shape_key = (int(xb.shape[0]), tuple(xb.shape[1:]),
-                             str(xb.dtype))
-                scored.append(int(xb.shape[0]))
-                with tracer.start_span("runner.step", padded=int(xb.shape[0]),
-                                       rows=m):
-                    # jit compiles once per entry here; the counters make
-                    # ragged shapes defeating the ladder visible
-                    # (recompiles > 0)
+        root.set(rows=n, batch_size=bs, row_shape=list(x.shape[1:]))
+        for xb, m in prefetch:
+            shape_key = (int(xb.shape[0]), tuple(xb.shape[1:]),
+                         str(xb.dtype))
+            scored.append(int(xb.shape[0]))
+            with tracer.start_span("runner.step", padded=int(xb.shape[0]),
+                                   rows=m):
+                # jit compiles once per entry here (its `jax.*` spans
+                # hang under the dispatch); the counters make ragged
+                # shapes defeating the ladder visible (recompiles > 0)
+                with tracer.start_span("runner.dispatch") as dispatch:
                     fn = self._exec_cache.get_or_build(family, shape_key,
                                                        build)
                     out = fn(variables, xb)
-                    if paid_before:
-                        self._exec_cache.add_compile_seconds(
-                            family, shape_key,
-                            jax_compile_seconds() - paid_before.pop())
-                    chunks.extend(readback.push((out, m)))
-            chunks.extend(readback.drain())
-            # a batch's counters follow its fetched outputs in the module's
-            # order; what they say of the call is the module's to tell
-            counted = {name: np.stack([c[nf + i] for c in chunks])
-                       for i, name in enumerate(counters)} if chunks else {}
-            report = getattr(self.bundle.module, "call_span_arguments", None)
-            if report is not None:
-                root.set(**report(counted, scored, x.shape[1:]))
+                    dispatch.set(cache="miss" if paid_before else "hit")
+                if paid_before:
+                    self._exec_cache.add_compile_seconds(
+                        family, shape_key,
+                        jax_compile_seconds() - paid_before.pop())
+                chunks.extend(readback.push((out, m, len(scored) - 1)))
+        chunks.extend(readback.drain())
+        # a batch's counters follow its fetched outputs in the module's
+        # order; what they say of the call is the module's to tell
+        counted = {name: np.stack([c[nf + i] for c in chunks])
+                   for i, name in enumerate(counters)} if chunks else {}
+        report = getattr(self.bundle.module, "call_span_arguments", None)
+        if report is not None:
+            root.set(**report(counted, scored, x.shape[1:]))
         self.last_pipeline_stats = {
             **prefetch.stats,
             "overlap_fraction": prefetch.overlap_fraction(),

@@ -308,6 +308,7 @@ class DNNLearner(HasFeaturesCol, HasLabelCol, Estimator):
                             resume_k, None),
                         prep,
                         depth=int(self.get("prefetch_depth")), name="trainer",
+                        span=ep_span, tracer=tracer,
                     ):
                         params, batch_stats, opt_state, loss = step(
                             params, batch_stats, opt_state, bx, by, step_rng

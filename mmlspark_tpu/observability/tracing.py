@@ -41,6 +41,40 @@ The order interleaves: `sar.slice` and `sar.dispatch` of block b+1 come
 before `sar.wait` and `sar.readback` of block b, so at most two blocks are
 in flight and the last is drained after the loop.
 
+Spans inside a streamed `DeepModelTransformer.transform` (process-default
+tracer; `nn/runner.py`, `core/dataplane.py` `Prefetcher`): `runner.transform`,
+one a call, opened at the entry where the stage streams by its own setting
+(`fused_dispatch` false) and parent of the rest (arguments `rows`,
+`batch_size`, `row_shape` and what the model family reports of the call); a
+table that the fused path's budget sends to the loop gets its root there,
+after the stacking, and the fused one-dispatch path opens no `runner.*` span.
+Under the root: `runner.stack` (`bytes`: the column made one host array and
+the jitted forward looked up), and a batch `runner.feed_wait` (`item`: the
+loop blocked on the prefetcher's queue, the `get` alone; the first of a call
+is the start gap, the thread's own start lying before it in the root's self
+time, the others should be microseconds, and at depth 2 one more finds the
+end mark),
+`runner.prepare` (`item`, `rows`, `padded`, `bytes`: slice, pad and upload
+of batch N+1 under the device's work on N, on the PREFETCHER'S thread with
+the root handed over as parent (`Prefetcher(..., span=root, tracer=...)`),
+so it is never parentless; at depth 0 it
+nests in the `runner.feed_wait` on the calling thread) with `runner.upload`
+(`bytes`: `jnp.asarray`, the host-to-device copy as the host sees it)
+inside it, and `runner.step` (`rows`, `padded`), which holds
+`runner.dispatch` (`cache`: `hit` / `miss`; the executable cache's lookup
+and the jitted call until it returns its futures; on a miss the `jax.*`
+spans hang under it) and then, of the batch BEFORE, `runner.wait` (`batch`:
+`block_until_ready` on its parked outputs, where the host should be in a
+loop the device bounds) and `runner.readback` (`batch`, `bytes`: the copies
+to the host and the slice of the padding). The last batch's wait and
+readback hang under the root. On the calling thread `runner.stack`,
+`runner.feed_wait`, `runner.dispatch`, `runner.wait` and `runner.readback`
+never overlap, so with the root's self time they add up to the call. A
+`Prefetcher` names its two spans after itself (`trainer.feed_wait` and
+`trainer.prepare` under `trainer.epoch` in a streamed `DNNLearner.fit`,
+which hands it the epoch's span) and records nothing when it is handed no
+span, whatever is active around it.
+
 Where a start, or a recompile, goes (end of this file): the package's
 imports (`package.import`) and JAX's own trace, lowering and compile events
 (`jax.trace`, `jax.lower`, `jax.compile`) are recorded as spans whose end

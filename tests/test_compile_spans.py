@@ -300,27 +300,37 @@ def test_transform_of_a_new_shape_leaves_the_three_under_its_step(tracer):
     first_call = len(tracer.spans())
     steps = named(tracer, "runner.step")
     assert len(steps) == 2
+    # a step's enqueue is its `runner.dispatch`, and what JAX does for a
+    # new shape hangs under that
+    dispatches = named(tracer, "runner.dispatch")
+    assert [d.parent for d in dispatches] == steps
+    assert [d.args["cache"] for d in dispatches] == ["miss", "hit"]
     by_step = [collections.Counter(
-        s.name for s in tracer.spans() if s.parent is step) for step in steps]
+        s.name for s in tracer.spans() if s.parent is d) for d in dispatches]
     # the first step of the shape paid; the second, the same shape, nothing
     assert by_step[0]["jax.lower"] == by_step[0]["jax.compile"] == 1
     assert by_step[0]["jax.trace"] >= 1
     assert by_step[1] == {}
     paid = stage.last_pipeline_stats["compile_seconds"]
     assert paid > 0
-    under = [s for s in tracer.spans() if s.parent is steps[0]]
+    under = [s for s in tracer.spans() if s.parent is dispatches[0]]
     assert paid == pytest.approx(union_seconds(under), abs=5e-3)
     assert steps[0].dur_us * 1e-6 >= paid - 5e-3
     # a second transform: no `jax.*` span, and the ledger does not grow
     stage.transform(table)
     again = tracer.spans()[first_call:]
-    assert {s.name for s in again} == {"runner.transform", "runner.step"}
+    assert {s.name for s in again} == {
+        "runner.transform", "runner.stack", "runner.feed_wait",
+        "runner.prepare", "runner.upload", "runner.step", "runner.dispatch",
+        "runner.wait", "runner.readback"}
     assert stage.last_pipeline_stats["compile_seconds"] == paid
     assert stage._exec_cache.compile_seconds == paid
     # another length is another entry: its first step pays, and is named
     stage.transform(Table({"tokens": _ids(4, 12)}))
     (step,) = [s for s in named(tracer, "runner.step")[4:]]
-    assert {s.name for s in tracer.spans() if s.parent is step} == set(
+    (dispatch,) = [s for s in named(tracer, "runner.dispatch")[4:]]
+    assert dispatch.parent is step and dispatch.args["cache"] == "miss"
+    assert {s.name for s in tracer.spans() if s.parent is dispatch} == set(
         JAX_NAMES)
     assert stage.last_pipeline_stats["compile_seconds"] > paid
     ledger = stage._exec_cache.compile_ledger()

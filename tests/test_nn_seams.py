@@ -84,7 +84,7 @@ def test_what_a_module_reports_lands_on_the_calls_root_span(monkeypatch):
     root = _root_after_a_call(monkeypatch, _Reporting)
     assert root.args["marker"] == [{}, [4, 2], (5,)]
     plain = _root_after_a_call(monkeypatch, MLP)
-    assert set(plain.args) == {"rows", "batch_size"}
+    assert set(plain.args) == {"rows", "batch_size", "row_shape"}
 
 
 # What the six families that keep nothing were BEFORE the skeleton gained its
